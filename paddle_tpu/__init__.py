@@ -17,32 +17,11 @@ import jax as _jax
 # dtype unless the user explicitly asks for float64).
 _jax.config.update("jax_enable_x64", True)
 
-# Newer jax exposes shard_map at the top level with `axis_names` /
-# `check_vma`; this jax (0.4.37) only has jax.experimental.shard_map with
-# the older `auto` / `check_rep` spelling. Without the adapter every
-# shard_map call site (pipeline, TP serving decode, ring attention) died
-# with AttributeError on this jax — same failure class as the kernels'
-# enable_x64 shim (paddle_tpu.kernels.x64_off).
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
+# persistent compile cache: JAX_COMPILATION_CACHE_DIR when set, else one
+# fixed directory in the checkout — before anything compiles
+from .framework import compile_cache as _compile_cache
 
-    def _shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None,
-                          check_vma=None, **kw):
-        if axis_names is not None:
-            # new API names the MANUAL axes; old API names the AUTO ones
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            if auto:
-                kw["auto"] = auto
-        # this jax's check_rep=True has no replication rule for
-        # pallas_call (flash/paged kernels run inside these regions) —
-        # default it off, honoring an explicit check_vma when given.
-        # (no bool() here: this module exports paddle.bool, which shadows
-        # the builtin in module globals by the time this runs)
-        kw["check_rep"] = True if check_vma else False
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-    _jax.shard_map = _shard_map_compat
+_compile_cache.configure()
 
 # --- framework core ---
 from .framework import config as _config

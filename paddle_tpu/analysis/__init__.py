@@ -2,7 +2,7 @@
 
 Every rule encodes a bug this repo actually shipped (CHANGES.md):
 
-  jax-compat               jax APIs absent on the pinned jax 0.4.37
+  jax-compat               jax APIs the installed jax 0.9.0 removed
                            (the PR 2 dead-kernel-library class)
   weak-float-in-kernel     bare float literals lowering f64 inside
                            Pallas kernel bodies under global x64

@@ -22,7 +22,7 @@ class FlagDecl:
 
     def one_line_help(self) -> str:
         text = re.sub(r"\s+", " ", self.help).strip()
-        # first sentence: split on ". " but not inside e.g. "0.4.37"
+        # first sentence: split on ". " but not inside e.g. "0.9.0"
         m = re.search(r"\.\s+[A-Z`'\"(]", text)
         return text[: m.start() + 1] if m else text
 

@@ -1,106 +1,72 @@
-"""Rule: jax-compat — direct use of jax APIs that do not exist on the
-pinned jax (0.4.37).
+"""Rule: jax-compat — use of jax APIs that the installed jax (0.9.0) no
+longer has.
 
-PR 2's root cause: `jax.enable_x64` is absent on this jax, so every
-Pallas kernel entry raised AttributeError and dispatch silently fell
-back to XLA — the whole kernel library was dead code with green tests.
-`jax.shard_map` is the same class. Both failures are pure attribute
-lookups, i.e. statically detectable from a versioned compat table.
+The repo runs on one installation. An API that an older jax spelled
+differently and this one removed fails as a pure attribute lookup or
+import — `AttributeError` / `ImportError` at the first call, or, behind a
+catch-everything handler, a silent fall to the slow path on every step
+(how the whole Pallas library once ran as dead code with green tests).
+Both are statically detectable from a table.
 
-Skipped on purpose:
-  * attribute STORES (`_jax.shard_map = adapter` — installing a shim);
-  * lookups inside a try/except-AttributeError guard (the
-    feature-detection idiom the shims themselves use), including
-    aliases assigned there;
-  * entries marked `shimmed_in_package` when the file lives inside
-    `paddle_tpu/` or imports paddle_tpu: the package __init__ installs
-    the adapter onto the jax module before any submodule runs.
+Skipped on purpose: lookups inside a try/except-AttributeError guard, the
+feature-detection idiom, including aliases assigned there.
 """
 from __future__ import annotations
 
 import ast
-import dataclasses
 
 from ..core import Rule, register
 
-
-@dataclasses.dataclass(frozen=True)
-class CompatEntry:
-    advice: str
-    # True: paddle_tpu/__init__ patches the attr onto jax at import, so
-    # use inside the package (or after `import paddle_tpu`) is sound.
-    shimmed_in_package: bool = False
-
-
-# Verified against the container's jax 0.4.37 (hasattr probes).
+# path -> what to write instead. Verified against jax 0.9.0 (hasattr
+# probes): each of these is gone there.
 COMPAT_TABLE = {
-    "jax.enable_x64": CompatEntry(
-        "absent on jax 0.4.37 — use paddle_tpu.kernels.x64_off() "
-        "(wraps jax.experimental.enable_x64); a direct lookup raises "
-        "AttributeError and guarded call sites silently fall back to "
-        "XLA"),
-    "jax.shard_map": CompatEntry(
-        "absent on jax 0.4.37 — the adapter over "
-        "jax.experimental.shard_map is installed by paddle_tpu/"
-        "__init__; import paddle_tpu first or call "
-        "jax.experimental.shard_map.shard_map directly",
-        shimmed_in_package=True),
-    "jax.typeof": CompatEntry(
-        "absent on jax 0.4.37 (added in later jax) — use "
-        "jax.eval_shape / ShapeDtypeStruct probes instead"),
-    "jax.P": CompatEntry(
-        "absent on jax 0.4.37 — use jax.sharding.PartitionSpec"),
+    "jax.experimental.enable_x64":
+        "removed in jax 0.9.0 — use jax.enable_x64 (in this package: "
+        "paddle_tpu.kernels.x64_off())",
+    "jax.experimental.disable_x64":
+        "removed in jax 0.9.0 — use jax.enable_x64(False)",
+    "jax.experimental.host_callback":
+        "removed — use jax.pure_callback / jax.experimental.io_callback",
+    "jax.tree_map":
+        "removed — use jax.tree.map (or jax.tree_util.tree_map)",
 }
 
 
 @register
 class JaxCompatRule(Rule):
     name = "jax-compat"
-    description = ("use of jax APIs absent on the pinned jax 0.4.37 "
-                   "(jax.enable_x64, jax.shard_map, ...) — raises "
-                   "AttributeError at runtime, or worse, a guarded "
-                   "call site silently falls back to XLA")
-    hazard = ("The repo pins jax 0.4.37; APIs that moved or landed "
-              "later (jax.enable_x64, jax.shard_map, ...) raise "
-              "AttributeError at runtime — or a hasattr-guarded call "
-              "silently takes the slow fallback path on every step.")
-    example = ("`with jax.enable_x64():` (0.4.37 spells it "
-               "`jax.experimental.enable_x64`)")
-    fix = ("Use the 0.4.37 spelling listed in the finding, or wrap "
-           "the new API behind a version probe in one shim module.")
+    description = ("use of jax APIs the installed jax 0.9.0 removed "
+                   "(jax.experimental.enable_x64, jax.tree_map, ...) — "
+                   "raises at runtime, or worse, a guarded call site "
+                   "silently falls back to XLA")
+    hazard = ("The repo runs on jax 0.9.0; an API that jax removed "
+              "raises AttributeError/ImportError at the first call — or "
+              "a catch-everything handler around it silently takes the "
+              "slow fallback path on every step.")
+    example = ("`with jax.experimental.enable_x64(False):` (0.9.0 spells "
+               "it `jax.enable_x64`)")
+    fix = "Use the 0.9.0 spelling listed in the finding."
 
     def check(self, ctx):
-        imports_paddle = any(
-            v == "paddle_tpu" or v.startswith("paddle_tpu.")
-            for v in ctx.imports.alias.values())
-        in_package = ctx.relpath.startswith("paddle_tpu/")
-
-        def exempt(entry, lineno):
-            if ctx.in_attr_guard(lineno):
-                return True  # feature-detection try/except
-            return entry.shimmed_in_package and (in_package
-                                                 or imports_paddle)
-
         for node in ctx.nodes:
             if isinstance(node, ast.Attribute):
                 if not isinstance(node.ctx, ast.Load):
-                    continue  # shim installation / del
-                path = ctx.imports.expand(node)
-                entry = COMPAT_TABLE.get(path) if path else None
-                if entry is None or exempt(entry, node.lineno):
                     continue
-                yield ctx.finding(
-                    self.name, node, f"`{path}` {entry.advice}")
+                path = ctx.imports.expand(node)
+                advice = COMPAT_TABLE.get(path) if path else None
+                if advice is None or ctx.in_attr_guard(node.lineno):
+                    continue
+                yield ctx.finding(self.name, node, f"`{path}` {advice}")
             elif isinstance(node, ast.ImportFrom) and node.level == 0:
-                # `from jax import enable_x64` fails identically
-                # (ImportError instead of AttributeError) — same table
+                # the from-import spelling of a removed API fails
+                # identically (ImportError instead of AttributeError)
                 for a in node.names:
                     path = f"{node.module}.{a.name}" \
                         if node.module else a.name
-                    entry = COMPAT_TABLE.get(path)
-                    if entry is None or exempt(entry, node.lineno):
+                    advice = COMPAT_TABLE.get(path)
+                    if advice is None or ctx.in_attr_guard(node.lineno):
                         continue
                     yield ctx.finding(
                         self.name, node,
                         f"`from {node.module} import {a.name}`: "
-                        f"`{path}` {entry.advice}")
+                        f"`{path}` {advice}")

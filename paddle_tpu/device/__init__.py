@@ -27,7 +27,9 @@ def get_all_custom_device_type():
 
 
 def get_available_device():
-    return [f"tpu:{i}" for i in range(device_count("tpu"))] or ["cpu"]
+    import jax
+
+    return [f"{d.platform}:{i}" for i, d in enumerate(jax.devices())]
 
 
 def get_available_custom_device():
@@ -93,7 +95,7 @@ class cuda:
 
     @staticmethod
     def device_count():
-        return device_count("tpu")
+        return device_count("tpu") if is_compiled_with_tpu() else 0
 
     @staticmethod
     def current_stream(device=None):

@@ -257,14 +257,13 @@ def _all_reduce_impl(tensor, op, group):
         tensor._rebind(reducer(a, axes))
         return tensor
     # eager multi-device: run a tiny shard_map program
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = _mesh.get_mesh()
     reducer = {"sum": jax.lax.psum, "max": jax.lax.pmax,
                "min": jax.lax.pmin, "avg": jax.lax.pmean}[op]
-    fn = shard_map(lambda x: reducer(x, axes), mesh=m,
-                   in_specs=P(), out_specs=P())
+    fn = jax.shard_map(lambda x: reducer(x, axes), mesh=m,
+                       in_specs=P(), out_specs=P())
     tensor._rebind(fn(a))
     return tensor
 

@@ -47,12 +47,7 @@ def _distributed_client_up() -> bool:
     """Whether jax.distributed is already initialized, WITHOUT touching the
     XLA backend (jax.process_count() would initialize it and make a later
     jax.distributed.initialize impossible)."""
-    try:
-        return jax.distributed.is_initialized()
-    except AttributeError:  # older jax
-        from jax._src import distributed as _dist
-
-        return getattr(_dist.global_state, "client", None) is not None
+    return jax.distributed.is_initialized()
 
 
 def _gather_endpoints(rank: int, world: int, timeout: float = None) -> None:

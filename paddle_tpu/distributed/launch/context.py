@@ -1,11 +1,13 @@
 """Launch context: CLI args + env -> a resolved job description.
 
 Reference parity: python/paddle/distributed/launch/context (SURVEY.md §3.5):
-`Context` parses --nnodes/--nproc_per_node/--master/--devices/--log_dir and
-the PADDLE_* env, producing the per-rank env contract. TPU-native notes: on
-TPU pods the natural unit is ONE process PER HOST (jax owns all local
-chips), so nproc_per_node defaults to 1; multi-proc-per-node remains for
-CPU tests and the reference's GPU-style flows.
+`Context` parses --nnodes/--nproc_per_node/--master/--log_dir and the
+PADDLE_* env, producing the per-rank env contract. TPU-native notes: a
+chip belongs to one process and libtpu hands a process every chip of its
+host, so the unit is ONE process PER HOST driving all local chips through
+one `Mesh`; nproc_per_node defaults to 1, and on a host with TPU chips a
+larger value is refused (`check_one_process_per_host`) unless the workers
+are held to the CPU. Multi-proc-per-node remains for CPU workers.
 """
 from __future__ import annotations
 
@@ -31,7 +33,6 @@ class JobContext:
     nproc_per_node: int = 1
     master: Optional[str] = None
     log_dir: str = "log"
-    devices: Optional[str] = None
     job_id: str = "default"
     max_restarts: int = 0  # >0 enables elastic restart-from-failure
     # fleet telemetry root: each rank writes <dir>/rank_<i>/ shards
@@ -109,7 +110,6 @@ def parse_args(argv=None) -> JobContext:
     p.add_argument("--master", type=str,
                    default=os.environ.get("PADDLE_MASTER"))
     p.add_argument("--log_dir", type=str, default="log")
-    p.add_argument("--devices", "--gpus", type=str, default=None)
     p.add_argument("--job_id", type=str, default="default")
     p.add_argument("--max_restarts", type=int,
                    default=int(os.environ.get("PADDLE_ELASTIC_MAX_RESTARTS",
@@ -137,7 +137,7 @@ def parse_args(argv=None) -> JobContext:
     return JobContext(
         script=a.script, script_args=a.script_args, nnodes=a.nnodes,
         node_rank=a.node_rank, nproc_per_node=a.nproc_per_node,
-        master=a.master, log_dir=a.log_dir, devices=a.devices,
+        master=a.master, log_dir=a.log_dir,
         job_id=a.job_id, max_restarts=a.max_restarts,
         telemetry_dir=a.telemetry_dir,
         telemetry_port=a.telemetry_port)
@@ -173,7 +173,24 @@ def rank_env(ctx: JobContext, local_rank: int) -> dict:
         # one live HTTP plane per rank at base+rank — distinct ports
         # even with multiple workers on one host (observability/httpd)
         env["FLAGS_telemetry_port"] = str(ctx.telemetry_port + rank)
-    if ctx.devices is not None:
-        devs = ctx.devices.split(",")
-        env["CUDA_VISIBLE_DEVICES"] = devs[local_rank % len(devs)]
     return env
+
+
+def check_one_process_per_host(nproc: int, tpu_chips: int, env) -> None:
+    """Refuse N > 1 workers that would fight over the host's chips.
+
+    libtpu gives each process ALL local chips and a chip serves one
+    process, so N > 1 TPU workers on one host fail or hang at backend
+    start-up. Workers held to the CPU (`env` has JAX_PLATFORMS without
+    "tpu") do not touch the chips and may be as many as asked."""
+    if nproc <= 1 or tpu_chips == 0:
+        return
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.lower().split(","):
+        return
+    raise SystemExit(
+        f"[launch] {nproc} worker processes on a host with {tpu_chips} "
+        f"TPU chip(s): every worker would claim all of them. Run ONE "
+        f"process per host and drive the {tpu_chips} chips through one "
+        f"mesh (paddle_tpu.distributed.mesh.init_mesh, e.g. "
+        f"tp={tpu_chips}); or set JAX_PLATFORMS=cpu for CPU workers.")

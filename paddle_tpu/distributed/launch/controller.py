@@ -18,7 +18,8 @@ import time
 from dataclasses import dataclass, field
 from typing import List, Optional
 
-from .context import JobContext, rank_env
+from ...framework import jax_compat as _jc
+from .context import JobContext, check_one_process_per_host, rank_env
 
 
 @dataclass
@@ -109,6 +110,9 @@ class CollectiveController:
                 ctx.envs["PADDLE_STORE_ENDPOINT"] = ""
 
     def build_pod(self):
+        check_one_process_per_host(
+            self.ctx.nproc_per_node, _jc.tpu_chips_on_host(),
+            {**os.environ, **self.ctx.envs})
         for lr in range(self.ctx.nproc_per_node):
             rank = self.ctx.rank_of(lr)
             log = os.path.join(self.ctx.log_dir, f"workerlog.{rank}")

@@ -51,19 +51,11 @@ def _pcast_varying(x, axes):
     varying over are cast (pcast rejects varying->varying)."""
     if isinstance(axes, str):
         axes = (axes,)
-    try:
-        # AttributeError: no jax.typeof on this jax (0.4.37);
-        # TypeError: non-tracer values have no aval on newer jax
-        cur = getattr(jax.typeof(x), "vma", frozenset())
-    except (AttributeError, TypeError):
-        cur = frozenset()
+    cur = jax.typeof(x).vma
     need = tuple(a for a in axes if a not in cur)
     if not need:
         return x
-    try:
-        return jax.lax.pcast(x, need, to="varying")
-    except (AttributeError, TypeError, ValueError):
-        return x
+    return jax.lax.pcast(x, need, to="varying")
 
 
 def _manual_batch_axes(mesh, axis_name):
@@ -180,6 +172,11 @@ def spmd_pipeline(stage_fn: Callable, stage_params, microbatches, *,
         in_specs=(stacked_spec, data_spec, buf_spec),
         out_specs=(tm(lambda _: P(axis_name), microbatches), buf_spec),
         axis_names=frozenset({axis_name}),
+        # check_vma on: the schedule's ppermute/psum need the varying-axes
+        # types (its out_specs and autodiff rely on them); the flash
+        # kernel a stage may contain types its own out_shape with vma
+        # (kernels/flash_attention._sds)
+        check_vma=True,
     )(stage_params, microbatches, buf_arg)
     outs = tm(lambda a: a[-1], stacked_out)
     if stage_buffers is None:
@@ -407,6 +404,7 @@ def spmd_pipeline_1f1b(stage_fn, stage_params, microbatches, head_fn,
         out_specs=(P(), stacked_spec, head_spec,
                    tm(lambda _: P(axis_name), microbatches), buf_spec),
         axis_names=frozenset({axis_name}),
+        check_vma=True,  # see spmd_pipeline
     )(stage_params, microbatches, head_params, targets, buf_arg)
     d_head = tm(lambda a, p: a.astype(p.dtype), d_head, head_params)
     # stage 0's shard holds the input cotangents — one-shard gather
@@ -889,6 +887,7 @@ def spmd_pipeline_vpp(stage_fn, stage_params, microbatches, head_fn,
                    tm(lambda _: P(axis_name, None, dp_spec), microbatches),
                    buf_spec),
         axis_names=frozenset(manual_axes),
+        check_vma=True,  # see spmd_pipeline
     )(stage_params, microbatches, head_params, targets, buf_arg)
     d_head = tm(lambda a, p: a.astype(p.dtype), d_head, head_params)
     # stage 0's shard holds the input cotangents — one-shard gather

@@ -5,7 +5,8 @@ from __future__ import annotations
 import multiprocessing as mp
 import os
 
-from .launch.context import free_port
+from ..framework import jax_compat as _jc
+from .launch.context import check_one_process_per_host, free_port
 
 
 def _worker(func, rank, nprocs, master, args):
@@ -21,14 +22,19 @@ def _worker(func, rank, nprocs, master, args):
 
 
 def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
+    chips = _jc.tpu_chips_on_host()
     if nprocs <= 0:
-        # reference semantics: one process per visible device
-        try:
+        # reference semantics: one process per visible device. A host's
+        # TPU chips all belong to ONE process, so there the count is 1
+        # (and the parent must not start the backend to ask); CPU
+        # devices can be counted
+        if chips:
+            nprocs = 1
+        else:
             import jax
 
             nprocs = jax.local_device_count()
-        except Exception:
-            nprocs = 1
+    check_one_process_per_host(nprocs, chips, os.environ)
     master = options.get("master") or f"127.0.0.1:{free_port()}"
     ctx = mp.get_context(options.get("start_method", "spawn"))
     procs = []

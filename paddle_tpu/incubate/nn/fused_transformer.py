@@ -212,7 +212,7 @@ class FusedTransformerEncoderLayer(Layer):
 class FusedMultiTransformer(Layer):
     """The whole decoder stack as one fused module with KV cache — the
     serving engine (reference: fused_multi_transformer_op; config-5 model,
-    BASELINE.md #5).
+    BASELINE.json #5).
 
     Weights are per-layer lists, same structure as the reference op inputs
     (ln_scales, qkv_weights[3,nh,hd,d], out_proj, ffn1/ffn2, ffn_ln). Only

@@ -7,9 +7,14 @@ the ReplicaServer generate bridge, and publishes its endpoint through
 a fleet heartbeat under ``--fleet-dir`` — after which the parent
 discovers it with ``inference.auto_replicas(D)`` (the ``--replicas
 auto`` path). One process per replica is the point: the router's
-throughput gates (tools/router_smoke.py, bench.py serving rows with
-``BENCH_SERVING_REPLICAS>1``) measure N processes with N GILs, which
+gates (tools/router_smoke.py) exercise N processes with N GILs, which
 threads in one interpreter cannot show.
+
+CPU BY DESIGN: the worker and ``spawn_replicas`` hold their processes to
+the CPU (``JAX_PLATFORMS`` defaults to ``cpu``). A TPU chip belongs to one
+process and a process that starts jax takes every local chip, so N worker
+processes on one TPU host would fight for them. Replicas on chips are N
+engines on N devices inside ONE process (``LocalReplica``; ROADMAP R6).
 
 The worker prints exactly one ``READY {json}`` line on stdout when it
 is routable, then heartbeats until its parent disappears or it is
@@ -196,7 +201,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 # ---------------------------------------------------------------------------
-# parent-side spawner (shared by tools/router_smoke.py and bench.py)
+# parent-side spawner (shared by the tools/*_smoke.py drives)
 # ---------------------------------------------------------------------------
 
 

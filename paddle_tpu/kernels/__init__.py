@@ -1,22 +1,22 @@
 """Pallas TPU kernel library — the Phi-fusion equivalent (SURVEY.md §2.1
 "Phi fusion kernels", §7 phase 9): flash attention, fused rope, rmsnorm,
-ring attention, paged-KV decode. Kernels fall back to interpret mode on CPU
-so the same tests run in CI without a TPU."""
+ring attention, paged-KV decode. On the CPU the kernels run in Pallas
+interpret mode so the same tests run in CI without a TPU; on every other
+platform they are compiled by Mosaic."""
 import jax as _jax
-
-try:
-    # some jax versions alias the context manager at the top level
-    _enable_x64 = _jax.enable_x64
-except AttributeError:
-    # jax 0.4.37 here only ships it under experimental; without this the
-    # kernels' `with x64_off():` regions raised AttributeError and every
-    # guarded call site silently fell back to XLA — the Pallas library
-    # was dead code on this jax until ISSUE 2
-    from jax.experimental import enable_x64 as _enable_x64
 
 
 def x64_off():
     """Context manager running its body with jax x64 disabled (pallas
     index maps / kernel constants must stay 32-bit; the package enables
     x64 globally for paddle int64 semantics)."""
-    return _enable_x64(False)
+    return _jax.enable_x64(False)
+
+
+def interpret() -> bool:
+    """The `interpret=` argument of every pallas_call in this package.
+    Only the CPU emulates: it has no Mosaic. Any other platform compiles
+    the kernel, so a backend Mosaic cannot target fails at lowering
+    instead of running the emulator without a word."""
+    return _jax.default_backend() == "cpu"
+

@@ -8,8 +8,8 @@ p-blocks from the saved row logsumexp (standard flash backward, two kernels:
 dk/dv then dq). Grids put the contraction dim innermost so accumulators live
 in VMEM scratch across grid steps; blocks are MXU-aligned (128).
 
-On non-TPU backends the same kernels run in interpreter mode so CPU CI
-exercises identical code paths (SURVEY.md §7 "interpret-mode fallback").
+On the CPU the same kernels run in interpreter mode so CPU CI exercises
+identical code paths (SURVEY.md §7 "interpret-mode fallback").
 """
 from __future__ import annotations
 
@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret as _interpret
 from . import x64_off as _x64_off
 
 # pallas_call runs under x64-off so index maps / constants stay 32-bit
@@ -33,10 +34,6 @@ NEG_INF = np.float32(-1e30)  # f32 scalar: x64 mode must not leak f64 into kerne
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -100,13 +97,10 @@ def _dropout_keep(seed, bh, i, j, block_q, block_k, rate):
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying the varying-manual-axes (vma) type of
     `like` — required when the kernel runs inside a shard_map manual
-    region (ring attention), harmless otherwise."""
-    try:
-        vma = jax.typeof(like).vma
-        if vma:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):
-        pass
+    region that checks vma (pipeline stages), harmless otherwise."""
+    vma = jax.typeof(like).vma
+    if vma:
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
@@ -750,16 +744,9 @@ def _dispatch_bwd(res, g, scale, causal, block_q, block_k, d_lse=None):
         from . import autotune as _at
 
         if _at.enabled():
-            try:
-                # a tuner failure (e.g. OOM allocating bucket-shaped
-                # example arrays) must degrade to legacy dispatch, not
-                # crash the train step's backward
-                win = _at.choose_flash_bwd(q.shape[0], s_q, s_kv, d,
-                                           jnp.dtype(q.dtype).name,
-                                           scale, causal, block_q,
-                                           block_k)
-            except Exception:  # noqa: BLE001
-                win = None
+            win = _at.choose_flash_bwd(q.shape[0], s_q, s_kv, d,
+                                       jnp.dtype(q.dtype).name,
+                                       scale, causal, block_q, block_k)
             if win is not None:
                 impl = win.meta["impl"]
                 if impl == "xla":
@@ -953,8 +940,8 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None,
     scalar) keys the counter-based threefry mask, so the same seed
     reproduces the same mask — pass a fresh seed per training step.
 
-    Raises ValueError for unsupported shapes — callers (F.sdpa) catch and
-    fall back to the fused XLA path.
+    Raises ValueError for unsupported shapes — callers (F.sdpa) ask
+    `supports()` first and take the fused XLA path themselves.
     """
     b, s_q, h, d = q.shape
     s_kv = k.shape[1]

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret as _interpret
 from . import x64_off as _x64_off
 
 _pc = pl.pallas_call
@@ -38,10 +39,6 @@ BLOCK_GRID_K = (128, 256, 512)
 _M_ALIGN = 8
 _SINGLE_M_MAX = 512
 _BLOCK_M = 256
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def matmul_xla(x, w):

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret as _interpret
 from . import x64_off as _x64_off
 
 _pc = pl.pallas_call
@@ -49,10 +50,6 @@ BLOCK_GRID_K = (128, 256, 512)
 # under speculative verify) — one m block, padded to the f32 sublane tile
 _M_ALIGN = 8
 _MAX_M = 1024
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -150,8 +147,13 @@ def supports(m, k, n, weight_dtype="int8", group_size=-1,
         return False
     if group_size not in (-1, 64, 128):
         return False
-    if group_size != -1 and block_k % group_size:
-        return False  # a k block must cover whole scale groups
+    if group_size != -1:
+        if block_k % group_size:
+            return False  # a k block must cover whole scale groups
+        # the k block's scale rows form a [block_k // group_size, block_n]
+        # tile: Mosaic takes 8-row tiles, or the whole scale array
+        if (block_k // group_size) % 8 and block_k != k:
+            return False
     if weight_dtype == "int4":
         # packed rows: block_k//2 int8 rows must hit the (32, 128) tile
         if block_k % 64:
@@ -296,12 +298,9 @@ def quant_matmul_dispatch(x, qw, scales, weight_dtype="int8",
         from . import autotune as _at
 
         if _at.enabled() and (not _interpret() or _at.has_custom_timer()):
-            try:
-                win = _at.choose_quant_matmul(m, k, n, weight_dtype,
-                                              group_size,
-                                              jnp.dtype(x.dtype).name)
-            except Exception:  # noqa: BLE001 — tuner failure degrades
-                win = None
+            win = _at.choose_quant_matmul(m, k, n, weight_dtype,
+                                          group_size,
+                                          jnp.dtype(x.dtype).name)
             if win is not None and win.meta["impl"] == "fused":
                 out = quant_matmul_fused(
                     x2, qw, scales, weight_dtype, group_size,

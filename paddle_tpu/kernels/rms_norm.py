@@ -10,6 +10,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret as _interpret
 from . import x64_off as _x64_off
 
 # pallas_call runs under x64-off so index maps / constants stay 32-bit
@@ -18,9 +19,15 @@ _pc = pl.pallas_call
 
 BLOCK_ROWS = 256
 
-
-def _interpret():
-    return jax.default_backend() != "tpu"
+# Rows per block shrink with the width and the itemsize so that one input
+# block is at most 1 MiB: the kernels' working set (double-buffered x / g /
+# dx blocks plus f32 temporaries) then stays inside the 16 MiB of VMEM
+# Mosaic scopes to a kernel on v5e. Found by ahead-of-time compiles for
+# v5e, forward and backward, bf16 and f32, 2048 to 8192 columns, 2048 to
+# 131072 rows: every shape at the bound compiles, and shapes at twice the
+# bound are refused from 8192 rows up ("Scoped allocation ... exceeded
+# scoped vmem limit"). tests/test_kernels_compile_tpu.py keeps it so.
+_MAX_BLOCK_BYTES = 1 << 20
 
 
 def _fwd_kernel(x_ref, w_ref, o_ref, rstd_ref, *, eps):
@@ -57,20 +64,26 @@ def _bwd_kernel(x_ref, w_ref, rstd_ref, g_ref, dx_ref, dw_ref, dw_acc, *,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def rms_norm_2d(x, w, eps, block_rows=None):
-    """block_rows: rows per grid step for BOTH passes (None: the legacy
-    min(BLOCK_ROWS, rows) choice). The autotuner sweeps it (128/256/512)
-    per shape bucket; explicit callers keep the default."""
+    """block_rows: rows per grid step for BOTH passes (None: `_block`'s
+    choice from width and dtype). The autotuner sweeps it (128/256/512)
+    per shape bucket, filtered by `supports`; explicit callers keep the
+    default."""
     out, _ = _fwd(x, w, eps, block_rows)
     return out
 
 
-def _block(rows, block_rows):
-    return min(BLOCK_ROWS, rows) if block_rows is None else block_rows
+def _block(rows, cols, itemsize, block_rows=None):
+    if block_rows is not None:
+        return block_rows
+    block = BLOCK_ROWS
+    while block * cols * itemsize > _MAX_BLOCK_BYTES:
+        block //= 2
+    return min(block, rows)
 
 
 def _fwd(x, w, eps, block_rows=None):
     rows, cols = x.shape
-    block = _block(rows, block_rows)
+    block = _block(rows, cols, x.dtype.itemsize, block_rows)
     kernel = functools.partial(_fwd_kernel, eps=eps)
     with _x64_off():
         out, rstd = _pc(
@@ -101,7 +114,7 @@ def _rms_fwd(x, w, eps, block_rows=None):
 def _rms_bwd(eps, block_rows, res, g):
     x, w, rstd = res
     rows, cols = x.shape
-    block = _block(rows, block_rows)
+    block = _block(rows, cols, x.dtype.itemsize, block_rows)
     n_blocks = rows // block
     kernel = functools.partial(_bwd_kernel, n_rows_blocks=n_blocks)
     with _x64_off():
@@ -131,12 +144,16 @@ def _rms_bwd(eps, block_rows, res, g):
 rms_norm_2d.defvjp(_rms_fwd, _rms_bwd)
 
 
-def supports(rows, cols, block_rows=None):
+def supports(rows, cols, block_rows=None, itemsize=4):
+    """Can the kernel run [rows, cols] of `itemsize`-byte elements (at an
+    explicit `block_rows`, if given)? The one gate: beyond it the callers
+    take the XLA expression, inside it a Mosaic refusal is an error."""
     if rows <= 0:
         return False
-    block = _block(rows, block_rows)
+    block = _block(rows, cols, itemsize, block_rows)
     return (rows % block == 0 and rows >= block and cols % 128 == 0
-            and cols <= 8192)
+            and cols <= 8192
+            and block * cols * itemsize <= _MAX_BLOCK_BYTES)
 
 
 def rms_norm(x, weight, eps=1e-6, block_rows=None):

@@ -1,4 +1,4 @@
-"""Flagship model family (BASELINE.md configs 3/4/5)."""
+"""Flagship model family (BASELINE.json configs 3/4/5)."""
 from .llama import (  # noqa: F401
     LlamaConfig,
     LlamaForCausalLM,

@@ -1,4 +1,4 @@
-"""GPT-2/3-family causal LM (BASELINE.md config 3: GPT-3 1.3B TP=4).
+"""GPT-2/3-family causal LM (BASELINE.json config 3: GPT-3 1.3B TP=4).
 
 Reference parity: the PaddleNLP GPT trainer over the reference's fused
 stack and Fleet HybridParallel. Architecture differences from the LLaMA

@@ -1,4 +1,4 @@
-"""LLaMA-family causal LM — the flagship model (BASELINE.md configs 3/4:
+"""LLaMA-family causal LM — the flagship model (BASELINE.json configs 3/4:
 GPT-3 1.3B TP=4 and LLaMA-2-13B TP×PP×sharding).
 
 Reference parity: the PaddleNLP LLaMA trainer runs on the reference's fused

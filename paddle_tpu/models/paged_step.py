@@ -114,9 +114,12 @@ def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
             ((ps, ps) if kv_quant else ()) + \
             ((rs,) if limit is not None else ())
         out_specs = (hs, ps, ps) + ((ps, ps) if kv_quant else ())
+        # check_vma off: the step has no collective for the check to
+        # type, and the Pallas decode kernel's plain out_shape is
+        # rejected at trace time under it
         run = jax.shard_map(
             step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=frozenset({"tp"}))
+            axis_names=frozenset({"tp"}), check_vma=False)
 
     args = [q, k, v, Tensor(as_array(k_pages)),
             Tensor(as_array(v_pages)), Tensor(as_array(block_tables)),

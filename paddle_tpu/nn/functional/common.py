@@ -21,27 +21,24 @@ def _matmul(a, w):
     from ...framework import config as _config
 
     if _config.get_flag("FLAGS_use_pallas_kernels", True):
-        try:
-            from ...kernels import autotune as _at
-            from ...kernels import matmul as _kmm
+        from ...kernels import autotune as _at
+        from ...kernels import matmul as _kmm
 
-            if _at.enabled() and (not _kmm._interpret()
-                                  or _at.has_custom_timer()) \
-                    and w.ndim == 2 and a.dtype == w.dtype \
-                    and jnp.issubdtype(a.dtype, jnp.floating):
-                m = int(np.prod(a.shape[:-1]))
-                k = a.shape[-1]
-                n = w.shape[-1]
-                if _kmm.supports(m, k, n):
-                    win = _at.choose_matmul(m, k, n,
-                                            jnp.dtype(a.dtype).name)
-                    if win is not None and win.meta["impl"] == "pallas":
-                        out = _kmm.matmul_fused(
-                            a.reshape(-1, k), w,
-                            win.meta["block_n"], win.meta["block_k"])
-                        return out.reshape(a.shape[:-1] + (n,))
-        except Exception:  # noqa: BLE001 — any kernel failure -> XLA
-            pass
+        if _at.enabled() and (not _kmm._interpret()
+                              or _at.has_custom_timer()) \
+                and w.ndim == 2 and a.dtype == w.dtype \
+                and jnp.issubdtype(a.dtype, jnp.floating):
+            m = int(np.prod(a.shape[:-1]))
+            k = a.shape[-1]
+            n = w.shape[-1]
+            if _kmm.supports(m, k, n):
+                win = _at.choose_matmul(m, k, n,
+                                        jnp.dtype(a.dtype).name)
+                if win is not None and win.meta["impl"] == "pallas":
+                    out = _kmm.matmul_fused(
+                        a.reshape(-1, k), w,
+                        win.meta["block_n"], win.meta["block_k"])
+                    return out.reshape(a.shape[:-1] + (n,))
     return jnp.matmul(a, w)
 
 

@@ -39,26 +39,34 @@ PEAK_HBM_BYTES_PER_S = {
     "v6e": 1640e9,
 }
 
-# bench.py's CPU-fallback denominator: a liveness artifact's "MFU" is
-# meaningless, but the division must not crash — keep the historical 1
-# TFLOP placeholder in one named place instead of a magic literal
-CPU_FALLBACK_PEAK_FLOPS = 1e12
-
-
 def normalize_kind(device_kind: str) -> Optional[str]:
     """Map a jax `device_kind` string onto a table key (None when
-    unrecognized). The v5e check runs before the bare-v5 one: the chip
-    reports "TPU v5 lite"."""
+    unrecognized). A generation is matched only when the string names
+    it: v5e reports "TPU v5 lite", and a bare "TPU v5" is NOT taken for
+    v5p."""
     kind = (device_kind or "").lower()
     if "v5 lite" in kind or "v5e" in kind:
         return "v5e"
-    if "v5p" in kind or "v5" in kind:
+    if "v5p" in kind:
         return "v5p"
     if "v4" in kind:
         return "v4"
-    if "v6" in kind:
+    if "v6 lite" in kind or "v6e" in kind:
         return "v6e"
     return None
+
+
+def require_kind(device_kind: str) -> str:
+    """Table key for `device_kind`, or ValueError: measurement entry
+    points must not divide by another chip's peak."""
+    kind = normalize_kind(device_kind)
+    if kind is None:
+        raise ValueError(
+            f"device_kind {device_kind!r} is not in the peak table "
+            f"(observability/device_peaks.py: "
+            f"{sorted(PEAK_FLOPS_BF16)}); add its published peaks "
+            f"before measuring on it")
+    return kind
 
 
 def detect_kind(default: Optional[str] = None) -> Optional[str]:

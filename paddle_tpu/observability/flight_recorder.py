@@ -1,6 +1,6 @@
 """Stall flight-recorder: an event ring + a watchdog thread.
 
-A silent TPU hang (a wedged collective, a dead tunnel, a deadlocked host
+A silent TPU hang (a wedged collective, a lost device, a deadlocked host
 thread) looks identical to "still computing" from the outside. The
 flight recorder turns it into an artifact:
 

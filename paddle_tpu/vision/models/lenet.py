@@ -1,5 +1,5 @@
 """LeNet (reference: python/paddle/vision/models/lenet.py) — config-1 model
-(BASELINE.md #1)."""
+(BASELINE.json #1)."""
 from __future__ import annotations
 
 from ... import nn

@@ -1,5 +1,5 @@
 """ResNet family (reference: python/paddle/vision/models/resnet.py) —
-config-2 model (BASELINE.md #2)."""
+config-2 model (BASELINE.json #2)."""
 from __future__ import annotations
 
 from ... import nn

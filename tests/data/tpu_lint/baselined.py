@@ -4,4 +4,4 @@ import jax
 
 
 def old_code(x):
-    return jax.enable_x64  # known finding, baselined in the test
+    return jax.tree_map  # known finding, baselined in the test

@@ -6,7 +6,7 @@ from paddle_tpu.distributed.collective import all_reduce
 
 
 def guarded(x, rank):
-    with jax.enable_x64(False):  # tpu-lint: disable=jax-compat
+    with jax.experimental.enable_x64(False):  # tpu-lint: disable=jax-compat
         pass
     if rank == 0:
         all_reduce(x)  # tpu-lint: disable=rank-divergent-collective

@@ -186,19 +186,7 @@ class TestGate:
                         "--history", hp]) == 0
 
 
-class TestCommittedAnchor:
-    def test_smoke_anchor_row_is_committed(self):
-        """tools/ci.sh's bench_compare gate needs a comparable row for
-        the CPU smoke on a fresh clone — the committed smoke:cpu
-        anchor provides it (and the history ledger takes over after
-        the first run)."""
-        with open(os.path.join(REPO, "BENCH_TPU_CACHE.json")) as f:
-            cache = json.load(f)
-        row = cache.get("smoke:cpu")
-        assert row, "smoke:cpu anchor row missing from the cache"
-        assert row.get("smoke") is True
-        assert (row.get("extra") or {}).get("backend") == "cpu"
-
+class TestCommittedHistory:
     def test_history_ledger_seeded(self):
         path = os.path.join(REPO, "BENCH_HISTORY.jsonl")
         assert os.path.exists(path), \
@@ -222,12 +210,41 @@ class TestHistoryAppend:
         assert len(rows) == 2
         assert rows[0]["value"] == 123.0
         assert "commit" in rows[0] and "date" in rows[0]
-        # probe noise is stripped from the trajectory
-        noisy = _row()
-        noisy["tpu_probe_error"] = {"attempts": [1]}
-        noisy["tpu_cached"] = {"rows_file": "x"}
-        bench._append_history(noisy)
-        rows = [json.loads(ln) for ln in
-                open(path).read().splitlines()]
-        assert "tpu_probe_error" not in rows[-1]
-        assert "tpu_cached" not in rows[-1]
+
+
+def test_bench_fails_without_a_chip_and_prints_no_metric():
+    """bench.py measures on a TPU: on the CPU, without --smoke, it exits
+    non-zero and stdout carries no metric line (no CPU fallback, no
+    cached row, no exit-0 error row)."""
+    import subprocess
+    import sys
+
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")],
+        capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "no CPU fallback" in r.stderr
+
+
+def test_bench_smoke_row_has_checks_and_no_rate():
+    """--smoke is the CPU correctness run: its row carries checks and
+    the device, and nothing under a device metric's name."""
+    import subprocess
+    import sys
+
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py"), "--smoke"],
+        capture_output=True, text=True, timeout=600,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "BENCH_CONFIG": "serving"})
+    assert r.returncode == 0, r.stderr[-2000:]
+    row = json.loads(r.stdout.strip().splitlines()[-1])
+    assert row["smoke"] is True and row["ok"] is True
+    assert not {"metric", "value", "unit", "vs_baseline"} & set(row)
+    checks = row["checks"]
+    assert checks["platform"] == "cpu"
+    assert checks["generated_tokens"] == \
+        checks["requests"] * checks["new_tokens"]
+    assert checks["decode_recompiles"] == 0

@@ -1,5 +1,5 @@
 """End-to-end training tests — the driver-visible milestones
-(SURVEY.md §7 phase 3 "MINIMUM E2E SLICE", BASELINE.md config 1) + the
+(SURVEY.md §7 phase 3 "MINIMUM E2E SLICE", BASELINE.json config 1) + the
 eager-vs-jit parity assertion (§4.4 dy2static pattern)."""
 import numpy as np
 import pytest
@@ -91,7 +91,7 @@ class TestJitTraining:
 
 class TestLeNetMNIST:
     def test_config1_lenet_mnist(self):
-        """BASELINE.md config 1: LeNet on MNIST, loss decreases."""
+        """BASELINE.json config 1: LeNet on MNIST, loss decreases."""
         paddle.seed(42)
         net = paddle.vision.models.LeNet()
         ds = paddle.vision.datasets.MNIST(mode="train")

@@ -1,5 +1,5 @@
 """Serving-path tests: paged KV cache kernel, cached decode, generate(),
-continuous-batching engine (SURVEY.md §7 phase 10 / BASELINE.md config 5)."""
+continuous-batching engine (SURVEY.md §7 phase 10 / BASELINE.json config 5)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

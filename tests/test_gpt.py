@@ -1,4 +1,4 @@
-"""GPT family (BASELINE.md config 3; reference: PaddleNLP GPT trainer on
+"""GPT family (BASELINE.json config 3; reference: PaddleNLP GPT trainer on
 the fused stack): architecture sanity, training convergence, eager-vs-
 cached decode parity, pipeline contract, TP mesh parity."""
 import numpy as np

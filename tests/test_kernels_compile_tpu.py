@@ -1,0 +1,309 @@
+"""Every Pallas kernel, compiled by Mosaic for a TPU v5e — on the CPU.
+
+libtpu builds a compile-only client from a topology description
+(`jax.experimental.topologies`), and `jit(f).lower(<ShapeDtypeStructs
+sharded to its devices>).compile()` then runs Mosaic and XLA:TPU for real,
+with no chip attached. So a kernel the compiler refuses (a block that does
+not fit the 16 MiB of scoped VMEM, an illegal tile, a `shard_map` that
+rejects the kernel's out_shape) fails HERE, in tier-1, instead of on the
+chip budget. Interpret mode is switched off for the test; nothing executes.
+
+Covered: the kernel table `chip_smoke.py` runs on the chip (same shapes,
+GPT-3 1.3B head geometry), each kernel at the corners of its `supports()`
+range, the Pallas paged kernel inside the tp=4 `shard_map` of
+models/paged_step.py over the four topology devices, and the flash kernel
+inside a `check_vma=True` shard_map like distributed/pipeline.py's.
+
+libtpu's stderr chatter about TPU_ACCELERATOR_TYPE / worker hostnames is
+harmless: there is no TPU VM metadata here to read.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from conftest import load_repo_script
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import matmul as mm
+from paddle_tpu.kernels import paged_attention as pa
+from paddle_tpu.kernels import quant_matmul as qm
+from paddle_tpu.kernels import rms_norm as rn
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+chip_smoke = load_repo_script("chip_smoke.py")
+CASES = {c.name: c for c in chip_smoke.kernel_cases()}
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Four compile-only `TPU v5 lite` devices (one 2x2 host)."""
+    devs = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    assert [d.device_kind for d in devs] == ["TPU v5 lite"] * 4
+    return devs
+
+
+@pytest.fixture(autouse=True)
+def mosaic(monkeypatch):
+    """interpret=False in every kernel module although the default backend
+    is the CPU: the programs are lowered for the topology's devices."""
+    for mod in (fa, pa, rn, mm, qm):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def compile_for(dev, fn, *args):
+    """AOT-compile fn for one topology device; `args` are arrays or
+    ShapeDtypeStructs (only shape and dtype are used). Returns the number
+    of Mosaic custom calls in the compiled program."""
+    sharding = SingleDeviceSharding(dev)
+    specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+             for a in args]
+    flags = chip_smoke.pallas_interpret_flags(fn, *specs)
+    assert flags and not any(flags), flags
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    n = text.count("tpu_custom_call")
+    assert n >= 1
+    return n
+
+
+def S(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ---------------------------------------------------------------------------
+# the chip phase's table, shape for shape
+# ---------------------------------------------------------------------------
+
+
+_ARGS = {}  # make_args -> its arrays: fwd and fwd+bwd cases share them
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_chip_smoke_kernel_case_compiles(v5e, name):
+    case = CASES[name]
+    if case.make_args not in _ARGS:
+        _ARGS[case.make_args] = case.make_args(np.random.default_rng(7))
+    compile_for(v5e[0], case.fn, *_ARGS[case.make_args])
+
+
+# ---------------------------------------------------------------------------
+# supports() corners
+# ---------------------------------------------------------------------------
+
+
+def _sum_grad(f, n):
+    return jax.grad(lambda *a: jnp.sum(f(*a).astype(F32)),
+                    argnums=tuple(range(n)))
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("cols", [2048, 4096, 5120, 8192])
+def test_rms_norm_fits_scoped_vmem(v5e, cols, dtype):
+    """Rows per block follow width and dtype: 4096 and 5120 (LLaMA-2
+    7B/13B) and 8192 columns were refused at the old fixed 256 rows
+    ("Scoped allocation ... exceeded scoped vmem limit"). The refusals
+    begin at 8192 rows, so a 2048-row probe would miss them."""
+    rows = 32768
+    assert rn.supports(rows, cols, itemsize=jnp.dtype(dtype).itemsize)
+    x, w = S((rows, cols), dtype), S((cols,), dtype)
+    compile_for(v5e[0], rn.rms_norm, x, w)
+    compile_for(v5e[0], _sum_grad(rn.rms_norm, 2), x, w)
+
+
+def test_rms_norm_supports_is_the_gate():
+    # the default block shrinks with width and itemsize: 1 MiB a block
+    assert [rn._block(8192, c, 2) for c in (2048, 4096, 5120, 8192)] \
+        == [256, 128, 64, 64]
+    assert [rn._block(8192, c, 4) for c in (2048, 4096, 5120, 8192)] \
+        == [128, 64, 32, 32]
+    # an explicit block (the autotuner's sweep) past the VMEM bound is
+    # refused by supports(), not left for Mosaic to refuse
+    assert rn.supports(8192, 2048, block_rows=256, itemsize=2)
+    assert not rn.supports(8192, 2048, block_rows=256, itemsize=4)
+    assert not rn.supports(8192, 4096, block_rows=256, itemsize=2)
+    assert not rn.supports(8192, 8320)  # beyond 8192 columns
+    # few rows: one block of all of them
+    assert rn.supports(8, 2048) and rn._block(8, 2048, 4) == 8
+
+
+def test_rms_norm_decode_rows(v5e):
+    compile_for(v5e[0], rn.rms_norm, S((8, 2048), BF16), S((2048,), BF16))
+
+
+@pytest.mark.parametrize("head_dim,block", [(128, 512), (256, 128),
+                                            (256, 512)])
+def test_flash_block_and_head_dim_corners(v5e, head_dim, block):
+    """The autotuner's largest blocks and a 256-wide head, fwd and bwd."""
+    s = 1024
+    assert fa.supports(s, s, head_dim, block, block)
+    q = S((1, s, 4, head_dim), BF16)
+
+    def f(q, k, v):
+        return fa.flash_attention_bshd(q, k, v, causal=True, block_q=block,
+                                       block_k=block)
+
+    compile_for(v5e[0], f, q, q, q)
+    # force the streamed backward below its 4096 threshold
+    saved, fa._PALLAS_BWD_MIN_SEQ = fa._PALLAS_BWD_MIN_SEQ, 0
+    try:
+        assert compile_for(v5e[0], _sum_grad(f, 3), q, q, q) == 3
+    finally:
+        fa._PALLAS_BWD_MIN_SEQ = saved
+
+
+def test_flash_cross_attention_lengths(v5e):
+    """seq_q != seq_kv (bottom-right aligned causal), non-causal too."""
+    q, kv = S((2, 256, 4, 128), BF16), S((2, 1024, 4, 128), BF16)
+    for causal in (True, False):
+        compile_for(v5e[0], lambda q, k, v: fa.flash_attention_bshd(
+            q, k, v, causal=causal), q, kv, kv)
+
+
+def test_flash_with_lse(v5e):
+    q = S((1, 512, 4, 128), BF16)
+    compile_for(v5e[0], lambda q, k, v: fa.flash_attention_with_lse_bshd(
+        q, k, v, causal=True), q, q, q)
+
+
+def _paged_specs(batch, q_heads, kv_heads, page, pages_per_seq, quant):
+    n_pages = batch * pages_per_seq
+    pool = S((kv_heads, n_pages, page, 128), I8 if quant else BF16)
+    out = [S((batch, q_heads, 128), BF16), pool, pool,
+           S((batch, pages_per_seq), I32), S((batch,), I32)]
+    if quant:
+        sc = S((kv_heads, n_pages, pa._SCALE_LANES), F32)
+        out += [sc, sc]
+    return out
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("page,pages_per_seq", [(16, 256), (128, 32)])
+def test_paged_decode_page_sizes(v5e, page, pages_per_seq, quant):
+    """ctx 4096, batch 8, both page sizes, float and int8-KV pools: the
+    int8 scale tile (1, 1, 1, 128) that Mosaic rejected as (1, 1, 128) on
+    the 993efc6 record lowers."""
+    def f(q, kp, vp, tables, lens, *scales):
+        kw = dict(k_scales=scales[0], v_scales=scales[1]) if scales else {}
+        return pa.paged_attention(q, kp, vp, tables, lens, **kw)
+
+    compile_for(v5e[0], f, *_paged_specs(8, 16, 16, page, pages_per_seq,
+                                         quant))
+
+
+def test_paged_decode_gqa(v5e):
+    """32 query heads over 4 kv heads (group 8), per-page and grouped."""
+    specs = _paged_specs(8, 32, 4, 16, 256, False)
+    compile_for(v5e[0], pa.paged_attention, *specs)
+    compile_for(v5e[0], pa.paged_attention_grouped, *specs)
+
+
+@pytest.mark.parametrize("m", [8, 8192])
+@pytest.mark.parametrize("block", [128, 512])
+def test_matmul_fused_corners(v5e, m, block):
+    k, n = 2048, 8192
+    assert mm.supports(m, k, n, block, block)
+    compile_for(v5e[0], lambda x, w: mm.matmul_fused(x, w, block, block),
+                S((m, k), BF16), S((k, n), BF16))
+
+
+@pytest.mark.parametrize("weight_dtype,group_size,block,k", [
+    ("int8", -1, 128, 2048), ("int8", -1, 512, 2048),
+    ("int4", -1, 128, 2048), ("int4", -1, 512, 2048),
+    # grouped scales: an 8-row scale tile (512 / 64) ...
+    ("int8", 64, 512, 2048), ("int4", 64, 512, 2048),
+    # ... or one k block, whose scale tile is the whole scale array
+    ("int8", 128, 512, 512), ("int4", 64, 128, 128)])
+@pytest.mark.parametrize("m", [8, qm._MAX_M])
+def test_quant_matmul_fused_corners(v5e, m, weight_dtype, group_size,
+                                    block, k):
+    n = 8192
+    assert qm.supports(m, k, n, weight_dtype, group_size, block, block)
+    rows = k // 2 if weight_dtype == "int4" else k
+    scales = S((n,), F32) if group_size == -1 \
+        else S((k // group_size, n), F32)
+
+    def f(x, qw, sc):
+        return qm.quant_matmul_fused(x, qw, sc, weight_dtype, group_size,
+                                     block, block)
+
+    compile_for(v5e[0], f, S((m, k), BF16), S((rows, n), I8), scales)
+
+
+def test_quant_matmul_grouped_scale_tiles_are_gated():
+    """A [block_k // group_size, block_n] scale tile of 2 or 4 rows is
+    refused by Mosaic ("last two dimensions of your block shape are
+    divisible by 8 and 128"): supports() says no, the dispatch takes the
+    XLA dequant expression."""
+    assert not qm.supports(8, 2048, 8192, "int8", 64, 128, 128)
+    assert not qm.supports(8, 2048, 8192, "int8", 128, 512, 512)
+    assert not qm.supports(8, 2048, 8192, "int4", 128, 256, 256)
+    assert qm.supports(8, 2048, 8192, "int8", 64, 256, 512)
+
+
+# ---------------------------------------------------------------------------
+# kernels inside shard_map over the four devices
+# ---------------------------------------------------------------------------
+
+
+def _lower_on_mesh(mesh, fn, specs_and_pspecs):
+    specs = [jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                  sharding=NamedSharding(mesh, p))
+             for s, p in specs_and_pspecs]
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+def test_paged_kernel_inside_tp4_shard_map(v5e, quant):
+    """The TP decode step of models/paged_step.py with the XLA/Pallas
+    crossover lowered: under `check_vma=True` (jax.shard_map's default)
+    the kernel's out_shape is refused at trace time; the step states
+    check_vma=False."""
+    from paddle_tpu.framework import config as _config
+    from paddle_tpu.models.paged_step import paged_attention_step
+    from paddle_tpu.tensor import Tensor, as_array
+
+    mesh = Mesh(np.asarray(v5e), ("tp",))
+    batch, heads, page, pages_per_seq = 8, 16, 16, 128
+    q = S((batch, 1, heads, 128), BF16)
+    _, pool, _, tables, lens, *scales = _paged_specs(
+        batch, heads, heads, page, pages_per_seq, quant)
+
+    def step(q, k, v, kp, vp, tables, lens, *scales):
+        out, cache = paged_attention_step(
+            Tensor(q), Tensor(k), Tensor(v), (kp, vp, *scales), tables,
+            lens, mesh=mesh)
+        return as_array(out), tuple(as_array(c) for c in cache)
+
+    heads_p, pool_p, rep = P(None, None, "tp"), P("tp"), P()
+    _config.set_flags({"FLAGS_paged_xla_max_ctx": 1})
+    try:
+        text = _lower_on_mesh(mesh, step, [
+            (q, heads_p), (q, heads_p), (q, heads_p), (pool, pool_p),
+            (pool, pool_p), (tables, rep), (lens, rep),
+            *[(s, pool_p) for s in scales]])
+    finally:
+        _config.set_flags({"FLAGS_paged_xla_max_ctx": 0})
+    assert text.count("tpu_custom_call") >= 1
+
+
+def test_flash_kernel_inside_check_vma_shard_map(v5e):
+    """distributed/pipeline.py keeps check_vma=True on its shard_maps (the
+    schedule's collectives and autodiff need the vma types); a stage may
+    hold the flash kernel, whose out_shape carries the vma of its
+    operands (kernels/flash_attention._sds)."""
+    mesh = Mesh(np.asarray(v5e), ("pp",))
+
+    def stage(q, k, v):
+        return jax.shard_map(
+            lambda q, k, v: fa.flash_attention_bshd(q, k, v, causal=True),
+            mesh=mesh, in_specs=P("pp"), out_specs=P("pp"),
+            axis_names=frozenset({"pp"}), check_vma=True)(q, k, v)
+
+    q = S((4, 512, 4, 128), BF16)
+    text = _lower_on_mesh(mesh, stage, [(q, P("pp"))] * 3)
+    assert text.count("tpu_custom_call") >= 1

@@ -94,7 +94,13 @@ class TestKernelParity:
     def test_supports_edges(self):
         # a k block must cover whole scale groups
         assert not qm.supports(8, 256, 256, "int8", 64, 128, 100)
-        assert qm.supports(8, 256, 256, "int8", 64, 128, 128)
+        # ... and its scale rows must form a tile Mosaic takes: 8 rows,
+        # or the whole scale array (one k block) — a 2-row tile compiles
+        # in interpret mode and is refused on the chip
+        # (tests/test_kernels_compile_tpu.py)
+        assert not qm.supports(8, 256, 256, "int8", 64, 128, 128)
+        assert qm.supports(8, 256, 256, "int8", 64, 128, 256)
+        assert qm.supports(8, 1024, 256, "int8", 64, 128, 512)
         # shape must tile
         assert not qm.supports(8, 250, 256, "int8", -1, 128, 128)
         assert not qm.supports(8, 256, 200, "int8", -1, 128, 128)

@@ -1,19 +1,21 @@
 """Scheduler-policy extraction: golden parity + SLO-aware choices.
 
-The golden trace (tests/data/serving_golden_trace.json) was captured
-from the engine BEFORE the SchedulerPolicy extraction: scripted
+The golden trace (tests/data/serving_golden_trace.json) holds scripted
 traffic exercising all four extracted decisions — staggered FIFO
 admission, recompute preemption under a withheld (tight) page pool,
 prefill bucketing across mixed prompt lengths, and {1, decode_burst}
-burst sizing. The default policy must reproduce those token streams
-bit-identically (ISSUE 13 acceptance)."""
+burst sizing — and the token streams the default policy produced for it.
+The default policy must reproduce those streams bit-identically (ISSUE 13
+acceptance). tools/capture_serving_golden_trace.py records the file (and
+names the jax it was recorded under: the streams follow the weights
+`paddle.seed` draws, which follow jax's random streams) and owns the
+replay loop used here."""
 import json
-import os
 
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
+from conftest import load_repo_script
 from paddle_tpu.framework import config as _cfg
 from paddle_tpu.inference import ServingEngine
 from paddle_tpu.inference.scheduler import (FifoSchedulerPolicy,
@@ -21,65 +23,28 @@ from paddle_tpu.inference.scheduler import (FifoSchedulerPolicy,
                                             SloAwareSchedulerPolicy,
                                             available_policies,
                                             resolve_policy)
-from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
-GOLDEN = os.path.join(os.path.dirname(__file__), "data",
-                      "serving_golden_trace.json")
+_capture = load_repo_script("tools/capture_serving_golden_trace.py")
 
-with open(GOLDEN) as f:
+with open(_capture.GOLDEN) as f:
     _TRACE = json.load(f)
 
 
 def _tiny_model():
-    mc = _TRACE["model"]
-    paddle.seed(mc["seed"])
-    cfg = LlamaConfig.tiny(vocab=mc["vocab"], hidden=mc["hidden"],
-                           layers=mc["layers"], heads=mc["heads"],
-                           seq=mc["seq"])
-    m = LlamaForCausalLM(cfg)
-    m.eval()
-    return m
+    return _capture.tiny_model(_TRACE["model"])
 
 
 def _replay(scenario, scheduler=None):
-    """Drive a fresh engine through the scenario's scripted traffic
-    (same admission schedule as the capture script) and return the
-    per-request outputs in request-id order + the preemption count."""
-    sc = _TRACE["scenarios"][scenario]
-    eng = ServingEngine(_tiny_model(), decode_strategy="greedy_search",
-                        seed=0, scheduler=scheduler, **sc["engine"])
-    # the preemption counter lives in the process-wide default
-    # registry — other tests' engines share it, so count the DELTA
-    preempt0 = int(eng._m.preemptions.value)
-    if sc["withhold_pages"]:
-        eng._free_pages = eng._free_pages[:-sc["withhold_pages"]]
-    sampling_rows = set(sc["sampling_rows"])
-    rids, finished = [], {}
+    return _capture.replay(_TRACE["scenarios"][scenario], _tiny_model(),
+                           scheduler=scheduler)
 
-    def _add(i, p, b):
-        extra = {}
-        if i in sampling_rows:
-            extra = dict(decode_strategy="sampling", temperature=0.8,
-                         top_k=8, top_p=0.9)
-        rids.append(eng.add_request(np.asarray(p, np.int64),
-                                    max_new_tokens=b, **extra))
 
-    prompts, budgets = sc["prompts"], sc["budgets"]
-    for i in range(5):
-        _add(i, prompts[i], budgets[i])
-    steps = 0
-    late = list(range(5, len(prompts)))
-    while eng.has_work() and steps < 500:
-        for fin in eng.step():
-            finished[fin.request_id] = fin.output_ids.tolist()
-        steps += 1
-        if steps == 2 and late:
-            for i in late:
-                _add(i, prompts[i], budgets[i])
-            late = []
-    assert len(finished) == len(rids)
-    return [finished[r] for r in rids], \
-        int(eng._m.preemptions.value) - preempt0
+def test_golden_trace_was_recorded_under_this_jax():
+    import jax
+
+    assert _TRACE["jax_version"] == jax.__version__, (
+        "re-record with tools/capture_serving_golden_trace.py: the token "
+        "streams follow jax's random streams through the seeded weights")
 
 
 # marked per-scenario: single_step is the tier-1 canary; the rest ride

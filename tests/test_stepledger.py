@@ -277,11 +277,19 @@ class TestRoofline:
         table = sweep.load_device_peaks()
         assert table.PEAK_FLOPS_BF16 == dp.PEAK_FLOPS_BF16
         assert table.PEAK_HBM_BYTES_PER_S == dp.PEAK_HBM_BYTES_PER_S
-        # kind normalization: v5e must match before bare v5
+        # kind normalization: a generation matches only when the string
+        # names it — a bare "TPU v5" is not taken for v5p
         assert dp.normalize_kind("TPU v5 lite") == "v5e"
         assert dp.normalize_kind("TPU v5p") == "v5p"
+        assert dp.normalize_kind("TPU v5") is None
         assert dp.normalize_kind("TPU v4") == "v4"
         assert dp.normalize_kind("weird accelerator") is None
+        # measurement entry points: an unknown kind is an error, not a
+        # default peak (bench.py and chip_smoke.py go through this)
+        assert dp.require_kind("TPU v5 lite") == "v5e"
+        with pytest.raises(ValueError, match="not in the peak table"):
+            dp.require_kind("cpu")
+        assert "require_kind" in bench_src
 
     def test_autotune_ground_truth_rows(self, ledger_on, tmp_path,
                                         monkeypatch):

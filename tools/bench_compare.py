@@ -1,21 +1,19 @@
 """Bench regression gate: compare a fresh bench.py metric JSON against
-the banked baselines.
+earlier measured rows.
 
 Dependency-free (stdlib json only — runs before any framework import
-can fail). The fresh row is the compact JSON line bench.py prints
-last (pass the captured file, or `-` to read stdin and take the last
-parseable line). Baselines come from two sources, most-recent
-comparable row wins:
+can fail). The fresh row is the compact JSON line a measured (on-chip)
+bench.py run prints last (pass the captured file, or `-` to read stdin
+and take the last parseable line); a `--smoke` row carries no metric
+and is not a fresh row. Baselines, most-recent comparable row wins:
 
 - `BENCH_HISTORY.jsonl` — the append-only trajectory bench.py writes
-  one row per run (commit + date), so consecutive CI runs on the same
-  backend compare like for like;
-- `BENCH_TPU_CACHE.json` — the committed last-known-good captures
-  (on-chip rows plus the committed `smoke:cpu` CI anchor).
+  one row per measured run (commit + date);
+- `--baseline FILE` — optionally, a JSON object of further rows.
 
 Rows are comparable when metric AND backend AND geometry (batch / seq /
-hidden / layers, where both sides carry them) match — a CPU smoke run
-is never judged against an on-chip capture. Per-metric tolerances,
+hidden / layers, where both sides carry them) match — rows from
+different platforms never judge each other. Per-metric tolerances,
 direction-aware:
 
     value            default 10% (lower is a regression)
@@ -31,7 +29,7 @@ direction-aware:
                           (higher regresses — a compile-count jump is
                           the recompile-storm smell)
 
-    python tools/bench_compare.py --fresh /tmp/ci_bench_smoke.json
+    python tools/bench_compare.py --fresh chiprun_out/bench_row.json
     python tools/bench_compare.py --fresh - --tolerance 0.10 < out.txt
 
 Exit codes: 0 = within tolerance, 1 = regression beyond tolerance,
@@ -133,19 +131,21 @@ def load_fresh(path: str):
 
 
 def load_baselines(cache_path: str, history_path: str):
-    """Candidate baseline rows in source order (committed cache rows,
-    then the history trajectory); the gate re-orders the comparable
-    ones by their `date` field before taking the most recent."""
+    """Candidate baseline rows in source order (the optional
+    `--baseline` object's rows, then the history trajectory); the gate
+    re-orders the comparable ones by their `date` field before taking
+    the most recent."""
     rows = []
-    try:
-        with open(cache_path) as f:
-            cache = json.load(f)
-        for key in sorted(cache):
-            row = cache[key]
-            if isinstance(row, dict) and "metric" in row:
-                rows.append({**row, "_source": f"cache[{key}]"})
-    except (OSError, ValueError):
-        pass
+    if cache_path:
+        try:
+            with open(cache_path) as f:
+                cache = json.load(f)
+            for key in sorted(cache):
+                row = cache[key]
+                if isinstance(row, dict) and "metric" in row:
+                    rows.append({**row, "_source": f"cache[{key}]"})
+        except (OSError, ValueError):
+            pass
     try:
         with open(history_path) as f:
             for i, line in enumerate(f):
@@ -217,15 +217,14 @@ def main(argv=None) -> int:
     ap.add_argument("--fresh", required=True,
                     help="file holding the fresh compact JSON row "
                          "('-' = stdin, last parseable line)")
-    ap.add_argument("--baseline",
-                    default=os.path.join(REPO, "BENCH_TPU_CACHE.json"),
-                    help="committed last-known-good rows (default: "
-                         "BENCH_TPU_CACHE.json)")
+    ap.add_argument("--baseline", default="",
+                    help="optional JSON object {key: row} of further "
+                         "baseline rows (default: none)")
     ap.add_argument("--history",
                     default=os.path.join(REPO, "BENCH_HISTORY.jsonl"),
                     help="bench trajectory ledger (default: "
                          "BENCH_HISTORY.jsonl); most recent comparable "
-                         "row wins over the cache")
+                         "row wins")
     ap.add_argument("--tolerance", type=float, default=None,
                     help="widen the relative tolerance of the noisy "
                          "timing-derived metrics (value/mfu) to "
@@ -253,9 +252,9 @@ def main(argv=None) -> int:
               f"once to seed the history ledger", file=sys.stderr)
         return 2
     # most recent comparable row wins BY DATE (ISO-8601 UTC strings
-    # order lexicographically; stable sort keeps the cache→history
-    # source order for date-less or tied rows) — a re-banked cache row
-    # newer than the history tail must beat it, not lose on file order
+    # order lexicographically; stable sort keeps the baseline→history
+    # source order for date-less or tied rows) — a --baseline row newer
+    # than the history tail must beat it, not lose on file order
     baselines.sort(key=lambda b: str(b.get("date") or ""))
     base = baselines[-1]
     # bench.py appends the fresh run's own row to the history ledger
@@ -271,8 +270,7 @@ def main(argv=None) -> int:
         if len(baselines) < 2:
             print("bench_compare: the only comparable baseline is this "
                   "run's own history echo — the gate is unarmed until "
-                  "a prior run (or a committed anchor row) exists for "
-                  "this config", file=sys.stderr)
+                  "a prior run exists for this config", file=sys.stderr)
             return 2
         base = baselines[-2]
     rows = compare(fresh, base, tolerance=args.tolerance)

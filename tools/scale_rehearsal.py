@@ -6,7 +6,7 @@ pathologies and per-device HBM blowups on free CPU time instead of scarce
 chip time.
 
 Outputs one JSON line + SCALE_REHEARSAL.json with compile wall-times and
-XLA's per-device memory analysis; BASELINE.md's rehearsal table is
+XLA's per-device memory analysis; BASELINE.json's rehearsal table is
 maintained from those numbers.
 
 Memory strategy on this host (125 GB, no accelerator): params are

@@ -32,7 +32,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 jax.config.update("jax_platforms", "cpu")
@@ -59,9 +58,7 @@ def inner(lg, lb):
     return jax.lax.psum(jnp.sum(picked), "pp")
 
 
-# jax 0.4.37 has no top-level jax.shard_map (tpu-lint: jax-compat); the
-# experimental spelling names the AUTO axes ("pp" stays manual) — this
-# repro must stay runnable without importing paddle_tpu's adapter
-fn = shard_map(inner, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-               auto=frozenset({"dp", "tp"}), check_rep=False)
+# manual over "pp" only; "dp" and "tp" stay GSPMD-auto inside
+fn = jax.shard_map(inner, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+                   axis_names=frozenset({"pp"}), check_vma=False)
 print(MODE, "->", float(jax.jit(fn)(logits, labels)))
