@@ -1,0 +1,1 @@
+"""One window driver per traffic kind, found by the mix's `kind`."""
