@@ -1,0 +1,11 @@
+"""Share of the traced part in which the device was idle while the host was
+dispatching the eager per-layer page scatters after a prefill, and the re-
+pin (`serving.kv_scatter`). The five `idle_pct.*` sum to
+`device_idle_pct.serve`."""
+from benchmark import program_trace
+
+SPANS = ("serving.kv_scatter",)
+
+
+def read(trace, host, cell):
+    return program_trace.idle_pct(program_trace.current(trace), SPANS)

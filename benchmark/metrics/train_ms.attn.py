@@ -1,0 +1,13 @@
+"""Device time of one train step under the scope `attn` (attention: its
+LayerNorm, QKV, the paged gather or the scores, softmax, the output
+projection, the cache write, the residual add), forward, recompute and
+backward alike: self time of the step program's operations whose `op_name`
+carries it, per execution."""
+from benchmark import program_trace
+
+MODULE = r"pure_step"
+
+
+def read(trace, host, cell):
+    return program_trace.scope_ms(program_trace.current(trace), MODULE,
+                                  "attn")

@@ -775,6 +775,12 @@ class ServingEngine:
         return rid
 
     def _admit(self):
+        with _trace.phase("serving.admit"):
+            new = self._admit_slots()
+        if new:
+            self._prefill_batch(new)
+
+    def _admit_slots(self):
         # collect ALL admissible requests first, then prefill them in ONE
         # compiled batched call — admission no longer serializes at batch 1
         # (VERDICT round-1: per-request prefill dominates serving cost).
@@ -828,6 +834,7 @@ class ServingEngine:
             # one-shot: a preempted request re-enters _pending with its
             # original t_enq — re-observing would book its prior decode
             # time as "queue wait"
+            requeue = int(bool(rp is not None and rp.get("qw_seen")))
             if rp is not None and "t_enq" in rp \
                     and not rp.get("qw_seen"):
                 rp["qw_seen"] = True
@@ -836,6 +843,12 @@ class ServingEngine:
                 # observation alone forgets which request it was)
                 rp["queue_s"] = qw
                 self._m.queue_wait.observe(qw)
+            # the moment this request got its slot, on the phases'
+            # timeline; a re-admission after preemption says so and
+            # repeats the FIRST admission's wait
+            _trace.mark("serving.admitted", rid=rid,
+                        queued_us=int(1e6 * (rp or {}).get("queue_s", 0.0)),
+                        requeue=requeue)
             if rp is not None and n_promoted:
                 rp["tier_promoted"] = \
                     rp.get("tier_promoted", 0) + int(n_promoted)
@@ -903,8 +916,7 @@ class ServingEngine:
                                    prompt=len(ctx))
                     s.trace_id = tr.trace_id
         self._m.queue_depth.set(len(self._pending))
-        if new:
-            self._prefill_batch(new)
+        return new
 
     def warmup(self, prompt_len=None, sampling=None):
         """Pre-compile the serving programs BEFORE traffic: runs one
@@ -1359,103 +1371,114 @@ class ServingEngine:
         """new: list of (slot_idx, prompt_ids) — ONE compiled forward for
         all admitted prompts + ONE paged scatter per layer."""
         n = len(new)
-        t0_prefill = _time_mod.perf_counter() if self._traces else 0.0
-        # packing is the scheduler policy's call (default: next-pow2
-        # batch capped at max_batch, token bucket = next page multiple)
-        nb, bucket = self.scheduler.prefill_bucket(self, new)
-        # clamp against policy bugs: the batch must hold every prompt
-        # and the token bucket must page-align and cover the longest
-        nb = min(max(nb, n), self.max_batch)
-        longest = max(len(ids) for _, ids in new)
-        bucket = max(-(-bucket // self.page_size) * self.page_size,
-                     -(-longest // self.page_size) * self.page_size)
-        all_greedy = all(self.slots[si].greedy for si, _ in new)
-        fn = self._get_prefill_fn(nb, bucket, all_greedy)
-        params, buffers = self._cached_params()
-        padded = np.zeros((nb, bucket), np.int64)
-        true_lens = np.ones((nb,), np.int32)
-        greedy = np.ones((nb,), bool)
-        temp = np.ones((nb,), np.float32)
-        tk = np.zeros((nb,), np.int32)
-        tp_arr = np.ones((nb,), np.float32)
-        for row, (si, ids) in enumerate(new):
-            padded[row, :len(ids)] = ids
-            true_lens[row] = len(ids)
-            rp = self._req_params[self.slots[si].request_id]
-            greedy[row] = rp["greedy"]
-            temp[row] = rp["temperature"]
-            tk[row] = rp["top_k"]
-            tp_arr[row] = rp["top_p"]
-        self._key, sk = jax.random.split(self._key)
-        first, ks, vs = fn(params, buffers, jnp.asarray(padded),
-                           jnp.asarray(true_lens), jax.random.key_data(sk),
-                           jnp.asarray(greedy), jnp.asarray(temp),
-                           jnp.asarray(tk), jnp.asarray(tp_arr))
-        tables = jnp.asarray(np.stack(
-            [self.block_tables[si] for si, _ in new]))
-        lens = jnp.asarray(true_lens[:n], jnp.int32)
-        for li in range(len(self.k_pages)):
-            if self.k_scales is not None:
-                (self.k_pages[li], self.k_scales[li], self.v_pages[li],
-                 self.v_scales[li]) = _pa.prefill_paged_kv_cache_q8(
-                    self.k_pages[li], self.k_scales[li], self.v_pages[li],
-                    self.v_scales[li], ks[li][:n], vs[li][:n], tables, lens)
-            else:
-                self.k_pages[li], self.v_pages[li] = \
-                    _pa.prefill_paged_kv_cache(
-                        self.k_pages[li], self.v_pages[li],
-                        ks[li][:n], vs[li][:n], tables, lens)
-        if self._draft_model is not None:
-            # the separate draft model needs the prompt in ITS pages too
-            # (two-model speculative decoding prefills twice — the draft
-            # is small, that is the trade); its sampled token is ignored
-            fn_d = self._get_prefill_fn(nb, bucket, all_greedy,
-                                        which="draft")
-            dparams, dbuffers = self._cached_draft_params()
-            _f, dks, dvs = fn_d(dparams, dbuffers, jnp.asarray(padded),
-                                jnp.asarray(true_lens),
-                                jax.random.key_data(sk),
-                                jnp.asarray(greedy), jnp.asarray(temp),
-                                jnp.asarray(tk), jnp.asarray(tp_arr))
-            for li in range(len(self._draft_k_pages)):
-                if self._draft_k_scales is not None:
-                    (self._draft_k_pages[li], self._draft_k_scales[li],
-                     self._draft_v_pages[li],
-                     self._draft_v_scales[li]) = \
-                        _pa.prefill_paged_kv_cache_q8(
-                            self._draft_k_pages[li],
-                            self._draft_k_scales[li],
-                            self._draft_v_pages[li],
-                            self._draft_v_scales[li],
-                            dks[li][:n], dvs[li][:n], tables, lens)
-                else:
-                    self._draft_k_pages[li], self._draft_v_pages[li] = \
-                        _pa.prefill_paged_kv_cache(
-                            self._draft_k_pages[li],
-                            self._draft_v_pages[li],
-                            dks[li][:n], dvs[li][:n], tables, lens)
-        # re-pin: the eager scatter can drop the kv-head tp sharding, and
-        # the decode jit donates pages in this layout
-        self._pin_pages()
-        if self._prefix_cache is not None:
-            # cache the freshly prefilled FULL pages; the partial tail
-            # page never enters the trie (the copy-on-write guard —
-            # decode keeps appending to it exclusively)
-            for si, ids in new:
-                self._prefix_cache.insert(ids, self.block_tables[si])
-        first_np = np.asarray(first)  # [nb] ints — tiny transfer
-        for row, (si, _) in enumerate(new):
-            self.slots[si]._first_token = int(first_np[row])
-        if self._traces:
-            # ONE batched compiled prefill served every admitted prompt:
-            # each participating trace gets the shared interval with its
-            # bucket attrs (the span naming scheme's `prefill[bucket]`)
-            t1_prefill = _time_mod.perf_counter()
-            for _row, (si, ids) in enumerate(new):
-                tr = self._traces.get(self.slots[si].request_id)
-                if tr is not None:
-                    tr.emit("serving.prefill", t0_prefill, t1_prefill,
-                            bucket=bucket, nb=nb, prompt_len=len(ids))
+        with _trace.phase("serving.prefill_batch"):
+            t0_prefill = _time_mod.perf_counter() if self._traces else 0.0
+            # packing is the scheduler policy's call (default: next-pow2
+            # batch capped at max_batch, token bucket = next page multiple)
+            nb, bucket = self.scheduler.prefill_bucket(self, new)
+            # clamp against policy bugs: the batch must hold every prompt
+            # and the token bucket must page-align and cover the longest
+            nb = min(max(nb, n), self.max_batch)
+            longest = max(len(ids) for _, ids in new)
+            bucket = max(-(-bucket // self.page_size) * self.page_size,
+                         -(-longest // self.page_size) * self.page_size)
+            all_greedy = all(self.slots[si].greedy for si, _ in new)
+            with _trace.phase("serving.prefill.launch"):
+                fn = self._get_prefill_fn(nb, bucket, all_greedy)
+                params, buffers = self._cached_params()
+                padded = np.zeros((nb, bucket), np.int64)
+                true_lens = np.ones((nb,), np.int32)
+                greedy = np.ones((nb,), bool)
+                temp = np.ones((nb,), np.float32)
+                tk = np.zeros((nb,), np.int32)
+                tp_arr = np.ones((nb,), np.float32)
+                for row, (si, ids) in enumerate(new):
+                    padded[row, :len(ids)] = ids
+                    true_lens[row] = len(ids)
+                    rp = self._req_params[self.slots[si].request_id]
+                    greedy[row] = rp["greedy"]
+                    temp[row] = rp["temperature"]
+                    tk[row] = rp["top_k"]
+                    tp_arr[row] = rp["top_p"]
+                self._key, sk = jax.random.split(self._key)
+                first, ks, vs = fn(
+                    params, buffers, jnp.asarray(padded),
+                    jnp.asarray(true_lens), jax.random.key_data(sk),
+                    jnp.asarray(greedy), jnp.asarray(temp),
+                    jnp.asarray(tk), jnp.asarray(tp_arr))
+            # the eager per-layer scatter (and, with a separate draft model,
+            # its own prefill call and scatter) plus the re-pin
+            with _trace.phase("serving.kv_scatter"):
+                tables = jnp.asarray(np.stack(
+                    [self.block_tables[si] for si, _ in new]))
+                lens = jnp.asarray(true_lens[:n], jnp.int32)
+                for li in range(len(self.k_pages)):
+                    if self.k_scales is not None:
+                        (self.k_pages[li], self.k_scales[li],
+                         self.v_pages[li], self.v_scales[li]) = \
+                            _pa.prefill_paged_kv_cache_q8(
+                                self.k_pages[li], self.k_scales[li],
+                                self.v_pages[li], self.v_scales[li],
+                                ks[li][:n], vs[li][:n], tables, lens)
+                    else:
+                        self.k_pages[li], self.v_pages[li] = \
+                            _pa.prefill_paged_kv_cache(
+                                self.k_pages[li], self.v_pages[li],
+                                ks[li][:n], vs[li][:n], tables, lens)
+                if self._draft_model is not None:
+                    # the separate draft model needs the prompt in ITS
+                    # pages too (two-model speculative decoding prefills
+                    # twice — the draft is small, that is the trade); its
+                    # sampled token is ignored
+                    fn_d = self._get_prefill_fn(nb, bucket, all_greedy,
+                                                which="draft")
+                    dparams, dbuffers = self._cached_draft_params()
+                    _f, dks, dvs = fn_d(dparams, dbuffers, jnp.asarray(padded),
+                                        jnp.asarray(true_lens),
+                                        jax.random.key_data(sk),
+                                        jnp.asarray(greedy), jnp.asarray(temp),
+                                        jnp.asarray(tk), jnp.asarray(tp_arr))
+                    for li in range(len(self._draft_k_pages)):
+                        if self._draft_k_scales is not None:
+                            (self._draft_k_pages[li], self._draft_k_scales[li],
+                             self._draft_v_pages[li],
+                             self._draft_v_scales[li]) = \
+                                _pa.prefill_paged_kv_cache_q8(
+                                    self._draft_k_pages[li],
+                                    self._draft_k_scales[li],
+                                    self._draft_v_pages[li],
+                                    self._draft_v_scales[li],
+                                    dks[li][:n], dvs[li][:n], tables, lens)
+                        else:
+                            (self._draft_k_pages[li],
+                             self._draft_v_pages[li]) = \
+                                _pa.prefill_paged_kv_cache(
+                                    self._draft_k_pages[li],
+                                    self._draft_v_pages[li],
+                                    dks[li][:n], dvs[li][:n], tables, lens)
+                # re-pin: the eager scatter can drop the kv-head tp
+                # sharding, and the decode jit donates pages in this layout
+                self._pin_pages()
+            if self._prefix_cache is not None:
+                # cache the freshly prefilled FULL pages; the partial tail
+                # page never enters the trie (the copy-on-write guard —
+                # decode keeps appending to it exclusively)
+                for si, ids in new:
+                    self._prefix_cache.insert(ids, self.block_tables[si])
+            with _trace.phase("serving.prefill.sync"):
+                first_np = np.asarray(first)  # [nb] ints — tiny transfer
+            for row, (si, _) in enumerate(new):
+                self.slots[si]._first_token = int(first_np[row])
+            if self._traces:
+                # ONE batched compiled prefill served every admitted prompt:
+                # each participating trace gets the shared interval with its
+                # bucket attrs (the span naming scheme's `prefill[bucket]`)
+                t1_prefill = _time_mod.perf_counter()
+                for _row, (si, ids) in enumerate(new):
+                    tr = self._traces.get(self.slots[si].request_id)
+                    if tr is not None:
+                        tr.emit("serving.prefill", t0_prefill, t1_prefill,
+                                bucket=bucket, nb=nb, prompt_len=len(ids))
 
     # ------------------------------------------------------------------
     # chunked prefill: the uncached suffix streams through the model's
@@ -1885,58 +1908,59 @@ class ServingEngine:
         draft_fn = self._get_spec_draft_fn(n_scan)
         verify_fn = self._get_spec_verify_fn(window)
         Ld = self.spec_draft_layers if shallow else None
-        try:
-            # arg prep inside the try: transfer-time OOM must reach the
-            # forensics + preempt-retry path (same rule as burst/decode)
-            tok_dev = jnp.asarray(tokens)
-            tables_dev = jnp.asarray(self.block_tables)
-            lens_dev = jnp.asarray(lens)
-            act_dev = jnp.asarray(act_mask)
-            lim_dev = jnp.asarray(limit)
-            if shallow:
-                draft_args = (
-                    params, buffers, tuple(self.k_pages[:Ld]),
-                    tuple(self.v_pages[:Ld]),
-                    tuple((self.k_scales or [])[:Ld]),
-                    tuple((self.v_scales or [])[:Ld]),
-                    tok_dev, tables_dev, lens_dev, act_dev, lim_dev)
-            else:
-                dparams, dbuffers = self._cached_draft_params()
-                draft_args = (
-                    dparams, dbuffers, tuple(self._draft_k_pages),
-                    tuple(self._draft_v_pages),
-                    tuple(self._draft_k_scales or ()),
-                    tuple(self._draft_v_scales or ()),
-                    tok_dev, tables_dev, lens_dev, act_dev, lim_dev)
-            drafts, dk, dv, dks, dvs = draft_fn(*draft_args)
-            # re-point the drafted pools at the live buffers BEFORE the
-            # verify dispatch donates the engine's page lists again
-            if shallow:
-                self.k_pages[:Ld] = list(dk)
-                self.v_pages[:Ld] = list(dv)
-                if self.k_scales is not None:
-                    self.k_scales[:Ld] = list(dks)
-                    self.v_scales[:Ld] = list(dvs)
-            else:
-                self._draft_k_pages = list(dk)
-                self._draft_v_pages = list(dv)
-                if self._draft_k_scales is not None:
-                    self._draft_k_scales = list(dks)
-                    self._draft_v_scales = list(dvs)
-            verify_args = (
-                params, buffers, tuple(self.k_pages),
-                tuple(self.v_pages), tuple(self.k_scales or ()),
-                tuple(self.v_scales or ()), tok_dev, drafts,
-                tables_dev, lens_dev, act_dev, lim_dev)
-            g, nk, nv, nks, nvs = verify_fn(*verify_args)
-        except BaseException as e:
-            if _memwatch.is_oom(e) and \
-                    self._handle_decode_oom(e, "spec_decode"):
-                return None
-            self._poison_if_donated(
-                "spec decode fn raised after donating the KV pages",
-                self.k_pages, self.v_pages)
-            raise
+        with _trace.phase("serving.decode.launch"):
+            try:
+                # arg prep inside the try: transfer-time OOM must reach the
+                # forensics + preempt-retry path (same rule as burst/decode)
+                tok_dev = jnp.asarray(tokens)
+                tables_dev = jnp.asarray(self.block_tables)
+                lens_dev = jnp.asarray(lens)
+                act_dev = jnp.asarray(act_mask)
+                lim_dev = jnp.asarray(limit)
+                if shallow:
+                    draft_args = (
+                        params, buffers, tuple(self.k_pages[:Ld]),
+                        tuple(self.v_pages[:Ld]),
+                        tuple((self.k_scales or [])[:Ld]),
+                        tuple((self.v_scales or [])[:Ld]),
+                        tok_dev, tables_dev, lens_dev, act_dev, lim_dev)
+                else:
+                    dparams, dbuffers = self._cached_draft_params()
+                    draft_args = (
+                        dparams, dbuffers, tuple(self._draft_k_pages),
+                        tuple(self._draft_v_pages),
+                        tuple(self._draft_k_scales or ()),
+                        tuple(self._draft_v_scales or ()),
+                        tok_dev, tables_dev, lens_dev, act_dev, lim_dev)
+                drafts, dk, dv, dks, dvs = draft_fn(*draft_args)
+                # re-point the drafted pools at the live buffers BEFORE the
+                # verify dispatch donates the engine's page lists again
+                if shallow:
+                    self.k_pages[:Ld] = list(dk)
+                    self.v_pages[:Ld] = list(dv)
+                    if self.k_scales is not None:
+                        self.k_scales[:Ld] = list(dks)
+                        self.v_scales[:Ld] = list(dvs)
+                else:
+                    self._draft_k_pages = list(dk)
+                    self._draft_v_pages = list(dv)
+                    if self._draft_k_scales is not None:
+                        self._draft_k_scales = list(dks)
+                        self._draft_v_scales = list(dvs)
+                verify_args = (
+                    params, buffers, tuple(self.k_pages),
+                    tuple(self.v_pages), tuple(self.k_scales or ()),
+                    tuple(self.v_scales or ()), tok_dev, drafts,
+                    tables_dev, lens_dev, act_dev, lim_dev)
+                g, nk, nv, nks, nvs = verify_fn(*verify_args)
+            except BaseException as e:
+                if _memwatch.is_oom(e) and \
+                        self._handle_decode_oom(e, "spec_decode"):
+                    return None
+                self._poison_if_donated(
+                    "spec decode fn raised after donating the KV pages",
+                    self.k_pages, self.v_pages)
+                raise
         if led is not None:
             # the verify program dominates the round's device time —
             # register ITS cost for the roofline; the draft rides in the
@@ -1950,8 +1974,10 @@ class ServingEngine:
         self.k_pages, self.v_pages = list(nk), list(nv)
         if self.k_scales is not None:
             self.k_scales, self.v_scales = list(nks), list(nvs)
-        finished = self._commit_spec(np.asarray(drafts), np.asarray(g),
-                                     active, window)
+        with _trace.phase("serving.decode.sync"):
+            drafts, g = np.asarray(drafts), np.asarray(g)
+        with _trace.phase("serving.emit"):
+            finished = self._commit_spec(drafts, g, active, window)
         self._step_metrics(t0, len(active), tok0)
         return finished
 
@@ -2440,7 +2466,8 @@ class ServingEngine:
         pf = [i for i, s in enumerate(self.slots)
               if s.active and s.prefilling]
         if pf:
-            self._prefill_chunk_round(pf)
+            with _trace.phase("serving.prefill_batch"):
+                self._prefill_chunk_round(pf)
         # prefilling slots are excluded from decode (their context is
         # partial and they have no last token yet)
         active = [i for i, s in enumerate(self.slots)
@@ -2452,45 +2479,51 @@ class ServingEngine:
         tokens = np.zeros((self.max_batch,), np.int64)
         first_done = []
         now = _time_mod.perf_counter()
-        for i, s in enumerate(self.slots):
-            if not s.active or s.prefilling:
-                continue  # mid-chunked-prefill: no last token yet
-            if s.needs_first_sample:
-                s.needs_first_sample = False
-                s.tokens.append(s._first_token)
-                rp = self._req_params.get(s.request_id)
-                # popping t_enq makes TTFT one-shot: a request preempted
-                # AFTER its first token re-prefills (needs_first_sample
-                # fires again) but must not record a second "TTFT"; one
-                # preempted BEFORE it still records the true
-                # enqueue-to-first-token time, preemption delay included
-                if rp is not None and "t_enq" in rp:
-                    ttft = now - rp.pop("t_enq")
-                    rp["ttft_s"] = ttft  # retained for the ledger
-                    ex = None
+        # the first tokens' commit (callbacks, a finish on the first
+        # token) is an emit phase of its own; a step that has none opens
+        # nothing here
+        with (_trace.phase("serving.emit")
+              if any(self.slots[i].needs_first_sample for i in active)
+              else _trace.NOOP_SPAN):
+            for i, s in enumerate(self.slots):
+                if not s.active or s.prefilling:
+                    continue  # mid-chunked-prefill: no last token yet
+                if s.needs_first_sample:
+                    s.needs_first_sample = False
+                    s.tokens.append(s._first_token)
+                    rp = self._req_params.get(s.request_id)
+                    # popping t_enq makes TTFT one-shot: a request preempted
+                    # AFTER its first token re-prefills (needs_first_sample
+                    # fires again) but must not record a second "TTFT"; one
+                    # preempted BEFORE it still records the true
+                    # enqueue-to-first-token time, preemption delay included
+                    if rp is not None and "t_enq" in rp:
+                        ttft = now - rp.pop("t_enq")
+                        rp["ttft_s"] = ttft  # retained for the ledger
+                        ex = None
+                        if self._traces:
+                            tr0 = self._traces.get(s.request_id)
+                            if tr0 is not None and \
+                                    tr0.trace_id is not None:
+                                # OpenMetrics exemplar: this observation's
+                                # trace_id, so a TTFT outlier in /metrics
+                                # links straight to its distributed trace
+                                ex = {"trace_id": f"{tr0.trace_id:x}"}
+                        self._m.ttft.observe(ttft, exemplar=ex)
                     if self._traces:
-                        tr0 = self._traces.get(s.request_id)
-                        if tr0 is not None and \
-                                tr0.trace_id is not None:
-                            # OpenMetrics exemplar: this observation's
-                            # trace_id, so a TTFT outlier in /metrics
-                            # links straight to its distributed trace
-                            ex = {"trace_id": f"{tr0.trace_id:x}"}
-                    self._m.ttft.observe(ttft, exemplar=ex)
-                if self._traces:
-                    tr = self._traces.get(s.request_id)
-                    if tr is not None:
-                        tr.instant("serving.first_token")
-                self._stream(s.request_id, s._first_token)
-                eos = self._req_eos(s.request_id)
-                if (eos is not None and s.tokens[-1] == eos) or \
-                        len(s.tokens) >= s.max_new_tokens:
-                    first_done.append(i)
-            tokens[i] = s.tokens[-1]
-        for i in first_done:
-            # request finished on its very first token; never decode it
-            active = [j for j in active if j != i]
-        finished_early = [self._finish(i) for i in first_done]
+                        tr = self._traces.get(s.request_id)
+                        if tr is not None:
+                            tr.instant("serving.first_token")
+                    self._stream(s.request_id, s._first_token)
+                    eos = self._req_eos(s.request_id)
+                    if (eos is not None and s.tokens[-1] == eos) or \
+                            len(s.tokens) >= s.max_new_tokens:
+                        first_done.append(i)
+                tokens[i] = s.tokens[-1]
+            for i in first_done:
+                # request finished on its very first token; never decode it
+                active = [j for j in active if j != i]
+            finished_early = [self._finish(i) for i in first_done]
         if not active:
             if finished_early:
                 self._admit()
@@ -2509,58 +2542,125 @@ class ServingEngine:
         # slot) before the engine poisons — the launch state is rebuilt
         # from the surviving slots and the dispatch retried.
         while True:
-            rem_of = self._rem_of(active)
-            # speculative rounds replace the burst path when eligible
-            # (all-greedy batch with more than one token of budget)
-            spec_w = self._spec_window(active, rem_of)
-            # scan length is the scheduler policy's call (default
-            # buckets to {1, decode_burst}); clamp to sizes the engine
-            # compiles programs for
-            k_burst = int(self.scheduler.burst_k(self, active, rem_of))
-            k_burst = self.decode_burst if k_burst > 1 else 1
-            # on-demand page growth for the positions this step writes
-            # (one per single step, up to min(burst, remaining) for a
-            # burst, up to min(window, remaining) for a spec round);
-            # pool exhaustion preempts the youngest slot (recompute
-            # policy) and retries, so the oldest slots always make
-            # progress
-            reserve = spec_w if spec_w else k_burst
-            while True:
-                stalled = [i for i in active if not self._ensure_pages(
-                    i, min(reserve, rem_of[i]))]
-                if not stalled:
-                    break
-                victim = self.scheduler.select_victim(
-                    self, stalled, "page_stall")
-                self._preempt(victim)
-                active = [j for j in active if j != victim]
-                if not active:
-                    return finished_early
-            st = self._decode_launch_state(active)
-            if _faults.enabled():
-                # deterministic chaos (faults/chaos.py): rank.kill dies
-                # HARD mid-serve (the kv-fabric drill proves the router
-                # loses zero requests when a worker vanishes); an
-                # injected decode OOM takes the SAME handler as an
-                # organic RESOURCE_EXHAUSTED from the compiled call;
-                # rank.slow sleeps the decode step, turning this rank
-                # into a straggler the anomaly detectors must catch
-                _faults.maybe_kill()
-                _faults.maybe_slow()
-                try:
-                    _faults.maybe_decode_oom()
-                except BaseException as e:
-                    if _memwatch.is_oom(e) and \
-                            self._handle_decode_oom(e, "decode"):
-                        active = [i for i in active
-                                  if self.slots[i].active]
-                        if not active:
-                            return finished_early
-                        continue
-                    raise
+            # everything from page growth to the compiled call is ONE
+            # launch phase; a retry round (`continue`) opens another
+            with _trace.phase("serving.decode.launch"):
+                rem_of = self._rem_of(active)
+                # speculative rounds replace the burst path when eligible
+                # (all-greedy batch with more than one token of budget)
+                spec_w = self._spec_window(active, rem_of)
+                # scan length is the scheduler policy's call (default
+                # buckets to {1, decode_burst}); clamp to sizes the engine
+                # compiles programs for
+                k_burst = int(self.scheduler.burst_k(self, active, rem_of))
+                k_burst = self.decode_burst if k_burst > 1 else 1
+                # on-demand page growth for the positions this step writes
+                # (one per single step, up to min(burst, remaining) for a
+                # burst, up to min(window, remaining) for a spec round);
+                # pool exhaustion preempts the youngest slot (recompute
+                # policy) and retries, so the oldest slots always make
+                # progress
+                reserve = spec_w if spec_w else k_burst
+                while True:
+                    stalled = [i for i in active
+                               if not self._ensure_pages(
+                                   i, min(reserve, rem_of[i]))]
+                    if not stalled:
+                        break
+                    victim = self.scheduler.select_victim(
+                        self, stalled, "page_stall")
+                    self._preempt(victim)
+                    active = [j for j in active if j != victim]
+                    if not active:
+                        return finished_early
+                st = self._decode_launch_state(active)
+                if _faults.enabled():
+                    # deterministic chaos (faults/chaos.py): rank.kill dies
+                    # HARD mid-serve (the kv-fabric drill proves the router
+                    # loses zero requests when a worker vanishes); an
+                    # injected decode OOM takes the SAME handler as an
+                    # organic RESOURCE_EXHAUSTED from the compiled call;
+                    # rank.slow sleeps the decode step, turning this rank
+                    # into a straggler the anomaly detectors must catch
+                    _faults.maybe_kill()
+                    _faults.maybe_slow()
+                    try:
+                        _faults.maybe_decode_oom()
+                    except BaseException as e:
+                        if _memwatch.is_oom(e) and \
+                                self._handle_decode_oom(e, "decode"):
+                            active = [i for i in active
+                                      if self.slots[i].active]
+                            if not active:
+                                return finished_early
+                            continue
+                        raise
+                if not spec_w:
+                    all_greedy = st["all_greedy"]
+                    lens, act_mask = st["lens"], st["act_mask"]
+                    greedy, temp, tk, tp_arr = (st["greedy"], st["temp"],
+                                                st["tk"], st["tp"])
+                    self._key, sk = jax.random.split(self._key)
+                    params, buffers = self._cached_params()
+                    t0 = _time_mod.perf_counter()
+                    tok0 = self._m.tokens.value
+                    if self._traces:
+                        # the per-request aggregate decode span runs from
+                        # the first dispatch that includes the slot to its
+                        # finish
+                        for i in active:
+                            tr = self._traces.get(
+                                self.slots[i].request_id)
+                            if tr is not None \
+                                    and "decode_t0" not in tr.marks:
+                                tr.mark("decode_t0", t0)
+                    # step-time ledger (one flag read when off): open the
+                    # measured dispatch window for this decode step
+                    led = _stepledger.begin()
+                    burst = k_burst > 1
+                    fn = self._get_burst_fn(all_greedy, k_burst) if burst \
+                        else self._get_decode_fn(all_greedy)
+                    try:
+                        # arg prep stays INSIDE the try: the host->device
+                        # transfers can themselves raise RESOURCE_EXHAUSTED
+                        # near the HBM ceiling, and that must reach the
+                        # same forensics + preempt-retry path as the call
+                        args = (
+                            params, buffers, tuple(self.k_pages),
+                            tuple(self.v_pages),
+                            tuple(self.k_scales or ()),
+                            tuple(self.v_scales or ()),
+                            jnp.asarray(tokens),
+                            jnp.asarray(self.block_tables),
+                            jnp.asarray(lens), jnp.asarray(act_mask))
+                        if burst:
+                            args += (jnp.asarray(st["rem"]),
+                                     jnp.asarray(st["eos"]))
+                        args += (jax.random.key_data(sk),
+                                 jnp.asarray(greedy), jnp.asarray(temp),
+                                 jnp.asarray(tk), jnp.asarray(tp_arr))
+                        if burst:
+                            (toks, emits, nk, nv, nks, nvs, *_carry) = \
+                                fn(*args)
+                        else:
+                            toks, nk, nv, nks, nvs = fn(*args)
+                    except BaseException as e:
+                        if _memwatch.is_oom(e) and self._handle_decode_oom(
+                                e, "burst_decode" if burst else "decode"):
+                            active = [i for i in active
+                                      if self.slots[i].active]
+                            if not active:
+                                return finished_early
+                            continue
+                        self._poison_if_donated(
+                            ("burst decode" if burst else "decode")
+                            + " fn raised after donating the KV pages",
+                            self.k_pages, self.v_pages)
+                        raise
             if spec_w:
-                tokens_np = tokens  # the [max_batch] last-token array
-                got = self._dispatch_spec(spec_w, active, st, tokens_np)
+                # the speculative round has launch, sync and emit phases
+                # of its own
+                got = self._dispatch_spec(spec_w, active, st, tokens)
                 if got is None:
                     # OOM preemption round: rebuild the launch state
                     # from the surviving slots and retry the dispatch
@@ -2572,141 +2672,49 @@ class ServingEngine:
                 if finished:
                     self._admit()
                 return finished
-            all_greedy = st["all_greedy"]
-            lens, act_mask = st["lens"], st["act_mask"]
-            greedy, temp, tk, tp_arr = (st["greedy"], st["temp"],
-                                        st["tk"], st["tp"])
-            self._key, sk = jax.random.split(self._key)
-            params, buffers = self._cached_params()
-            t0 = _time_mod.perf_counter()
-            tok0 = self._m.tokens.value
-            if self._traces:
-                # the per-request aggregate decode span runs from the
-                # first dispatch that includes the slot to its finish
-                for i in active:
-                    tr = self._traces.get(self.slots[i].request_id)
-                    if tr is not None and "decode_t0" not in tr.marks:
-                        tr.mark("decode_t0", t0)
-            # step-time ledger (one flag read when off): open the
-            # measured dispatch window for this decode step
-            led = _stepledger.begin()
-            if k_burst > 1:
-                fn = self._get_burst_fn(all_greedy, k_burst)
-                try:
-                    # arg prep stays INSIDE the try: the host->device
-                    # transfers can themselves raise RESOURCE_EXHAUSTED
-                    # near the HBM ceiling, and that must reach the
-                    # same forensics + preempt-retry path as the call
-                    burst_args = (
-                        params, buffers, tuple(self.k_pages),
-                        tuple(self.v_pages),
-                        tuple(self.k_scales or ()),
-                        tuple(self.v_scales or ()),
-                        jnp.asarray(tokens),
-                        jnp.asarray(self.block_tables),
-                        jnp.asarray(lens), jnp.asarray(act_mask),
-                        jnp.asarray(st["rem"]), jnp.asarray(st["eos"]),
-                        jax.random.key_data(sk),
-                        jnp.asarray(greedy), jnp.asarray(temp),
-                        jnp.asarray(tk), jnp.asarray(tp_arr))
-                    (toks, emits, nk, nv, nks, nvs, *_carry) = \
-                        fn(*burst_args)
-                except BaseException as e:
-                    if _memwatch.is_oom(e) and \
-                            self._handle_decode_oom(e, "burst_decode"):
-                        active = [i for i in active
-                                  if self.slots[i].active]
-                        if not active:
-                            return finished_early
-                        continue
-                    self._poison_if_donated(
-                        "burst decode fn raised after donating the KV "
-                        "pages", self.k_pages, self.v_pages)
-                    raise
-                if led is not None:
-                    # blocked window + bucket attribution; cost
-                    # registration lowers on ShapeDtypeStructs (safe
-                    # post-donation), once per process under the flag
-                    _stepledger.end(led, "serving.decode_burst",
-                                    _time_mod.perf_counter(),
-                                    out=(nk, nv, toks))
-                    _stepledger.register_from_lowered(
-                        "serving.decode_burst", fn, burst_args,
-                        quant=self._quant_algo,
-                        quant_bytes_delta=(
-                            self._quant_bytes_correction() * k_burst))
-                self.k_pages, self.v_pages = list(nk), list(nv)
-                if self.k_scales is not None:
-                    self.k_scales, self.v_scales = list(nks), list(nvs)
-                finished = finished_early
-                # intentional sync: the burst's tokens must reach the
-                # host to be emitted/stream-called — this is the one
-                # read per burst, not a stray transfer
-                finished.extend(self._replay_burst(
-                    np.asarray(toks), np.asarray(emits),  # tpu-lint: disable=sync-transfer-in-step-loop
-                    active))
-                self._step_metrics(t0, len(active), tok0)
-                if finished:
-                    self._admit()
-                return finished
-            fn = self._get_decode_fn(all_greedy)
-            try:
-                # arg prep inside the try for the same reason as the
-                # burst path: transfer-time OOM must hit the
-                # forensics + preempt-retry handler, not escape it
-                decode_args = (
-                    params, buffers, tuple(self.k_pages),
-                    tuple(self.v_pages),
-                    tuple(self.k_scales or ()),
-                    tuple(self.v_scales or ()),
-                    jnp.asarray(tokens), jnp.asarray(self.block_tables),
-                    jnp.asarray(lens), jnp.asarray(act_mask),
-                    jax.random.key_data(sk), jnp.asarray(greedy),
-                    jnp.asarray(temp), jnp.asarray(tk),
-                    jnp.asarray(tp_arr))
-                nxt, nk, nv, nks, nvs = fn(*decode_args)
-            except BaseException as e:
-                if _memwatch.is_oom(e) and \
-                        self._handle_decode_oom(e, "decode"):
-                    active = [i for i in active if self.slots[i].active]
-                    if not active:
-                        return finished_early
-                    continue
-                self._poison_if_donated(
-                    "decode fn raised after donating the KV pages",
-                    self.k_pages, self.v_pages)
-                raise
-            if led is not None:
-                _stepledger.end(led, "serving.decode_step",
-                                _time_mod.perf_counter(),
-                                out=(nk, nv, nxt))
-                _stepledger.register_from_lowered(
-                    "serving.decode_step", fn, decode_args,
-                    quant=self._quant_algo,
-                    quant_bytes_delta=self._quant_bytes_correction())
             break
+        if led is not None:
+            # blocked window + bucket attribution; cost registration
+            # lowers on ShapeDtypeStructs (safe post-donation), once per
+            # process under the flag
+            name = "serving.decode_burst" if burst else "serving.decode_step"
+            _stepledger.end(led, name, _time_mod.perf_counter(),
+                            out=(nk, nv, toks))
+            _stepledger.register_from_lowered(
+                name, fn, args, quant=self._quant_algo,
+                quant_bytes_delta=self._quant_bytes_correction()
+                * (k_burst if burst else 1))
         self.k_pages, self.v_pages = list(nk), list(nv)
         if self.k_scales is not None:
             self.k_scales, self.v_scales = list(nks), list(nvs)
-        # intentional sync: the sampled token must reach the host to be
-        # appended/streamed — the one per-step read
-        nxt = np.asarray(nxt)  # tpu-lint: disable=sync-transfer-in-step-loop
+        # intentional sync: the sampled tokens must reach the host to be
+        # appended/streamed — the one read per burst or step, not a stray
+        # transfer
+        with _trace.phase("serving.decode.sync"):
+            toks = np.asarray(toks)  # tpu-lint: disable=sync-transfer-in-step-loop
+            if burst:
+                emits = np.asarray(emits)  # tpu-lint: disable=sync-transfer-in-step-loop
         finished = finished_early
-        for i in active:
-            s = self.slots[i]
-            if not s.active:
-                continue  # abort()ed from an on_token callback this step
-            s.context_len += 1  # the token we just fed is now cached
-            s.tokens.append(int(nxt[i]))
-            self._stream(s.request_id, s.tokens[-1])
-            if not s.active:
-                continue  # the callback above aborted THIS request
-            # finish at append time (slots at max_new never re-enter decode;
-            # add_request guarantees context_len stays <= max_seq_len)
-            eos = self._req_eos(s.request_id)
-            if len(s.tokens) >= s.max_new_tokens or (
-                    eos is not None and s.tokens[-1] == eos):
-                finished.append(self._finish(i))
+        with _trace.phase("serving.emit"):
+            if burst:
+                finished.extend(self._replay_burst(toks, emits, active))
+            else:
+                for i in active:
+                    s = self.slots[i]
+                    if not s.active:
+                        continue  # abort()ed from an on_token callback
+                    s.context_len += 1  # the token just fed is now cached
+                    s.tokens.append(int(toks[i]))
+                    self._stream(s.request_id, s.tokens[-1])
+                    if not s.active:
+                        continue  # the callback above aborted THIS request
+                    # finish at append time (slots at max_new never
+                    # re-enter decode; add_request guarantees context_len
+                    # stays <= max_seq_len)
+                    eos = self._req_eos(s.request_id)
+                    if len(s.tokens) >= s.max_new_tokens or (
+                            eos is not None and s.tokens[-1] == eos):
+                        finished.append(self._finish(i))
         self._step_metrics(t0, len(active), tok0)
         if finished:
             self._admit()
@@ -2716,44 +2724,39 @@ class ServingEngine:
         """Per-step telemetry close-out: ZERO registry allocations —
         handle attribute reads + float ops only (the overhead guard test
         pins this)."""
-        t1 = _time_mod.perf_counter()
-        dt = t1 - t0
-        n_tok = self._m.tokens.value - tok0
-        ex = None
-        if self._traces:
-            # decode-step exemplar: one traced rider of this batched
-            # step (tracing off => self._traces empty => no alloc, the
-            # overhead guard's zero-registry-allocation path)
-            for s in self.slots:
-                if s.active and s.trace_id != -1:
-                    ex = {"trace_id": f"{s.trace_id:x}"}
-                    break
-        self._m.step_lat.observe(dt, exemplar=ex)
-        self._m.token_lat.observe(dt / n_tok if n_tok > 0 else dt,
-                                  exemplar=ex)
-        self._m.occupancy.set(n_active / self.max_batch)
-        self._m.page_util.set(
-            1.0 - len(self._free_pages) / self._n_pages_total)
-        if self._traces:
-            # engine-timeline step span (thread track, not per-request):
-            # step granularity for the viewer without duplicating the
-            # interval across every active request's track
-            _trace.emit("serving.decode_step", t0, t1, active=n_active,
-                        tokens=n_tok)
-        _flight.record_event("serving.step", active=n_active,
-                             tokens=n_tok, seconds=round(dt, 6))
-        _flight.beat_all()
-        # memwatch channel (one flag read when off): KV pool occupancy/
-        # fragmentation histograms + an HBM watermark sample
-        if _memwatch.enabled():
-            self._observe_memory()
-        # fleet heartbeat (rank shard liveness; also lazily boots the
-        # live HTTP plane — fleet.heartbeat is the ONE ensure_server
-        # call site) + SLO window snapshot: flag reads only when
-        # FLAGS_telemetry_port/_dir are unset (the off-path alloc
-        # guard pins zero allocations per step)
-        _fleet.heartbeat()
-        _slo.tick()
+        with _trace.phase("serving.close"):
+            t1 = _time_mod.perf_counter()
+            dt = t1 - t0
+            n_tok = self._m.tokens.value - tok0
+            ex = None
+            if self._traces:
+                # decode-step exemplar: one traced rider of this batched
+                # step (tracing off => self._traces empty => no alloc, the
+                # overhead guard's zero-registry-allocation path)
+                for s in self.slots:
+                    if s.active and s.trace_id != -1:
+                        ex = {"trace_id": f"{s.trace_id:x}"}
+                        break
+            self._m.step_lat.observe(dt, exemplar=ex)
+            self._m.token_lat.observe(dt / n_tok if n_tok > 0 else dt,
+                                      exemplar=ex)
+            self._m.occupancy.set(n_active / self.max_batch)
+            self._m.page_util.set(
+                1.0 - len(self._free_pages) / self._n_pages_total)
+            _flight.record_event("serving.step", active=n_active,
+                                 tokens=n_tok, seconds=round(dt, 6))
+            _flight.beat_all()
+            # memwatch channel (one flag read when off): KV pool
+            # occupancy/fragmentation histograms + an HBM watermark sample
+            if _memwatch.enabled():
+                self._observe_memory()
+            # fleet heartbeat (rank shard liveness; also lazily boots the
+            # live HTTP plane — fleet.heartbeat is the ONE ensure_server
+            # call site) + SLO window snapshot: flag reads only when
+            # FLAGS_telemetry_port/_dir are unset (the off-path alloc
+            # guard pins zero allocations per step)
+            _fleet.heartbeat()
+            _slo.tick()
 
     def _replay_burst(self, toks, emits, active):
         """Token-by-token host replay of one harvested burst: identical
@@ -3206,42 +3209,44 @@ class ServingEngine:
         try:
             while (dispatched < n_bursts and not stop) or inflight:
                 if dispatched < n_bursts and not stop:
-                    if _reserve():
-                        try:
-                            (toks, emits, nk, nv, nks, nvs,
-                             tok_f, ln_f, act_f, rm_f, key_f) = fn(
-                                params, buffers, *pages, carry[0],
-                                jnp.asarray(self.block_tables), carry[1],
-                                carry[2], carry[3], eos_arr, carry[4],
-                                greedy, temp, tk, tp_arr)
-                        except BaseException as e:
-                            # on a post-donation failure `pages` names
-                            # deleted buffers and the finally below
-                            # re-points the engine at them — poison so
-                            # step()/run() fail fast (ADVICE.md round-5);
-                            # pre-donation failures keep the engine live.
-                            # An OOM still gets its forensic dump here;
-                            # the graceful preemption round belongs to
-                            # the classic step() the caller falls back
-                            # to.
-                            if _memwatch.is_oom(e):
-                                path = _memwatch.dump_oom(
-                                    "serving_async_decode", exc=e,
-                                    extra=self._page_table_report())
-                                _flight.record_event(
-                                    "serving.oom", where="async_decode",
-                                    dump=path)
-                            self._poison_if_donated(
-                                "async burst decode fn raised after "
-                                "donating the KV pages",
-                                pages[0], pages[1])
-                            raise
-                        pages = (nk, nv, nks, nvs)
-                        carry = (tok_f, ln_f, act_f, rm_f, key_f)
-                        inflight.append(
-                            (toks, emits, _time_mod.perf_counter()))
-                        dispatched += 1
-                    else:
+                    with _trace.phase("serving.decode.launch"):
+                        reserved = _reserve()
+                        if reserved:
+                            try:
+                                (toks, emits, nk, nv, nks, nvs,
+                                 tok_f, ln_f, act_f, rm_f, key_f) = fn(
+                                    params, buffers, *pages, carry[0],
+                                    jnp.asarray(self.block_tables), carry[1],
+                                    carry[2], carry[3], eos_arr, carry[4],
+                                    greedy, temp, tk, tp_arr)
+                            except BaseException as e:
+                                # on a post-donation failure `pages` names
+                                # deleted buffers and the finally below
+                                # re-points the engine at them — poison so
+                                # step()/run() fail fast (ADVICE.md round-5);
+                                # pre-donation failures keep the engine live.
+                                # An OOM still gets its forensic dump here;
+                                # the graceful preemption round belongs to
+                                # the classic step() the caller falls back
+                                # to.
+                                if _memwatch.is_oom(e):
+                                    path = _memwatch.dump_oom(
+                                        "serving_async_decode", exc=e,
+                                        extra=self._page_table_report())
+                                    _flight.record_event(
+                                        "serving.oom", where="async_decode",
+                                        dump=path)
+                                self._poison_if_donated(
+                                    "async burst decode fn raised after "
+                                    "donating the KV pages",
+                                    pages[0], pages[1])
+                                raise
+                            pages = (nk, nv, nks, nvs)
+                            carry = (tok_f, ln_f, act_f, rm_f, key_f)
+                            inflight.append(
+                                (toks, emits, _time_mod.perf_counter()))
+                            dispatched += 1
+                    if not reserved:
                         # page-pool pressure: drain, then let the classic
                         # step() run its preemption policy
                         stop = True
@@ -3255,8 +3260,11 @@ class ServingEngine:
                     toks, emits, t_disp = inflight.popleft()
                     gen0 = self._release_gen
                     tok0 = self._m.tokens.value
-                    finished.extend(self._replay_burst(
-                        np.asarray(toks), np.asarray(emits), active))
+                    with _trace.phase("serving.decode.sync"):
+                        toks, emits = np.asarray(toks), np.asarray(emits)
+                    with _trace.phase("serving.emit"):
+                        finished.extend(
+                            self._replay_burst(toks, emits, active))
                     self._step_metrics(t_disp, len(active), tok0)
                     if self._release_gen != gen0:
                         # pages were freed (finish OR a callback abort):

@@ -35,9 +35,13 @@ import numpy as np
 from ..framework import random as _random
 from ..nn.layer_base import Layer
 from ..observability import compilewatch as _cw
+from ..observability.tracing import scope as _scope
 from ..tensor import Tensor, as_array
 
 _tls = threading.local()
+# every build of a program made here is marked `jit.build` on the phases'
+# timeline, flag or no flag
+_cw.ensure_listener()
 
 
 def in_to_static_trace() -> bool:
@@ -453,9 +457,10 @@ def train_step(model: Layer, criterion: Callable, optimizer, donate=True,
         grads = _bucket_tree(grads)
         if sharding_stage >= 2:
             grads = _constrain(grads, grad_shardings)
-        new_params, new_opt_state = optimizer.apply_gradients_functional(
-            params, grads, opt_state, lr
-        )
+        with _scope("optimizer"):
+            new_params, new_opt_state = \
+                optimizer.apply_gradients_functional(
+                    params, grads, opt_state, lr)
         if stored_shardings:
             new_params = _constrain(new_params, stored_shardings)
         return loss, new_params, new_buffers, new_opt_state
@@ -510,8 +515,9 @@ def train_step(model: Layer, criterion: Callable, optimizer, donate=True,
                       for n, a in accum.items()}
             if sharding_stage >= 2:
                 merged = _constrain(merged, grad_shardings)
-            new_params, new_opt = optimizer.apply_gradients_functional(
-                params, merged, opt_state, lr)
+            with _scope("optimizer"):
+                new_params, new_opt = optimizer.apply_gradients_functional(
+                    params, merged, opt_state, lr)
             if stored_shardings:
                 new_params = _constrain(new_params, stored_shardings)
             zeros = {n: jnp.zeros_like(a) for n, a in accum.items()}

@@ -7,6 +7,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .. import nn
+from ..observability.tracing import scope
 
 
 class CausalLMBase(nn.Layer):
@@ -42,6 +43,7 @@ class CausalLMBase(nn.Layer):
                          eos_token_id=eos_token_id,
                          pad_token_id=pad_token_id, seed=seed)
 
+    @scope("head")
     def _head(self, h):
         if self.lm_head is None:
             # tied head reuses the [vocab, hidden] embedding weight via a
@@ -52,6 +54,7 @@ class CausalLMBase(nn.Layer):
                           transpose_y=True)
         return self.lm_head(h)
 
+    @scope("head")
     def compute_loss(self, logits, labels):
         from ..ops.reduction import mean
 
@@ -68,6 +71,7 @@ class CausalLMBase(nn.Layer):
                 return getattr(self, name)
         raise NotImplementedError("subclass must expose its backbone")
 
+    @scope("head")
     def compute_loss_hidden(self, hidden, labels, chunks=None):
         """Fused chunked lm-head + cross entropy: the [tokens, vocab]
         logits tensor is NEVER materialized.

@@ -20,9 +20,11 @@ import numpy as np
 
 from ..autograd import tape as _tape
 from ..framework import random as _random
+from ..observability.tracing import scope
 from ..tensor import Tensor, as_array
 
 
+@scope("head/sample")
 def sample_logits(logits, key, decode_strategy="sampling", temperature=1.0,
                   top_k=0, top_p=1.0):
     """Sample next tokens from [b, vocab] logits. Returns (tokens [b] i32,
@@ -57,6 +59,7 @@ def sample_logits(logits, key, decode_strategy="sampling", temperature=1.0,
     return tok, jnp.take_along_axis(lp, tok[:, None], axis=-1)[:, 0]
 
 
+@scope("head/sample")
 def sample_logits_per_row(logits, key, greedy, temperature, top_k, top_p):
     """Vectorized per-ROW sampling from [b, vocab] logits — each request
     carries its own decode params (the serving engine's per-request

@@ -25,6 +25,7 @@ from ..distributed.fleet.layers.mpu import (
 )
 from ..distributed.sharding_utils import shard_tensor
 from ..nn import functional as F
+from ..observability.tracing import scope
 from ..tensor import Tensor, as_array
 
 
@@ -133,7 +134,8 @@ class GPTAttention(nn.Layer):
             return jax.lax.dynamic_update_slice(
                 c, as_array(new).astype(c.dtype), (zero, cl, zero, zero))
 
-        nk, nv = upd(ck, k), upd(cv, v)
+        with scope("kv_write"):
+            nk, nv = upd(ck, k), upd(cv, v)
         # causal against positions < cur_len + s
         total = nk.shape[1]
         pos_q = cur_len + jnp.arange(s)[:, None]
@@ -190,9 +192,15 @@ class GPTDecoderLayer(nn.Layer):
         self.mlp = GPTMLP(config)
         self.use_recompute = config.use_recompute
 
+    # `attn` and `mlp` each take their LayerNorm and their residual add, so
+    # that a layer's operations all stand under one of the two names
+
     def _inner(self, hidden_states, attn_mask=None):
-        h = hidden_states + self.attn(self.ln_1(hidden_states), attn_mask)
-        return h + self.mlp(self.ln_2(h))
+        with scope("attn"):
+            h = hidden_states + self.attn(self.ln_1(hidden_states),
+                                          attn_mask)
+        with scope("mlp"):
+            return h + self.mlp(self.ln_2(h))
 
     def forward(self, hidden_states, attn_mask=None):
         if self.use_recompute and self.training:
@@ -202,20 +210,24 @@ class GPTDecoderLayer(nn.Layer):
         return self._inner(hidden_states, attn_mask)
 
     def forward_cached(self, hidden_states, kv_cache, cur_len):
-        a, new_cache = self.attn.forward_cached(
-            self.ln_1(hidden_states), kv_cache, cur_len)
-        h = hidden_states + a
-        return h + self.mlp(self.ln_2(h)), new_cache
+        with scope("attn"):
+            a, new_cache = self.attn.forward_cached(
+                self.ln_1(hidden_states), kv_cache, cur_len)
+            h = hidden_states + a
+        with scope("mlp"):
+            return h + self.mlp(self.ln_2(h)), new_cache
 
     def forward_paged(self, hidden_states, paged_cache, block_tables,
                       context_lens, active=None, mesh=None,
                       limit_lens=None):
-        a, new_cache = self.attn.forward_paged(
-            self.ln_1(hidden_states), paged_cache, block_tables,
-            context_lens, active=active, mesh=mesh,
-            limit_lens=limit_lens)
-        h = hidden_states + a
-        return h + self.mlp(self.ln_2(h)), new_cache
+        with scope("attn"):
+            a, new_cache = self.attn.forward_paged(
+                self.ln_1(hidden_states), paged_cache, block_tables,
+                context_lens, active=active, mesh=mesh,
+                limit_lens=limit_lens)
+            h = hidden_states + a
+        with scope("mlp"):
+            return h + self.mlp(self.ln_2(h)), new_cache
 
 
 class GPTModel(nn.Layer):
@@ -251,9 +263,10 @@ class GPTModel(nn.Layer):
         # a tracer inside the jitted decode loop
         off = as_array(position_offset) if hasattr(position_offset, "_data") \
             else position_offset
-        pos = Tensor((jnp.arange(s, dtype=jnp.int64) + off)[None])
-        h = self.embed_tokens(input_ids) + self.embed_positions(pos)
-        return shard_tensor(h, "dp", ("sp", "sep"), None)
+        with scope("embed"):
+            pos = Tensor((jnp.arange(s, dtype=jnp.int64) + off)[None])
+            h = self.embed_tokens(input_ids) + self.embed_positions(pos)
+            return shard_tensor(h, "dp", ("sp", "sep"), None)
 
     def forward(self, input_ids, attn_mask=None):
         from .scan_stack import forward_scan, use_scan_layers
@@ -265,7 +278,11 @@ class GPTModel(nn.Layer):
         else:
             for layer in self.layers:
                 h = layer(h, attn_mask)
-        return self.ln_f(h)
+        return self._final_norm(h)
+
+    def _final_norm(self, h):
+        with scope("head"):
+            return self.ln_f(h)
 
     def forward_cached(self, input_ids, caches, cur_len):
         h = self._embed(input_ids, position_offset=cur_len)
@@ -273,7 +290,7 @@ class GPTModel(nn.Layer):
         for layer, cache in zip(self.layers, caches):
             h, nc = layer.forward_cached(h, cache, cur_len)
             new_caches.append(nc)
-        return self.ln_f(h), new_caches
+        return self._final_norm(h), new_caches
 
     def forward_paged(self, input_ids, paged_caches, block_tables,
                       context_lens, active=None, mesh=None,
@@ -282,9 +299,10 @@ class GPTModel(nn.Layer):
         # context_lens[b]..+s-1 (unlike forward_cached's shared scalar
         # offset); max_layers = shallow-exit draft (ln_f still applies)
         s = input_ids.shape[1]
-        pos = Tensor(as_array(context_lens).astype(jnp.int64)[:, None]
-                     + jnp.arange(s, dtype=jnp.int64)[None, :])
-        h = self.embed_tokens(input_ids) + self.embed_positions(pos)
+        with scope("embed"):
+            pos = Tensor(as_array(context_lens).astype(jnp.int64)[:, None]
+                         + jnp.arange(s, dtype=jnp.int64)[None, :])
+            h = self.embed_tokens(input_ids) + self.embed_positions(pos)
         layers = self.layers if max_layers is None \
             else list(self.layers)[:max_layers]
         new_caches = []
@@ -293,7 +311,7 @@ class GPTModel(nn.Layer):
                                         context_lens, active=active,
                                         mesh=mesh, limit_lens=limit_lens)
             new_caches.append(nc)
-        return self.ln_f(h), new_caches
+        return self._final_norm(h), new_caches
 
 
 class GPTForCausalLM(CausalLMBase):
@@ -339,4 +357,4 @@ class GPTForCausalLM(CausalLMBase):
         return list(self.gpt.layers)
 
     def pp_head(self, hidden):
-        return self._head(self.gpt.ln_f(hidden))
+        return self._head(self.gpt._final_norm(hidden))

@@ -28,6 +28,7 @@ from ..distributed.fleet.layers.mpu import (
 from ..distributed.sharding_utils import shard_tensor
 from ..nn import functional as F
 from ..nn.functional.rope import apply_rope, rope_tables
+from ..observability.tracing import scope
 from ..tensor import Tensor, _apply_op, as_array
 
 
@@ -295,10 +296,11 @@ class LlamaAttention(nn.Layer):
         def attend(qq, kk, vv, kc, vc):
             cur = _jnp.asarray(cur_len, dtype=_jnp.int32)
             z = _jnp.zeros((), _jnp.int32)
-            kc2 = _lax.dynamic_update_slice(
-                kc, kk.astype(kc.dtype), (z, cur, z, z))
-            vc2 = _lax.dynamic_update_slice(
-                vc, vv.astype(vc.dtype), (z, cur, z, z))
+            with scope("kv_write"):
+                kc2 = _lax.dynamic_update_slice(
+                    kc, kk.astype(kc.dtype), (z, cur, z, z))
+                vc2 = _lax.dynamic_update_slice(
+                    vc, vv.astype(vc.dtype), (z, cur, z, z))
             kr, vr = kc2, vc2
             if rep != 1:
                 kr = _jnp.repeat(kr, rep, axis=2)
@@ -338,15 +340,20 @@ class LlamaDecoderLayer(nn.Layer):
         self.mlp = LlamaMLP(config)
         self.use_recompute = config.use_recompute
 
+    # `attn` and `mlp` each take their norm and their residual add (the
+    # same split as GPTDecoderLayer)
+
     def _inner(self, hidden_states, attn_mask=None):
-        residual = hidden_states
-        h = self.input_layernorm(hidden_states)
-        h = self.self_attn(h, attn_mask)
-        h = residual + h
-        residual = h
-        h2 = self.post_attention_layernorm(h)
-        h2 = self.mlp(h2)
-        return residual + h2
+        with scope("attn"):
+            residual = hidden_states
+            h = self.input_layernorm(hidden_states)
+            h = self.self_attn(h, attn_mask)
+            h = residual + h
+        return self._mlp_block(h)
+
+    def _mlp_block(self, h):
+        with scope("mlp"):
+            return h + self.mlp(self.post_attention_layernorm(h))
 
     def forward(self, hidden_states, attn_mask=None):
         if self.use_recompute and self.training:
@@ -358,29 +365,25 @@ class LlamaDecoderLayer(nn.Layer):
     def forward_cached(self, hidden_states, kv_cache, cur_len):
         """Decode/prefill step writing into a dense KV cache; returns
         (hidden, new_kv_cache)."""
-        residual = hidden_states
-        h = self.input_layernorm(hidden_states)
-        h, new_cache = self.self_attn(h, position_offset=cur_len,
-                                      kv_cache=kv_cache)
-        h = residual + h
-        residual = h
-        h2 = self.post_attention_layernorm(h)
-        h2 = self.mlp(h2)
-        return residual + h2, new_cache
+        with scope("attn"):
+            residual = hidden_states
+            h = self.input_layernorm(hidden_states)
+            h, new_cache = self.self_attn(h, position_offset=cur_len,
+                                          kv_cache=kv_cache)
+            h = residual + h
+        return self._mlp_block(h), new_cache
 
     def forward_paged(self, hidden_states, paged_cache, block_tables,
                       context_lens, active=None, mesh=None,
                       limit_lens=None):
-        residual = hidden_states
-        h = self.input_layernorm(hidden_states)
-        h, new_cache = self.self_attn.forward_paged(
-            h, paged_cache, block_tables, context_lens, active=active,
-            mesh=mesh, limit_lens=limit_lens)
-        h = residual + h
-        residual = h
-        h2 = self.post_attention_layernorm(h)
-        h2 = self.mlp(h2)
-        return residual + h2, new_cache
+        with scope("attn"):
+            residual = hidden_states
+            h = self.input_layernorm(hidden_states)
+            h, new_cache = self.self_attn.forward_paged(
+                h, paged_cache, block_tables, context_lens, active=active,
+                mesh=mesh, limit_lens=limit_lens)
+            h = residual + h
+        return self._mlp_block(h), new_cache
 
 
 class LlamaModel(nn.Layer):
@@ -395,14 +398,22 @@ class LlamaModel(nn.Layer):
         self.norm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
 
     def forward(self, input_ids, attn_mask=None):
-        h = self.embed_tokens(input_ids)
+        h = self._embed(input_ids)
         h = shard_tensor(h, "dp", ("sp", "sep"), None)
         if self._use_scan_layers():
             h = self._forward_scan(h, attn_mask)
         else:
             for layer in self.layers:
                 h = layer(h, attn_mask)
-        return self.norm(h)
+        return self._final_norm(h)
+
+    def _embed(self, input_ids):
+        with scope("embed"):
+            return self.embed_tokens(input_ids)
+
+    def _final_norm(self, h):
+        with scope("head"):
+            return self.norm(h)
 
     def _use_scan_layers(self):
         from .scan_stack import use_scan_layers
@@ -418,12 +429,12 @@ class LlamaModel(nn.Layer):
     def forward_cached(self, input_ids, caches, cur_len):
         """caches: list of per-layer (k_cache, v_cache). Returns
         (hidden, new_caches)."""
-        h = self.embed_tokens(input_ids)
+        h = self._embed(input_ids)
         new_caches = []
         for layer, cache in zip(self.layers, caches):
             h, nc = layer.forward_cached(h, cache, cur_len)
             new_caches.append(nc)
-        return self.norm(h), new_caches
+        return self._final_norm(h), new_caches
 
     def forward_paged(self, input_ids, paged_caches, block_tables,
                       context_lens, active=None, mesh=None,
@@ -432,7 +443,7 @@ class LlamaModel(nn.Layer):
         LayerSkip-style shallow-exit draft path of self-speculative
         decoding) — `paged_caches` then carries N entries and the final
         norm still applies, so the lm head sees a normed early exit."""
-        h = self.embed_tokens(input_ids)
+        h = self._embed(input_ids)
         layers = self.layers if max_layers is None \
             else list(self.layers)[:max_layers]
         new_caches = []
@@ -441,7 +452,7 @@ class LlamaModel(nn.Layer):
                                         context_lens, active=active,
                                         mesh=mesh, limit_lens=limit_lens)
             new_caches.append(nc)
-        return self.norm(h), new_caches
+        return self._final_norm(h), new_caches
 
 
 class LlamaForCausalLM(CausalLMBase):
@@ -489,12 +500,12 @@ class LlamaForCausalLM(CausalLMBase):
     # and it keeps the stages homogeneous — the SPMD-pipelining contract).
     # ------------------------------------------------------------------
     def pp_embed(self, input_ids):
-        h = self.llama.embed_tokens(input_ids)
+        h = self.llama._embed(input_ids)
         return shard_tensor(h, "dp", ("sp", "sep"), None)
 
     def pp_layers(self):
         return list(self.llama.layers)
 
     def pp_head(self, hidden):
-        return self._head(self.llama.norm(hidden))
+        return self._head(self.llama._final_norm(hidden))
 

@@ -25,6 +25,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..observability.tracing import scope
 from ..tensor import Tensor, _apply_op, as_array
 
 
@@ -71,30 +72,34 @@ def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
             # by the host commit, but the clobbered page is not)
             wm = act_mask if lim is None else act_mask & (lens < lim)
             if kv_quant:
-                kp2, ksc2, vp2, vsc2 = _pa.update_paged_kv_cache_q8(
-                    kp, ksc, vp, vsc, kk[:, 0], vv[:, 0],
-                    tables, lens, active=wm)
+                with scope("kv_write"):
+                    kp2, ksc2, vp2, vsc2 = _pa.update_paged_kv_cache_q8(
+                        kp, ksc, vp, vsc, kk[:, 0], vv[:, 0],
+                        tables, lens, active=wm)
                 out = attn(qq[:, 0], kp2, vp2, tables, lens + 1,
                            k_scales=ksc2, v_scales=vsc2)
                 return out[:, None], kp2, vp2, ksc2, vsc2
-            kp2, vp2 = _pa.update_paged_kv_cache(
-                kp, vp, kk[:, 0].astype(kp.dtype),
-                vv[:, 0].astype(vp.dtype), tables, lens, active=wm)
+            with scope("kv_write"):
+                kp2, vp2 = _pa.update_paged_kv_cache(
+                    kp, vp, kk[:, 0].astype(kp.dtype),
+                    vv[:, 0].astype(vp.dtype), tables, lens, active=wm)
             out = attn(qq[:, 0], kp2, vp2, tables, lens + 1)
             return out[:, None], kp2, vp2
         # window step (speculative verify): scatter the whole window,
         # then per-position causal attention over the paged prefix
         if kv_quant:
-            kp2, ksc2, vp2, vsc2 = _pa.scatter_paged_kv_window_q8(
-                kp, ksc, vp, vsc, kk, vv, tables, lens,
-                limit_lens=lim, active=act_mask)
+            with scope("kv_write"):
+                kp2, ksc2, vp2, vsc2 = _pa.scatter_paged_kv_window_q8(
+                    kp, ksc, vp, vsc, kk, vv, tables, lens,
+                    limit_lens=lim, active=act_mask)
             out = _pa.paged_attention_window_xla(
                 qq, kp2, vp2, tables, lens, k_scales=ksc2,
                 v_scales=vsc2)
             return out, kp2, vp2, ksc2, vsc2
-        kp2, vp2 = _pa.scatter_paged_kv_window(
-            kp, vp, kk, vv, tables, lens, limit_lens=lim,
-            active=act_mask)
+        with scope("kv_write"):
+            kp2, vp2 = _pa.scatter_paged_kv_window(
+                kp, vp, kk, vv, tables, lens, limit_lens=lim,
+                active=act_mask)
         out = _pa.paged_attention_window_xla(qq, kp2, vp2, tables, lens)
         return out, kp2, vp2
 

@@ -65,7 +65,10 @@ The channels correlate: spans and flight-recorder breadcrumbs carry
 the same `rid`/`trace_id` fields, the watchdog stall dump appends the
 in-flight span stack AND the current memory report, slow traces bump
 `trace_slow_requests_total`, and compiles land as `compile.<name>`
-spans on the same timeline as the steps they stall.
+spans on the same timeline as the steps they stall. The serving
+engine's own phases (`tracing.phase`) are profiler annotations as well,
+flag or no flag, so a `jax.profiler` session holds them on the device
+trace's clock.
 
 Exported metric names are documented in README.md ("Observability").
 """

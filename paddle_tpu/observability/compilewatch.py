@@ -14,9 +14,14 @@ burst programs, autotune candidate timing) and reports:
   `/jax/core/compile/backend_compile_duration` monitoring event
   attributes every real backend compile to the wrapped callable that
   triggered it (`compilewatch_compiles_total{callable}` /
-  `compilewatch_compile_seconds_total{callable}`), and emits a
-  `compile.<name>` span on the tracer when tracing is on — compiles
-  land on the same timeline as the steps they stall.
+  `compilewatch_compile_seconds_total{callable}`), and puts a
+  `compile.<name>` mark on the phases' timeline (`tracing.mark`: the
+  profiler's clock, and the ring when tracing is on) — compiles land on
+  the same timeline as the steps they stall. Flag or no flag, the same
+  listener marks every build `jit.build` with its `kind`: `compile`, or
+  `cache_load` when the persistent cache answered (a retrace that HITS
+  the cache still costs the caller its tracing and loading time, and
+  adds no file for a cache census to count).
 
 - **Shape-signature tracking**: each wrapped call records an abstract
   signature (shape/dtype of array leaves + static values — the same
@@ -43,10 +48,10 @@ tests/test_compilewatch.py, the tracing alloc-guard discipline).
 from __future__ import annotations
 
 import threading
-import time
 from typing import Dict, List, Optional
 
 from . import metrics as _metrics
+from . import tracing as _tracing
 
 _MAX_SIGS_PER_CALLABLE = 64  # bounded: a storm must not become a leak
 
@@ -236,12 +241,8 @@ class CompileWatch:
         h = self._h()
         h["compiles"].labels(name).inc()
         h["compile_s"].labels(name).inc(max(float(dur_s), 0.0))
-        from . import tracing as _tracing
-
-        if _tracing.enabled():
-            now = time.perf_counter()
-            _tracing.emit(f"compile.{name}", now - max(dur_s, 0.0), now,
-                          sig=format_sig(sig) if sig else None)
+        _tracing.mark(f"compile.{name}", seconds=float(dur_s),
+                      sig=format_sig(sig) if sig else "")
         if rec.warmup_done:
             rec.recompiles += 1
             h["recompiles"].labels(name).inc()
@@ -378,27 +379,41 @@ class _CallCtx:
 
 
 # ---------------------------------------------------------------------------
-# the jax monitoring listener (registered once, on first enabled use)
+# the jax monitoring listener (registered once, where the jit entry
+# points are made)
 # ---------------------------------------------------------------------------
 
 _listener_lock = threading.Lock()
 _listener_on = False
+_listener_tls = threading.local()
+# jax 0.9.0 (pxla.py) times `compile_or_get_cached` as a whole under the
+# first name, load or compile; inside it, compiler.py reports the second
+# only when the persistent cache held the program
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def _on_event_duration(event: str, duration_secs: float, **_kw):
-    if event != _COMPILE_EVENT or not enabled():
+    if event == _CACHE_LOAD_EVENT:
+        _listener_tls.loaded = True
         return
+    if event != _COMPILE_EVENT:
+        return
+    loaded = getattr(_listener_tls, "loaded", False)
+    _listener_tls.loaded = False
     try:
-        _watch.observe_compile(duration_secs)
+        _tracing.mark("jit.build", seconds=float(duration_secs),
+                      kind="cache_load" if loaded else "compile")
+        if enabled():
+            _watch.observe_compile(duration_secs)
     except Exception:  # noqa: BLE001 — telemetry must never take a
         pass           # compile (or the caller) down
 
 
 def ensure_listener():
-    """Register the compile-event listener (idempotent). Called lazily
-    from the first enabled wrapped call so an off process never touches
-    jax monitoring."""
+    """Register the compile-event listener (idempotent): `jit/api.py`
+    at import and `watch_jit` for every serving program, flag or no
+    flag, since `jit.build` is marked either way."""
     global _listener_on
     if _listener_on:
         return
@@ -464,7 +479,6 @@ class _WatchedJit:
     def __call__(self, *args, **kwargs):
         if not enabled():
             return self.__wrapped__(*args, **kwargs)
-        ensure_listener()
         with _watch.call(self._name,
                          signature(args, kwargs, tag=self._tag)):
             return self.__wrapped__(*args, **kwargs)
@@ -480,6 +494,7 @@ class _WatchedJit:
 def watch_jit(name: str, fn, tag=None):
     """Wrap a jitted callable for per-callable compile attribution (see
     _WatchedJit)."""
+    ensure_listener()
     return _WatchedJit(name, fn, tag)
 
 

@@ -11,7 +11,8 @@ intervals that export directly into the Chrome trace-event JSON format
 Perfetto / chrome://tracing load natively, and that
 `tools/trace_report.py` turns into TTFT breakdowns and a critical path.
 
-Design (dependency-free, thread-safe, zero-overhead when off):
+Design (thread-safe, nothing allocated in the ring when off; the only
+dependency is jax's profiler, for `phase`):
 
 - `span(name, **attrs)` — context manager for synchronous phases;
   `begin(...)`/`end()` — explicit open spans for async phases that cross
@@ -39,6 +40,16 @@ Design (dependency-free, thread-safe, zero-overhead when off):
   NOTHING (`Tracer.spans_created` counts every span/trace allocation so
   tests can pin the fast path, same discipline as
   `Registry.allocations`).
+- `phase(name, **attrs)` / `mark(name, seconds, **attrs)` — the serving
+  engine's own timeline (and the builds of every program). A phase IS a
+  `jax.profiler.TraceAnnotation`, opened whatever the flags say: with no
+  profiler session that is one small object and a flag test inside
+  TraceMe, with one (`jax.profiler.start_trace`) the span lands in the
+  `.xplane.pb` on the device trace's own clock, where an idle gap of the
+  chip can be laid against it. Only when `enabled()` does it ALSO commit
+  to the ring, as `emit` does. `SCOPES` are the `jax.named_scope` names
+  the models give their parts, so that the device's operations can be
+  summed the same way (`scope(name)`).
 
 Correlation across the three channels: spans carry the same `rid` /
 `trace_id` fields `flight_recorder.record_event` breadcrumbs carry, the
@@ -55,7 +66,15 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from jax import named_scope as scope  # noqa: F401 — `with scope("attn")`
+from jax.profiler import TraceAnnotation
+
 from . import metrics as _metrics
+
+# what the models call their parts in `op_name` (HLO metadata only, the
+# compiled programs do not change). Finer names nest under these:
+# `attn/kv_write` (the cache write), `head/sample` (the sampler).
+SCOPES = ("embed", "attn", "mlp", "head", "optimizer")
 
 # span record ring entry: (ph, name, t0, t1, tid, trace_id, attrs)
 #   ph: "X" complete span | "i" instant event
@@ -680,6 +699,52 @@ def emit(name, t0, t1, **attrs):
 
 def instant(name, **attrs):
     return _default.instant(name, **attrs)
+
+
+class _Phase:
+    """One host phase: a profiler annotation, and a ring span when
+    tracing is enabled."""
+
+    __slots__ = ("_annotation", "name", "attrs", "_t0")
+
+    def __init__(self, name, attrs):
+        self._annotation = TraceAnnotation(name, **attrs)
+        self.name = name
+        self.attrs = attrs
+        self._t0 = None
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        if enabled():
+            self._t0 = _clock()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._annotation.__exit__(exc_type, exc, tb)
+        if self._t0 is not None:
+            _default.emit(self.name, self._t0, _clock(), **self.attrs)
+        return False
+
+
+def phase(name, **attrs):
+    """`with phase("serving.admit"): ...` — see the module docstring.
+    Attribute values are numbers or short strings; give only those that
+    something reads."""
+    return _Phase(name, attrs)
+
+
+def mark(name, seconds=None, **attrs):
+    """A moment on the phases' timeline (a request got its slot), or
+    something that ENDED now and took `seconds` (a compile that a
+    listener reports afterwards): a zero-length annotation carrying
+    `seconds`, and in the ring the interval itself."""
+    if seconds is not None:
+        attrs["seconds"] = seconds
+    with TraceAnnotation(name, **attrs):
+        pass
+    if enabled():
+        now = _clock()
+        _default.emit(name, now - max(seconds or 0.0, 0.0), now, **attrs)
 
 
 def open_spans():

@@ -21,6 +21,11 @@ def _clean():
     anomaly._reset_for_tests()
     httpd._reset_for_tests()
     slo._reset_for_tests()
+    # the poison gauge leaks from other suites through the process's
+    # default registry (test_memwatch and test_faults poison engines on
+    # purpose): without this /healthz reads 503 whenever such a file ran
+    # first on the same worker (as test_telemetry_httpd.py's fixture says)
+    om.default_registry().gauge("serving_engine_poisoned").set(0.0)
     yield
     canary._reset_for_tests()
     anomaly._reset_for_tests()

@@ -189,19 +189,24 @@ class TestOomForensics:
         assert "== custom section ==" in txt
         assert reg.value("memwatch_oom_dumps_total") == d0 + 1
 
-    def test_transient_oom_preempts_once_and_recovers(self, memwatch_on):
+    @pytest.mark.parametrize("burst", [1, 4])
+    def test_transient_oom_preempts_once_and_recovers(self, memwatch_on,
+                                                      burst):
         # the graceful-degradation path: first decode OOM -> forensic
         # dump + ONE preemption round; the retry succeeds and the
-        # request still completes on the SAME engine (no poison)
+        # request still completes on the SAME engine (no poison). A
+        # single step and a burst are launched by the same code in
+        # step(): both are held to it
         reg = om.default_registry()
         p0 = reg.value("serving_preemptions_total")
-        eng, cfg = _tiny_engine()
-        rid = eng.add_request(np.arange(4), max_new_tokens=4)
-        real = eng._get_decode_fn
+        eng, cfg = _tiny_engine(decode_burst=burst)
+        rid = eng.add_request(np.arange(4), max_new_tokens=6)
+        getter = "_get_burst_fn" if burst > 1 else "_get_decode_fn"
+        real = getattr(eng, getter)
         state = {"raised": False}
 
-        def flaky(all_greedy):
-            fn = real(all_greedy)
+        def flaky(*key):
+            fn = real(*key)
 
             def wrapper(*a, **k):
                 if not state["raised"]:
@@ -211,14 +216,15 @@ class TestOomForensics:
 
             return wrapper
 
-        eng._get_decode_fn = flaky
+        setattr(eng, getter, flaky)
         out = eng.run()
         assert state["raised"]
         assert len(out) == 1 and out[0].request_id == rid
-        assert len(out[0].output_ids) == 4
+        assert len(out[0].output_ids) == 6
         assert not eng._poisoned
         assert reg.value("serving_preemptions_total") == p0 + 1
-        dumps = glob.glob(str(memwatch_on / "oom_serving_decode_*"))
+        where = "burst_decode" if burst > 1 else "decode"
+        dumps = glob.glob(str(memwatch_on / f"oom_serving_{where}_*"))
         assert len(dumps) == 1
         txt = open(dumps[0]).read()
         # the serving dump carries the page-table report

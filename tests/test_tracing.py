@@ -59,6 +59,50 @@ def _tiny_engine(**kw):
     return ServingEngine(m, **kw), cfg
 
 
+# the engine's host phases (tracing.phase), as serving.py opens them in
+# one step() that admits, and the zero-length mark, one per request. No
+# name is shared with the per-request spans (serving.prefill, ...)
+SERVING_PHASES = ("serving.admit", "serving.prefill_batch",
+                  "serving.prefill.launch", "serving.kv_scatter",
+                  "serving.prefill.sync", "serving.decode.launch",
+                  "serving.decode.sync", "serving.emit", "serving.close")
+SERVING_MARKS = ("serving.admitted",)
+INSIDE = {"serving.admitted": "serving.admit",
+          "serving.prefill.launch": "serving.prefill_batch",
+          "serving.kv_scatter": "serving.prefill_batch",
+          "serving.prefill.sync": "serving.prefill_batch"}
+
+
+def _profiled(tmp_path, fn):
+    """Run fn() under a jax.profiler session (host tracer only) and
+    return the program's own events: [(name, start_ns, end_ns, stats)]
+    per host line."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                    dict(ev.stats)) for ev in line.events
+                   if ev.name.startswith(("serving.", "train.", "jit."))]
+            if evs:
+                lines.append(evs)
+    return lines
+
+
 def _load_trace_report():
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "tools", "trace_report.py")
@@ -233,8 +277,11 @@ class TestServingTracing:
             # first-token instant present (TTFT anchor)
             assert any(e["name"] == "serving.first_token"
                        for e in mine if e["ph"] == "i")
-        # engine-timeline decode steps recorded on a thread track
-        assert any(e["name"] == "serving.decode_step" for e in events)
+        # the engine's own timeline: the host phases on a thread track
+        # (they took the place of the after-the-fact serving.decode_step)
+        engine = {e["name"] for e in events if e["ph"] == "X"}
+        assert set(SERVING_PHASES) | set(SERVING_MARKS) <= engine
+        assert "serving.decode_step" not in engine
 
     def test_trace_id_on_flight_recorder_events(self, tracer):
         rec = fr.default_recorder()
@@ -315,6 +362,207 @@ class TestServingTracing:
         assert tracer_off.spans_created - c0 == 0
         assert len(tracer_off) == 0
         assert eng._traces == {}
+
+
+class TestPhases:
+    """tracing.phase / mark: the profiler's annotations, whatever the
+    flags; the ring only when tracing is enabled."""
+
+    def _count(self, monkeypatch):
+        opened = []
+
+        class Counting(tr.TraceAnnotation):
+            def __init__(self, name, **kw):
+                opened.append(name)
+                super().__init__(name, **kw)
+
+        monkeypatch.setattr(tr, "TraceAnnotation", Counting)
+        return opened
+
+    def test_off_allocates_nothing_and_a_step_opens_at_most_ten(
+            self, tracer_off, monkeypatch):
+        eng, _ = _tiny_engine(decode_burst=4)
+        eng.add_request(np.arange(6), max_new_tokens=20)
+        eng.step()                      # compile outside the count
+        opened = self._count(monkeypatch)
+        c0 = tracer_off.spans_created
+        with tr.phase("x.y", n=1):
+            pass
+        tr.mark("x.z", seconds=0.5)
+        assert opened == ["x.y", "x.z"]
+        del opened[:]
+        eng.step()                      # decode only
+        assert opened == ["serving.admit", "serving.decode.launch",
+                          "serving.decode.sync", "serving.emit",
+                          "serving.close"]
+        del opened[:]
+        eng.add_request(np.arange(5), max_new_tokens=8)
+        eng.step()                      # admits one request
+        # (a step in which a request also FINISHES re-admits at its end:
+        # one more serving.admit, and a prefill's four if it admits)
+        spans = [n for n in opened if n not in SERVING_MARKS]
+        assert set(spans) == set(SERVING_PHASES)
+        assert len(spans) <= 10
+        # one mark per request admitted, none per token or layer
+        assert [n for n in opened if n in SERVING_MARKS] == \
+            list(SERVING_MARKS)
+        assert tracer_off.spans_created == c0 and len(tracer_off) == 0
+
+    def test_profiler_session_holds_every_phase_nested(self, tracer_off,
+                                                       tmp_path):
+        eng, _ = _tiny_engine(decode_burst=4)
+        rids = []
+
+        def drive():
+            rids.append(eng.add_request(np.arange(6), max_new_tokens=6))
+            eng.step()
+            rids.append(eng.add_request(np.arange(9), max_new_tokens=5))
+            while eng.has_work():
+                eng.step()
+
+        lines = _profiled(tmp_path, drive)
+        evs = max(lines, key=len)       # the thread that ran the engine
+        names = {e[0] for e in evs}
+        assert set(SERVING_PHASES) | set(SERVING_MARKS) <= names
+        # the first steps compiled: each build is marked, with its kind
+        builds = [e[3] for line in lines for e in line
+                  if e[0] == "jit.build"]
+        assert builds and all(b["kind"] == "compile" and b["seconds"] > 0
+                              for b in builds)
+        for name, start, end, _ in evs:
+            outer = INSIDE.get(name)
+            if outer is not None:
+                assert any(o[0] == outer and o[1] <= start and end <= o[2]
+                           for o in evs), name
+            elif name in SERVING_PHASES:
+                # a top-level phase lies inside no other serving phase
+                assert not any(o[0] in SERVING_PHASES and o[0] != name
+                               and o[1] <= start and end <= o[2]
+                               and (o[1], o[2]) != (start, end)
+                               for o in evs), name
+        admitted = [e[3] for e in evs if e[0] == "serving.admitted"]
+        assert sorted(a["rid"] for a in admitted) == sorted(rids)
+        assert all(set(a) == {"rid", "queued_us", "requeue"}
+                   and a["queued_us"] >= 0 and a["requeue"] == 0
+                   for a in admitted)
+        # the phases carry no attribute: none has a reader
+        assert not any(e[3] for e in evs if e[0] in SERVING_PHASES)
+        # one prefill round per request, each around its three parts
+        assert sum(e[0] == "serving.prefill_batch" for e in evs) == 2
+        assert tracer_off.spans_created == 0
+
+    def test_ring_holds_the_same_phases_when_tracing_is_on(self, tracer):
+        eng, _ = _tiny_engine(decode_burst=4)
+        eng.add_request(np.arange(6), max_new_tokens=6)
+        eng.run()
+        xs = [e for e in tr.to_chrome_trace() if e["ph"] == "X"]
+        names = {e["name"] for e in xs}
+        assert set(SERVING_PHASES) | set(SERVING_MARKS) <= names
+        adm = next(e for e in xs if e["name"] == "serving.admitted")
+        assert adm["args"]["rid"] == 0 and "queued_us" in adm["args"]
+        # a build is an interval in the ring, as `emit` would record it
+        build = next(e for e in xs if e["name"] == "jit.build")
+        assert build["args"]["kind"] == "compile"
+        assert build["dur"] == pytest.approx(
+            1e6 * build["args"]["seconds"], rel=0.05, abs=50)
+
+    def test_cache_load_is_marked_as_such(self, tracer):
+        from paddle_tpu.observability import compilewatch as cw
+
+        cw._on_event_duration(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+        cw._on_event_duration(
+            "/jax/core/compile/backend_compile_duration", 0.5)
+        cw._on_event_duration(
+            "/jax/core/compile/backend_compile_duration", 2.0)
+        kinds = sorted((e["args"]["kind"], e["args"]["seconds"])
+                       for e in tr.to_chrome_trace()
+                       if e["name"] == "jit.build")
+        assert kinds == [("cache_load", 0.5), ("compile", 2.0)]
+
+
+class TestScopes:
+    """jax.named_scope names in the models: HLO metadata only."""
+
+    def _locs(self, lowered):
+        """Every `op_name` of the compiled program's HLO."""
+        import re
+
+        return re.findall(r'op_name="([^"]+)"', lowered.compile().as_text())
+
+    def test_programs_carry_the_scopes_and_tokens_are_unchanged(self):
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.inference import ServingEngine
+        from paddle_tpu.jit.api import flatten_call
+        from paddle_tpu.models import (GPTConfig, GPTForCausalLM,
+                                       build_train_step)
+
+        assert tr.SCOPES == ("embed", "attn", "mlp", "head", "optimizer")
+        paddle.seed(0)
+        m = GPTForCausalLM(GPTConfig.tiny(vocab=97, seq=32))
+        m.eval()
+        eng = ServingEngine(m, max_batch=2, max_seq_len=32, page_size=8,
+                            decode_burst=4)
+        params, buffers = eng._cached_params()
+        key = jax.random.key_data(jax.random.key(0))
+        pages = tuple(eng.k_pages)
+        slot = lambda dt: jnp.zeros((2,), dt)  # noqa: E731
+        burst = self._locs(eng._get_burst_fn(True, 4).lower(
+            params, buffers, pages, pages, (), (), slot(jnp.int64),
+            jnp.asarray(eng.block_tables), slot(jnp.int32),
+            slot(jnp.bool_), slot(jnp.int32), slot(jnp.int32), key,
+            slot(jnp.bool_), slot(jnp.float32), slot(jnp.int32),
+            slot(jnp.float32)))
+        for name in ("embed", "attn", "attn/kv_write", "mlp", "head",
+                     "head/sample"):
+            assert any(f"/while/body/closed_call/{name}/" in loc
+                       for loc in burst), name
+        prefill = self._locs(eng._get_prefill_fn(1, 8, True).lower(
+            params, buffers, jnp.zeros((1, 8), jnp.int64),
+            jnp.ones((1,), jnp.int32), key, jnp.ones((1,), jnp.bool_),
+            jnp.ones((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
+            jnp.ones((1,), jnp.float32)))
+        # (the dense cache's write fills the whole cache in a prefill and
+        # is compiled away, so no attn/kv_write survives here)
+        for name in ("embed", "attn", "mlp", "head", "head/sample"):
+            assert any(f"/{name}/" in loc for loc in prefill), name
+
+        # the served tokens are what generate() gives on the same weights
+        prompt = np.arange(5) + 3
+        eng.add_request(prompt, max_new_tokens=6)
+        served = eng.run()[0].output_ids
+        want = m.generate(paddle.to_tensor(prompt[None]), max_new_tokens=6,
+                          decode_strategy="greedy_search")[0]
+        assert list(served) == list(np.asarray(want.numpy())[0][-6:])
+
+        m.train()
+        m.config.use_recompute = True
+        for layer in m.gpt.layers:
+            layer.use_recompute = True
+        opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                     parameters=m.parameters())
+        step = build_train_step(m, opt)
+        x = paddle.to_tensor(np.random.randint(0, 97, (2, 16)))
+        leaves, structure = flatten_call((x, x), {})
+        p = m.parameters_pytree()
+        train = self._locs(jax.jit(
+            step._raw_step._pure_step, static_argnames=("structure",)
+        ).lower(p, m.buffers_pytree(), opt.init_state_pytree(p),
+                jnp.float32(1e-4), key, leaves, structure=structure))
+        import re
+
+        # under a gradient the scope stands inside the transform's name:
+        # jvp(attn), transpose(jvp(attn))
+        for name in ("embed", "attn", "mlp", "head", "optimizer"):
+            assert any(re.search(rf"[/(]{name}[/)]", loc)
+                       for loc in train), name
+        # backward and recompute keep the forward's scope in op_name
+        assert any("transpose(" in loc and re.search(r"[/(]attn[/)]", loc)
+                   for loc in train)
+        assert any("checkpoint" in loc and re.search(r"[/(]mlp[/)]", loc)
+                   for loc in train)
 
 
 class TestTrainTracing:
