@@ -452,6 +452,7 @@ class ServingEngine:
         self._decode_fns: Dict[bool, object] = {}
         self._burst_fns: Dict[tuple, object] = {}
         self._prefill_fns: Dict[tuple, object] = {}
+        self._page_write_fns: Dict[tuple, object] = {}
         # multi-step scheduling (vLLM-style): run `decode_burst` decode
         # steps inside ONE compiled lax.scan — on-device sampling feeds
         # the next step, per-slot budget/eos masks deactivate finished
@@ -1313,7 +1314,7 @@ class ServingEngine:
 
     # ------------------------------------------------------------------
     # prefill: batched dense-cache forward on the admitted prompts, then
-    # one scatter of all their K/V into the pages
+    # one compiled write per layer of their K/V into the donated pages
     # ------------------------------------------------------------------
     def _get_prefill_fn(self, nb, bucket, all_greedy, which="target"):
         """One compiled prefill per (batch-bucket, token-bucket,
@@ -1367,9 +1368,74 @@ class ServingEngine:
                           tag=(nb, bucket, all_greedy, which))
         return fn
 
+    def _get_page_write_fn(self, nb, bucket, which="target"):
+        """One compiled page write per (batch-bucket, token-bucket), the
+        prefill programs' own key: layer `li` of a prefill's stacked K/V
+        lands in that layer's pools, which are DONATED (written in
+        place). Rows past the admitted ones carry a write length of 0,
+        so every one of their positions is dropped. int8 pages bring
+        their scale pools in the same tuple; which="draft" is the same
+        program at the draft model's shapes."""
+        fn = self._page_write_fns.get((nb, bucket, which))
+        if fn is not None:
+            return fn
+
+        def pure_page_write(pools, ks, vs, tables, lens, li):
+            write = _pa.prefill_paged_kv_cache_q8 if len(pools) == 4 \
+                else _pa.prefill_paged_kv_cache
+            return write(
+                *pools, jax.lax.dynamic_index_in_dim(ks, li, keepdims=False),
+                jax.lax.dynamic_index_in_dim(vs, li, keepdims=False),
+                tables, lens)
+
+        fn = self._page_write_fns[(nb, bucket, which)] = _cw.watch_jit(
+            "serving.kv_scatter",
+            jax.jit(pure_page_write, donate_argnums=(0,)),
+            tag=(nb, bucket, which))
+        return fn
+
+    def _write_prefill_pages(self, fn, k_pages, v_pages, k_scales,
+                             v_scales, ks, vs, tables, lens):
+        """Write every layer of a prefill's K/V ([L, nb, bucket, kvh,
+        hd]) through `fn`, re-binding each layer's donated pools to the
+        program's results. False when an OOM was absorbed by a recovery
+        (every request is back in the queue and the round is void)."""
+        # the scales, where the pages are int8, in the order the q8 write
+        # takes them: whether they exist is a fact of the tuple
+        lists = (k_pages, v_pages) if k_scales is None \
+            else (k_pages, k_scales, v_pages, v_scales)
+        try:
+            for li in range(len(k_pages)):
+                written = fn(tuple(pools[li] for pools in lists), ks, vs,
+                             tables, lens, np.int32(li))
+                for pools, new in zip(lists, written):
+                    pools[li] = new
+        except BaseException as e:
+            if _memwatch.is_oom(e):
+                # no preempt-and-retry here: the admitted rows have no
+                # first token yet, so the whole round goes back to the
+                # queue through the drain -> rebuild -> re-admit recovery
+                path = _memwatch.dump_oom(
+                    "serving_prefill_page_write", exc=e,
+                    extra=self._page_table_report())
+                _flight.record_event("serving.oom",
+                                     where="prefill_page_write", dump=path)
+                if self._begin_recovery(
+                        "decode_oom",
+                        f"prefill page write raised RESOURCE_EXHAUSTED "
+                        f"(forensics: {path})"):
+                    return False
+                raise
+            self._poison_if_donated(
+                "prefill page write raised after donating the KV pages",
+                *lists)
+            raise
+        return True
+
     def _prefill_batch(self, new):
         """new: list of (slot_idx, prompt_ids) — ONE compiled forward for
-        all admitted prompts + ONE paged scatter per layer."""
+        all admitted prompts + ONE compiled page write per layer into
+        that layer's donated pools."""
         n = len(new)
         with _trace.phase("serving.prefill_batch"):
             t0_prefill = _time_mod.perf_counter() if self._traces else 0.0
@@ -1401,30 +1467,29 @@ class ServingEngine:
                     tk[row] = rp["top_k"]
                     tp_arr[row] = rp["top_p"]
                 self._key, sk = jax.random.split(self._key)
-                first, ks, vs = fn(
-                    params, buffers, jnp.asarray(padded),
-                    jnp.asarray(true_lens), jax.random.key_data(sk),
-                    jnp.asarray(greedy), jnp.asarray(temp),
-                    jnp.asarray(tk), jnp.asarray(tp_arr))
-            # the eager per-layer scatter (and, with a separate draft model,
-            # its own prefill call and scatter) plus the re-pin
+                prefill_args = (
+                    jnp.asarray(padded), jnp.asarray(true_lens),
+                    jax.random.key_data(sk), jnp.asarray(greedy),
+                    jnp.asarray(temp), jnp.asarray(tk),
+                    jnp.asarray(tp_arr))
+                first, ks, vs = fn(params, buffers, *prefill_args)
+            # the compiled per-layer page write (and, with a separate
+            # draft model, its own prefill call and write)
             with _trace.phase("serving.kv_scatter"):
-                tables = jnp.asarray(np.stack(
-                    [self.block_tables[si] for si, _ in new]))
-                lens = jnp.asarray(true_lens[:n], jnp.int32)
-                for li in range(len(self.k_pages)):
-                    if self.k_scales is not None:
-                        (self.k_pages[li], self.k_scales[li],
-                         self.v_pages[li], self.v_scales[li]) = \
-                            _pa.prefill_paged_kv_cache_q8(
-                                self.k_pages[li], self.k_scales[li],
-                                self.v_pages[li], self.v_scales[li],
-                                ks[li][:n], vs[li][:n], tables, lens)
-                    else:
-                        self.k_pages[li], self.v_pages[li] = \
-                            _pa.prefill_paged_kv_cache(
-                                self.k_pages[li], self.v_pages[li],
-                                ks[li][:n], vs[li][:n], tables, lens)
+                # padded to nb like the prefill: a padded row has a table
+                # row of zeros and a write length of 0 (true_lens says 1
+                # there for the prefill's last-position index)
+                tables = np.zeros((nb, self.pages_per_seq), np.int32)
+                tables[:n] = self.block_tables[[si for si, _ in new]]
+                write_lens = true_lens.copy()
+                write_lens[n:] = 0
+                tables, write_lens = jnp.asarray(tables), \
+                    jnp.asarray(write_lens)
+                if not self._write_prefill_pages(
+                        self._get_page_write_fn(nb, bucket),
+                        self.k_pages, self.v_pages, self.k_scales,
+                        self.v_scales, ks, vs, tables, write_lens):
+                    return
                 if self._draft_model is not None:
                     # the separate draft model needs the prompt in ITS
                     # pages too (two-model speculative decoding prefills
@@ -1433,32 +1498,13 @@ class ServingEngine:
                     fn_d = self._get_prefill_fn(nb, bucket, all_greedy,
                                                 which="draft")
                     dparams, dbuffers = self._cached_draft_params()
-                    _f, dks, dvs = fn_d(dparams, dbuffers, jnp.asarray(padded),
-                                        jnp.asarray(true_lens),
-                                        jax.random.key_data(sk),
-                                        jnp.asarray(greedy), jnp.asarray(temp),
-                                        jnp.asarray(tk), jnp.asarray(tp_arr))
-                    for li in range(len(self._draft_k_pages)):
-                        if self._draft_k_scales is not None:
-                            (self._draft_k_pages[li], self._draft_k_scales[li],
-                             self._draft_v_pages[li],
-                             self._draft_v_scales[li]) = \
-                                _pa.prefill_paged_kv_cache_q8(
-                                    self._draft_k_pages[li],
-                                    self._draft_k_scales[li],
-                                    self._draft_v_pages[li],
-                                    self._draft_v_scales[li],
-                                    dks[li][:n], dvs[li][:n], tables, lens)
-                        else:
-                            (self._draft_k_pages[li],
-                             self._draft_v_pages[li]) = \
-                                _pa.prefill_paged_kv_cache(
-                                    self._draft_k_pages[li],
-                                    self._draft_v_pages[li],
-                                    dks[li][:n], dvs[li][:n], tables, lens)
-                # re-pin: the eager scatter can drop the kv-head tp
-                # sharding, and the decode jit donates pages in this layout
-                self._pin_pages()
+                    _f, dks, dvs = fn_d(dparams, dbuffers, *prefill_args)
+                    if not self._write_prefill_pages(
+                            self._get_page_write_fn(nb, bucket, "draft"),
+                            self._draft_k_pages, self._draft_v_pages,
+                            self._draft_k_scales, self._draft_v_scales,
+                            dks, dvs, tables, write_lens):
+                        return
             if self._prefix_cache is not None:
                 # cache the freshly prefilled FULL pages; the partial tail
                 # page never enters the trie (the copy-on-write guard —

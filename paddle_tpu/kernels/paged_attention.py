@@ -150,7 +150,11 @@ def _quant_kv_token(x):
     """Per-(row, head) symmetric int8 quant of [..., head_dim] values."""
     xf = x.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(xf), axis=-1)
-    scale = jnp.maximum(absmax / 127.0, np.float32(1e-12))
+    # the reciprocal spelled out: XLA compiles `absmax / 127.0` to this
+    # multiply (CPU and TPU alike), an eager dispatch divides, and the two
+    # differ by an ulp in one scale of twenty; every caller is compiled
+    scale = jnp.maximum(absmax * np.float32(1.0 / 127.0),
+                        np.float32(1e-12))
     q = jnp.clip(jnp.rint(xf / scale[..., None]), -127, 127).astype(jnp.int8)
     return q, scale
 

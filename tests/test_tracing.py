@@ -451,6 +451,31 @@ class TestPhases:
         assert sum(e[0] == "serving.prefill_batch" for e in evs) == 2
         assert tracer_off.spans_created == 0
 
+    def test_second_prefill_of_a_bucket_builds_nothing_in_the_page_write(
+            self, tracer_off, tmp_path):
+        # the page write is ONE compiled program per (nb, bucket): a second
+        # prefill round of that bucket, of another number of requests,
+        # compiles nothing inside serving.kv_scatter (an eager operation on
+        # a new shape would: every one is a program of its own)
+        eng, _ = _tiny_engine(max_batch=4, decode_burst=4)
+
+        def drive():
+            for n in (3, 4):            # both pad to nb = 4, bucket 8
+                for _ in range(n):
+                    eng.add_request(np.arange(6), max_new_tokens=3)
+                while eng.has_work():
+                    eng.step()
+
+        lines = _profiled(tmp_path, drive)
+        evs = max(lines, key=len)
+        first, second = sorted((e[1], e[2]) for e in evs
+                               if e[0] == "serving.kv_scatter")
+        builds = [e[1] for line in lines for e in line
+                  if e[0] == "jit.build"]
+        assert any(first[0] <= b <= first[1] for b in builds)
+        assert not any(second[0] <= b <= second[1] for b in builds)
+        assert list(eng._page_write_fns) == [(4, 8, "target")]
+
     def test_ring_holds_the_same_phases_when_tracing_is_on(self, tracer):
         eng, _ = _tiny_engine(decode_burst=4)
         eng.add_request(np.arange(6), max_new_tokens=6)
