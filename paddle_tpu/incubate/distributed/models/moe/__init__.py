@@ -12,11 +12,12 @@ import jax
 
 from .gate import BaseGate, GShardGate, NaiveGate, SwitchGate
 from .moe_layer import ExpertFFN, MoELayer
+from .expert_share import ExpertShareLayer, SigmoidTopKGate
 from . import routing
 
 __all__ = [
     "MoELayer", "ExpertFFN", "BaseGate", "NaiveGate", "SwitchGate",
-    "GShardGate", "routing", "global_scatter", "global_gather",
+    "GShardGate", "SigmoidTopKGate", "ExpertShareLayer", "routing", "global_scatter", "global_gather",
 ]
 
 

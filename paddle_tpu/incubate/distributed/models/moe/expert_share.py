@@ -1,0 +1,175 @@
+"""One chip's share of a wide expert-parallel MoE layer, without drops.
+
+`MoELayer` routes with a capacity (a full expert drops tokens) and holds
+every expert. A deployment that spreads 256 experts over 16 chips asks for
+something else of each chip: a router that keeps its published width, its
+picks and the denominator over ALL picks; the experts that live here
+(`ep_rank` of `ep_degree`, a contiguous block); and, for every token, the
+part of the layer's result that the picked experts held here give,
+
+    y_here = sum_{e in S(x) & H} w_e(x) E_e(x),
+
+with no token dropped. What the absent experts would add is left out; the
+exchange that would bring other chips' tokens here, and send this share
+back, is not stood in for. The shares of all `ep_degree` ranks add up to
+the whole layer's routed result (tests/test_latent_moe.py holds that).
+
+`SigmoidTopKGate` scores with a sigmoid in float32 and picks the `top_k`
+largest (no groups, no correction bias); `ExpertShareLayer` owns the gate
+and the held experts' stacked gated-SiLU weights. Every held expert's
+product is taken for every token and weighted by `w_e` (0 where the token
+did not pick it): at decode batch sizes the step is bound by reading the
+experts' weights either way, and nothing depends on the data's shape.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .....nn import initializer as I
+from .....nn.layer_base import Layer
+from .....observability import tracing as _trace
+from .....tensor import _apply_op
+
+# tokens of one block of `share_ffn`: bounds the [block, held x width]
+# intermediates of a prefill (16,384 tokens x 16 x 2,048 would be 1 GB each)
+TOKEN_BLOCK = 2048
+
+
+def over_token_blocks(fn, block, *arrays):
+    """`fn(*arrays)` on arrays whose leading axis counts tokens, `block`
+    tokens at a time (`lax.map` over zero-padded blocks) once there are
+    more than that: bounds what `fn` holds between its products."""
+    n = arrays[0].shape[0]
+    if n <= block:
+        return fn(*arrays)
+    pad = -n % block
+    blocks = tuple(
+        jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(
+            (-1, block) + a.shape[1:]) for a in arrays)
+    out = jax.lax.map(lambda args: fn(*args), blocks)
+    return out.reshape((-1,) + out.shape[2:])[:n]
+
+
+def sigmoid_topk(x, w_router, top_k, scale=1.0, norm_topk=True):
+    """x [n, d], w_router [d, E] -> (picks [n, k] int32, weights [n, k]
+    float32): the `top_k` largest of sigmoid(x W_r), scored in float32;
+    weights are the scores, over their sum where `norm_topk`, times
+    `scale`."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x, w_router.astype(x.dtype), preferred_element_type=jnp.float32))
+    top, picks = jax.lax.top_k(scores, top_k)
+    if norm_topk:
+        top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    return picks.astype(jnp.int32), top * scale
+
+
+def held_weights(picks, weights, first, held):
+    """[n, held] float32: the routing weight of each expert held here
+    (`first` .. `first + held - 1`) for each token, 0 where the token did
+    not pick it."""
+    local = picks[..., None] - first == jnp.arange(held, dtype=picks.dtype)
+    return jnp.sum(jnp.where(local, weights[..., None], 0.0), axis=1)
+
+
+def share_ffn(x, dense_w, w_gate, w_up, w_down):
+    """sum_e dense_w[:, e] * E_e(x) over the held experts: x [n, d],
+    dense_w [n, held], w_gate / w_up [held, d, f], w_down [held, f, d].
+    The weight goes onto the hidden activations, so the sum over experts
+    is the down projection's own float32 accumulation."""
+    held, _, f = w_gate.shape
+
+    def block(xb, wb):
+        gate = jnp.einsum("nd,edf->nef", xb, w_gate.astype(xb.dtype),
+                          preferred_element_type=jnp.float32)
+        up = jnp.einsum("nd,edf->nef", xb, w_up.astype(xb.dtype),
+                        preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(gate) * up * wb[..., None]).astype(xb.dtype)
+        return jnp.matmul(hidden.reshape(-1, held * f),
+                          w_down.astype(xb.dtype).reshape(held * f, -1),
+                          preferred_element_type=jnp.float32
+                          ).astype(xb.dtype)
+
+    return over_token_blocks(block, TOKEN_BLOCK, x, dense_w)
+
+
+class SigmoidTopKGate(Layer):
+    """The router: `weight` [d_model, num_experts] over ALL experts of the
+    deployment. forward(x [n, d]) -> (picks, weights), see
+    `sigmoid_topk`."""
+
+    def __init__(self, d_model, num_experts, top_k, routed_scaling_factor=1.0,
+                 norm_topk_prob=True):
+        super().__init__()
+        self.num_experts, self.top_k = num_experts, top_k
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.weight = self.create_parameter(
+            shape=[d_model, num_experts],
+            default_initializer=I.XavierUniform())
+
+    def forward(self, x):
+        return _apply_op(
+            sigmoid_topk, x, self.weight, _name="moe_sigmoid_gate",
+            top_k=self.top_k, scale=self.routed_scaling_factor,
+            norm_topk=self.norm_topk_prob)
+
+
+class ExpertShareLayer(Layer):
+    """Routed experts `ep_rank * held .. + held - 1` of `num_experts`
+    (`held = num_experts // ep_degree`) and the router over all of them.
+    forward(x [..., d_model]) -> this chip's share of the routed result,
+    the same shape. `live` ([tokens] bool, optional) says which tokens
+    are real: it enters the counts only, never the result."""
+
+    def __init__(self, d_model, d_hidden, num_experts, top_k, ep_rank=0,
+                 ep_degree=1, routed_scaling_factor=1.0,
+                 norm_topk_prob=True):
+        super().__init__()
+        if num_experts % ep_degree or not 0 <= ep_rank < ep_degree:
+            raise ValueError(
+                f"ep_rank {ep_rank} of ep_degree {ep_degree} does not "
+                f"name a share of {num_experts} experts")
+        self.d_model = d_model
+        self.held = num_experts // ep_degree
+        self.first = ep_rank * self.held
+        self.gate = SigmoidTopKGate(d_model, num_experts, top_k,
+                                    routed_scaling_factor, norm_topk_prob)
+        for name, shape in (("w_gate", [self.held, d_model, d_hidden]),
+                            ("w_up", [self.held, d_model, d_hidden]),
+                            ("w_down", [self.held, d_hidden, d_model])):
+            setattr(self, name, self.create_parameter(
+                shape=shape, default_initializer=I.XavierUniform()))
+
+    def forward(self, x, live=None):
+        shape = [int(s) for s in x.shape]
+        tokens = x.reshape([-1, self.d_model])
+        with _trace.scope("router"):
+            picks, weights = self.gate(tokens)
+            dense_w = _apply_op(held_weights, picks, weights,
+                                _name="moe_held_weights", first=self.first,
+                                held=self.held)
+            self._count(dense_w, live)
+        with _trace.scope("experts"):
+            y = _apply_op(share_ffn, tokens, dense_w, self.w_gate, self.w_up,
+                          self.w_down, _name="moe_share_ffn")
+        return y.reshape(shape)
+
+    def _count(self, dense_w, live):
+        """`expert_pairs`: (live token, held expert it picked) pairs;
+        `experts_hit`: held experts some live token picked; and what each
+        is a share of, `expert_layer_steps` (1 for a call with a live
+        token) and `experts_held`. Nothing is computed where nobody
+        collects (`tracing.device_counts`)."""
+        if not _trace.counting():
+            return
+        picked = dense_w._data > 0
+        if live is not None:
+            picked = picked & jnp.reshape(live, (-1, 1))
+        any_live = jnp.int32(1) if live is None \
+            else jnp.any(live).astype(jnp.int32)
+        _trace.count("expert_pairs", jnp.sum(picked, dtype=jnp.int32))
+        _trace.count("experts_hit",
+                     jnp.sum(jnp.any(picked, axis=0), dtype=jnp.int32))
+        _trace.count("expert_layer_steps", any_live)
+        _trace.count("experts_held", any_live * self.held)

@@ -384,10 +384,19 @@ class ServingEngine:
         # matches the old exclusive-ownership pop/extend bit for bit).
         self._page_refs = [0] * n_pages
         L = self.cfg.num_hidden_layers
-        # GPT-family configs have no GQA field: kv heads == heads
-        kvh = getattr(self.cfg, "num_key_value_heads",
-                      self.cfg.num_attention_heads)
-        hd = self.cfg.hidden_size // self.cfg.num_attention_heads
+        # what a layer caches is the model's to say: (k, v) of every kv
+        # head for full attention, ONE pool of latent rows for latent
+        # attention (`CausalLMBase.kv_cache_layout`). `k_pages` holds the
+        # first pool of each layer and `v_pages` the second; a layout of
+        # one pool leaves `v_pages` empty (its rows are the one key head
+        # of absorbed-form attention, and carry the value in themselves)
+        self._kv_layout = tuple(model.kv_cache_layout())
+        self._one_pool = len(self._kv_layout) == 1
+        kvh = self._kv_layout[0][0]
+        self._check_latent_support(
+            kv_cache_quant=kv_cache_quant, spec_decode=spec_decode,
+            draft_model=draft_model, prefix_cache=prefix_cache,
+            prefill_chunk=prefill_chunk)
         # KV pages in the MODEL's dtype (round-2 verdict weak #5: hard-coded
         # f32 pages made a bf16 model pay 2x KV memory + bandwidth); the
         # paged kernel upcasts per-block to f32 for the softmax/accum
@@ -409,10 +418,8 @@ class ServingEngine:
         else:
             self.k_scales = self.v_scales = None
         self.kv_dtype = kv_dtype
-        self.k_pages = [jnp.zeros((kvh, n_pages, page_size, hd),
-                                  kv_dtype) for _ in range(L)]
-        self.v_pages = [jnp.zeros((kvh, n_pages, page_size, hd),
-                                  kv_dtype) for _ in range(L)]
+        self._n_pages_total = n_pages
+        self.k_pages, self.v_pages = self._alloc_pools()
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -421,6 +428,11 @@ class ServingEngine:
             place_model(model, self.mesh)
             tp = int(self.mesh.shape["tp"]) \
                 if "tp" in self.mesh.axis_names else 1
+            if tp > 1 and self._one_pool:
+                raise ValueError(
+                    "a latent page pool has one head and cannot be sharded "
+                    f"over tp={tp}: latent attention serves at tp=1 "
+                    "(ROADMAP R10 keeps the sharded form)")
             if tp > 1 and kvh % tp:
                 raise ValueError(
                     f"TP serving shards the {kvh} kv heads over tp={tp}; "
@@ -585,7 +597,6 @@ class ServingEngine:
         # subsequent step()/run() fails fast instead of crashing on
         # deleted-buffer access (ADVICE.md round-5)
         self._poisoned = None
-        self._n_pages_total = n_pages
         self._m = _EngineMetrics()
         # tiered spill (README.md "Tiered KV cache + cross-host
         # handoff"): evicted prefix pages keep their bytes in host RAM
@@ -669,6 +680,36 @@ class ServingEngine:
         # falsy dict check — the alloc-guard test pins zero span
         # allocations per decode step with tracing off.
         self._traces: Dict[int, object] = {}
+
+    def _alloc_pools(self):
+        """(k_pages, v_pages): empty pools for every layer, as the model's
+        cache layout shapes them; `v_pages` is [] for a layout of one."""
+        pools = [[jnp.zeros((heads, self._n_pages_total, self.page_size,
+                             width), self.kv_dtype)
+                  for _ in range(self.cfg.num_hidden_layers)]
+                 for heads, width in self._kv_layout]
+        return pools[0], pools[1] if len(pools) > 1 else []
+
+    def _check_latent_support(self, **asked):
+        """A layout of one pool (latent attention) has no int8 pages, no
+        window step (speculative decoding, chunked prefill and with it
+        the prefix cache and its tiers) and no draft pools yet: asking
+        for one raises here, at construction, and nothing falls back."""
+        if not self._one_pool:
+            return
+        from ..framework import config as _config
+
+        flags = {"spec_decode": "FLAGS_spec_decode",
+                 "prefix_cache": "FLAGS_prefix_cache",
+                 "prefill_chunk": "FLAGS_prefill_chunk"}
+        for name, value in asked.items():
+            if value is None and name in flags:
+                value = _config.get_flag(flags[name], 0)
+            if value is not None and value != 0 and value != "":
+                raise ValueError(
+                    f"{name}={value!r} is not built for a model that caches "
+                    "a latent (one page pool a layer, decoded one token a "
+                    "row): serve it without, or see ROADMAP R10")
 
     def _pin_pages(self):
         """Lay the page pools out in the serving sharding (kv heads over
@@ -1001,8 +1042,8 @@ class ServingEngine:
         readonly with a warm cache)."""
         from ..kernels import autotune as _at
 
-        if not _at.enabled():
-            return
+        if not _at.enabled() or self._one_pool:
+            return  # latent pages do not go through the tuned dispatch
         kvh, _n, page, hd = self.k_pages[0].shape
         qh = self.cfg.num_attention_heads
         # under TP the decode dispatch runs INSIDE a shard_map with
@@ -1359,9 +1400,13 @@ class ServingEngine:
                 else:
                     first, _ = sample_logits_per_row(last, key, greedy,
                                                      temp, tk, tp)
-                ks = jnp.stack([as_array(k) for k, v in caches])
-                vs = jnp.stack([as_array(v) for k, v in caches])
-            return first, ks, vs  # ks: [L, nb, bucket, kvh, hd]
+                # one stack per pool of the layout: (ks, vs) of [L, nb,
+                # bucket, kvh, hd] for full attention, the latent rows
+                # alone for latent attention
+                stacks = tuple(
+                    jnp.stack([as_array(c[j]) for c in caches])
+                    for j in range(len(caches[0])))
+            return (first,) + stacks
 
         fn = self._prefill_fns[(nb, bucket, all_greedy, which)] = \
             _cw.watch_jit("serving.prefill", jax.jit(pure_prefill),
@@ -1381,6 +1426,11 @@ class ServingEngine:
             return fn
 
         def pure_page_write(pools, ks, vs, tables, lens, li):
+            if len(pools) == 1:  # a layout of one pool: `vs` is None
+                return (_pa.prefill_paged_pool(
+                    pools[0],
+                    jax.lax.dynamic_index_in_dim(ks, li, keepdims=False),
+                    tables, lens),)
             write = _pa.prefill_paged_kv_cache_q8 if len(pools) == 4 \
                 else _pa.prefill_paged_kv_cache
             return write(
@@ -1402,7 +1452,9 @@ class ServingEngine:
         (every request is back in the queue and the round is void)."""
         # the scales, where the pages are int8, in the order the q8 write
         # takes them: whether they exist is a fact of the tuple
-        lists = (k_pages, v_pages) if k_scales is None \
+        # (a layout of one pool, latent rows, has no `v_pages`: `vs` is None)
+        lists = (k_pages,) if not v_pages \
+            else (k_pages, v_pages) if k_scales is None \
             else (k_pages, k_scales, v_pages, v_scales)
         try:
             for li in range(len(k_pages)):
@@ -1472,7 +1524,8 @@ class ServingEngine:
                     jax.random.key_data(sk), jnp.asarray(greedy),
                     jnp.asarray(temp), jnp.asarray(tk),
                     jnp.asarray(tp_arr))
-                first, ks, vs = fn(params, buffers, *prefill_args)
+                first, ks, *vs = fn(params, buffers, *prefill_args)
+                vs = vs[0] if vs else None
             # the compiled per-layer page write (and, with a separate
             # draft model, its own prefill call and write)
             with _trace.phase("serving.kv_scatter"):
@@ -1709,11 +1762,15 @@ class ServingEngine:
                  temp, tk, tp):
             # kss/vss non-empty iff kv_cache_quant: per-layer cache entry
             # is then (k_pages, v_pages, k_scales, v_scales)
+            # a layout of one pool leaves vps empty: (pool,) a layer
             caches = list(zip(kps, vps, kss, vss)) if kss \
-                else list(zip(kps, vps))
-            logits, new_caches = model.forward_paged(
-                Tensor(tok[:, None]), caches, tables, lens,
-                active=act, mesh=serving_mesh)
+                else list(zip(kps, vps)) if vps else [(kp,) for kp in kps]
+            # what the model counts of its own work while it is traced
+            # (token-expert pairs of an expert layer); {} for most
+            with _trace.device_counts() as counts:
+                logits, new_caches = model.forward_paged(
+                    Tensor(tok[:, None]), caches, tables, lens,
+                    active=act, mesh=serving_mesh)
             if all_greedy:
                 # static specialization: no vocab sort, argmax only
                 nxt, _ = sample_logits(as_array(logits)[:, 0], key,
@@ -1722,10 +1779,10 @@ class ServingEngine:
                 nxt, _ = sample_logits_per_row(
                     as_array(logits)[:, 0], key, greedy, temp, tk, tp)
             nk = tuple(as_array(c[0]) for c in new_caches)
-            nv = tuple(as_array(c[1]) for c in new_caches)
+            nv = tuple(as_array(c[1]) for c in new_caches) if vps else ()
             nks = tuple(as_array(c[2]) for c in new_caches) if kss else ()
             nvs = tuple(as_array(c[3]) for c in new_caches) if kss else ()
-            return nxt, nk, nv, nks, nvs
+            return nxt, nk, nv, nks, nvs, counts
 
         return core
 
@@ -1743,10 +1800,10 @@ class ServingEngine:
                         greedy, temp, tk, tp):
             with _tape.no_grad(), _LayerScope(model, params, buffers):
                 key = jax.random.wrap_key_data(seed)
-                nxt, nk, nv, nks, nvs = core(
+                nxt, nk, nv, nks, nvs, counts = core(
                     tokens, k_pages, v_pages, k_scales, v_scales, tables,
                     lens, active, key, greedy, temp, tk, tp)
-            return nxt, nk, nv, nks, nvs
+            return nxt, nk, nv, nks, nvs, counts
 
         fn = self._decode_fns[all_greedy] = _cw.watch_jit(
             "serving.decode",
@@ -1776,7 +1833,7 @@ class ServingEngine:
                 def one(carry, _):
                     tok, kps, vps, kss, vss, ln, act, rm, key = carry
                     key, sk = jax.random.split(key)
-                    nxt, nk, nv, nks, nvs = core(
+                    nxt, nk, nv, nks, nvs, counts = core(
                         tok, kps, vps, kss, vss, tables, ln, act, sk,
                         greedy, temp, tk, tp)
                     nxt = nxt.astype(tok.dtype)
@@ -1786,10 +1843,10 @@ class ServingEngine:
                     act2 = act & (rm2 > 0) & (nxt != eos)
                     tok2 = jnp.where(act, nxt, tok)
                     return (tok2, nk, nv, nks, nvs, ln2, act2, rm2, key), \
-                        (nxt, emitted)
+                        (nxt, emitted, counts)
 
                 key = jax.random.wrap_key_data(seed)
-                carry, (toks, emits) = jax.lax.scan(
+                carry, (toks, emits, counts) = jax.lax.scan(
                     one, (tokens, k_pages, v_pages, k_scales, v_scales,
                           lens, active, rem, key),
                     None, length=n_steps)
@@ -1798,8 +1855,11 @@ class ServingEngine:
             # can chain burst N+1 directly off burst N's DEVICE outputs
             # (no host round-trip between dispatches); the sync path just
             # ignores these leaves
+            # the model's counts, summed over the burst's steps, ride out
+            # LAST, beside the tokens: read with them, no sync of their own
             return (toks, emits, nk, nv, nks, nvs,
-                    tok_f, ln_f, act_f, rm_f, jax.random.key_data(key_f))
+                    tok_f, ln_f, act_f, rm_f, jax.random.key_data(key_f),
+                    {k: jnp.sum(v) for k, v in counts.items()})
 
         fn = self._burst_fns[(all_greedy, n_steps)] = _cw.watch_jit(
             "serving.decode_burst",
@@ -2391,20 +2451,13 @@ class ServingEngine:
             # buffers, and even live ones hold KV for contexts that
             # will re-prefill anyway (mirrors __init__'s allocation)
             L = self.cfg.num_hidden_layers
-            kvh = getattr(self.cfg, "num_key_value_heads",
-                          self.cfg.num_attention_heads)
-            hd = self.cfg.hidden_size // self.cfg.num_attention_heads
+            kvh = self._kv_layout[0][0]
             n_pages = self._n_pages_total
             if self.kv_cache_quant == "int8":
                 self.k_scales, self.v_scales = map(list, zip(*[
                     _pa.alloc_page_scales(n_pages, self.page_size, kvh)
                     for _ in range(L)]))
-            self.k_pages = [
-                jnp.zeros((kvh, n_pages, self.page_size, hd),
-                          self.kv_dtype) for _ in range(L)]
-            self.v_pages = [
-                jnp.zeros((kvh, n_pages, self.page_size, hd),
-                          self.kv_dtype) for _ in range(L)]
+            self.k_pages, self.v_pages = self._alloc_pools()
             if self._page_sharding is not None:
                 self._pin_pages()
             if self._draft_model is not None:
@@ -2686,10 +2739,10 @@ class ServingEngine:
                                  jnp.asarray(greedy), jnp.asarray(temp),
                                  jnp.asarray(tk), jnp.asarray(tp_arr))
                         if burst:
-                            (toks, emits, nk, nv, nks, nvs, *_carry) = \
-                                fn(*args)
+                            (toks, emits, nk, nv, nks, nvs, *_carry,
+                             counts) = fn(*args)
                         else:
-                            toks, nk, nv, nks, nvs = fn(*args)
+                            toks, nk, nv, nks, nvs, counts = fn(*args)
                     except BaseException as e:
                         if _memwatch.is_oom(e) and self._handle_decode_oom(
                                 e, "burst_decode" if burst else "decode"):
@@ -2740,8 +2793,10 @@ class ServingEngine:
             toks = np.asarray(toks)  # tpu-lint: disable=sync-transfer-in-step-loop
             if burst:
                 emits = np.asarray(emits)  # tpu-lint: disable=sync-transfer-in-step-loop
+            # the program is done once its tokens are here: no second wait
+            counts = {k: int(v) for k, v in counts.items()}
         finished = finished_early
-        with _trace.phase("serving.emit"):
+        with _trace.phase("serving.emit", **counts):
             if burst:
                 finished.extend(self._replay_burst(toks, emits, active))
             else:
@@ -2953,6 +3008,12 @@ class ServingEngine:
             if after <= before:  # OOM drained/preempted: no progress
                 break
 
+    def _no_latent_handoff(self):
+        if self._one_pool:
+            raise NotImplementedError(
+                "KV hand-off packs (k, v) pages: a latent page pool has no "
+                "hand-off format yet (ROADMAP R10)")
+
     def detach_request(self, request_id: int) -> "KVHandoff":
         """Extract a prefilled request from this engine: gather its KV
         pages to the host, free the slot, and return a KVHandoff that
@@ -2962,6 +3023,7 @@ class ServingEngine:
         against them). The uncommitted prefill-time sample rides the
         handoff, so the first token is committed exactly once, by the
         attaching engine."""
+        self._no_latent_handoff()
         self._check_poisoned()
         slot_idx = next((i for i, s in enumerate(self.slots)
                          if s.active and s.request_id == request_id),
@@ -3032,6 +3094,7 @@ class ServingEngine:
         shapes are checked)."""
         self._check_poisoned()
         t_attach0 = _time_mod.perf_counter()
+        self._no_latent_handoff()
         if handoff.page_size != self.page_size:
             raise ValueError(
                 f"page_size mismatch: handoff {handoff.page_size} vs "
@@ -3260,7 +3323,8 @@ class ServingEngine:
                         if reserved:
                             try:
                                 (toks, emits, nk, nv, nks, nvs,
-                                 tok_f, ln_f, act_f, rm_f, key_f) = fn(
+                                 tok_f, ln_f, act_f, rm_f, key_f,
+                                 _counts) = fn(
                                     params, buffers, *pages, carry[0],
                                     jnp.asarray(self.block_tables), carry[1],
                                     carry[2], carry[3], eos_arr, carry[4],

@@ -685,3 +685,63 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgs,hbsd->bhgd", p, v_dense.astype(jnp.float32))
     return out.reshape(b, n_q_heads, head_dim).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA) pages: ONE pool a layer, [1, n_pages, page_size, width], whose
+# rows are (compressed latent | rope key). In absorbed form decode attention
+# is multi-query attention over that one key head, and the first
+# `value_width` columns of a row are also its value.
+# ---------------------------------------------------------------------------
+
+
+def update_paged_pool(pages, new, block_tables, context_lens, active=None):
+    """`update_paged_kv_cache` for a layout of one pool: new [batch, heads,
+    width] lands at position context_lens[b]; inactive rows write
+    nothing."""
+    page_size = pages.shape[2]
+    page_ids = jnp.take_along_axis(
+        block_tables, (context_lens // page_size)[:, None], axis=1)[:, 0]
+    if active is not None:
+        page_ids = jnp.where(active, page_ids, pages.shape[1])
+    return pages.at[:, page_ids, context_lens % page_size, :].set(
+        new.astype(pages.dtype).transpose(1, 0, 2), mode="drop")
+
+
+def prefill_paged_pool(pages, seq, block_tables, seq_lens):
+    """`prefill_paged_kv_cache` for a layout of one pool: seq [batch, s,
+    heads, width]; positions j >= seq_lens[b] are dropped."""
+    b, s = seq.shape[0], seq.shape[1]
+    page_size = pages.shape[2]
+    pos = jnp.arange(s)[None, :]
+    page_ids = jnp.take_along_axis(block_tables, pos // page_size, axis=1)
+    slots = jnp.broadcast_to(pos % page_size, (b, s))
+    page_ids = jnp.where(pos < seq_lens[:, None], page_ids, pages.shape[1])
+    rows = seq.astype(pages.dtype).transpose(2, 0, 1, 3).reshape(
+        seq.shape[2], b * s, -1)
+    return pages.at[:, page_ids.reshape(-1), slots.reshape(-1), :].set(
+        rows, mode="drop")
+
+
+def paged_latent_attention_xla(q, pages, block_tables, context_lens,
+                               value_width, scale):
+    """Absorbed-form decode attention over latent pages (dense gather).
+
+    q: [batch, heads, width] (the absorbed query | the rope query); pages:
+    [1, n_pages, page_size, width]. Returns [batch, heads, value_width]:
+    softmax(q . row * scale) over the first context_lens[b] rows, weighted
+    sum of the rows' first `value_width` columns. The rows stay in the
+    pool's dtype: both products accumulate in float32 without a float32
+    copy of the mapped context."""
+    b = q.shape[0]
+    page_size = pages.shape[2]
+    S = block_tables.shape[1] * page_size
+    rows = pages[0][block_tables].reshape(b, S, pages.shape[3])
+    s = jnp.einsum("bhw,bsw->bhs", q.astype(rows.dtype), rows,
+                   preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(S)[None, :] < context_lens[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, :], s, NEG_INF), axis=-1)
+    out = jnp.einsum("bhs,bsv->bhv", p.astype(rows.dtype),
+                     rows[..., :value_width],
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)

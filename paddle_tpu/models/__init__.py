@@ -10,6 +10,11 @@ from .gpt import (  # noqa: F401
     GPTForCausalLM,
     GPTModel,
 )
+from .latent_moe import (  # noqa: F401
+    LatentMoEConfig,
+    LatentMoEForCausalLM,
+    LatentMoEModel,
+)
 from .generation import generate, sample_logits  # noqa: F401
 from .trainer import (build_train_step, place_model,  # noqa: F401
                       prefetch_batches)

@@ -21,14 +21,24 @@ class CausalLMBase(nn.Layer):
         return getattr(cfg, "num_key_value_heads",
                        cfg.num_attention_heads)
 
-    def init_kv_caches(self, batch_size, max_length, dtype=None):
-        """Dense per-layer (k, v) caches for incremental decoding."""
+    def kv_cache_layout(self):
+        """((heads, width), ...): the pools one layer's cache is made of,
+        in the order the layer's cache tuple holds them. Keys and values of
+        every kv head for full attention; a model that caches something
+        else (a latent) states it by overriding this, and the dense caches
+        below and the serving engine's page pools follow."""
         cfg = self.config
+        kv = (self._kv_heads(), cfg.hidden_size // cfg.num_attention_heads)
+        return (kv, kv)
+
+    def init_kv_caches(self, batch_size, max_length, dtype=None):
+        """Dense per-layer caches for incremental decoding: one `[batch,
+        max_length, heads, width]` array per pool of the layout ((k, v) for
+        full attention)."""
         dt = dtype or jnp.float32
-        shape = (batch_size, max_length, self._kv_heads(),
-                 cfg.hidden_size // cfg.num_attention_heads)
-        return [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
-                for _ in range(cfg.num_hidden_layers)]
+        return [tuple(jnp.zeros((batch_size, max_length, heads, width), dt)
+                      for heads, width in self.kv_cache_layout())
+                for _ in range(self.config.num_hidden_layers)]
 
     def generate(self, input_ids, max_length=None, max_new_tokens=None,
                  decode_strategy="greedy_search", temperature=1.0,
@@ -66,7 +76,7 @@ class CausalLMBase(nn.Layer):
         return self._backbone()(input_ids, attn_mask)
 
     def _backbone(self):
-        for name in ("llama", "gpt"):
+        for name in ("llama", "gpt", "model"):
             if hasattr(self, name):
                 return getattr(self, name)
         raise NotImplementedError("subclass must expose its backbone")

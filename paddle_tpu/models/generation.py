@@ -119,7 +119,7 @@ def _build_generate_fn(model, batch, prompt_len, total_len, decode_strategy,
             caches = model.init_kv_caches(batch, total_len)
             logits, caches = model.forward_cached(Tensor(ids), caches, 0)
             last = as_array(logits)[:, -1, :]
-            caches = tuple((as_array(k), as_array(v)) for k, v in caches)
+            caches = tuple(tuple(as_array(a) for a in c) for c in caches)
             tokens = jnp.concatenate(
                 [ids.astype(jnp.int64),
                  jnp.full((batch, n_new), pad_token_id, dtype=jnp.int64)],
@@ -155,7 +155,7 @@ def _build_generate_fn(model, batch, prompt_len, total_len, decode_strategy,
                         Tensor(tok[:, None].astype(ids.dtype)),
                         [tuple(c) for c in caches], cur)
                     return (as_array(logits2)[:, -1, :], tuple(
-                        (as_array(k), as_array(v)) for k, v in caches2))
+                        tuple(as_array(a) for a in c) for c in caches2))
 
                 def hold(operand):
                     tok, caches, cur, last = operand

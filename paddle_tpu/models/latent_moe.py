@@ -1,0 +1,550 @@
+"""Latent-attention mixture-of-experts causal LM (the DeepSeek-V3 family's
+shape, as openPangu-Ultra-MoE publishes it: `model_type` pangu_ultra_moe).
+
+ONE decoder stack over a per-layer list of kinds (`LatentMoEConfig
+.layer_kinds()`): the mixer (`latent`: multi-head latent attention), the
+FFN (`dense`, or `routed+shared`: this chip's share of the routed experts
+plus the shared expert) and where the norms stand (`sandwich`: a norm
+before AND after each sublayer, the residual added outside both; `pre`).
+`gpt.py` and `llama.py` keep their own stacks (ROADMAP D6).
+
+The equations (`N(x; g) = x / sqrt(mean(x^2) + eps) * g`, no biases):
+
+    block    x' = x + N(Attn(N(x; g_in)); g_post_attn)
+             y  = x' + N(FFN(N(x'; g_pre_mlp)); g_post_mlp)
+    latent   c_q = N(x W_qa; g_qa); q = c_q W_qb -> heads x (nope + rope)
+             [c_kv | k_r] = x W_kva; c = N(c_kv; g_kva)
+             rope on q's rope dims and on k_r (ONE rope key for all heads),
+             rotate-half pairing (dimension i with i + rope/2)
+             [k_nope | v] = c W_kvb -> heads x (nope + v)
+             scores_h = (q_nope,h . k_nope,h + q_rope,h . k_r) / sqrt(nope + rope)
+             o = concat_h(softmax_h v_h) W_o
+    experts  s = sigmoid(x W_r) in float32; S = the top-k; w_e = scale s_e / sum_S s
+             y = sum_{e in S & held here} w_e E_e(x) + E_shared(x)
+
+The cache holds `(c | rope(k_r))`, `kv_lora_rank + qk_rope_head_dim` numbers
+a token a layer, never K or V: `kv_cache_layout()` says so and the serving
+engine allocates ONE pool a layer from it. Prefill and `forward` decompress
+K and V from the latent and attend in blocks (a row and a group of heads at
+a time: float32 scores of 16 x 128 x 1024 x 1024 would be 8.6 GB); decode
+over pages uses the absorbed form, multi-query attention over the one
+latent key head: `q~_h = q_nope,h W_kvb[K,h]^T`, `scores_h = (q~_h . c +
+q_rope,h . k_r) / sqrt(nope + rope)`, `o_h = (P_h c) W_kvb[V,h]`. The same
+mathematics; tests/test_latent_moe.py holds the two forms to each other.
+
+Config fields carry the source's key names; `n_routed_experts` is the
+deployment's count (the router's width) and `ep_rank` / `ep_degree` say
+which contiguous block of them lives here.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+
+from .. import nn
+from ..incubate.distributed.models.moe.expert_share import (
+    ExpertShareLayer, over_token_blocks)
+from ..kernels import paged_attention as _pa
+from ..nn import initializer as I
+from ..nn.functional.rope import apply_rope
+from ..observability.tracing import scope
+from ..tensor import _apply_op, as_array
+from .causal_lm import CausalLMBase
+
+F32 = jnp.float32
+# float32 scores one block of the decompressed attention may hold
+SCORE_BLOCK_BYTES = 128 << 20
+# tokens of one block of the dense FFN ([block, intermediate] activations)
+TOKEN_BLOCK = 4096
+
+
+@dataclass
+class LatentMoEConfig:
+    vocab_size: int = 153600
+    hidden_size: int = 7680
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    num_hidden_layers: int = 61
+    num_attention_heads: int = 128
+    num_key_value_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 256
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    first_k_dense_replace: int = 3
+    sandwich_norm: bool = True
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 25600000.0
+    max_position_embeddings: int = 131072
+    tie_word_embeddings: bool = False
+    # this chip's share of the routed experts: block `ep_rank` of
+    # `ep_degree` equal contiguous blocks
+    ep_rank: int = 0
+    ep_degree: int = 1
+    dtype: str = "float32"
+
+    @property
+    def cache_width(self):
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def layer_kinds(self):
+        """[(mixer, ffn, norms)] per layer: what the stack is built from."""
+        norms = "sandwich" if self.sandwich_norm else "pre"
+        return [("latent",
+                 "dense" if i < self.first_k_dense_replace
+                 else "routed+shared", norms)
+                for i in range(self.num_hidden_layers)]
+
+    @staticmethod
+    def tiny(vocab=96, layers=3, ep_rank=0, ep_degree=4):
+        """Every width shrunk, the kinds and ratios kept: 1 dense + 2
+        expert layers, 16 experts top-4 of which 4 held."""
+        return LatentMoEConfig(
+            vocab_size=vocab, hidden_size=48, intermediate_size=96,
+            moe_intermediate_size=24, num_hidden_layers=layers,
+            num_attention_heads=4, num_key_value_heads=4, q_lora_rank=24,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, n_routed_experts=16, num_experts_per_tok=4,
+            first_k_dense_replace=1, max_position_embeddings=128,
+            ep_rank=ep_rank, ep_degree=ep_degree)
+
+
+# ---------------------------------------------------------------------------
+# the mathematics, on plain arrays
+# ---------------------------------------------------------------------------
+
+
+def rms_norm(x, gain, eps):
+    xf = x.astype(F32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
+    return (xf * gain.astype(F32)).astype(x.dtype)
+
+
+def _mm(x, w):
+    return jnp.matmul(x, w.astype(x.dtype),
+                      preferred_element_type=F32).astype(x.dtype)
+
+
+def gated_ffn(x, w_gate, w_up, w_down):
+    """W_down(silu(x W_gate) * x W_up) on [n, d] tokens, a block of tokens
+    at a time beyond TOKEN_BLOCK."""
+    def block(xb):
+        gate = jnp.matmul(xb, w_gate.astype(xb.dtype),
+                          preferred_element_type=F32)
+        up = jnp.matmul(xb, w_up.astype(xb.dtype),
+                        preferred_element_type=F32)
+        return _mm((jax.nn.silu(gate) * up).astype(xb.dtype), w_down)
+
+    return over_token_blocks(block, TOKEN_BLOCK, x)
+
+
+def _rope(x, positions, theta):
+    """x [b, s, heads, d] rotated at `positions` [b, s], rotate-half."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
+    freqs = positions.astype(F32)[..., None] * inv_freq
+    return apply_rope(x.astype(F32), jnp.cos(freqs), jnp.sin(freqs),
+                      neox=True).astype(x.dtype)
+
+
+def latent_projections(x, p, cfg, positions):
+    """x [b, s, hidden] (normed) -> (q_nope [b, s, h, nope], q_rope [b, s,
+    h, rope], latent [b, s, rank + rope]): the queries, and the row the
+    cache keeps for each token, `(c | rope(k_r))`."""
+    b, s, _ = x.shape
+    h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+    c_q = rms_norm(_mm(x, p["q_a_proj"]), p["q_a_layernorm"],
+                   cfg.rms_norm_eps)
+    q = _mm(c_q, p["q_b_proj"]).reshape(
+        b, s, h, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+    kv = _mm(x, p["kv_a_proj_with_mqa"])
+    c = rms_norm(kv[..., :rank], p["kv_a_layernorm"], cfg.rms_norm_eps)
+    k_r = _rope(kv[..., None, rank:], positions, cfg.rope_theta)[:, :, 0]
+    q_rope = _rope(q_rope, positions, cfg.rope_theta)
+    return q_nope, q_rope, jnp.concatenate([c, k_r], axis=-1)
+
+
+def _split_kv_b(w_kv_b, cfg):
+    """W_kvb [rank, h x (nope + v)] -> (W_K [rank, h, nope], W_V [rank, h,
+    v])."""
+    w = w_kv_b.reshape(cfg.kv_lora_rank, cfg.num_attention_heads,
+                       cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def decompressed_attention(q_nope, q_rope, latent, w_kv_b, cfg, offset=0):
+    """Causal attention with K and V decompressed from the latent rows.
+
+    q_* [b, s, h, .] are the queries of positions offset .. offset + s - 1,
+    latent [b, t, rank + rope] the rows of positions 0 .. t - 1; a query
+    sees the keys at or before its own position. Returns [b, s, h x v]. One
+    batch row and one group of heads at a time, so the float32 scores of a
+    block stay under SCORE_BLOCK_BYTES."""
+    b, s, h, _ = q_nope.shape
+    t, rank = latent.shape[1], cfg.kv_lora_rank
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    w_k, w_v = _split_kv_b(w_kv_b.astype(latent.dtype), cfg)
+    group = h
+    while group > 1 and group * s * t * 4 > SCORE_BLOCK_BYTES \
+            and group % 2 == 0:
+        group //= 2
+    visible = (jnp.arange(t)[None, :] <= offset + jnp.arange(s)[:, None])
+
+    def heads(args):
+        qn, qr, wk, wv, c, k_r = args   # qn [s, g, nope], wk [rank, g, nope]
+        k_nope = jnp.einsum("tr,rgn->tgn", c, wk,
+                            preferred_element_type=F32).astype(c.dtype)
+        v = jnp.einsum("tr,rgv->tgv", c, wv,
+                       preferred_element_type=F32).astype(c.dtype)
+        scores = (jnp.einsum("sgn,tgn->gst", qn, k_nope,
+                             preferred_element_type=F32)
+                  + jnp.einsum("sgr,tr->gst", qr, k_r,
+                               preferred_element_type=F32)) * scale
+        probs = jax.nn.softmax(
+            jnp.where(visible[None], scores, _pa.NEG_INF), axis=-1)
+        return jnp.einsum("gst,tgv->sgv", probs.astype(v.dtype), v,
+                          preferred_element_type=F32).astype(qn.dtype)
+
+    def row(args):
+        qn, qr, lat = args
+        c, k_r = lat[:, :rank], lat[:, rank:]
+        if group == h:
+            return heads((qn, qr, w_k, w_v, c, k_r)).reshape(s, -1)
+        n = h // group
+        split = lambda a, axis: jnp.moveaxis(  # noqa: E731
+            a.reshape(a.shape[:axis] + (n, group) + a.shape[axis + 1:]),
+            axis, 0)
+        out = jax.lax.map(
+            lambda g: heads(g + (c, k_r)),
+            (split(qn, 1), split(qr, 1), split(w_k, 1), split(w_v, 1)))
+        return jnp.moveaxis(out, 0, 1).reshape(s, -1)   # [n, s, g, v]
+
+    if b == 1:
+        return row((q_nope[0], q_rope[0], latent[0]))[None]
+    return jax.lax.map(row, (q_nope, q_rope, latent))
+
+
+def absorbed_queries(q_nope, q_rope, w_kv_b, cfg):
+    """[b, h, rank + rope]: `q~_h = q_nope,h W_K,h^T` beside the rope
+    query, the query of multi-query attention over latent rows."""
+    w_k, _ = _split_kv_b(w_kv_b.astype(q_nope.dtype), cfg)
+    q_abs = jnp.einsum("bhn,rhn->bhr", q_nope, w_k,
+                       preferred_element_type=F32).astype(q_nope.dtype)
+    return jnp.concatenate([q_abs, q_rope], axis=-1)
+
+
+def absorbed_values(o_latent, w_kv_b, cfg):
+    """[b, h x v]: `o_h = (P_h c) W_V,h` from the attention's [b, h,
+    rank]."""
+    _, w_v = _split_kv_b(w_kv_b.astype(o_latent.dtype), cfg)
+    return jnp.einsum("bhr,rhv->bhv", o_latent, w_v,
+                      preferred_element_type=F32
+                      ).astype(o_latent.dtype).reshape(o_latent.shape[0], -1)
+
+
+# ---------------------------------------------------------------------------
+# layers
+# ---------------------------------------------------------------------------
+
+
+class _Weight(nn.Layer):
+    """One `weight` leaf under a sublayer's name, [in, out] for a matrix
+    (no bias anywhere in this family), [n] of ones for a norm's gain."""
+
+    def __init__(self, *shape):
+        super().__init__()
+        self.weight = self.create_parameter(
+            shape=list(shape),
+            default_initializer=I.Constant(1.0) if len(shape) == 1
+            else I.Normal(0.0, 0.02))
+
+
+class LatentAttention(nn.Layer):
+    LEAVES = ("q_a_proj", "q_a_layernorm", "q_b_proj", "kv_a_proj_with_mqa",
+              "kv_a_layernorm", "kv_b_proj", "o_proj")
+
+    def __init__(self, config: LatentMoEConfig):
+        super().__init__()
+        c = self.config = config
+        h = c.num_attention_heads
+        self.q_a_proj = _Weight(c.hidden_size, c.q_lora_rank)
+        self.q_a_layernorm = _Weight(c.q_lora_rank)
+        self.q_b_proj = _Weight(
+            c.q_lora_rank, h * (c.qk_nope_head_dim + c.qk_rope_head_dim))
+        self.kv_a_proj_with_mqa = _Weight(c.hidden_size, c.cache_width)
+        self.kv_a_layernorm = _Weight(c.kv_lora_rank)
+        self.kv_b_proj = _Weight(
+            c.kv_lora_rank, h * (c.qk_nope_head_dim + c.v_head_dim))
+        self.o_proj = _Weight(h * c.v_head_dim, c.hidden_size)
+
+    def _run(self, fn, *inputs, name):
+        """`fn(p, *arrays)` as one op, `p` the layer's leaves by name."""
+        leaves = [getattr(self, n).weight for n in self.LEAVES]
+
+        def f(*arrays):
+            p = dict(zip(self.LEAVES, arrays[:len(leaves)]))
+            return fn(p, *arrays[len(leaves):])
+
+        return _apply_op(f, *leaves, *inputs, _name=name)
+
+    def forward_cached(self, x, cache, cur_len):
+        """x [b, s, hidden] at positions cur_len .. cur_len + s - 1; cache
+        `(latent rows [b, t, 1, width],)`. Decompressed attention over the
+        whole cache. Returns (out, new cache)."""
+        cfg = self.config
+        (rows,) = cache
+        rows = as_array(rows)
+        start = as_array(cur_len) if hasattr(cur_len, "_data") else cur_len
+
+        def f(p, x, rows):
+            b, s, _ = x.shape
+            positions = jnp.broadcast_to(start + jnp.arange(s), (b, s))
+            q_nope, q_rope, latent = latent_projections(x, p, cfg, positions)
+            with scope("kv_write"):
+                zero = jnp.zeros((), jnp.int32)
+                rows = jax.lax.dynamic_update_slice(
+                    rows, latent[:, :, None].astype(rows.dtype),
+                    (zero, jnp.asarray(start, jnp.int32), zero, zero))
+            with scope("latent"):
+                ctx = decompressed_attention(
+                    q_nope, q_rope, rows[:, :, 0].astype(x.dtype),
+                    p["kv_b_proj"], cfg, offset=start)
+            return _mm(ctx, p["o_proj"]), rows
+
+        out, rows = self._run(f, x, rows, name="latent_attention")
+        return out, (as_array(rows),)
+
+    def forward(self, x):
+        b, s = x.shape[0], x.shape[1]
+        empty = jnp.zeros((b, s, 1, self.config.cache_width),
+                          as_array(x).dtype)
+        return self.forward_cached(x, (empty,), 0)[0]
+
+    def forward_paged(self, x, cache, block_tables, context_lens,
+                      active=None):
+        """One new token a row (x [b, 1, hidden]) over latent pages `(pool
+        [1, n_pages, page, width],)`: the row is written at context_lens[b],
+        then absorbed-form attention over the row's pages."""
+        cfg = self.config
+        (pool,) = cache
+        tables, lens = as_array(block_tables), as_array(context_lens)
+        act = None if active is None else as_array(active)
+        scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+        def f(p, x, pool):
+            q_nope, q_rope, latent = latent_projections(
+                x, p, cfg, lens[:, None])
+            with scope("kv_write"):
+                pool = _pa.update_paged_pool(pool, latent, tables, lens,
+                                             active=act)
+            q = absorbed_queries(q_nope[:, 0], q_rope[:, 0],
+                                 p["kv_b_proj"], cfg)
+            with scope("latent"):
+                o_latent = _pa.paged_latent_attention_xla(
+                    q, pool, tables, lens + 1, cfg.kv_lora_rank, scale)
+            ctx = absorbed_values(o_latent, p["kv_b_proj"], cfg)
+            return _mm(ctx, p["o_proj"])[:, None], pool
+
+        out, pool = self._run(f, x, as_array(pool), name="latent_attention")
+        return out, (as_array(pool),)
+
+
+class GatedFFN(nn.Layer):
+    def __init__(self, hidden, width):
+        super().__init__()
+        self.gate_proj = _Weight(hidden, width)
+        self.up_proj = _Weight(hidden, width)
+        self.down_proj = _Weight(width, hidden)
+
+    def forward(self, x):
+        shape = [int(s) for s in x.shape]
+        y = _apply_op(gated_ffn, x.reshape([-1, shape[-1]]),
+                      self.gate_proj.weight, self.up_proj.weight,
+                      self.down_proj.weight, _name="gated_ffn")
+        return y.reshape(shape)
+
+
+class RoutedSharedFFN(nn.Layer):
+    """This chip's share of the routed experts plus the shared expert
+    (every chip computes that alike)."""
+
+    def __init__(self, config: LatentMoEConfig):
+        super().__init__()
+        c = config
+        self.experts = ExpertShareLayer(
+            c.hidden_size, c.moe_intermediate_size, c.n_routed_experts,
+            c.num_experts_per_tok, ep_rank=c.ep_rank, ep_degree=c.ep_degree,
+            routed_scaling_factor=c.routed_scaling_factor,
+            norm_topk_prob=c.norm_topk_prob)
+        self.shared_experts = GatedFFN(
+            c.hidden_size, c.n_shared_experts * c.moe_intermediate_size)
+
+    def forward(self, x, live=None):
+        routed = self.experts(x, live=live)
+        with scope("shared"):
+            return routed + self.shared_experts(x)
+
+
+class LatentMoEDecoderLayer(nn.Layer):
+    def __init__(self, config: LatentMoEConfig, kinds):
+        super().__init__()
+        mixer, ffn, norms = kinds
+        if mixer != "latent" or norms not in ("sandwich", "pre") \
+                or ffn not in ("dense", "routed+shared"):
+            raise ValueError(f"unknown layer kinds {kinds}")
+        self.eps = config.rms_norm_eps
+        self.routed = ffn == "routed+shared"
+        self.sandwich = norms == "sandwich"
+        self.input_layernorm = _Weight(config.hidden_size)
+        self.self_attn = LatentAttention(config)
+        self.pre_mlp_layernorm = _Weight(config.hidden_size)
+        self.mlp = RoutedSharedFFN(config) if self.routed else GatedFFN(
+            config.hidden_size, config.intermediate_size)
+        if self.sandwich:
+            self.post_attention_layernorm = _Weight(config.hidden_size)
+            self.post_mlp_layernorm = _Weight(config.hidden_size)
+
+    def _norm(self, x, which):
+        return _apply_op(rms_norm, x, getattr(self, which).weight,
+                         _name="rms_norm", eps=self.eps)
+
+    def _block(self, x, attend, live=None):
+        """The block around `attend(normed x) -> (out, cache)`."""
+        with scope("attn"):
+            a, cache = attend(self._norm(x, "input_layernorm"))
+            if self.sandwich:
+                a = self._norm(a, "post_attention_layernorm")
+            x = x + a
+        with scope("mlp"):
+            m = self._norm(x, "pre_mlp_layernorm")
+            m = self.mlp(m, live=live) if self.routed else self.mlp(m)
+            if self.sandwich:
+                m = self._norm(m, "post_mlp_layernorm")
+            return x + m, cache
+
+    def forward(self, x):
+        return self._block(x, lambda a: (self.self_attn(a), None))[0]
+
+    def forward_cached(self, x, cache, cur_len):
+        return self._block(
+            x, lambda a: self.self_attn.forward_cached(a, cache, cur_len))
+
+    def forward_paged(self, x, cache, block_tables, context_lens,
+                      active=None):
+        return self._block(
+            x, lambda a: self.self_attn.forward_paged(
+                a, cache, block_tables, context_lens, active=active),
+            live=None if active is None else as_array(active))
+
+
+class LatentMoEModel(nn.Layer):
+    def __init__(self, config: LatentMoEConfig):
+        super().__init__()
+        self.config = config
+        self.embed_tokens = _Weight(config.vocab_size, config.hidden_size)
+        self.layers = nn.LayerList(
+            [LatentMoEDecoderLayer(config, kinds)
+             for kinds in config.layer_kinds()])
+        self.norm = _Weight(config.hidden_size)
+
+    def _embed(self, input_ids):
+        with scope("embed"):
+            return _apply_op(
+                lambda ids, w: jnp.take(w, ids, axis=0), input_ids,
+                self.embed_tokens.weight, _name="embedding")
+
+    def _final_norm(self, h):
+        with scope("head"):
+            return _apply_op(rms_norm, h, self.norm.weight, _name="rms_norm",
+                             eps=self.config.rms_norm_eps)
+
+    def forward(self, input_ids, attn_mask=None):
+        if attn_mask is not None:
+            raise NotImplementedError(
+                "LatentMoEModel attends causally; it takes no attn_mask")
+        h = self._embed(input_ids)
+        for layer in self.layers:
+            h = layer(h)
+        return self._final_norm(h)
+
+    def forward_cached(self, input_ids, caches, cur_len):
+        h = self._embed(input_ids)
+        new_caches = []
+        for layer, cache in zip(self.layers, caches):
+            h, nc = layer.forward_cached(h, cache, cur_len)
+            new_caches.append(nc)
+        return self._final_norm(h), new_caches
+
+    def forward_paged(self, input_ids, paged_caches, block_tables,
+                      context_lens, active=None):
+        h = self._embed(input_ids)
+        new_caches = []
+        for layer, cache in zip(self.layers, paged_caches):
+            h, nc = layer.forward_paged(h, cache, block_tables,
+                                        context_lens, active=active)
+            new_caches.append(nc)
+        return self._final_norm(h), new_caches
+
+
+class _Head(_Weight):
+    """The untied head, [hidden, vocab]: float32 logits whatever the
+    model's dtype (a bf16 logit near 2 moves in steps of 1/64)."""
+
+    def forward(self, h):
+        return _apply_op(
+            lambda h, w: jnp.matmul(h, w.astype(h.dtype),
+                                    preferred_element_type=F32),
+            h, self.weight, _name="lm_head")
+
+
+class LatentMoEForCausalLM(CausalLMBase):
+    """The serving contract of `GPTForCausalLM` (`forward`,
+    `forward_cached`, `forward_paged`, `generate`) over `LatentMoEModel`.
+    Serving only: nothing here has been trained through (the expert layer
+    and the blocked attention have no tested backward)."""
+
+    def __init__(self, config: LatentMoEConfig):
+        super().__init__()
+        self.config = config
+        self.model = LatentMoEModel(config)
+        self.lm_head = None if config.tie_word_embeddings else _Head(
+            config.hidden_size, config.vocab_size)
+        self.loss_fn = nn.CrossEntropyLoss()
+
+    def kv_cache_layout(self):
+        """One pool a layer: one head of `(c | rope(k_r))` rows."""
+        return ((1, self.config.cache_width),)
+
+    def _backbone_embed_weight(self):
+        return self.model.embed_tokens.weight
+
+    def forward(self, input_ids, attn_mask=None):
+        return self._head(self.model(input_ids, attn_mask))
+
+    def forward_cached(self, input_ids, caches, cur_len):
+        h, new_caches = self.model.forward_cached(input_ids, caches, cur_len)
+        return self._head(h), new_caches
+
+    def forward_paged(self, input_ids, paged_caches, block_tables,
+                      context_lens, active=None, mesh=None, limit_lens=None,
+                      max_layers=None):
+        if int(input_ids.shape[1]) != 1 or limit_lens is not None \
+                or max_layers is not None:
+            raise NotImplementedError(
+                "latent pages are decoded one token a row: no window step, "
+                "no shallow-exit draft (speculative decoding and chunked "
+                "prefill are not built for latent attention)")
+        h, new_caches = self.model.forward_paged(
+            input_ids, paged_caches, block_tables, context_lens,
+            active=active)
+        return self._head(h), new_caches

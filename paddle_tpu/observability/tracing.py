@@ -73,7 +73,9 @@ from . import metrics as _metrics
 
 # what the models call their parts in `op_name` (HLO metadata only, the
 # compiled programs do not change). Finer names nest under these:
-# `attn/kv_write` (the cache write), `head/sample` (the sampler).
+# `attn/kv_write` (the cache write), `attn/latent` (scores, softmax and
+# weighted sum over latent pages), `mlp/router`, `mlp/experts`, `mlp/shared`
+# (an expert layer's parts), `head/sample` (the sampler).
 SCOPES = ("embed", "attn", "mlp", "head", "optimizer")
 
 # span record ring entry: (ph, name, t0, t1, tid, trace_id, attrs)
@@ -745,6 +747,40 @@ def mark(name, seconds=None, **attrs):
     if enabled():
         now = _clock()
         _default.emit(name, now - max(seconds or 0.0, 0.0), now, **attrs)
+
+
+# -- counts a compiled program makes of its own work --------------------------
+# A model `count`s a traced integer (token-expert pairs of an expert layer)
+# while the engine, tracing its decode step, collects: the sums ride out of
+# the program beside the tokens and the engine puts them on the phase that
+# follows the step's one read. Outside a `device_counts()` nothing collects
+# and `count` drops its value, so a program nobody asks compiles unchanged.
+
+_collecting = threading.local()
+
+
+class device_counts:
+    """`with device_counts() as counts:` — `counts[name]` is the sum of
+    every `count(name, value)` made while the body was traced."""
+
+    def __enter__(self):
+        self._outer = getattr(_collecting, "counts", None)
+        _collecting.counts = counts = {}
+        return counts
+
+    def __exit__(self, exc_type, exc, tb):
+        _collecting.counts = self._outer
+        return False
+
+
+def counting() -> bool:
+    return getattr(_collecting, "counts", None) is not None
+
+
+def count(name, value):
+    counts = getattr(_collecting, "counts", None)
+    if counts is not None:
+        counts[name] = counts[name] + value if name in counts else value
 
 
 def open_spans():

@@ -93,6 +93,14 @@ def _hand_made_trace_as_a_file(request):
     modules.append(["jit_pure_step(9)", 75 * ms, 10 * ms])
     ops.append(["fusion.5", 76 * ms, 4 * ms,
                 "jit(pure_step)/transpose(jvp(attn))/dot_general"])
+    # a model that names parts of its scopes (models/latent_moe.py): inside
+    # the burst's two operations run the latent attention's and an expert
+    # layer's, and the step's counts ride on `serving.emit`
+    body = "jit(pure_burst)/while/body/closed_call/"
+    ops += [["fusion.11", 12 * ms, 2 * ms, body + "attn/latent/dot_general"],
+            ["fusion.12", 26 * ms, ms, body + "mlp/router/top_k"],
+            ["fusion.13", 27 * ms, 3 * ms, body + "mlp/experts/dot_general"],
+            ["fusion.14", 30 * ms, ms, body + "mlp/shared/dot_general"]]
     host["lines"][0]["events"] += [
         ["serving.admit", 8 * ms, ms],
         ["serving.admitted", 8 * ms + ms // 2, 900,
@@ -100,7 +108,10 @@ def _hand_made_trace_as_a_file(request):
         ["serving.decode.launch", 9 * ms, 2 * ms],
         ["serving.decode.sync", 11 * ms, 30 * ms],
         ["serving.prefill_batch", 45 * ms, 29 * ms],
-        ["serving.kv_scatter", 70 * ms, 4 * ms]]
+        ["serving.kv_scatter", 70 * ms, 4 * ms],
+        ["serving.emit", 41 * ms, 2 * ms,
+         {"expert_pairs": 12, "experts_hit": 9, "expert_layer_steps": 8,
+          "experts_held": 32}]]
     directory = request.getfixturevalue("tmp_path")
     write(raw, directory)
     request.getfixturevalue("monkeypatch").setattr(
