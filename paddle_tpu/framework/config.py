@@ -90,9 +90,11 @@ define_flag("FLAGS_paged_grouped_kernel", False,
 define_flag("FLAGS_paged_xla_max_ctx", 0,
             "Mapped-context crossover below which decode attention uses "
             "the XLA dense-gather path instead of the Pallas page-grid "
-            "kernel; 0 defers to the built-in default (2048, extrapolated "
-            "from the measured 2.2x XLA win at ctx 1024 — re-tune via the "
-            "kernel bench ctx sweep).", type_=int)
+            "kernel, for pages under 128 tokens and int8 pools (float "
+            "pools at pages of 128 and more take the kernel and never "
+            "read it); 0 defers to the built-in default (2048, "
+            "extrapolated from the measured 2.2x XLA win at ctx 1024 — "
+            "re-tune via the kernel bench ctx sweep).", type_=int)
 define_flag("FLAGS_flash_fwd_min_seq", 0,
             "Min seq for the Pallas flash forward in no-grad attention; "
             "0 defers to the built-in measured default (4096 — the v5e "

@@ -7,14 +7,22 @@ variants) — SURVEY.md §2.1 "Fused transformer ops", §7 phase 10 (hard part
 
 TPU-native design: KV lives in fixed-size pages `[kv_heads, n_pages,
 page_size, head_dim]`; each sequence owns a block table row. The decode
-kernel prefetches the block table as scalars (PrefetchScalarGridSpec) so the
-page index feeds the BlockSpec index_map — the gather happens in the
-pipeline DMA, never materializing a dense [b, s, h, d] cache. Online softmax
-accumulates across the page grid dimension in VMEM scratch.
+kernel (`paged_attention`) walks a grid of (row, page): the page index of a
+step is a prefetched scalar that feeds the BlockSpec index map, so the
+gather happens in the pipeline's DMA and nothing of the size of the mapped
+context is ever written. A step takes ALL kv heads of its page in one block
+(split only where a block would pass `_BLOCK_BYTES`), feeds the MXU the
+pool's own type and accumulates the online softmax in float32 scratch. The
+index of a page at or beyond a row's context length repeats the row's last
+live page (and a row with nothing to read repeats what the row before it
+left), so the pipeline sees an unchanged block and copies nothing: the
+kernel reads the LIVE pages, whatever the tables map.
 
-On the CPU the kernel runs in interpreter mode (CPU CI parity), and
-`paged_attention_xla` is the dense-gather reference implementation the
-tests compare against and the dispatch uses below its crossover.
+Which path decodes (`paged_attention_dispatch`): float pools whose page
+fills a K tile (`page_size >= 128`) take the kernel on the chip whatever the
+mapped context; pages under 128 tokens and int8 pools keep the crossover
+`_XLA_DECODE_MAX_CTX`; interpret mode (the CPU) takes `paged_attention_xla`,
+the dense-gather reference the tests compare against.
 """
 from __future__ import annotations
 
@@ -34,14 +42,21 @@ NEG_INF = np.float32(-1e30)
 _pc = pl.pallas_call
 
 
-# Recorded on a v5e at commit 993efc6 (KERNEL_BENCH.json, 2026-07-31): at
-# a mapped context of 1024 the XLA dense-gather path decoded 2.2x faster
-# than the Pallas page-grid kernel (one 16-token page per grid step
-# starves the MXU), while the gather's HBM traffic grows linearly with the
-# MAPPED context (pages_per_seq * page_size), so the paged kernel owns
-# long contexts. 2048 is an extrapolated crossover, not a measured one
-# (ROADMAP S4 re-times it); FLAGS_paged_xla_max_ctx overrides it.
+# The rule for pages UNDER 128 tokens and for int8 pools, which no cell of
+# the benchmark measures. Recorded on a v5e at commit 993efc6
+# (KERNEL_BENCH.json, 2026-07-31): at a mapped context of 1024 the XLA
+# dense-gather path decoded 2.2x faster than the page-grid kernel as it
+# then was (one 16-token page of ONE head per grid step starves the MXU),
+# while the gather's HBM traffic grows linearly with the MAPPED context
+# (pages_per_seq * page_size). 2048 is an extrapolated crossover, not a
+# measured one; FLAGS_paged_xla_max_ctx overrides it. Float pools at pages
+# of 128 and more never read it: there the gather writes float32 copies of
+# the whole mapped context (26 of 30 ms a decode step at 8 rows x 2,048,
+# ledger PR 27) where the kernel reads the live pages (PERF.md section 6,
+# PR 28).
 _XLA_DECODE_MAX_CTX = 2048
+
+_KERNEL_MIN_PAGE = 128  # a page that fills a K tile of the MXU
 
 
 def _xla_decode_max_ctx():
@@ -54,16 +69,19 @@ def _xla_decode_max_ctx():
 def paged_attention_dispatch(q, k_pages, v_pages, block_tables,
                              context_lens, scale=None, k_scales=None,
                              v_scales=None):
-    """Decode-attention dispatch: XLA dense-gather below the measured
-    crossover of mapped context, Pallas page-grid kernel above it (and
-    always under interpret mode, where the Pallas path is emulation).
+    """Decode-attention dispatch, from what the call can see: interpret
+    mode takes the XLA dense gather (the Pallas path is emulation there);
+    float pools at pages of `_KERNEL_MIN_PAGE` and more take the page-grid
+    kernel whatever the mapped context; smaller pages and int8 pools take
+    the gather up to the crossover of mapped context and the kernel above
+    it.
 
     With FLAGS_autotune on/readonly and no explicit
     FLAGS_paged_xla_max_ctx override, the measured winner for this
-    decode bucket (xla / per-page pallas / grouped-fetch) takes over
-    the hand-pinned crossover. Interpret mode still short-circuits to
-    XLA unless a custom timer is installed (CPU emulation timings of the
-    page-grid kernel are meaningless)."""
+    decode bucket (xla / per-page pallas / grouped-fetch) takes over.
+    Interpret mode still short-circuits to XLA unless a custom timer is
+    installed (CPU emulation timings of the page-grid kernel are
+    meaningless)."""
     from ..framework import config as _config
     from . import autotune as _at
 
@@ -90,14 +108,19 @@ def paged_attention_dispatch(q, k_pages, v_pages, block_tables,
                 q, k_pages, v_pages, block_tables, context_lens,
                 scale=scale, k_scales=k_scales, v_scales=v_scales)
 
-    mapped_ctx = block_tables.shape[1] * k_pages.shape[2]
-    if _interpret() or mapped_ctx <= _xla_decode_max_ctx():
+    page_size = k_pages.shape[2]
+    if _interpret():
+        use_xla = True
+    elif not quant and page_size >= _KERNEL_MIN_PAGE:
+        use_xla = False
+    else:
+        use_xla = block_tables.shape[1] * page_size <= _xla_decode_max_ctx()
+    if use_xla:
         return paged_attention_xla(q, k_pages, v_pages, block_tables,
                                    context_lens, scale=scale,
                                    k_scales=k_scales, v_scales=v_scales)
 
-    if (k_scales is None and v_scales is None
-            and k_pages.shape[2] == 16
+    if (not quant and page_size == 16
             and block_tables.shape[1] % _GROUP_PAGES == 0
             and _config.get_flag("FLAGS_paged_grouped_kernel", False)):
         # float 16-token pages above the crossover: the grouped-fetch
@@ -161,24 +184,37 @@ def _quant_kv_token(x):
 
 def update_paged_kv_cache(k_pages, v_pages, k_new, v_new, block_tables,
                           context_lens, active=None):
-    """Scatter one new token per sequence into its page.
+    """Write one new token per sequence into its page.
 
     k_new/v_new: [batch, kv_heads, head_dim]; context_lens[b] is the number
     of tokens already present (the new token lands at that position).
     active: optional [batch] bool — False rows write nothing (their block
-    table row may be stale, e.g. a retired serving slot)."""
-    page_size = k_pages.shape[2]
+    table row may be stale, e.g. a retired serving slot).
+
+    The write is a scatter of ROWS into the pools' flat
+    [kv_heads * n_pages * page_size, head_dim] view (a bitcast): the one
+    indexed dimension is the major one of the default layout, which the
+    decode kernel's operands are pinned to. Indexed as
+    `.at[:, page_ids, slots, :]`, XLA:TPU lays each pool out with the
+    indexed dimensions outermost and, inside the decode scan, copies it to
+    the kernel's layout and back every step (PERF.md section 6, PR 28)."""
+    kv_heads, n_pages, page_size, head_dim = k_pages.shape
     page_ids = jnp.take_along_axis(
         block_tables, (context_lens // page_size)[:, None], axis=1)[:, 0]
+    rows = (jnp.arange(kv_heads, dtype=jnp.int32)[None, :] * n_pages
+            + page_ids.astype(jnp.int32)[:, None]) * page_size \
+        + (context_lens % page_size).astype(jnp.int32)[:, None]
     if active is not None:
         # redirect inactive rows out of range; mode="drop" discards them
-        page_ids = jnp.where(active, page_ids, k_pages.shape[1])
-    slots = context_lens % page_size
-    k_pages = k_pages.at[:, page_ids, slots, :].set(
-        k_new.transpose(1, 0, 2), mode="drop")
-    v_pages = v_pages.at[:, page_ids, slots, :].set(
-        v_new.transpose(1, 0, 2), mode="drop")
-    return k_pages, v_pages
+        rows = jnp.where(active[:, None], rows,
+                         kv_heads * n_pages * page_size)
+    rows = rows.reshape(-1)  # [batch * kv_heads], as k_new's rows
+
+    def put(pages, new):
+        return pages.reshape(-1, head_dim).at[rows].set(
+            new.reshape(-1, head_dim), mode="drop").reshape(pages.shape)
+
+    return put(k_pages, k_new), put(v_pages, v_new)
 
 
 def prefill_paged_kv_cache(k_pages, v_pages, k_seq, v_seq, block_tables,
@@ -363,60 +399,68 @@ def paged_attention_window_xla(q, k_pages, v_pages, block_tables,
 
 def _decode_accumulate(q, k, v, base_pos, ctx, scale, m_scr, l_scr, acc,
                        k_col_scale=None, v_col_scale=None):
-    """One online-softmax block update shared by the per-page and
+    """One online-softmax block update shared by the page-grid and
     grouped decode kernels: scores for a K/V block starting at absolute
     position `base_pos`, masked at `ctx`, folded into the running
-    (m, l, acc) state. Optional per-COLUMN scales implement exact int8
-    dequantization (K scales after q·k, V scales on the weights; the l
-    normalizer uses unscaled pexp)."""
+    (m, l, acc) state. q [.., rows, d] and k/v [.., tokens, d] share
+    their leading dims (the kv heads of a block), which are batch dims
+    of both products; the operands go to the MXU in the type they come
+    in, and everything from the scores to the accumulator is float32 (the
+    weights take V's type for their product alone). Optional per-COLUMN
+    scales implement exact int8 dequantization (K scales after q·k, V
+    scales on the weights; the l normalizer uses unscaled pexp)."""
+    lead = tuple(range(q.ndim - 2))
+    last = q.ndim - 1
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
+        q, k, (((last,), (last,)), (lead, lead)),
         preferred_element_type=jnp.float32) * np.float32(scale)
     if k_col_scale is not None:
-        s = s * k_col_scale[None, :]
-    kpos = base_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = s * k_col_scale[..., None, :]
+    kpos = base_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, last)
     s = jnp.where(kpos < ctx, s, NEG_INF)
-    m_prev = m_scr[:, :1]
+    m_prev = m_scr[..., :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     pexp = jnp.exp(s - m_new)
-    l_scr[:, :1] = alpha * l_scr[:, :1] + jnp.sum(pexp, axis=-1,
-                                                  keepdims=True)
-    pw = pexp if v_col_scale is None else pexp * v_col_scale[None, :]
+    l_scr[..., :1] = alpha * l_scr[..., :1] + jnp.sum(pexp, axis=-1,
+                                                      keepdims=True)
+    pw = pexp if v_col_scale is None else pexp * v_col_scale[..., None, :]
     pv = jax.lax.dot_general(
-        pw, v, (((1,), (0,)), ((), ())),
+        pw.astype(v.dtype), v, (((last,), (last - 1,)), (lead, lead)),
         preferred_element_type=jnp.float32)
-    acc[:] = acc[:] * alpha + pv
-    m_scr[:, :1] = m_new
+    acc[...] = acc[...] * alpha + pv
+    m_scr[..., :1] = m_new
 
 
 def _decode_init(m_scr, l_scr, acc):
-    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc[:] = jnp.zeros_like(acc)
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc[...] = jnp.zeros_like(acc)
 
 
-def _decode_epilogue(o_ref, m_scr, l_scr, acc):
-    l = l_scr[:, :1]
-    o_ref[0, 0] = (acc[:] / jnp.where(l == 0.0, np.float32(1.0), l)).astype(
-        o_ref.dtype)
+def _decode_epilogue(l_scr, acc, dtype):
+    """acc / l, and zeros for a row that read nothing (l == 0)."""
+    l = l_scr[..., :1]
+    return (acc[...] / jnp.where(l == 0.0, np.float32(1.0), l)).astype(dtype)
 
 
-def _decode_kernel(lens_ref, tables_ref, q_ref, k_ref, v_ref, *rest,
+def _decode_kernel(lens_ref, fetch_ref, q_ref, k_ref, v_ref, *rest,
                    page_size, scale, n_pages, quant=False):
-    """Online-softmax decode over the page grid dimension.
+    """Online-softmax decode over the page grid dimension, every kv head
+    of the block at once. `fetch_ref` is read by the index maps alone.
 
-    One body serves both storage formats: with `quant` the pages hold
-    int8 and `rest` leads with the per-slot scale refs — K scales
-    multiply the score COLUMNS after q·k_int8 and V scales multiply the
-    softmax weights before p·v_int8, which is algebraically exact
+    One body serves both storage formats: float pages go to the MXU as
+    they are (q in their type); with `quant` the pages hold int8, are
+    upcast to float32, and `rest` leads with the per-slot scale refs — K
+    scales multiply the score COLUMNS after q·k_int8 and V scales multiply
+    the softmax weights before p·v_int8, which is algebraically exact
     dequantization (the l normalizer uses unscaled pexp in both modes).
     """
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc = rest
     else:
         o_ref, m_scr, l_scr, acc = rest
-    b = pl.program_id(0)
+    b = pl.program_id(1)
     p = pl.program_id(2)
 
     @pl.when(p == 0)
@@ -427,17 +471,18 @@ def _decode_kernel(lens_ref, tables_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(p * page_size < ctx)
     def _():
+        k, v = k_ref[:, 0], v_ref[:, 0]
+        if quant:
+            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
         _decode_accumulate(
-            q_ref[0, 0].astype(jnp.float32),
-            k_ref[0, 0].astype(jnp.float32),
-            v_ref[0, 0].astype(jnp.float32),
+            q_ref[0].astype(k.dtype), k, v,
             p * page_size, ctx, scale, m_scr, l_scr, acc,
-            k_col_scale=ks_ref[0, 0, 0][:page_size] if quant else None,
-            v_col_scale=vs_ref[0, 0, 0][:page_size] if quant else None)
+            k_col_scale=ks_ref[:, 0, 0, :page_size] if quant else None,
+            v_col_scale=vs_ref[:, 0, 0, :page_size] if quant else None)
 
     @pl.when(p == n_pages - 1)
     def _():
-        _decode_epilogue(o_ref, m_scr, l_scr, acc)
+        o_ref[0] = _decode_epilogue(l_scr, acc, o_ref.dtype)
 
 
 def _decode_grouped_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm,
@@ -510,7 +555,7 @@ def _decode_grouped_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm,
 
     @pl.when(g == n_groups - 1)
     def _():
-        _decode_epilogue(o_ref, m_scr, l_scr, acc)
+        o_ref[0, 0] = _decode_epilogue(l_scr, acc, o_ref.dtype)
 
 
 _GROUP_PAGES = 8  # pages per grouped-fetch step (8 x 16 = one 128 K-tile)
@@ -577,6 +622,30 @@ def paged_attention_grouped(q, k_pages, v_pages, block_tables,
     return out[:, :, :group, :].reshape(b, n_q_heads, head_dim)
 
 
+_BLOCK_BYTES = 1 << 20  # one K (or V) block: 16 heads of a 256 x 128 bf16 page
+
+
+def _live_page_ids(block_tables, context_lens, page_size):
+    """[batch, pages_per_seq] int32: the page the kernel holds at grid step
+    (row, p). The row's own p-th page while that page is live; from its
+    last live page on, that page again, so the pipeline sees an unchanged
+    block index and copies nothing. A row with nothing to read (length 0:
+    an inactive slot) repeats the page the last live row before it ended
+    on, and the rows before the first live one repeat its first page.
+    Only entries below a live row's length are taken from the tables: a
+    retired slot's stale row is never dereferenced."""
+    b, pages_per_seq = block_tables.shape
+    live = context_lens > 0
+    last = jnp.maximum(context_lens - 1, 0) // page_size
+    cols = jnp.minimum(jnp.arange(pages_per_seq)[None, :], last[:, None])
+    ids = jnp.take_along_axis(block_tables, cols, axis=1)
+    prev = jax.lax.cummax(jnp.where(live, jnp.arange(b), -1))
+    held = jnp.where(prev >= 0, ids[jnp.maximum(prev, 0), -1],
+                     ids[jnp.argmax(live), 0])
+    ids = jnp.where(live[:, None], ids, held[:, None])
+    return jnp.where(jnp.any(live), ids, 0).astype(jnp.int32)
+
+
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                     scale=None, k_scales=None, v_scales=None):
     """Single-token decode attention over a paged KV cache.
@@ -585,10 +654,17 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     k_pages/v_pages: [num_kv_heads, n_pages, page_size, head_dim]
     block_tables: [batch, pages_per_seq] int32 (page indices)
     context_lens: [batch] int32 — tokens valid in the cache (q attends over
-        these; the current token's K/V must already be written)
+        these; the current token's K/V must already be written). A row of
+        length 0 reads nothing and returns zeros.
     k_scales/v_scales: [num_kv_heads, n_pages, 128] f32 — present iff the
         pages hold int8 (see `alloc_page_scales`)
     -> [batch, num_q_heads, head_dim]
+
+    Grid (head blocks, batch, pages_per_seq), the pages innermost: a step
+    holds one page of `hb` kv heads, `hb` the most heads (a divisor of
+    num_kv_heads, which is whatever the caller's shard holds) whose block
+    stays within `_BLOCK_BYTES`. Head blocks are outermost so that a row
+    with nothing to read finds the block of the row before it resident.
     """
     b, n_q_heads, head_dim = q.shape
     n_kv_heads, _, page_size, _ = k_pages.shape
@@ -597,6 +673,11 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     if scale is None:
         scale = 1.0 / float(np.sqrt(head_dim))
     quant = k_scales is not None
+
+    page_bytes = page_size * head_dim * jnp.dtype(k_pages.dtype).itemsize
+    hb = max(h for h in range(1, n_kv_heads + 1)
+             if n_kv_heads % h == 0
+             and (h == 1 or h * page_bytes <= _BLOCK_BYTES))
 
     # [b, kv_heads, group, d]; pad group to the sublane tile (8)
     qg = q.reshape(b, n_kv_heads, group, head_dim)
@@ -608,40 +689,38 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         _decode_kernel, page_size=page_size, scale=scale,
         n_pages=pages_per_seq, quant=quant)
 
-    page_spec = pl.BlockSpec((1, 1, page_size, head_dim),
-                             lambda b, h, p, lens, tables:
-                             (h, tables[b, p], 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, gpad, head_dim),
-                     lambda b, h, p, lens, tables: (b, h, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
+    def row_map(h, b, p, lens, fetch):
+        return (b, h, 0, 0)
+
+    def page_map(h, b, p, lens, fetch):
+        return (h, fetch[b, p], 0, 0)
+
+    page_spec = pl.BlockSpec((hb, 1, page_size, head_dim), page_map)
+    in_specs = [pl.BlockSpec((1, hb, gpad, head_dim), row_map),
+                page_spec, page_spec]
     operands = [qg, k_pages, v_pages]
     if quant:
         # Scales ride in with a singleton sublane dim: a (1, lanes) trailing
         # tile over the 3D [kvh, n_pages, lanes] pool is illegal on Mosaic
         # (second-to-minor must be a multiple of 8 or the full dim), but
-        # (1, 1, 1, lanes) over [kvh, n_pages, 1, lanes] matches the array
+        # (hb, 1, 1, lanes) over [kvh, n_pages, 1, lanes] matches the array
         # dims exactly and lowers clean.
-        scale_spec = pl.BlockSpec((1, 1, 1, _SCALE_LANES),
-                                  lambda b, h, p, lens, tables:
-                                  (h, tables[b, p], 0, 0))
+        scale_spec = pl.BlockSpec((hb, 1, 1, _SCALE_LANES), page_map)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scales[:, :, None, :], v_scales[:, :, None, :]]
 
+    context_lens = context_lens.astype(jnp.int32)
+    fetch = _live_page_ids(block_tables, context_lens, page_size)
     with _x64_off():
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, n_kv_heads, pages_per_seq),
+            grid=(n_kv_heads // hb, b, pages_per_seq),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, gpad, head_dim),
-                lambda b, h, p, lens, tables: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, hb, gpad, head_dim), row_map),
             scratch_shapes=[
-                pltpu.VMEM((gpad, 128), jnp.float32),
-                pltpu.VMEM((gpad, 128), jnp.float32),
-                pltpu.VMEM((gpad, head_dim), jnp.float32),
+                pltpu.VMEM((hb, gpad, 128), jnp.float32),
+                pltpu.VMEM((hb, gpad, 128), jnp.float32),
+                pltpu.VMEM((hb, gpad, head_dim), jnp.float32),
             ],
         )
         out = _pc(
@@ -650,17 +729,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
             out_shape=jax.ShapeDtypeStruct((b, n_kv_heads, gpad, head_dim),
                                            q.dtype),
             interpret=_interpret(),
-        )(context_lens.astype(jnp.int32),
-          block_tables.astype(jnp.int32),
-          *operands)
+        )(context_lens, fetch, *operands)
     return out[:, :, :group, :].reshape(b, n_q_heads, head_dim)
 
 
 def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
                         scale=None, k_scales=None, v_scales=None):
     """Dense-gather reference: materialize [b, S, kv_h, d] then masked
-    attention. The tests' reference, and the dispatch's choice below the
-    crossover and in interpret mode."""
+    attention. The tests' reference, the dispatch's choice in interpret
+    mode, and below the crossover for small pages and int8 pools."""
     b, n_q_heads, head_dim = q.shape
     n_kv_heads, _, page_size, _ = k_pages.shape
     group = n_q_heads // n_kv_heads

@@ -11,8 +11,9 @@ plugs in via `rotate(q, k, lens)` applied INSIDE the mapped step, where
 the per-slot positions are available.
 
 Two shapes of step share this entry:
-- s == 1: classic single-token decode (the per-page Pallas kernel /
-  measured dispatch).
+- s == 1: classic single-token decode (the page-grid Pallas kernel /
+  measured dispatch). A row that is not active attends over nothing: the
+  kernel then fetches no page through its (possibly stale) table row.
 - s > 1: a WINDOW step — the speculative-decoding verify forward
   (inference/serving.py): all s tokens' K/V scatter into the pages at
   positions lens..lens+s-1 (positions at/beyond `limit_lens` masked —
@@ -25,6 +26,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..observability import tracing as _trace
 from ..observability.tracing import scope
 from ..tensor import Tensor, _apply_op, as_array
 
@@ -55,6 +57,16 @@ def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
         k_pages, v_pages = paged_cache
     act = active if active is not None else True
     limit = limit_lens
+    act_rows = jnp.broadcast_to(jnp.asarray(as_array(act), bool), (b,))
+    if s_win == 1 and _trace.counting():
+        # how far the live-page read engages, from the rows' lengths
+        # alone: pages a decode step has to read against pages mapped
+        page_size = as_array(k_pages).shape[2]
+        read = jnp.where(act_rows, as_array(context_lens) + 1, 0)
+        _trace.count("attn_pages_read", jnp.sum(
+            (read + page_size - 1) // page_size, dtype=jnp.int32))
+        _trace.count("attn_pages_mapped", jnp.int32(
+            b * as_array(block_tables).shape[1]))
 
     def step(qq, kk, vv, kp, vp, tables, lens, act_mask, *rest):
         if kv_quant:
@@ -71,19 +83,23 @@ def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
             # owned by OTHER live requests (its own output is discarded
             # by the host commit, but the clobbered page is not)
             wm = act_mask if lim is None else act_mask & (lens < lim)
+            # what a row attends over: its context and the token just
+            # written; nothing for a row that is not active (its output
+            # is discarded, and its table row may be stale)
+            read = jnp.where(act_mask, lens + 1, 0)
             if kv_quant:
                 with scope("kv_write"):
                     kp2, ksc2, vp2, vsc2 = _pa.update_paged_kv_cache_q8(
                         kp, ksc, vp, vsc, kk[:, 0], vv[:, 0],
                         tables, lens, active=wm)
-                out = attn(qq[:, 0], kp2, vp2, tables, lens + 1,
+                out = attn(qq[:, 0], kp2, vp2, tables, read,
                            k_scales=ksc2, v_scales=vsc2)
                 return out[:, None], kp2, vp2, ksc2, vsc2
             with scope("kv_write"):
                 kp2, vp2 = _pa.update_paged_kv_cache(
                     kp, vp, kk[:, 0].astype(kp.dtype),
                     vv[:, 0].astype(vp.dtype), tables, lens, active=wm)
-            out = attn(qq[:, 0], kp2, vp2, tables, lens + 1)
+            out = attn(qq[:, 0], kp2, vp2, tables, read)
             return out[:, None], kp2, vp2
         # window step (speculative verify): scatter the whole window,
         # then per-position causal attention over the paged prefix
@@ -129,7 +145,7 @@ def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
     args = [q, k, v, Tensor(as_array(k_pages)),
             Tensor(as_array(v_pages)), Tensor(as_array(block_tables)),
             Tensor(as_array(context_lens)),
-            Tensor(jnp.broadcast_to(jnp.asarray(act, bool), (b,)))]
+            Tensor(act_rows)]
     if kv_quant:
         args += [Tensor(as_array(k_scales)), Tensor(as_array(v_scales))]
     if limit is not None:

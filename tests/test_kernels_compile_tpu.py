@@ -17,6 +17,8 @@ inside a `check_vma=True` shard_map like distributed/pipeline.py's.
 libtpu's stderr chatter about TPU_ACCELERATOR_TYPE / worker hostnames is
 harmless: there is no TPU VM metadata here to read.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -195,6 +197,68 @@ def test_paged_decode_page_sizes(v5e, page, pages_per_seq, quant):
                                          quant))
 
 
+def test_paged_decode_at_the_chat_cell_shape(v5e):
+    """`gpt3-1.3b.chat-open`: batch 8, 16 + 16 heads of 128, pages of 256,
+    8 pages a row, bf16. One block holds all 16 heads of a page (1 MiB of
+    K and of V, double-buffered); both products are batched over them."""
+    assert compile_for(
+        v5e[0], pa.paged_attention,
+        *_paged_specs(8, 16, 16, 256, 8, False)) == 1
+
+
+def test_burst_holds_a_kernel_a_layer_and_copies_no_pool(v5e):
+    """The engine's decode burst at the chat cell's cache shape, lowered
+    for the v5e: one `tpu_custom_call` a layer, and inside the `while`
+    body neither a copy of a pool's size (XLA:TPU lays the operand of an
+    `.at[:, pages, slots]` scatter out with the indexed dimensions
+    outermost, the custom call pins the default layout: the token write
+    scatters rows of the flat view instead) nor anything float32 of the
+    mapped context's size."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ServingEngine
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+    paddle.seed(0)
+    layers, rows, burst = 2, 8, 16
+    cfg = GPTConfig(vocab_size=512, hidden_size=2048,
+                    num_hidden_layers=layers, num_attention_heads=16,
+                    intermediate_size=2048,
+                    max_position_embeddings=2048)
+    model = GPTForCausalLM(cfg)
+    paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+    model.eval()
+    eng = ServingEngine(model, max_batch=rows, max_seq_len=2048,
+                        page_size=256, decode_burst=burst)
+    pool = eng.k_pages[0]
+    assert pool.shape == (16, 64, 256, 128) and pool.dtype == BF16
+    one = SingleDeviceSharding(v5e[0])
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    row = lambda dt: S((rows,), dt)  # noqa: E731
+    params, buffers = eng._cached_params()
+    fn = eng._get_burst_fn(True, burst)
+    text = getattr(fn, "_fn", fn).lower(*described((
+        params, buffers, tuple(eng.k_pages), tuple(eng.v_pages), (), (),
+        row(jnp.int64), S((rows, eng.pages_per_seq), I32), row(I32),
+        row(jnp.bool_), row(I32), row(I32),
+        jax.random.key_data(jax.random.key(0)), row(jnp.bool_), row(F32),
+        row(I32), row(F32)))).compile().as_text()
+    assert text.count("tpu_custom_call") == layers
+    body = re.search(r"while\(.*body=%?([\w.\-]+)", text).group(1)
+    body = text[text.index(f"%{body} ("):]
+    body = body[:body.index("\n}\n")]
+    pool_bytes, mapped = pool.size * 2, rows * 2048 * 128
+    for shape, op in re.findall(
+            r" = (\w+\[[\d,]*\])\S* ([\w\-]+)\(", body):
+        dtype, dims = shape[:-1].split("[")
+        n = int(np.prod([int(d) for d in dims.split(",") if d]))
+        assert not (op.startswith("copy") and n * 2 >= pool_bytes), shape
+        assert not (dtype == "f32" and n >= mapped), (shape, op)
+
+
 def test_paged_decode_gqa(v5e):
     """32 query heads over 4 kv heads (group 8), per-page and grouped."""
     specs = _paged_specs(8, 32, 4, 16, 256, False)
@@ -257,18 +321,23 @@ def _lower_on_mesh(mesh, fn, specs_and_pspecs):
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
-def test_paged_kernel_inside_tp4_shard_map(v5e, quant):
-    """The TP decode step of models/paged_step.py with the XLA/Pallas
-    crossover lowered: under `check_vma=True` (jax.shard_map's default)
-    the kernel's out_shape is refused at trace time; the step states
-    check_vma=False."""
+@pytest.mark.parametrize("quant,page,pages_per_seq,max_ctx_flag", [
+    (False, 16, 128, 1), (True, 16, 128, 1), (False, 256, 8, 0)],
+    ids=["bf16", "int8kv", "bf16-page256-no-flag"])
+def test_paged_kernel_inside_tp4_shard_map(v5e, quant, page, pages_per_seq,
+                                           max_ctx_flag):
+    """The TP decode step of models/paged_step.py with the kernel lowered
+    (small pages: the XLA/Pallas crossover pushed down by its flag; the
+    chat cell's pages of 256: chosen from the page size alone, each chip's
+    step over its 4 heads): under `check_vma=True` (jax.shard_map's
+    default) the kernel's out_shape is refused at trace time; the step
+    states check_vma=False."""
     from paddle_tpu.framework import config as _config
     from paddle_tpu.models.paged_step import paged_attention_step
     from paddle_tpu.tensor import Tensor, as_array
 
     mesh = Mesh(np.asarray(v5e), ("tp",))
-    batch, heads, page, pages_per_seq = 8, 16, 16, 128
+    batch, heads = 8, 16
     q = S((batch, 1, heads, 128), BF16)
     _, pool, _, tables, lens, *scales = _paged_specs(
         batch, heads, heads, page, pages_per_seq, quant)
@@ -280,7 +349,7 @@ def test_paged_kernel_inside_tp4_shard_map(v5e, quant):
         return as_array(out), tuple(as_array(c) for c in cache)
 
     heads_p, pool_p, rep = P(None, None, "tp"), P("tp"), P()
-    _config.set_flags({"FLAGS_paged_xla_max_ctx": 1})
+    _config.set_flags({"FLAGS_paged_xla_max_ctx": max_ctx_flag})
     try:
         text = _lower_on_mesh(mesh, step, [
             (q, heads_p), (q, heads_p), (q, heads_p), (pool, pool_p),

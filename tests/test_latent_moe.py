@@ -302,7 +302,7 @@ def test_the_burst_hands_its_counts_to_the_emit_phase(model, cfg,
     assert 0 <= seen[0]["experts_hit"] <= seen[0]["expert_pairs"] <= 2 * 4 * 4
 
 
-def test_a_gpt_engine_counts_nothing_and_keeps_its_two_pools():
+def test_a_gpt_engine_keeps_its_two_pools():
     paddle.seed(0)
     m = GPTForCausalLM(GPTConfig.tiny())
     m.eval()

@@ -445,8 +445,13 @@ class TestPhases:
         assert all(set(a) == {"rid", "queued_us", "requeue"}
                    and a["queued_us"] >= 0 and a["requeue"] == 0
                    for a in admitted)
-        # the phases carry no attribute: none has a reader
-        assert not any(e[3] for e in evs if e[0] in SERVING_PHASES)
+        # the phases carry no attribute but the burst's own counts of the
+        # pages its decode attention reads and maps, on `serving.emit`
+        carried = [(e[0], set(e[3])) for e in evs
+                   if e[0] in SERVING_PHASES and e[3]]
+        assert carried and all(
+            c == ("serving.emit", {"attn_pages_read", "attn_pages_mapped"})
+            for c in carried)
         # one prefill round per request, each around its three parts
         assert sum(e[0] == "serving.prefill_batch" for e in evs) == 2
         assert tracer_off.spans_created == 0
