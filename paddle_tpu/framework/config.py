@@ -81,49 +81,15 @@ define_flag("FLAGS_cp_ring_balance", "",
             "empty (default) keeps the contiguous ring — the relayout "
             "gather cost is not chip-measured yet. Streams already in "
             "zigzag layout ignore this flag.")
-define_flag("FLAGS_paged_grouped_kernel", False,
-            "Route long-context float paged decode to the grouped-fetch "
-            "kernel (8 pages per grid step via HBM DMA). Opt-in until the "
-            "kernel is validated under real Mosaic (only interpret-mode "
-            "parity is tested so far); the dispatch policy is to never "
-            "route un-Mosaic-validated shapes into the serving hot path.")
-define_flag("FLAGS_paged_xla_max_ctx", 0,
-            "Mapped-context crossover below which decode attention uses "
-            "the XLA dense-gather path instead of the Pallas page-grid "
-            "kernel, for pages under 128 tokens and int8 pools (float "
-            "pools at pages of 128 and more take the kernel and never "
-            "read it); 0 defers to the built-in default (2048, "
-            "extrapolated from the measured 2.2x XLA win at ctx 1024 — "
-            "re-tune via the kernel bench ctx sweep).", type_=int)
-define_flag("FLAGS_flash_fwd_min_seq", 0,
-            "Min seq for the Pallas flash forward in no-grad attention; "
-            "0 defers to the built-in measured default (4096 — the v5e "
-            "crossover where XLA fused attention stops winning, "
-            "KERNEL_BENCH.json round-4).", type_=int)
 define_flag("FLAGS_flash_dropout_kernel", False,
             "Route training SDPA with dropout_p>0 to the in-kernel "
-            "threefry flash-attention dropout path. Opt-in until the "
-            "dropout kernel is validated under real Mosaic (only "
-            "interpret-mode parity is tested so far) — the same policy "
-            "as FLAGS_paged_grouped_kernel: never route un-Mosaic-"
-            "validated kernels into a hot path by default. Off: dropout "
+            "threefry flash-attention dropout path. Opt-in: "
+            "chip_smoke.py holds the kernel to its reference on the "
+            "chip, but no benchmark cell trains with dropout, so "
+            "nothing has measured it against the XLA path (ROADMAP "
+            "D2). Off: dropout "
             "attention takes the XLA reference path; dropout-free "
             "attention still uses the flash kernel.")
-define_flag("FLAGS_autotune", "off",
-            "Measured-dispatch autotuner for the Pallas kernels "
-            "(kernels/autotune.py): 'off' (default) keeps the legacy "
-            "hand-set flag dispatch bit-identical; 'on' times XLA vs the "
-            "Pallas block-size grid per (op, shape-bucket, dtype, "
-            "device-kind) on first call and caches the winner in "
-            "~/.cache/paddle_tpu/autotune_<device>.json; 'readonly' uses "
-            "cached winners but never re-times (serving hot paths must "
-            "not absorb measurement jitter). Explicit flags "
-            "(FLAGS_flash_*_min_seq, FLAGS_paged_xla_max_ctx) override "
-            "the tuner when set non-zero.")
-define_flag("FLAGS_autotune_cache_dir", "",
-            "Override directory for the autotune cache tables (empty: "
-            "~/.cache/paddle_tpu). CI points this at a temp dir so smoke "
-            "runs never touch the user cache.")
 define_flag("FLAGS_trace_sample", 0.0,
             "Span-tracing head-sampling probability "
             "(observability/tracing.py): 0 (default) disables tracing "
@@ -228,7 +194,7 @@ define_flag("FLAGS_compilewatch", False,
             "Compile observability channel "
             "(observability/compilewatch.py): counts XLA backend "
             "compiles per watched callable (jit entry points, serving "
-            "prefill/decode programs, autotune candidates) with "
+            "prefill/decode programs) with "
             "compile-time spans on the tracer, and detects recompile "
             "storms — a callable compiling for more than "
             "FLAGS_compilewatch_storm_shapes distinct argument-shape "
@@ -238,8 +204,8 @@ define_flag("FLAGS_compilewatch", False,
 define_flag("FLAGS_compilewatch_storm_shapes", 4,
             "Distinct post-warmup shape signatures per callable that "
             "trigger a recompile-storm report citing the offending "
-            "shapes (shape churn belongs in the autotuner's pow2 "
-            "buckets, not the jit executable cache).", type_=int)
+            "shapes (shape churn belongs in pow2 buckets, not the jit "
+            "executable cache).", type_=int)
 define_flag("FLAGS_stepledger", False,
             "Step-time ledger channel (observability/stepledger.py): "
             "reconcile every train/decode step's wall time into named "
@@ -311,15 +277,13 @@ define_flag("FLAGS_slo_error_budget", 0.01,
             "engine heals from (drain->rebuild->re-admit) count into "
             "serving_recoveries_total instead and do not burn budget.",
             type_=float)
-define_flag("FLAGS_quant_matmul", "auto",
+define_flag("FLAGS_quant_matmul", "xla",
             "Dispatch for the weight-only quantized linear matmul "
-            "(kernels/quant_matmul.py): 'auto' (default) consults the "
-            "FLAGS_autotune winner table for the quant_matmul op and "
-            "falls back to the legacy traced-dequant XLA expression "
-            "(bit-identical to the pre-kernel lowering) when the tuner "
-            "is off; 'fused' forces the fused dequant-in-kernel Pallas "
-            "path at the largest supported block grid (tests/smokes); "
-            "'xla' forces the traced-dequant path.")
+            "(kernels/quant_matmul.py): 'xla' (default) is the "
+            "traced-dequant XLA expression; 'fused' takes the fused "
+            "dequant-in-kernel Pallas path at the largest supported "
+            "block grid, and the XLA expression where the shape is not "
+            "supported.")
 define_flag("FLAGS_spec_decode", 0,
             "Self-speculative decoding window for the serving engine "
             "(inference/serving.py): when >= 2, greedy decode drafts "
@@ -338,14 +302,6 @@ define_flag("FLAGS_spec_draft_layers", 0,
             "paged KV for those layers. 0 (default) = half the model's "
             "layers (rounded up). Ignored when the engine was given a "
             "separate draft_model.", type_=int)
-define_flag("FLAGS_flash_bwd_min_seq", 0,
-            "Min seq for the Pallas streamed backward in training "
-            "attention; 0 defers to the built-in default (4096). At "
-            "exactly 4096 XLA's recompute grad is ~1.3x faster on the "
-            "isolated kernel but materializes the O(s^2) probs (the OOM "
-            "cliff the seq-8192 XLA reference hit); the streamed kernel "
-            "is the memory-safe default from 4096 and measured 8.3x "
-            "faster at 8192.", type_=int)
 define_flag("FLAGS_chaos", "",
             "Deterministic fault-injection schedule (faults/chaos.py): "
             "';'-separated entries `site@key=val:key=val`. Sites: "

@@ -328,11 +328,11 @@ class ServingEngine:
     rid = engine.add_request(prompt_ids, max_new_tokens=64)
     finished = engine.run()          # or: engine.step() in a loop
 
-    page_size: 16 (vLLM-style) minimizes fragmentation; on TPU at long
-    max_seq_len prefer 128 — the Pallas decode kernel processes one page
-    per grid step, so 128-token pages feed the MXU full 128x128 K-tiles
-    (8x the arithmetic per step of 16-token pages; KERNEL_BENCH.json
-    paged-decode rows measure both).
+    page_size: 16 (vLLM-style) minimizes fragmentation; on the chip float
+    pages of 128 tokens and more decode through the page-grid Pallas
+    kernel, which reads the live pages only (PERF.md section 6, PR 28:
+    7.8x on the decode step at page 256), while smaller pages gather the
+    whole mapped context (`kernels.paged_attention_dispatch`).
     """
 
     def __init__(self, model, max_batch=4, max_seq_len=256, page_size=16,
@@ -397,6 +397,19 @@ class ServingEngine:
             kv_cache_quant=kv_cache_quant, spec_decode=spec_decode,
             draft_model=draft_model, prefix_cache=prefix_cache,
             prefill_chunk=prefill_chunk)
+        # refused before anything is allocated or placed: a construction
+        # that raises leaves the caller's model where it was
+        tp = int(self.mesh.shape["tp"]) if self.mesh is not None \
+            and "tp" in self.mesh.axis_names else 1
+        if tp > 1 and self._one_pool:
+            raise ValueError(
+                "a latent page pool has one head and cannot be sharded "
+                f"over tp={tp}: latent attention serves at tp=1 "
+                "(ROADMAP R10 keeps the sharded form)")
+        if tp > 1 and kvh % tp:
+            raise ValueError(
+                f"TP serving shards the {kvh} kv heads over tp={tp}; "
+                f"the kv-head count must be divisible by tp")
         # KV pages in the MODEL's dtype (round-2 verdict weak #5: hard-coded
         # f32 pages made a bf16 model pay 2x KV memory + bandwidth); the
         # paged kernel upcasts per-block to f32 for the softmax/accum
@@ -426,17 +439,6 @@ class ServingEngine:
             from ..models.trainer import place_model
 
             place_model(model, self.mesh)
-            tp = int(self.mesh.shape["tp"]) \
-                if "tp" in self.mesh.axis_names else 1
-            if tp > 1 and self._one_pool:
-                raise ValueError(
-                    "a latent page pool has one head and cannot be sharded "
-                    f"over tp={tp}: latent attention serves at tp=1 "
-                    "(ROADMAP R10 keeps the sharded form)")
-            if tp > 1 and kvh % tp:
-                raise ValueError(
-                    f"TP serving shards the {kvh} kv heads over tp={tp}; "
-                    f"the kv-head count must be divisible by tp")
             self._page_sharding = NamedSharding(
                 self.mesh, P("tp") if tp > 1 else P())
             self._pin_pages()
@@ -1001,11 +1003,6 @@ class ServingEngine:
                 f"the compile in-traffic. Use a shorter prompt_len (<= "
                 f"{self.max_seq_len - max_new}) or a smaller decode_burst.")
         max_new = max(2, min(max_new, self.max_seq_len - plen))
-        # measured-dispatch warm: with FLAGS_autotune=on the decode
-        # bucket's candidate timing runs HERE, not under traffic (and in
-        # readonly mode this is a pure cache lookup / no-op). The tuned
-        # winner is then baked into the compiled decode program below.
-        self._autotune_decode_bucket()
         budgets = [max_new] + ([2] if self.decode_burst > 1 and
                                max_new > 2 else [])
         strategies = ["greedy_search"] + (["sampling"] if sampling else [])
@@ -1034,32 +1031,6 @@ class ServingEngine:
         # exists to prepay
         self._warmup_done = True
         return _time.perf_counter() - t0
-
-    def _autotune_decode_bucket(self):
-        """Resolve the paged-decode autotune winner for THIS engine's
-        exact cache geometry (kv heads, page size, pages/seq, dtype,
-        quant) ahead of traffic. No-op unless FLAGS_autotune is on (or
-        readonly with a warm cache)."""
-        from ..kernels import autotune as _at
-
-        if not _at.enabled() or self._one_pool:
-            return  # latent pages do not go through the tuned dispatch
-        kvh, _n, page, hd = self.k_pages[0].shape
-        qh = self.cfg.num_attention_heads
-        # under TP the decode dispatch runs INSIDE a shard_map with
-        # per-shard head counts (models/paged_step.py shards q and
-        # the pools over 'tp') — pre-tune the bucket the real
-        # dispatch will actually look up, not the full-head one
-        tp = 1
-        if self.mesh is not None and "tp" in self.mesh.axis_names:
-            tp = int(self.mesh.shape["tp"])
-        if tp > 1 and kvh % tp == 0:
-            qh //= tp
-            kvh //= tp
-        _at.choose_paged_decode(
-            self.max_batch, qh, kvh, hd, page, self.pages_per_seq,
-            jnp.dtype(self.kv_dtype).name,
-            self.kv_cache_quant == "int8")
 
     def _req_eos(self, rid):
         rp = self._req_params.get(rid)
@@ -2275,28 +2246,14 @@ class ServingEngine:
 
     def _quant_bytes_correction(self):
         """The byte delta to subtract for the CURRENT dispatch mode:
-        only when the fused dequant-in-kernel path can actually serve
-        (mirrors quant_matmul_dispatch's gate). Under the XLA traced
-        dequant the float weight IS materialized, so cost_analysis's
-        bytes are already honest — subtracting there would misclassify
-        memory-bound decode as compute-bound, the opposite dishonesty.
-        Auto mode is an approximation: a per-shape xla winner still
-        gets the correction, but the never-slower tie-break makes
-        fused the common winner wherever the tuner is live."""
-        if not self._quant_bytes_delta:
-            return 0.0
-        from ..framework import config as _config
-        from ..kernels import autotune as _at
+        only when the fused dequant-in-kernel path serves
+        (quant_matmul_dispatch's gate). Under the XLA traced dequant the
+        float weight IS materialized, so cost_analysis's bytes are
+        already honest — subtracting there would misclassify
+        memory-bound decode as compute-bound, the opposite dishonesty."""
         from ..kernels import quant_matmul as _qm
 
-        mode = str(_config.get_flag("FLAGS_quant_matmul",
-                                    "auto")).lower()
-        if mode == "fused":
-            return self._quant_bytes_delta
-        if mode == "auto" and _at.enabled() and (
-                not _qm._interpret() or _at.has_custom_timer()):
-            return self._quant_bytes_delta
-        return 0.0
+        return self._quant_bytes_delta if _qm.fused_requested() else 0.0
 
     # ------------------------------------------------------------------
     # memory observability (memwatch channel)

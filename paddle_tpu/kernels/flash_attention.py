@@ -576,32 +576,15 @@ def _run_dq_pass(q, k, v, do, lse8, delta8, scale, causal, block_q,
     return dq
 
 
-def _flash_bwd(res, g, scale, causal, block_q, block_k, seg_q=None,
-               seg_k=None, heads=1, d_lse=None, dropout=0.0, seed=None):
-    """Legacy fused backward: both passes share one block_q/block_k
-    choice (the pre-autotune behavior, bit-identical under
-    FLAGS_autotune=off)."""
-    do, lse8, delta8 = _bwd_delta(res, g, d_lse)
-    q, k, v = res[0], res[1], res[2]
-    dk, dv = _run_dkv_pass(q, k, v, do, lse8, delta8, scale, causal,
-                           block_q, block_k, seg_q=seg_q, seg_k=seg_k,
-                           heads=heads, dropout=dropout, seed=seed)
-    dq = _run_dq_pass(q, k, v, do, lse8, delta8, scale, causal, block_q,
-                      block_k, seg_q=seg_q, seg_k=seg_k, heads=heads,
-                      dropout=dropout, seed=seed)
-    return dq, dk, dv
-
-
 def _flash_bwd_split(res, g, scale, causal, dq_blocks=(DEFAULT_BLOCK_Q,
                                                        DEFAULT_BLOCK_K),
                      dkv_blocks=(DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K),
                      seg_q=None, seg_k=None, heads=1, d_lse=None,
                      dropout=0.0, seed=None):
-    """Split backward: the dq and dkv passes run with INDEPENDENT
-    grid/block choices so each gets MXU-friendly tiling instead of one
-    compromise (ISSUE 2 tentpole). Dropout regenerates the forward's
-    threefry mask from GLOBAL (q, k) coordinates, so the mask is
-    bit-identical regardless of either pass's block choice."""
+    """The backward as two passes, dkv then dq, each with its own
+    (block_q, block_k). Dropout regenerates the forward's threefry mask
+    from GLOBAL (q, k) coordinates, so the mask is bit-identical
+    regardless of either pass's block choice."""
     do, lse8, delta8 = _bwd_delta(res, g, d_lse)
     q, k, v = res[0], res[1], res[2]
     dk, dv = _run_dkv_pass(q, k, v, do, lse8, delta8, scale, causal,
@@ -615,19 +598,12 @@ def _flash_bwd_split(res, g, scale, causal, dq_blocks=(DEFAULT_BLOCK_Q,
     return dq, dk, dv
 
 
-def _flash_bwd_dq(res, g, scale, causal, block_q, block_k):
-    """Standalone dq pass (autotune candidate: the tuner times each pass
-    in isolation to pick its blocks)."""
-    do, lse8, delta8 = _bwd_delta(res, g)
-    return _run_dq_pass(res[0], res[1], res[2], do, lse8, delta8, scale,
-                        causal, block_q, block_k)
-
-
-def _flash_bwd_dkv(res, g, scale, causal, block_q, block_k):
-    """Standalone dkv pass (autotune candidate)."""
-    do, lse8, delta8 = _bwd_delta(res, g)
-    return _run_dkv_pass(res[0], res[1], res[2], do, lse8, delta8, scale,
-                         causal, block_q, block_k)
+def _flash_bwd(res, g, scale, causal, block_q, block_k, **kw):
+    """The backward every custom VJP here takes: both passes at the
+    forward's blocks."""
+    return _flash_bwd_split(res, g, scale, causal,
+                            dq_blocks=(block_q, block_k),
+                            dkv_blocks=(block_q, block_k), **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -646,29 +622,43 @@ def _flash_bhsd_fwd(q, k, v, scale, causal, block_q, block_k):
     return out, (q, k, v, out, lse)
 
 
+# From this sequence length on attention takes the Pallas kernels: the
+# backward below (streamed passes against XLA's recompute grad, which
+# materializes the O(s^2) scores) and `use_flash` for the forward. Both are
+# taken from v5e readings that predate the ledger (the flash forward crossed
+# XLA's fused attention near 4,096; the streamed backward was the
+# memory-safe choice from there). No cell of the benchmark stands on either
+# side: `train-2k` trains at 2,048, under both (ROADMAP S3 decides them).
 _PALLAS_BWD_MIN_SEQ = 4096
-# measured v5e forward-only crossover (KERNEL_BENCH.json round-4 ctx
-# sweep): XLA fused attention wins below, flash above (19.8x at 8192)
 _PALLAS_FWD_MIN_SEQ = 4096
 
 
-def _bwd_use_xla(s_q):
-    """Backward dispatch: XLA recompute grad below the threshold,
-    streamed Pallas kernels above — see FLAGS_flash_bwd_min_seq for the
-    measured rationale. Flag value 0 defers to the module constant (which
-    tests monkeypatch to force the streamed path at small seq)."""
+def use_flash(seq_q, seq_kv, head_dim, training, dropout=0.0):
+    """The one choice `scaled_dot_product_attention` asks for an unmasked
+    call: the flash kernel where it `supports` the shape at the default
+    blocks and the sequence reaches the threshold of its mode; with
+    dropout only under FLAGS_flash_dropout_kernel (ROADMAP D2 decides
+    the in-kernel dropout path)."""
     from ..framework import config as _config
 
-    thr = _config.get_flag("FLAGS_flash_bwd_min_seq", 0) \
-        or _PALLAS_BWD_MIN_SEQ
-    return s_q < thr
+    min_seq = _PALLAS_BWD_MIN_SEQ if training else _PALLAS_FWD_MIN_SEQ
+    return (supports(seq_q, seq_kv, head_dim) and seq_q >= min_seq
+            and (dropout == 0.0
+                 or bool(_config.get_flag("FLAGS_flash_dropout_kernel",
+                                          False))))
+
+
+def _bwd_use_xla(s_q):
+    """XLA recompute grad below the threshold, streamed Pallas kernels from
+    it on (tests monkeypatch the constant to force the streamed path at
+    small seq)."""
+    return s_q < _PALLAS_BWD_MIN_SEQ
 
 
 def _xla_ref_fwd(q_, k_, v_, scale, causal, seg_q=None, seg_k=None,
                  heads=1):
-    """Dense XLA reference forward over [bh, s, d]: (out, lse). Serves
-    the recompute backward's vjp AND the autotuner's XLA forward
-    candidate."""
+    """Dense XLA reference forward over [bh, s, d]: (out, lse), for the
+    recompute backward's vjp."""
     s_ = jax.lax.dot_general(
         q_, k_, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * np.float32(scale)
@@ -696,17 +686,6 @@ def _xla_ref_fwd(q_, k_, v_, scale, causal, seg_q=None, seg_k=None,
     return o_, lse_
 
 
-def _xla_sdpa_bhsd(q, k, v, scale, causal):
-    """Forward-only XLA reference (autotune candidate)."""
-    return _xla_ref_fwd(q, k, v, scale, causal)[0]
-
-
-def _flash_call(q, k, v, scale, causal, block_q, block_k):
-    """Differentiable flash entry at explicit blocks (autotune
-    candidate — timing its grad exercises the real custom-vjp path)."""
-    return _flash_bhsd(q, k, v, scale, causal, block_q, block_k)
-
-
 def _xla_ref_bwd(res, g, scale, causal, seg_q=None, seg_k=None, heads=1,
                  d_lse=None):
     """XLA-fused backward via recompute: at short sequence the O(s^2)
@@ -728,37 +707,8 @@ def _xla_ref_bwd(res, g, scale, causal, seg_q=None, seg_k=None, heads=1,
 
 
 def _dispatch_bwd(res, g, scale, causal, block_q, block_k, d_lse=None):
-    """Backward dispatch for the plain (non-seg, non-dropout) path.
-
-    Precedence: explicit flag override (FLAGS_flash_bwd_min_seq != 0)
-    beats everything; then, with FLAGS_autotune on/readonly, the measured
-    winner for this shape bucket (XLA vjp / fused pair / split dq+dkv at
-    per-pass tuned blocks); FLAGS_autotune=off is bit-identical to the
-    legacy threshold dispatch."""
-    from ..framework import config as _config
-
-    q = res[0]
-    s_q, s_kv, d = q.shape[1], res[1].shape[1], q.shape[2]
-    flag_override = bool(_config.get_flag("FLAGS_flash_bwd_min_seq", 0))
-    if not flag_override:
-        from . import autotune as _at
-
-        if _at.enabled():
-            win = _at.choose_flash_bwd(q.shape[0], s_q, s_kv, d,
-                                       jnp.dtype(q.dtype).name,
-                                       scale, causal, block_q, block_k)
-            if win is not None:
-                impl = win.meta["impl"]
-                if impl == "xla":
-                    return _xla_ref_bwd(res, g, scale, causal,
-                                        d_lse=d_lse)
-                if impl == "split":
-                    return _flash_bwd_split(
-                        res, g, scale, causal, dq_blocks=win.meta["dq"],
-                        dkv_blocks=win.meta["dkv"], d_lse=d_lse)
-                return _flash_bwd(res, g, scale, causal, block_q,
-                                  block_k, d_lse=d_lse)
-    if _bwd_use_xla(s_q):
+    """Backward of the plain (non-seg, non-dropout) path."""
+    if _bwd_use_xla(res[0].shape[1]):
         return _xla_ref_bwd(res, g, scale, causal, d_lse=d_lse)
     return _flash_bwd(res, g, scale, causal, block_q, block_k,
                       d_lse=d_lse)

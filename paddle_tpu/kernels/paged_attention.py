@@ -42,97 +42,43 @@ NEG_INF = np.float32(-1e30)
 _pc = pl.pallas_call
 
 
-# The rule for pages UNDER 128 tokens and for int8 pools, which no cell of
-# the benchmark measures. Recorded on a v5e at commit 993efc6
-# (KERNEL_BENCH.json, 2026-07-31): at a mapped context of 1024 the XLA
-# dense-gather path decoded 2.2x faster than the page-grid kernel as it
-# then was (one 16-token page of ONE head per grid step starves the MXU),
-# while the gather's HBM traffic grows linearly with the MAPPED context
-# (pages_per_seq * page_size). 2048 is an extrapolated crossover, not a
-# measured one; FLAGS_paged_xla_max_ctx overrides it. Float pools at pages
-# of 128 and more never read it: there the gather writes float32 copies of
-# the whole mapped context (26 of 30 ms a decode step at 8 rows x 2,048,
-# ledger PR 27) where the kernel reads the live pages (PERF.md section 6,
-# PR 28).
+# The crossover of MAPPED context (pages_per_seq * page_size) for pages under
+# `_KERNEL_MIN_PAGE` tokens and for int8 pools: the gather up to it, the
+# page-grid kernel above it. Taken from a v5e reading that predates the
+# ledger (at a mapped context of 1,024 and 16-token pages the gather decoded
+# 2.2x faster than the kernel as it then was, and the gather's traffic grows
+# with the mapped context); 2,048 extrapolates that reading and was never
+# measured itself. No cell of the benchmark stands on either side of it: the
+# first int8-KV cell decides it (ROADMAP D2).
 _XLA_DECODE_MAX_CTX = 2048
 
-_KERNEL_MIN_PAGE = 128  # a page that fills a K tile of the MXU
-
-
-def _xla_decode_max_ctx():
-    from ..framework import config as _config
-
-    v = _config.get_flag("FLAGS_paged_xla_max_ctx", 0)
-    return v if v else _XLA_DECODE_MAX_CTX
+# A page that fills a K tile of the MXU. Float pools at such pages take the
+# kernel whatever the mapped context: there the gather writes float32 copies
+# of the whole mapped context where the kernel reads the live pages (PERF.md
+# section 6, PR 28: `chat-open` at page 256 is the cell above it; no cell
+# serves a page under it).
+_KERNEL_MIN_PAGE = 128
 
 
 def paged_attention_dispatch(q, k_pages, v_pages, block_tables,
                              context_lens, scale=None, k_scales=None,
                              v_scales=None):
-    """Decode-attention dispatch, from what the call can see: interpret
-    mode takes the XLA dense gather (the Pallas path is emulation there);
-    float pools at pages of `_KERNEL_MIN_PAGE` and more take the page-grid
-    kernel whatever the mapped context; smaller pages and int8 pools take
-    the gather up to the crossover of mapped context and the kernel above
-    it.
-
-    With FLAGS_autotune on/readonly and no explicit
-    FLAGS_paged_xla_max_ctx override, the measured winner for this
-    decode bucket (xla / per-page pallas / grouped-fetch) takes over.
-    Interpret mode still short-circuits to XLA unless a custom timer is
-    installed (CPU emulation timings of the page-grid kernel are
-    meaningless)."""
-    from ..framework import config as _config
-    from . import autotune as _at
-
-    quant = k_scales is not None
-    if (_at.enabled()
-            and not _config.get_flag("FLAGS_paged_xla_max_ctx", 0)
-            and (not _interpret() or _at.has_custom_timer())):
-        b, n_q_heads, head_dim = q.shape
-        win = _at.choose_paged_decode(
-            b, n_q_heads, k_pages.shape[0], head_dim,
-            k_pages.shape[2], block_tables.shape[1],
-            jnp.dtype(k_pages.dtype).name, quant)
-        if win is not None:
-            impl = win.meta["impl"]
-            if impl == "xla":
-                return paged_attention_xla(
-                    q, k_pages, v_pages, block_tables, context_lens,
-                    scale=scale, k_scales=k_scales, v_scales=v_scales)
-            if impl == "grouped":
-                return paged_attention_grouped(
-                    q, k_pages, v_pages, block_tables, context_lens,
-                    scale=scale)
-            return paged_attention(
-                q, k_pages, v_pages, block_tables, context_lens,
-                scale=scale, k_scales=k_scales, v_scales=v_scales)
-
+    """Decode attention, chosen from what the call can see: interpret mode
+    takes the XLA dense gather (the Pallas path is emulation there); float
+    pools at pages of `_KERNEL_MIN_PAGE` and more take the page-grid kernel
+    whatever the mapped context; smaller pages and int8 pools take the
+    gather up to `_XLA_DECODE_MAX_CTX` of mapped context and the kernel
+    above it."""
     page_size = k_pages.shape[2]
     if _interpret():
         use_xla = True
-    elif not quant and page_size >= _KERNEL_MIN_PAGE:
+    elif k_scales is None and page_size >= _KERNEL_MIN_PAGE:
         use_xla = False
     else:
-        use_xla = block_tables.shape[1] * page_size <= _xla_decode_max_ctx()
-    if use_xla:
-        return paged_attention_xla(q, k_pages, v_pages, block_tables,
-                                   context_lens, scale=scale,
-                                   k_scales=k_scales, v_scales=v_scales)
-
-    if (not quant and page_size == 16
-            and block_tables.shape[1] % _GROUP_PAGES == 0
-            and _config.get_flag("FLAGS_paged_grouped_kernel", False)):
-        # float 16-token pages above the crossover: the grouped-fetch
-        # kernel feeds the MXU full K-tiles (G pages per step). Gated to
-        # the benchmarked page size — 128-token pages already fill a
-        # K-tile per page, and this session's int8 lesson says never
-        # route an un-Mosaic-validated shape into the serving hot path.
-        return paged_attention_grouped(q, k_pages, v_pages, block_tables,
-                                       context_lens, scale=scale)
-    return paged_attention(q, k_pages, v_pages, block_tables,
-                           context_lens, scale=scale, k_scales=k_scales,
-                           v_scales=v_scales)
+        use_xla = block_tables.shape[1] * page_size <= _XLA_DECODE_MAX_CTX
+    attend = paged_attention_xla if use_xla else paged_attention
+    return attend(q, k_pages, v_pages, block_tables, context_lens,
+                  scale=scale, k_scales=k_scales, v_scales=v_scales)
 
 
 # ---------------------------------------------------------------------------
@@ -399,10 +345,9 @@ def paged_attention_window_xla(q, k_pages, v_pages, block_tables,
 
 def _decode_accumulate(q, k, v, base_pos, ctx, scale, m_scr, l_scr, acc,
                        k_col_scale=None, v_col_scale=None):
-    """One online-softmax block update shared by the page-grid and
-    grouped decode kernels: scores for a K/V block starting at absolute
-    position `base_pos`, masked at `ctx`, folded into the running
-    (m, l, acc) state. q [.., rows, d] and k/v [.., tokens, d] share
+    """One online-softmax block update of the page-grid decode kernel:
+    scores for a K/V block starting at absolute position `base_pos`,
+    masked at `ctx`, folded into the running (m, l, acc) state. q [.., rows, d] and k/v [.., tokens, d] share
     their leading dims (the kv heads of a block), which are batch dims
     of both products; the operands go to the MXU in the type they come
     in, and everything from the scores to the accumulator is float32 (the
@@ -483,143 +428,6 @@ def _decode_kernel(lens_ref, fetch_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(p == n_pages - 1)
     def _():
         o_ref[0] = _decode_epilogue(l_scr, acc, o_ref.dtype)
-
-
-def _decode_grouped_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm,
-                           o_ref, k_vmem, v_vmem, ksem, vsem, m_scr,
-                           l_scr, acc, *, page_size, G, scale, n_groups):
-    """Grouped-fetch decode: G pages (G*page_size tokens) per grid step.
-
-    The page pools stay in HBM (memory_space=ANY); each step's pages are
-    gathered by per-page async copies into a double-buffered VMEM block,
-    so the score matmul runs on a [G*page_size, d] K-tile (full MXU
-    lanes) instead of one page — the per-page kernel's 16-token blocks
-    starve the systolic array 8-fold. Group g+2's fetch is issued after
-    group g's compute (classic two-slot pipeline: its slot was last read
-    at step g, and step g+1 computes from the other slot while the copy
-    flies)."""
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    g = pl.program_id(2)
-    gp = G * page_size
-
-    def start_group(gi, slot):
-        for p in range(G):  # static unroll: G tiny parallel DMAs
-            pid = tables_ref[b, gi * G + p]
-            pltpu.make_async_copy(
-                k_hbm.at[h, pid],
-                k_vmem.at[slot, pl.ds(p * page_size, page_size), :],
-                ksem.at[slot, p]).start()
-            pltpu.make_async_copy(
-                v_hbm.at[h, pid],
-                v_vmem.at[slot, pl.ds(p * page_size, page_size), :],
-                vsem.at[slot, p]).start()
-
-    def wait_group(slot):
-        # wait descriptors only need a shape/sem match with the started
-        # copy; page id 0 stands in for the (traced) real id
-        for p in range(G):
-            pltpu.make_async_copy(
-                k_hbm.at[h, 0],
-                k_vmem.at[slot, pl.ds(p * page_size, page_size), :],
-                ksem.at[slot, p]).wait()
-            pltpu.make_async_copy(
-                v_hbm.at[h, 0],
-                v_vmem.at[slot, pl.ds(p * page_size, page_size), :],
-                vsem.at[slot, p]).wait()
-
-    @pl.when(g == 0)
-    def _():
-        _decode_init(m_scr, l_scr, acc)
-        start_group(0, 0)
-        if n_groups > 1:
-            start_group(1, 1)
-
-    slot = jax.lax.rem(g, 2)
-    wait_group(slot)
-
-    ctx = lens_ref[b]
-
-    @pl.when(g * gp < ctx)
-    def _():
-        _decode_accumulate(
-            q_ref[0, 0].astype(jnp.float32),
-            k_vmem[slot].astype(jnp.float32),
-            v_vmem[slot].astype(jnp.float32),
-            g * gp, ctx, scale, m_scr, l_scr, acc)
-
-    # issue group g+2 into this slot AFTER the compute read it
-    @pl.when(g + 2 < n_groups)
-    def _():
-        start_group(g + 2, slot)
-
-    @pl.when(g == n_groups - 1)
-    def _():
-        o_ref[0, 0] = _decode_epilogue(l_scr, acc, o_ref.dtype)
-
-
-_GROUP_PAGES = 8  # pages per grouped-fetch step (8 x 16 = one 128 K-tile)
-
-
-def paged_attention_grouped(q, k_pages, v_pages, block_tables,
-                            context_lens, scale=None):
-    """Grouped-fetch variant of `paged_attention` (float pages only):
-    same contract, G pages per grid step via double-buffered HBM->VMEM
-    DMAs. Requires pages_per_seq % G == 0 (the engine's max_seq_len is a
-    page multiple; callers fall back to the per-page kernel otherwise)."""
-    b, n_q_heads, head_dim = q.shape
-    n_kv_heads, _, page_size, _ = k_pages.shape
-    pages_per_seq = block_tables.shape[1]
-    G = _GROUP_PAGES
-    if pages_per_seq % G:
-        raise ValueError(f"pages_per_seq {pages_per_seq} % {G} != 0")
-    n_groups = pages_per_seq // G
-    group = n_q_heads // n_kv_heads
-    if scale is None:
-        scale = 1.0 / float(np.sqrt(head_dim))
-
-    qg = q.reshape(b, n_kv_heads, group, head_dim)
-    gpad = max(8, ((group + 7) // 8) * 8)
-    if gpad != group:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gpad - group), (0, 0)))
-
-    kernel = functools.partial(
-        _decode_grouped_kernel, page_size=page_size, G=G, scale=scale,
-        n_groups=n_groups)
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
-    with _x64_off():
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, n_kv_heads, n_groups),
-            in_specs=[
-                pl.BlockSpec((1, 1, gpad, head_dim),
-                             lambda b, h, g, lens, tables: (b, h, 0, 0)),
-                hbm,
-                hbm,
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, gpad, head_dim),
-                lambda b, h, g, lens, tables: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, G * page_size, head_dim), k_pages.dtype),
-                pltpu.VMEM((2, G * page_size, head_dim), v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, G)),
-                pltpu.SemaphoreType.DMA((2, G)),
-                pltpu.VMEM((gpad, 128), jnp.float32),
-                pltpu.VMEM((gpad, 128), jnp.float32),
-                pltpu.VMEM((gpad, head_dim), jnp.float32),
-            ],
-        )
-        out = _pc(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, n_kv_heads, gpad, head_dim),
-                                           q.dtype),
-            interpret=_interpret(),
-        )(context_lens.astype(jnp.int32),
-          block_tables.astype(jnp.int32),
-          qg, k_pages, v_pages)
-    return out[:, :, :group, :].reshape(b, n_q_heads, head_dim)
 
 
 _BLOCK_BYTES = 1 << 20  # one K (or V) block: 16 heads of a 256 x 128 bf16 page

@@ -19,13 +19,8 @@ the in dim, low nibble = even row; scales are [n] per-channel or
 int4 round-trip golden is the reference the kernel is checked against.
 
 Dispatch: `quant_matmul_dispatch` is the ONE entry the quantized linears
-call. The measured-dispatch autotuner (kernels/autotune.py, op
-`quant_matmul`) times the XLA dequant reference against the fused kernel
-over the (block_n, block_k) grid per shape bucket with the same
-never-slower-than-XLA tie-break as flash/paged; FLAGS_quant_matmul
-forces a path for tests/smokes. Off / interpret-mode-without-timer falls
-back to the legacy XLA dequant expression, bit-identical to the
-pre-kernel behavior.
+call: the XLA dequant expression, or with FLAGS_quant_matmul=fused the
+kernel at the largest blocks the shape admits, where it admits any.
 """
 from __future__ import annotations
 
@@ -41,8 +36,8 @@ from . import x64_off as _x64_off
 
 _pc = pl.pallas_call
 
-# (block_n, block_k) sweep for the autotuner — the same grid family as
-# the flash kernels; block_k additionally has to divide the scale group
+# the (block_n, block_k) family `_default_blocks` picks from; block_k
+# additionally has to divide the scale group
 BLOCK_GRID_N = (128, 256, 512)
 BLOCK_GRID_K = (128, 256, 512)
 
@@ -53,7 +48,7 @@ _MAX_M = 1024
 
 
 # ---------------------------------------------------------------------------
-# XLA dequant reference (the legacy lowering; also the autotune baseline)
+# XLA dequant reference (the default lowering)
 # ---------------------------------------------------------------------------
 
 
@@ -262,57 +257,36 @@ def _fused_call(x, qw, scales, weight_dtype="int8",
 # ---------------------------------------------------------------------------
 
 
-def _mode():
+def fused_requested() -> bool:
     from ..framework import config as _config
 
-    m = str(_config.get_flag("FLAGS_quant_matmul", "auto")).lower()
-    return m if m in ("auto", "xla", "fused") else "auto"
+    return str(_config.get_flag("FLAGS_quant_matmul",
+                                "xla")).lower() == "fused"
 
 
 def quant_matmul_dispatch(x, qw, scales, weight_dtype="int8",
                           group_size=-1):
-    """Measured dispatch for y = x @ dequant(qw).
-
-    x: [..., k] float. FLAGS_quant_matmul forces 'xla' or 'fused'
-    (default block grid); 'auto' consults the autotuner's quant_matmul
-    winner table (same persistence + never-slower-than-XLA tie-break as
-    flash/paged) and falls back to the legacy XLA dequant expression
-    when the tuner is off, the shape is unsupported, or interpret mode
-    has no custom timer (CPU emulation timings are meaningless)."""
+    """y = x @ dequant(qw), x: [..., k] float: the fused kernel (largest
+    blocks the shape admits) if FLAGS_quant_matmul says 'fused' and the
+    shape is supported, else the XLA dequant expression."""
     lead = x.shape[:-1]
     k = x.shape[-1]
     x2 = x.reshape(-1, k)
     m = x2.shape[0]
     n = qw.shape[1]
-    mode = _mode()
-    if mode == "fused":
+    if fused_requested():
         bn, bk = _default_blocks(k, n, weight_dtype, group_size)
         if bn is not None and supports(m, k, n, weight_dtype, group_size,
                                        bn, bk):
             out = quant_matmul_fused(x2, qw, scales, weight_dtype,
                                      group_size, bn, bk)
             return out.reshape(lead + (n,))
-        return quant_matmul_xla(x2, qw, scales,
-                                weight_dtype).reshape(lead + (n,))
-    if mode == "auto":
-        from . import autotune as _at
-
-        if _at.enabled() and (not _interpret() or _at.has_custom_timer()):
-            win = _at.choose_quant_matmul(m, k, n, weight_dtype,
-                                          group_size,
-                                          jnp.dtype(x.dtype).name)
-            if win is not None and win.meta["impl"] == "fused":
-                out = quant_matmul_fused(
-                    x2, qw, scales, weight_dtype, group_size,
-                    win.meta["block_n"], win.meta["block_k"])
-                return out.reshape(lead + (n,))
     return quant_matmul_xla(x2, qw, scales,
                             weight_dtype).reshape(lead + (n,))
 
 
 def _default_blocks(k, n, weight_dtype, group_size):
-    """Largest grid blocks the shape admits (FLAGS_quant_matmul=fused
-    forcing path; the autotuner measures the full grid instead)."""
+    """Largest grid blocks the shape admits."""
     for bk in sorted(BLOCK_GRID_K, reverse=True):
         for bn in sorted(BLOCK_GRID_N, reverse=True):
             if supports(1, k, n, weight_dtype, group_size, bn, bk):

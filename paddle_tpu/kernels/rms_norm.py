@@ -62,28 +62,23 @@ def _bwd_kernel(x_ref, w_ref, rstd_ref, g_ref, dx_ref, dw_ref, dw_acc, *,
         dw_ref[:] = dw_acc[:].astype(dw_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def rms_norm_2d(x, w, eps, block_rows=None):
-    """block_rows: rows per grid step for BOTH passes (None: `_block`'s
-    choice from width and dtype). The autotuner sweeps it (128/256/512)
-    per shape bucket, filtered by `supports`; explicit callers keep the
-    default."""
-    out, _ = _fwd(x, w, eps, block_rows)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def rms_norm_2d(x, w, eps):
+    out, _ = _fwd(x, w, eps)
     return out
 
 
-def _block(rows, cols, itemsize, block_rows=None):
-    if block_rows is not None:
-        return block_rows
+def _block(rows, cols, itemsize):
+    """Rows per grid step of both passes, from width and dtype."""
     block = BLOCK_ROWS
     while block * cols * itemsize > _MAX_BLOCK_BYTES:
         block //= 2
     return min(block, rows)
 
 
-def _fwd(x, w, eps, block_rows=None):
+def _fwd(x, w, eps):
     rows, cols = x.shape
-    block = _block(rows, cols, x.dtype.itemsize, block_rows)
+    block = _block(rows, cols, x.dtype.itemsize)
     kernel = functools.partial(_fwd_kernel, eps=eps)
     with _x64_off():
         out, rstd = _pc(
@@ -106,15 +101,15 @@ def _fwd(x, w, eps, block_rows=None):
     return out, rstd
 
 
-def _rms_fwd(x, w, eps, block_rows=None):
-    out, rstd = _fwd(x, w, eps, block_rows)
+def _rms_fwd(x, w, eps):
+    out, rstd = _fwd(x, w, eps)
     return out, (x, w, rstd)
 
 
-def _rms_bwd(eps, block_rows, res, g):
+def _rms_bwd(eps, res, g):
     x, w, rstd = res
     rows, cols = x.shape
-    block = _block(rows, cols, x.dtype.itemsize, block_rows)
+    block = _block(rows, cols, x.dtype.itemsize)
     n_blocks = rows // block
     kernel = functools.partial(_bwd_kernel, n_rows_blocks=n_blocks)
     with _x64_off():
@@ -144,21 +139,21 @@ def _rms_bwd(eps, block_rows, res, g):
 rms_norm_2d.defvjp(_rms_fwd, _rms_bwd)
 
 
-def supports(rows, cols, block_rows=None, itemsize=4):
-    """Can the kernel run [rows, cols] of `itemsize`-byte elements (at an
-    explicit `block_rows`, if given)? The one gate: beyond it the callers
-    take the XLA expression, inside it a Mosaic refusal is an error."""
+def supports(rows, cols, itemsize=4):
+    """Can the kernel run [rows, cols] of `itemsize`-byte elements? The one
+    gate: beyond it the callers take the XLA expression, inside it a Mosaic
+    refusal is an error."""
     if rows <= 0:
         return False
-    block = _block(rows, cols, itemsize, block_rows)
+    block = _block(rows, cols, itemsize)
     return (rows % block == 0 and rows >= block and cols % 128 == 0
             and cols <= 8192
             and block * cols * itemsize <= _MAX_BLOCK_BYTES)
 
 
-def rms_norm(x, weight, eps=1e-6, block_rows=None):
+def rms_norm(x, weight, eps=1e-6):
     """x: [..., hidden]; weight: [hidden]."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
-    out = rms_norm_2d(x2, weight, float(eps), block_rows)
+    out = rms_norm_2d(x2, weight, float(eps))
     return out.reshape(shape)

@@ -3,8 +3,9 @@
 Reference parity: the flash-attn glue (paddle/phi/kernels/gpu/flash_attn_*,
 SURVEY.md §2.1 "Phi fusion kernels") and
 `paddle.nn.functional.scaled_dot_product_attention`. On TPU the fused path is
-a Pallas flash-attention kernel (paddle_tpu.kernels.flash_attention) gated by
-FLAGS_use_pallas_kernels; the fallback is one fused XLA softmax(QK^T)V.
+a Pallas flash-attention kernel (paddle_tpu.kernels.flash_attention), taken
+where its `use_flash` says so and FLAGS_use_pallas_kernels allows; otherwise
+one fused XLA softmax(QK^T)V.
 """
 from __future__ import annotations
 
@@ -55,65 +56,14 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
         rng_key = _random.next_key()
 
-    use_pallas = _config.get_flag("FLAGS_use_pallas_kernels", True)
     eff_dropout = dropout_p if training else 0.0
-    if use_pallas and attn_mask is None:
-        from ...kernels import autotune as _at
+    if attn_mask is None and _config.get_flag("FLAGS_use_pallas_kernels",
+                                              True):
         from ...kernels import flash_attention as fa
 
         qa = as_array(query)
-        b, s_q = qa.shape[0], qa.shape[1]
-        s_kv = as_array(key).shape[1]
-        h, d = qa.shape[2], qa.shape[3]
-        # explicit flags beat the autotuner (ISSUE 2 contract); with
-        # them unset and FLAGS_autotune on/readonly, dispatch follows
-        # the measured winner for this shape bucket instead of the
-        # hand-pinned min_seq constants
-        flag_name = ("FLAGS_flash_bwd_min_seq" if training
-                     else "FLAGS_flash_fwd_min_seq")
-        flag_override = bool(_config.get_flag(flag_name, 0))
-        blocks = (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K)
-        use_flash = None
-        if (_at.enabled() and not flag_override
-                and eff_dropout == 0.0 and fa.supports(s_q, s_kv, d)):
-            win = _at.choose_flash_fwd(
-                b * h, s_q, s_kv, d, jnp.dtype(qa.dtype).name,
-                bool(is_causal), 1.0 / math.sqrt(d),
-                training=training)
-            if win is not None:
-                if win.meta["impl"] == "xla":
-                    use_flash = False  # measured: XLA wins here
-                else:
-                    use_flash = True
-                    blocks = (win.meta["block_q"],
-                              win.meta["block_k"])
-        if use_flash is None:
-            # legacy threshold dispatch — measured on v5e
-            # (KERNEL_BENCH.json, in-scan timing): the flash forward
-            # crosses over XLA's fused attention at ~4096 (1.17x
-            # there, 19.8x at 8192 where the s^2 scores thrash); in
-            # training the streamed backward is the memory-safe
-            # choice from 4096 (see FLAGS_flash_bwd_min_seq)
-            if training:
-                min_seq = (_config.get_flag("FLAGS_flash_bwd_min_seq",
-                                            0)
-                           or fa._PALLAS_BWD_MIN_SEQ)
-            else:
-                min_seq = (_config.get_flag("FLAGS_flash_fwd_min_seq",
-                                            0)
-                           or fa._PALLAS_FWD_MIN_SEQ)
-            # in-kernel dropout is opt-in (ADVICE.md round-5: same
-            # policy as FLAGS_paged_grouped_kernel — un-Mosaic-
-            # validated kernels never route into a hot path by
-            # default); with the flag off, dropout attention falls
-            # through to the XLA reference path
-            dropout_ok = eff_dropout == 0.0 or _config.get_flag(
-                "FLAGS_flash_dropout_kernel", False)
-            use_flash = (fa.supports(s_q, s_kv, d)
-                         and s_q >= min_seq and dropout_ok)
-        if use_flash:
-            block_q, block_k = blocks
-
+        if fa.use_flash(qa.shape[1], as_array(key).shape[1], qa.shape[3],
+                        training, eff_dropout):
             def f(q, k, v):
                 if eff_dropout > 0.0:
                     # in-kernel threefry dropout; a fresh per-step
@@ -124,9 +74,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                     return fa.flash_attention_bshd(
                         q, k, v, causal=is_causal,
                         dropout=eff_dropout, dropout_seed=seed)
-                return fa.flash_attention_bshd(
-                    q, k, v, causal=is_causal,
-                    block_q=block_q, block_k=block_k)
+                return fa.flash_attention_bshd(q, k, v, causal=is_causal)
 
             return _apply_op(f, query, key, value,
                              _name="flash_attention")
@@ -134,14 +82,13 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     if attn_mask is not None:
 
         def f(q, k, v, m):
-            return _sdpa_reference(q, k, v, mask=m,
-                                   dropout_p=dropout_p if training else 0.0,
+            return _sdpa_reference(q, k, v, mask=m, dropout_p=eff_dropout,
                                    causal=is_causal, key=rng_key)
 
         return _apply_op(f, query, key, value, attn_mask, _name="sdpa")
 
     def f(q, k, v):
-        return _sdpa_reference(q, k, v, dropout_p=dropout_p if training else 0.0,
+        return _sdpa_reference(q, k, v, dropout_p=eff_dropout,
                                causal=is_causal, key=rng_key)
 
     return _apply_op(f, query, key, value, _name="sdpa")

@@ -11,44 +11,13 @@ from ...framework import random as _random
 from ...tensor import Tensor, _apply_op, as_array
 
 
-def _matmul(a, w):
-    """The linear/MLP matmul with measured dispatch: the autotuner's
-    `matmul` winner table (kernels/autotune.py op "matmul") picks the
-    blocked Pallas kernel only when it measured faster than XLA for this
-    shape bucket; everything else — tuner off, readonly miss, shape the
-    kernel can't tile, non-float operands — is XLA's default lowering,
-    bit-identical to the pre-autotune behavior."""
-    from ...framework import config as _config
-
-    if _config.get_flag("FLAGS_use_pallas_kernels", True):
-        from ...kernels import autotune as _at
-        from ...kernels import matmul as _kmm
-
-        if _at.enabled() and (not _kmm._interpret()
-                              or _at.has_custom_timer()) \
-                and w.ndim == 2 and a.dtype == w.dtype \
-                and jnp.issubdtype(a.dtype, jnp.floating):
-            m = int(np.prod(a.shape[:-1]))
-            k = a.shape[-1]
-            n = w.shape[-1]
-            if _kmm.supports(m, k, n):
-                win = _at.choose_matmul(m, k, n,
-                                        jnp.dtype(a.dtype).name)
-                if win is not None and win.meta["impl"] == "pallas":
-                    out = _kmm.matmul_fused(
-                        a.reshape(-1, k), w,
-                        win.meta["block_n"], win.meta["block_k"])
-                    return out.reshape(a.shape[:-1] + (n,))
-    return jnp.matmul(a, w)
-
-
 def linear(x, weight, bias=None, name=None):
     # paddle weight layout: [in_features, out_features]
     if bias is not None:
         return _apply_op(
-            lambda a, w, b: _matmul(a, w) + b, x, weight, bias, _name="linear"
-        )
-    return _apply_op(lambda a, w: _matmul(a, w), x, weight, _name="linear")
+            lambda a, w, b: jnp.matmul(a, w) + b, x, weight, bias,
+            _name="linear")
+    return _apply_op(jnp.matmul, x, weight, _name="linear")
 
 
 def dropout(x, p=0.5, axis=None, training=True, mode="upscale_in_train",

@@ -111,29 +111,13 @@ def rms_norm(x, weight=None, epsilon=1e-6, name=None):
 
     if weight is not None and _config.get_flag("FLAGS_use_pallas_kernels",
                                                True):
-        from ...kernels import autotune as _at
         from ...kernels import rms_norm as _krms
 
         a = as_array(x)
-        rows = int(np.prod(a.shape[:-1]))
-        cols = a.shape[-1]
-        use_pallas_rms = None
-        block_rows = None
-        itemsize = jnp.dtype(a.dtype).itemsize
-        if _at.enabled() and _krms.supports(rows, cols, itemsize=itemsize):
-            win = _at.choose_rms_norm(rows, cols,
-                                      jnp.dtype(a.dtype).name)
-            if win is not None:
-                if win.meta["impl"] == "xla":
-                    use_pallas_rms = False  # measured: XLA wins
-                else:
-                    use_pallas_rms = True
-                    block_rows = win.meta["block_rows"]
-        if use_pallas_rms is None:
-            use_pallas_rms = _krms.supports(rows, cols, itemsize=itemsize)
-        if use_pallas_rms:
+        if _krms.supports(int(np.prod(a.shape[:-1])), a.shape[-1],
+                          itemsize=jnp.dtype(a.dtype).itemsize):
             def fk(a_, w_):
-                return _krms.rms_norm(a_, w_, epsilon, block_rows)
+                return _krms.rms_norm(a_, w_, epsilon)
 
             return _apply_op(fk, x, weight, _name="rms_norm")
 

@@ -121,13 +121,12 @@ def weight_only_linear(x, weight, bias=None, weight_scale=None,
                        weight_dtype="int8", arch=None, group_size=-1):
     """y = x @ dequant(weight) + bias (reference: `weight_only_linear`).
 
-    The matmul routes through `kernels.quant_matmul.quant_matmul_dispatch`
-    — with the autotuner on (or FLAGS_quant_matmul=fused) the measured
-    winner may be the fused dequant-in-kernel Pallas path, which streams
-    int8/int4 weight tiles + group scales into VMEM and dequantizes
-    inside the matmul loop (the bf16 weight never exists in HBM).
-    Otherwise the legacy traced dequant (convert + scale, fused into the
-    weight load by XLA) runs bit-identically to the pre-kernel behavior.
+    The matmul routes through `kernels.quant_matmul.quant_matmul_dispatch`:
+    the traced dequant (convert + scale, fused into the weight load by XLA)
+    or, with FLAGS_quant_matmul=fused, the dequant-in-kernel Pallas path,
+    which streams int8/int4 weight tiles + group scales into VMEM and
+    dequantizes inside the matmul loop (the bf16 weight never exists in
+    HBM).
     """
     if weight_dtype not in ("int8", "int4"):
         raise ValueError("weight_dtype must be 'int8' or 'int4'")

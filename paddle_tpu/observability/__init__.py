@@ -23,8 +23,7 @@ recorder + span tracer (SURVEY.md §5 "Metrics / logging").
   atomic writers; the serving engine preempts one slot before
   poisoning).
 - `compilewatch` — compile accounting (fifth channel): every wrapped
-  jit entry point (StaticFunction, train_step, serving programs,
-  autotune candidates) gets per-callable compile counts + compile-time
+  jit entry point (StaticFunction, train_step, serving programs) gets per-callable compile counts + compile-time
   spans, and recompile storms after warmup are detected and reported
   with the offending argument shapes.
 - `httpd` — the live telemetry plane (seventh channel, the first

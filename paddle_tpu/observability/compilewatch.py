@@ -4,11 +4,10 @@ telemetry channel).
 
 XLA compiles are the silent tax of a jit runtime: a shape that misses
 the executable cache stalls the caller for seconds, and a callable fed
-unbucketed shapes recompiles forever — the pathology the autotuner's
-shape buckets exist to prevent, yet nothing reported WHERE compiles
-were happening. This module wraps the repo's jit entry points
+unbucketed shapes recompiles forever — the pathology shape buckets
+exist to prevent, yet nothing reported WHERE compiles were happening. This module wraps the repo's jit entry points
 (`jit/api.py` StaticFunction + train_step, the serving prefill/decode/
-burst programs, autotune candidate timing) and reports:
+burst programs) and reports:
 
 - **Compile counts + time per callable**: a listener on jax's
   `/jax/core/compile/backend_compile_duration` monitoring event
@@ -35,9 +34,8 @@ burst programs, autotune candidate timing) and reports:
   `FLAGS_compilewatch_storm_shapes` distinct post-warmup signatures is
   a storm: `compilewatch_storms_total` bumps, a `compilewatch.storm`
   breadcrumb lands in the flight-recorder ring, and `storm_report()`
-  names the callable and its shapes — closing the loop to the
-  autotuner's shape buckets (churning shapes belong in a bucket, not
-  the jit cache). `tools/ci.sh` gates the traced serving smoke on ZERO
+  names the callable and its shapes (churning shapes belong in a
+  bucket, not the jit cache). `tools/ci.sh` gates the traced serving smoke on ZERO
   decode recompiles after warmup.
 
 Zero-overhead contract: with `FLAGS_compilewatch` off, a wrapped call
@@ -309,9 +307,8 @@ class CompileWatch:
 
     def storm_report(self, name: Optional[str] = None) -> str:
         """The named recompile-storm report: which callable, how many
-        distinct post-warmup shapes, and the offending signatures —
-        with the autotune-bucket pointer, since shape churn is exactly
-        what the tuner's pow2 buckets absorb."""
+        distinct post-warmup shapes, and the offending signatures,
+        with a pointer to shape buckets, which absorb shape churn."""
         names = [name] if name else (self.storms() or
                                      sorted(self._records))
         lines = []
@@ -332,9 +329,9 @@ class CompileWatch:
         if lines:
             lines.append(
                 "hint: churning shapes belong in a shape bucket, not "
-                "the jit cache — pad/bucket the offending dims (the "
-                "kernels/autotune.py bucket_pow2 policy, serving's "
-                "page-multiple prefill buckets) so one compiled "
+                "the jit cache — pad/bucket the offending dims (next "
+                "power of two, serving's page-multiple prefill "
+                "buckets) so one compiled "
                 "program serves the whole family.")
         return "\n".join(lines) + ("\n" if lines else "")
 
@@ -348,8 +345,7 @@ class CompileWatch:
 
 class _CallCtx:
     """Thread-local (name, sig) attribution frame; nests (innermost
-    wins — an autotune candidate timed inside a serving warmup bills to
-    the candidate)."""
+    wins)."""
 
     __slots__ = ("_watch", "_name", "_sig", "_prev")
 
@@ -441,8 +437,8 @@ def default_watch() -> CompileWatch:
 
 
 def call(name: str, sig: Optional[tuple] = None):
-    """Attribution context for a dispatch region (autotune measurement,
-    StaticFunction program call). No-op singleton when off."""
+    """Attribution context for a dispatch region (a StaticFunction
+    program call). No-op singleton when off."""
     if not enabled():
         return _NOOP_CTX
     ensure_listener()

@@ -467,8 +467,7 @@ _atomic_seq = 0
 def atomic_write(path: str, text: str, append: bool = False):
     """Write `text` via a temp file in the target directory + os.replace:
     a scraper reading mid-write sees either the old complete file or the
-    new complete file, never a torn one (same discipline as the autotune
-    cache). The temp name is unique per (pid, thread, call) so concurrent
+    new complete file, never a torn one. The temp name is unique per (pid, thread, call) so concurrent
     writers of the SAME path can't truncate each other's temp file — the
     last replace wins whole, never torn.
 

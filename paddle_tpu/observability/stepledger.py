@@ -55,11 +55,6 @@ optimization target is read off a table instead of guessed:
   dtype only — safe AFTER a donating call consumed the real buffers)
   and compiles once per entry point, only under the flag.
 
-- **Autotuner ground truth** (`autotune_ground_truth()`): where the
-  kernel autotuner has measured per-candidate timings, the report
-  cites them — measured kernel milliseconds, not estimates, for the
-  kernels the roofline points at.
-
 Zero-overhead contract: `FLAGS_stepledger` unset = ONE flag read per
 step (`begin()` returns None), zero ledger records and zero registry
 allocations — pinned by tests/test_stepledger.py, the memwatch/
@@ -92,7 +87,6 @@ SPAN_BUCKETS = (
     ("serving.decode", "compute"),
     ("collective.", "collective"),
     ("compile.", "compile"),
-    ("autotune.", "compile"),
     ("dataloader.", "data_wait"),
     ("checkpoint.", "host"),
 )
@@ -120,8 +114,7 @@ ADVICE_COMPUTE = {
                  "paged-attention kernels (ROADMAP item 2), remat "
                  "policy",
     "compute-bound": "raise the MFU operating point (tools/"
-                     "mfu_sweep.py) and extend the autotuner to the "
-                     "matmul/MLP kernels (ROADMAP item 3)",
+                     "mfu_sweep.py) (ROADMAP item 3)",
     "comms-bound": "overlap communication with compute "
                    "(ROADMAP item 3)",
     "unknown": "register the entry point's cost_analysis "
@@ -606,36 +599,6 @@ def roofline(entry: str) -> dict:
     return out
 
 
-def autotune_ground_truth() -> List[dict]:
-    """Measured per-kernel timings from the autotuner's winner table —
-    ground truth for the kernels the roofline points at (empty when the
-    tuner never measured)."""
-    try:
-        from ..kernels import autotune as _at
-
-        snap = _at.get_tuner().snapshot()
-    except Exception:  # noqa: BLE001
-        return []
-    rows = []
-    for key, entry in sorted(snap.items()):
-        timings = entry.get("timings_ms") or {}
-        winner = entry.get("winner")
-        if not timings or winner not in timings:
-            continue
-        xla = min((v for k, v in timings.items()
-                   if k.startswith("xla")), default=None)
-        rows.append({
-            "op": entry.get("op") or key.split("|", 1)[0],
-            "key": key,
-            "winner": winner,
-            "winner_ms": timings[winner],
-            "xla_ms": xla,
-            "speedup_vs_xla": round(xla / timings[winner], 3)
-            if xla and timings[winner] else None,
-        })
-    return rows
-
-
 # ---------------------------------------------------------------------------
 # exposition + report
 # ---------------------------------------------------------------------------
@@ -793,15 +756,6 @@ def format_report(rows: Optional[List[dict]] = None,
                 f" {i + 1}. {t['entry']} · {t['bucket']} "
                 f"{t['share'] * 100.0:.1f}% of step{bound} -> "
                 f"{t['advice']}")
-        lines.append("")
-    gt = autotune_ground_truth()
-    if gt:
-        lines.append("== autotuner measured ground truth ==")
-        for r in gt[:10]:
-            sp = (f" ({r['speedup_vs_xla']}x vs xla)"
-                  if r.get("speedup_vs_xla") else "")
-            lines.append(f"  {r['op']}: winner {r['winner']} "
-                         f"{r['winner_ms']:.3f} ms{sp}")
         lines.append("")
     return "\n".join(lines) + "\n"
 

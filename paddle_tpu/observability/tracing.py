@@ -5,7 +5,7 @@ The metrics registry answers "what are the aggregates" and the flight
 recorder answers "what happened just before the hang" — neither answers
 "*why* was THIS request's TTFT 900 ms" or "which phase of step N ate the
 budget". Spans do: every instrumented hot path (serving request
-lifecycle, train step phases, autotune measurement, checkpoint saves,
+lifecycle, train step phases, checkpoint saves,
 collective calls, dataloader fetches) records bounded, monotonic-clock
 intervals that export directly into the Chrome trace-event JSON format
 Perfetto / chrome://tracing load natively, and that
@@ -324,8 +324,7 @@ class _OpenSpan:
         tracer._open[id(self)] = self
 
     def set(self, **attrs):
-        """Attach attributes discovered mid-span (e.g. the autotune
-        winner)."""
+        """Attach attributes discovered mid-span."""
         if self.attrs is None:
             self.attrs = attrs
         else:
@@ -561,8 +560,7 @@ class Tracer:
 
     def span(self, name, **attrs):
         """Freestanding synchronous span on the calling thread's track
-        (control-plane phases: autotune measurement, checkpoint saves,
-        collective calls). Committed whenever tracing is enabled — these
+        (control-plane phases: checkpoint saves, collective calls). Committed whenever tracing is enabled — these
         are low-rate and always worth keeping."""
         if not enabled():
             return NOOP_SPAN

@@ -3,6 +3,7 @@ tiny size on the CPU's eight host devices — so the script the driver runs
 on the TPU cannot rot between chip runs. What only a chip can show (Mosaic
 execution, HBM, the sync check) is chip_smoke.py's own business;
 tests/test_kernels_compile_tpu.py compiles its kernel table for a v5e."""
+import gc
 import os
 import subprocess
 import sys
@@ -59,7 +60,11 @@ def test_phases_at_tiny_size(cs, compilewatch_on, monkeypatch):
                 held[shard.device] += shard.data.nbytes
         return [held[d] for d in jax_.local_devices()]
 
-    # other tests of this process may have left arrays alive on device 0
+    # other tests of this process may have left arrays alive, and models
+    # (reference cycles) stay until a collection: chip_smoke's `release`
+    # collects between phases, so the baseline is taken collected too,
+    # or what an earlier file left as garbage reads as bytes given back
+    gc.collect()
     before = live_bytes(jax)
     monkeypatch.setattr(cs, "device_bytes_in_use", lambda jax_: [
         now - was for now, was in zip(live_bytes(jax_), before)])

@@ -76,8 +76,8 @@ class TestCounting:
             om.set_default_registry(prev)
 
     def test_attribution_context_nests(self, cw_on):
-        # innermost frame wins: an autotune-style inner region bills to
-        # itself, not the outer callable
+        # innermost frame wins: an inner region bills to itself, not
+        # the outer callable
         with cw.call("outer"):
             with cw.call("inner"):
                 jax.jit(lambda a: a - 1)(jnp.ones((5,)))
@@ -126,7 +126,7 @@ class TestWarmupAndStorms:
         assert "3 distinct" in report
         for shape in ("float32[4]", "float32[5]", "float32[6]"):
             assert shape in report
-        # closes the loop to the autotuner's shape buckets
+        # the hint points at shape buckets
         assert "bucket" in report
         # a breadcrumb landed in the flight-recorder ring
         assert any(k == "compilewatch.storm"

@@ -1,7 +1,7 @@
 """Pallas flash-attention kernel tests (interpret mode on CPU CI —
 SURVEY.md §7 phase 9; reference: phi flash_attn / flash_attn_varlen
-kernels). The same kernels run compiled on TPU (tools/tpu_kernel_bench.py
-validates numerics + speed on the chip)."""
+kernels). The same kernels run compiled on TPU (chip_smoke.py's kernels
+phase validates their numerics on the chip)."""
 import math
 
 import numpy as np
@@ -274,59 +274,3 @@ class TestLseVariant:
         for a, b_ in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        atol=5e-3, rtol=5e-3)
-
-
-class TestGroupedPagedDecode:
-    """Grouped-fetch decode kernel (G pages per grid step via HBM->VMEM
-    DMA): parity vs the dense-gather reference across contexts, GQA
-    padding, and page-boundary lens — interpret mode on CPU, the same
-    code path the real Mosaic compiler lowers on TPU."""
-
-    def _pools(self, rng, kvh, n_pages, page, hd, dtype):
-        import jax.numpy as jnp
-        kp = jnp.asarray(rng.standard_normal((kvh, n_pages, page, hd)),
-                         dtype)
-        vp = jnp.asarray(rng.standard_normal((kvh, n_pages, page, hd)),
-                         dtype)
-        return kp, vp
-
-    def test_parity_multi_group(self):
-        import jax.numpy as jnp
-        from paddle_tpu.kernels import paged_attention as pa
-        rng = np.random.default_rng(0)
-        kp, vp = self._pools(rng, 2, 96, 16, 128, jnp.float32)
-        q = jnp.asarray(rng.standard_normal((3, 4, 128)), jnp.float32)
-        bt = jnp.asarray(rng.permutation(96)[:3 * 24].reshape(3, 24),
-                         jnp.int32)
-        # lens cross group boundaries: 384 = full, 129 = just into g1,
-        # 16 = one page
-        cl = jnp.asarray([384, 129, 16], jnp.int32)
-        o = pa.paged_attention_grouped(q, kp, vp, bt, cl)
-        r = pa.paged_attention_xla(q, kp, vp, bt, cl)
-        np.testing.assert_allclose(np.asarray(o), np.asarray(r),
-                                   atol=1e-4)
-
-    def test_parity_gqa_bf16(self):
-        import jax.numpy as jnp
-        from paddle_tpu.kernels import paged_attention as pa
-        rng = np.random.default_rng(1)
-        kp, vp = self._pools(rng, 2, 32, 16, 128, jnp.bfloat16)
-        q = jnp.asarray(rng.standard_normal((2, 12, 128)), jnp.bfloat16)
-        bt = jnp.asarray(rng.integers(0, 32, (2, 8)), jnp.int32)
-        cl = jnp.asarray([100, 37], jnp.int32)
-        o = pa.paged_attention_grouped(q, kp, vp, bt, cl)
-        r = pa.paged_attention_xla(q, kp, vp, bt, cl)
-        np.testing.assert_allclose(
-            np.asarray(o, np.float32), np.asarray(r, np.float32),
-            atol=0.04)
-
-    def test_dispatch_requires_group_multiple(self):
-        from paddle_tpu.kernels import paged_attention as pa
-        import jax.numpy as jnp
-        rng = np.random.default_rng(2)
-        kp, vp = self._pools(rng, 1, 8, 16, 128, jnp.float32)
-        q = jnp.asarray(rng.standard_normal((1, 1, 128)), jnp.float32)
-        bt = jnp.asarray(rng.integers(0, 8, (1, 6)), jnp.int32)
-        cl = jnp.asarray([50], jnp.int32)
-        with pytest.raises(ValueError):
-            pa.paged_attention_grouped(q, kp, vp, bt, cl)

@@ -1,8 +1,8 @@
 """Split dq/dkv flash-attention backward (ISSUE 2 tentpole, second half).
 
-The backward is restructured into separately-callable dq and dkv Pallas
-passes with INDEPENDENT block choices (kernels/flash_attention.py
-`_flash_bwd_split` / `_flash_bwd_dq` / `_flash_bwd_dkv`). Acceptance:
+The backward is two Pallas passes, dq and dkv, with INDEPENDENT block
+choices (kernels/flash_attention.py `_flash_bwd_split`; `_flash_bwd`, what
+every custom VJP takes, is both passes at the forward's blocks). Acceptance:
 grad-check against the XLA recompute vjp to <= 1e-3 rel error in
 interpret mode across causal / GQA / dropout variants, matching the
 rigor of tests/test_flash_dropout.py (finite differences for the dropout
@@ -16,9 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.kernels import autotune as at
 from paddle_tpu.kernels import flash_attention as fa
-from paddle_tpu.framework import config as _config
 
 
 def _rand(shape, seed):
@@ -77,14 +75,17 @@ class TestSplitVsXlaVjp:
                     f"{name} blocks={dq_blocks}/{dkv_blocks} " \
                     f"causal={causal} gqa={kv_heads}: rel err {err}"
 
-    def test_standalone_passes_equal_split(self):
+    def test_each_pass_alone_equals_split(self):
         b, s, h, d = 1, 256, 2, 128
         res, g, scale = _make_res(b, s, h, d, True)
         dq, dk, dv = fa._flash_bwd_split(res, g, scale, True,
                                          dq_blocks=(128, 128),
                                          dkv_blocks=(256, 256))
-        dq2 = fa._flash_bwd_dq(res, g, scale, True, 128, 128)
-        dk2, dv2 = fa._flash_bwd_dkv(res, g, scale, True, 256, 256)
+        do, lse8, delta8 = fa._bwd_delta(res, g)
+        dq2 = fa._run_dq_pass(*res[:3], do, lse8, delta8, scale, True,
+                              128, 128)
+        dk2, dv2 = fa._run_dkv_pass(*res[:3], do, lse8, delta8, scale,
+                                    True, 256, 256)
         np.testing.assert_array_equal(np.asarray(dq), np.asarray(dq2))
         np.testing.assert_array_equal(np.asarray(dk), np.asarray(dk2))
         np.testing.assert_array_equal(np.asarray(dv), np.asarray(dv2))
@@ -220,87 +221,3 @@ class TestSegmentedSplit:
                                   seg_q=seg8, seg_k=seg8, heads=h)
         for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
             assert _rel_err(a, b_) <= 1e-3, name
-
-
-class TestAutotunedBwdDispatch:
-    def test_tuned_split_routes_through_custom_vjp(self, tmp_path,
-                                                   monkeypatch):
-        """End to end: a fake timer that makes the split strategy win
-        must route jax.grad(flash) through `_flash_bwd_split`, and the
-        grads must still match the XLA vjp."""
-        monkeypatch.setattr(_config._FLAGS["FLAGS_autotune"], "value",
-                            "on")
-        monkeypatch.setattr(_config._FLAGS["FLAGS_autotune_cache_dir"],
-                            "value", str(tmp_path))
-        at.reset_tuner()
-
-        def timer(fn, args):
-            return 1.0 if getattr(fn, "__name__", "") == "split_bwd" \
-                else 10.0
-
-        at.set_timer(timer)
-        hit = {"split": False}
-        orig = fa._flash_bwd_split
-
-        def spy(*a, **kw):
-            hit["split"] = True
-            return orig(*a, **kw)
-
-        monkeypatch.setattr(fa, "_flash_bwd_split", spy)
-        try:
-            b, s, h, d = 1, 256, 2, 128
-            q, k, v, g = (_rand((b, s, h, d), i) for i in range(4))
-
-            def loss(q_, k_, v_):
-                out = fa.flash_attention_bshd(q_, k_, v_, causal=True)
-                return jnp.sum(out * g)
-
-            grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-            assert hit["split"], "tuned winner must route to split bwd"
-            qt, kt, vt, gt = map(_bhsd, (q, k, v, g))
-            out, lse = fa._flash_fwd(qt, kt, vt, 1.0 / math.sqrt(d),
-                                     True, 128, 128)
-            want = fa._xla_ref_bwd((qt, kt, vt, out, lse), gt,
-                                   1.0 / math.sqrt(d), True)
-            bhsd = [_bhsd(x) for x in grads]
-            for name, a, b_ in zip(("dq", "dk", "dv"), bhsd, want):
-                assert _rel_err(a, b_) <= 1e-3, name
-        finally:
-            at.set_timer(None)
-            at.reset_tuner()
-
-    def test_flag_override_beats_tuned_bwd(self, tmp_path, monkeypatch):
-        """FLAGS_flash_bwd_min_seq set explicitly: the backward ignores
-        any cached winner and follows the flag (XLA below threshold)."""
-        monkeypatch.setattr(_config._FLAGS["FLAGS_autotune"], "value",
-                            "on")
-        monkeypatch.setattr(_config._FLAGS["FLAGS_autotune_cache_dir"],
-                            "value", str(tmp_path))
-        monkeypatch.setattr(_config._FLAGS["FLAGS_flash_bwd_min_seq"],
-                            "value", 10**9)
-        at.reset_tuner()
-        boom_calls = []
-        at.set_timer(lambda fn, args: boom_calls.append(fn) or 1.0)
-        hit = {"xla": False}
-        orig = fa._xla_ref_bwd
-
-        def spy(*a, **kw):
-            hit["xla"] = True
-            return orig(*a, **kw)
-
-        monkeypatch.setattr(fa, "_xla_ref_bwd", spy)
-        try:
-            b, s, h, d = 1, 256, 2, 128
-            q, k, v, g = (_rand((b, s, h, d), i) for i in range(4))
-
-            def loss(q_, k_, v_):
-                out = fa.flash_attention_bshd(q_, k_, v_, causal=True)
-                return jnp.sum(out * g)
-
-            jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-            assert hit["xla"], "flag must force the XLA backward"
-            assert boom_calls == [], \
-                "explicit flag override must bypass the tuner"
-        finally:
-            at.set_timer(None)
-            at.reset_tuner()

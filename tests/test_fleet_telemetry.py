@@ -469,6 +469,15 @@ from paddle_tpu.observability import fleet
 assert os.environ["FLAGS_telemetry_dir"], "controller must set the env"
 rank = int(os.environ["PADDLE_TRAINER_ID"])
 x = paddle.to_tensor(np.ones((64,), np.float32))
+# the two processes start some tenths of a second apart, either first,
+# and these collectives do not wait for each other: meet here, or the
+# start-up skew decides who is "last", not rank 1's sleep
+open(os.path.join({sync!r}, f"ready_{{rank}}"), "w").close()
+deadline = time.time() + 60
+while not all(os.path.exists(os.path.join({sync!r}, f"ready_{{r}}"))
+              for r in (0, 1)):
+    assert time.time() < deadline, "the other rank never arrived"
+    time.sleep(0.002)
 for step in range(3):
     if rank == 1:
         time.sleep(0.1)
@@ -487,7 +496,8 @@ class TestLauncherWiring:
 
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         script = tmp_path / "worker.py"
-        script.write_text(_LAUNCH_WORKER.format(repo=repo))
+        script.write_text(_LAUNCH_WORKER.format(repo=repo,
+                                                sync=str(tmp_path)))
         tdir = tmp_path / "telemetry"
         ctx = JobContext(script=str(script), nproc_per_node=2,
                          log_dir=str(tmp_path / "log"),

@@ -1,0 +1,292 @@
+"""Which implementation each operation with a Pallas kernel takes, from what
+the call can see: shapes, dtypes, the pool's type and `_interpret()`. One
+table an operation; the implementation is the one TRACED, counted as
+`chip_smoke.count_calls` counts (the chosen function is replaced by a
+recorder), never read back from a flag. Nothing is computed: every call is
+traced under `jax.eval_shape`.
+
+The rows hold the three benchmark cells' own shapes and both sides of every
+boundary the choice has (`paged_attention._KERNEL_MIN_PAGE`,
+`_XLA_DECODE_MAX_CTX`, `flash_attention._PALLAS_FWD_MIN_SEQ` /
+`_PALLAS_BWD_MIN_SEQ`, the kernels' `supports`)."""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.framework import config as _config
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import paged_attention as pa
+from paddle_tpu.kernels import quant_matmul as qm
+from paddle_tpu.kernels import rms_norm as rn
+from paddle_tpu.tensor import Tensor, as_array
+
+sdpa_mod = importlib.import_module("paddle_tpu.nn.functional.attention")
+norm_mod = importlib.import_module("paddle_tpu.nn.functional.norm")
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+KERNEL, GATHER = "paged_attention", "paged_attention_xla"
+FLASH, XLA = "flash_attention_bshd", "_sdpa_reference"
+
+
+def S(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def record(monkeypatch, taken, module, name, result):
+    """Replace `module.name` by a recorder that returns `result(*args)`."""
+    def recorder(*args, **kw):
+        taken.append(name)
+        return result(*args)
+
+    monkeypatch.setattr(module, name, recorder)
+
+
+# ---------------------------------------------------------------------------
+# decode attention over pages: paged_attention_dispatch
+# ---------------------------------------------------------------------------
+
+# (rows, q heads, kv heads, page, pages a row, pool type) -> off interpret mode
+PAGED = {
+    # gpt3-1.3b.chat-open: 8 slots, 16 heads of 128, pages of 256, bf16
+    "chat-open": ((8, 16, 16, 256, 8, BF16), KERNEL),
+    "page128-under-crossover": ((8, 16, 16, 128, 8, BF16), KERNEL),
+    "page127-under-crossover": ((8, 16, 16, 127, 8, BF16), GATHER),
+    "float32-page256": ((2, 4, 4, 256, 4, F32), KERNEL),
+    "gqa-tp-shard-page256": ((8, 8, 1, 256, 8, BF16), KERNEL),
+    "page16-mapped2048": ((8, 16, 16, 16, 128, BF16), GATHER),
+    "page16-mapped2064": ((8, 16, 16, 16, 129, BF16), KERNEL),
+    "int8-page16-mapped2048": ((8, 16, 16, 16, 128, I8), GATHER),
+    "int8-page16-mapped2064": ((8, 16, 16, 16, 129, I8), KERNEL),
+    "int8-page128-mapped2048": ((8, 16, 16, 128, 16, I8), GATHER),
+    "int8-page128-mapped2176": ((8, 16, 16, 128, 17, I8), KERNEL),
+}
+
+
+def _paged_taken(monkeypatch, row, interpret):
+    b, qh, kvh, page, pages_per_seq, pool_dtype = row
+    taken = []
+    monkeypatch.setattr(pa, "_interpret", lambda: interpret)
+    for name in (KERNEL, GATHER):
+        record(monkeypatch, taken, pa, name, lambda q, *a: q)
+    pool = S((kvh, b * pages_per_seq, page, 128), pool_dtype)
+    scales = S((kvh, b * pages_per_seq, pa._SCALE_LANES), F32)
+    kw = dict(k_scales=scales, v_scales=scales) if pool_dtype == I8 else {}
+    jax.eval_shape(
+        lambda q, kp, vp, tables, lens, **kw: pa.paged_attention_dispatch(
+            q, kp, vp, tables, lens, **kw),
+        S((b, qh, 128), BF16), pool, pool, S((b, pages_per_seq), I32),
+        S((b,), I32), **kw)
+    return taken
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["compiled", "interpret"])
+@pytest.mark.parametrize("row", sorted(PAGED))
+def test_paged_decode_choice(monkeypatch, row, interpret):
+    args, want = PAGED[row]
+    # interpret mode (the CPU) takes the reference in every row
+    assert _paged_taken(monkeypatch, args, interpret) \
+        == [GATHER if interpret else want]
+
+
+def _burst_traces(monkeypatch, model, page):
+    """The attention functions one decode burst of `model`'s engine traces,
+    off interpret mode."""
+    from paddle_tpu.inference import ServingEngine
+
+    eng = ServingEngine(model, max_batch=2, max_seq_len=128, page_size=page,
+                        decode_burst=2)
+    taken = []
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    for name in (KERNEL, GATHER):
+        record(monkeypatch, taken, pa, name, lambda q, *a: q)
+    record(monkeypatch, taken, pa, "paged_latent_attention_xla",
+           lambda q, pages, tables, lens, width, scale: q[..., :width])
+    params, buffers = eng._cached_params()
+    row = lambda dt: S((2,), dt)  # noqa: E731
+    jax.eval_shape(
+        eng._get_burst_fn(True, 2).__wrapped__, params, buffers,
+        tuple(eng.k_pages), tuple(eng.v_pages or ()), (), (),
+        row(jnp.int64), S((2, eng.pages_per_seq), I32), row(I32),
+        row(jnp.bool_), row(I32), row(I32),
+        jax.random.key_data(jax.random.key(0)), row(jnp.bool_), row(F32),
+        row(I32), row(F32))
+    return taken
+
+
+@pytest.mark.parametrize("kind,page,want", [
+    ("gpt", 128, KERNEL), ("gpt", 16, GATHER),
+    # openpangu-ultra-moe-ep16-l5.decode-closed: the latent decoder's own
+    # gather over its one pool a layer, whatever the page
+    ("latent", 128, "paged_latent_attention_xla")])
+def test_engine_burst_traces_one_choice_a_layer(monkeypatch, kind, page,
+                                                want):
+    from paddle_tpu.models import (GPTConfig, GPTForCausalLM,
+                                   LatentMoEConfig, LatentMoEForCausalLM)
+
+    paddle.seed(0)
+    if kind == "gpt":
+        cfg = GPTConfig.tiny(seq=128)
+        model = GPTForCausalLM(cfg)
+    else:
+        cfg = LatentMoEConfig.tiny()
+        model = LatentMoEForCausalLM(cfg)
+    model.eval()
+    assert _burst_traces(monkeypatch, model, page) \
+        == [want] * cfg.num_hidden_layers
+
+
+# ---------------------------------------------------------------------------
+# scaled_dot_product_attention: flash_attention.use_flash
+# ---------------------------------------------------------------------------
+
+# (batch, s_q, s_kv, heads, head_dim, training, dropout_p, mask) -> traced
+SDPA = {
+    # gpt3-1.3b-l12.train-2k: 4 x 2,048 tokens, 16 heads of 128, training
+    "train-2k": ((4, 2048, 2048, 16, 128, True, 0.0, False), XLA),
+    "train-3968": ((1, 3968, 3968, 2, 128, True, 0.0, False), XLA),
+    "train-4095": ((1, 4095, 4095, 2, 128, True, 0.0, False), XLA),
+    "train-4096": ((1, 4096, 4096, 2, 128, True, 0.0, False), FLASH),
+    "eval-3968": ((1, 3968, 3968, 2, 128, False, 0.0, False), XLA),
+    "eval-4095": ((1, 4095, 4095, 2, 128, False, 0.0, False), XLA),
+    "eval-4096": ((1, 4096, 4096, 2, 128, False, 0.0, False), FLASH),
+    "eval-8192": ((1, 8192, 8192, 2, 128, False, 0.0, False), FLASH),
+    # fa.supports false: a head Mosaic cannot tile, a ragged key length
+    "head-dim-64": ((1, 4096, 4096, 2, 64, True, 0.0, False), XLA),
+    "kv-4100": ((1, 4096, 4100, 2, 128, False, 0.0, False), XLA),
+    "masked-4096": ((1, 4096, 4096, 2, 128, False, 0.0, True), XLA),
+    # dropout: the in-kernel path waits behind FLAGS_flash_dropout_kernel
+    "train-4096-dropout": ((1, 4096, 4096, 2, 128, True, 0.1, False), XLA),
+    "eval-4096-dropout": ((1, 4096, 4096, 2, 128, False, 0.1, False),
+                          FLASH),
+}
+
+
+def _sdpa_traced(monkeypatch, row):
+    b, s_q, s_kv, h, d, training, dropout_p, masked = row
+    taken = []
+    record(monkeypatch, taken, fa, FLASH, lambda q, *a: q)
+    record(monkeypatch, taken, sdpa_mod, XLA, lambda q, *a: q)
+
+    def call(q, k, v, *mask):
+        return as_array(sdpa_mod.scaled_dot_product_attention(
+            Tensor(q), Tensor(k), Tensor(v),
+            attn_mask=Tensor(mask[0]) if mask else None,
+            dropout_p=dropout_p, is_causal=not masked, training=training))
+
+    kv = S((b, s_kv, h, d), BF16)
+    mask = (S((1, 1, s_q, s_kv), jnp.bool_),) if masked else ()
+    jax.eval_shape(call, S((b, s_q, h, d), BF16), kv, kv, *mask)
+    return taken
+
+
+@pytest.mark.parametrize("row", sorted(SDPA))
+def test_sdpa_choice(monkeypatch, row):
+    args, want = SDPA[row]
+    assert _sdpa_traced(monkeypatch, args) == [want]
+
+
+@pytest.mark.parametrize("flag,value,row,want", [
+    ("FLAGS_flash_dropout_kernel", True, "train-4096-dropout", FLASH),
+    ("FLAGS_use_pallas_kernels", False, "train-4096", XLA),
+    ("FLAGS_use_pallas_kernels", False, "eval-8192", XLA)])
+def test_sdpa_choice_under_the_two_flags_left(monkeypatch, flag, value, row,
+                                              want):
+    monkeypatch.setattr(_config._FLAGS[flag], "value", value)
+    assert _sdpa_traced(monkeypatch, SDPA[row][0]) == [want]
+
+
+# ---------------------------------------------------------------------------
+# rms_norm: rms_norm.supports
+# ---------------------------------------------------------------------------
+
+# (leading shape, columns, dtype, weight) -> the kernel traced?
+RMS = {
+    # the latent decoder's decode step and a 16 x 1,024 prefill, hidden 7,680
+    "latent-decode": (((16, 1), 7680, BF16, True), True),
+    "latent-prefill": (((16, 1024), 7680, BF16, True), True),
+    "f32-2048": (((8, 128), 2048, F32, True), True),
+    "cols-8192": (((8192,), 8192, BF16, True), True),
+    "cols-8320": (((8192,), 8320, BF16, True), False),   # beyond 8,192
+    "cols-100": (((256,), 100, F32, True), False),       # not a lane tile
+    "rows-300": (((300,), 2048, F32, True), False),      # not whole blocks
+    "no-weight": (((256,), 2048, F32, False), False),
+}
+
+
+@pytest.mark.parametrize("row", sorted(RMS))
+def test_rms_norm_choice(monkeypatch, row):
+    (lead, cols, dtype, weighted), want = RMS[row]
+    taken = []
+    record(monkeypatch, taken, rn, "rms_norm", lambda x, *a: x)
+    assert rn.supports(int(np.prod(lead)), cols,
+                       itemsize=jnp.dtype(dtype).itemsize) \
+        == (want or not weighted)
+    jax.eval_shape(
+        lambda x, *w: as_array(norm_mod.rms_norm(
+            Tensor(x), Tensor(w[0]) if w else None)),
+        S(lead + (cols,), dtype), *([S((cols,), dtype)] if weighted else []))
+    assert taken == (["rms_norm"] if want else [])
+
+
+def test_rms_norm_takes_the_reference_without_pallas(monkeypatch):
+    monkeypatch.setattr(_config._FLAGS["FLAGS_use_pallas_kernels"], "value",
+                        False)
+    taken = []
+    record(monkeypatch, taken, rn, "rms_norm", lambda x, *a: x)
+    jax.eval_shape(
+        lambda x, w: as_array(norm_mod.rms_norm(Tensor(x), Tensor(w))),
+        S((16, 7680), BF16), S((7680,), BF16))
+    assert taken == []
+
+
+# ---------------------------------------------------------------------------
+# weight-only quantized linear: FLAGS_quant_matmul and quant_matmul.supports
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("mode,n,want", [
+    (None, 256, "quant_matmul_xla"), ("xla", 256, "quant_matmul_xla"),
+    ("fused", 256, "quant_matmul_fused"),
+    ("fused", 96, "quant_matmul_xla"),      # 96 columns tile no lane
+    ("auto", 256, "quant_matmul_xla")])     # the value that went with the tuner
+def test_quant_matmul_choice(monkeypatch, mode, n, want):
+    if mode is not None:
+        monkeypatch.setattr(_config._FLAGS["FLAGS_quant_matmul"], "value",
+                            mode)
+    taken = []
+    for name in ("quant_matmul_xla", "quant_matmul_fused"):
+        record(monkeypatch, taken, qm, name,
+               lambda x, qw, *a: jnp.zeros((x.shape[0], qw.shape[1]),
+                                           x.dtype))
+    out = jax.eval_shape(
+        lambda x, qw, sc: qm.quant_matmul_dispatch(x, qw, sc, "int8", -1),
+        S((2, 4, 128), BF16), S((128, n), I8), S((n,), F32))
+    assert taken == [want] and out.shape == (2, 4, n)
+
+
+# ---------------------------------------------------------------------------
+# the flags that chose, and are gone
+# ---------------------------------------------------------------------------
+
+GONE = ("FLAGS_autotune", "FLAGS_autotune_cache_dir",
+        "FLAGS_paged_xla_max_ctx", "FLAGS_paged_grouped_kernel",
+        "FLAGS_flash_fwd_min_seq", "FLAGS_flash_bwd_min_seq")
+
+
+def test_the_six_flags_are_gone_from_the_registry(monkeypatch):
+    """Not declared, so `get_flags` knows none of them, and `set_flags` of
+    one does what it does for any unknown name: it declares a flag that no
+    code reads (the choice below stays what it was)."""
+    assert not set(GONE) & set(_config._FLAGS)
+    assert paddle.get_flags(list(GONE)) == {}
+    assert _config.get_flag("FLAGS_quant_matmul") == "xla"
+    paddle.set_flags({"FLAGS_paged_xla_max_ctx": 1})
+    try:
+        args, want = PAGED["page16-mapped2048"]
+        assert _paged_taken(monkeypatch, args, False) == [want] == [GATHER]
+    finally:
+        del _config._FLAGS["FLAGS_paged_xla_max_ctx"]

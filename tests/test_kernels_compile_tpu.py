@@ -29,7 +29,6 @@ from jax.sharding import PartitionSpec as P
 
 from conftest import load_repo_script
 from paddle_tpu.kernels import flash_attention as fa
-from paddle_tpu.kernels import matmul as mm
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import quant_matmul as qm
 from paddle_tpu.kernels import rms_norm as rn
@@ -54,7 +53,7 @@ def v5e():
 def mosaic(monkeypatch):
     """interpret=False in every kernel module although the default backend
     is the CPU: the programs are lowered for the topology's devices."""
-    for mod in (fa, pa, rn, mm, qm):
+    for mod in (fa, pa, rn, qm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -123,11 +122,6 @@ def test_rms_norm_supports_is_the_gate():
         == [256, 128, 64, 64]
     assert [rn._block(8192, c, 4) for c in (2048, 4096, 5120, 8192)] \
         == [128, 64, 32, 32]
-    # an explicit block (the autotuner's sweep) past the VMEM bound is
-    # refused by supports(), not left for Mosaic to refuse
-    assert rn.supports(8192, 2048, block_rows=256, itemsize=2)
-    assert not rn.supports(8192, 2048, block_rows=256, itemsize=4)
-    assert not rn.supports(8192, 4096, block_rows=256, itemsize=2)
     assert not rn.supports(8192, 8320)  # beyond 8192 columns
     # few rows: one block of all of them
     assert rn.supports(8, 2048) and rn._block(8, 2048, 4) == 8
@@ -140,7 +134,7 @@ def test_rms_norm_decode_rows(v5e):
 @pytest.mark.parametrize("head_dim,block", [(128, 512), (256, 128),
                                             (256, 512)])
 def test_flash_block_and_head_dim_corners(v5e, head_dim, block):
-    """The autotuner's largest blocks and a 256-wide head, fwd and bwd."""
+    """The largest blocks and a 256-wide head, fwd and bwd."""
     s = 1024
     assert fa.supports(s, s, head_dim, block, block)
     q = S((1, s, 4, head_dim), BF16)
@@ -260,19 +254,9 @@ def test_burst_holds_a_kernel_a_layer_and_copies_no_pool(v5e):
 
 
 def test_paged_decode_gqa(v5e):
-    """32 query heads over 4 kv heads (group 8), per-page and grouped."""
-    specs = _paged_specs(8, 32, 4, 16, 256, False)
-    compile_for(v5e[0], pa.paged_attention, *specs)
-    compile_for(v5e[0], pa.paged_attention_grouped, *specs)
-
-
-@pytest.mark.parametrize("m", [8, 8192])
-@pytest.mark.parametrize("block", [128, 512])
-def test_matmul_fused_corners(v5e, m, block):
-    k, n = 2048, 8192
-    assert mm.supports(m, k, n, block, block)
-    compile_for(v5e[0], lambda x, w: mm.matmul_fused(x, w, block, block),
-                S((m, k), BF16), S((k, n), BF16))
+    """32 query heads over 4 kv heads (group 8)."""
+    compile_for(v5e[0], pa.paged_attention,
+                *_paged_specs(8, 32, 4, 16, 256, False))
 
 
 @pytest.mark.parametrize("weight_dtype,group_size,block,k", [
@@ -321,18 +305,17 @@ def _lower_on_mesh(mesh, fn, specs_and_pspecs):
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
-@pytest.mark.parametrize("quant,page,pages_per_seq,max_ctx_flag", [
-    (False, 16, 128, 1), (True, 16, 128, 1), (False, 256, 8, 0)],
-    ids=["bf16", "int8kv", "bf16-page256-no-flag"])
-def test_paged_kernel_inside_tp4_shard_map(v5e, quant, page, pages_per_seq,
-                                           max_ctx_flag):
-    """The TP decode step of models/paged_step.py with the kernel lowered
-    (small pages: the XLA/Pallas crossover pushed down by its flag; the
-    chat cell's pages of 256: chosen from the page size alone, each chip's
-    step over its 4 heads): under `check_vma=True` (jax.shard_map's
-    default) the kernel's out_shape is refused at trace time; the step
-    states check_vma=False."""
-    from paddle_tpu.framework import config as _config
+@pytest.mark.parametrize("quant,page,pages_per_seq", [
+    (False, 16, 256), (True, 16, 256), (False, 256, 8), (False, 128, 16)],
+    ids=["bf16", "int8kv", "bf16-page256", "bf16-page128"])
+def test_paged_kernel_inside_tp4_shard_map(v5e, quant, page, pages_per_seq):
+    """The TP decode step of models/paged_step.py with the kernel lowered,
+    by the dispatch's own choice (small pages and int8 pools: a mapped
+    context of 4,096, above the crossover; the chat cell's pages of 256
+    and chip_smoke.py's tp=4 engine at pages of 128: from the page size
+    alone, each chip's step over its 4 heads): under
+    `check_vma=True` (jax.shard_map's default) the kernel's out_shape is
+    refused at trace time; the step states check_vma=False."""
     from paddle_tpu.models.paged_step import paged_attention_step
     from paddle_tpu.tensor import Tensor, as_array
 
@@ -349,14 +332,10 @@ def test_paged_kernel_inside_tp4_shard_map(v5e, quant, page, pages_per_seq,
         return as_array(out), tuple(as_array(c) for c in cache)
 
     heads_p, pool_p, rep = P(None, None, "tp"), P("tp"), P()
-    _config.set_flags({"FLAGS_paged_xla_max_ctx": max_ctx_flag})
-    try:
-        text = _lower_on_mesh(mesh, step, [
-            (q, heads_p), (q, heads_p), (q, heads_p), (pool, pool_p),
-            (pool, pool_p), (tables, rep), (lens, rep),
-            *[(s, pool_p) for s in scales]])
-    finally:
-        _config.set_flags({"FLAGS_paged_xla_max_ctx": 0})
+    text = _lower_on_mesh(mesh, step, [
+        (q, heads_p), (q, heads_p), (q, heads_p), (pool, pool_p),
+        (pool, pool_p), (tables, rep), (lens, rep),
+        *[(s, pool_p) for s in scales]])
     assert text.count("tpu_custom_call") >= 1
 
 

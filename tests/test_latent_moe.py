@@ -347,6 +347,9 @@ def test_what_latent_pages_cannot_do_yet_raises_at_construction(
 def test_latent_pages_are_not_sharded_and_not_handed_off(model, cfg, mesh8):
     with pytest.raises(ValueError, match="cannot be sharded over tp=4"):
         _engine(model, cfg, mesh=mesh8)
+    # refused before anything was placed: the model is where it was
+    assert all(len(p._data.sharding.device_set) == 1
+               for p in model.parameters())
     import paddle_tpu.distributed.mesh as mesh_mod
 
     mesh_mod.set_mesh(None)

@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.framework import config as _config
 from paddle_tpu.inference import ServingEngine
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.models import GPTConfig, GPTForCausalLM
@@ -128,8 +127,7 @@ def _dispatched(monkeypatch, page, quant, pages_per_seq=8, interpret=False):
     type."""
     taken = []
     monkeypatch.setattr(pa, "_interpret", lambda: interpret)
-    for name in ("paged_attention", "paged_attention_xla",
-                 "paged_attention_grouped"):
+    for name in ("paged_attention", "paged_attention_xla"):
         monkeypatch.setattr(
             pa, name, lambda *a, _n=name, **k: taken.append(_n))
     pool = jnp.zeros((2, 4, page, HEAD_DIM), jnp.int8 if quant
@@ -150,23 +148,10 @@ def test_dispatch_follows_page_size_pool_type_and_interpret_mode(
         assert _dispatched(monkeypatch, page, quant, interpret=True) \
             == "paged_attention_xla"
     # float pools at pages of 128 and more: the kernel whatever the mapped
-    # context (8 pages of 128 are under the crossover), and no flag is read
-    def no_flag():
-        raise AssertionError("float pools at a page of 128 read no flag")
-
-    with monkeypatch.context() as m:
-        m.setattr(pa, "_xla_decode_max_ctx", no_flag)
-        assert _dispatched(m, 128, False) == "paged_attention"
-        assert _dispatched(m, 256, False) == "paged_attention"
-    _config.set_flags({"FLAGS_paged_xla_max_ctx": 1 << 20})
-    try:
-        assert _dispatched(monkeypatch, 256, False) == "paged_attention"
-        # ... while the flag keeps its meaning for the small-page rule
-        assert _dispatched(monkeypatch, 16, False, pages_per_seq=512) \
-            == "paged_attention_xla"
-    finally:
-        _config.set_flags({"FLAGS_paged_xla_max_ctx": 0})
-    # pages under 128 and int8 pools: today's crossover of mapped context
+    # context (8 pages of 128 are under the crossover)
+    assert _dispatched(monkeypatch, 128, False) == "paged_attention"
+    assert _dispatched(monkeypatch, 256, False) == "paged_attention"
+    # pages under 128 and int8 pools: the crossover of mapped context
     assert _dispatched(monkeypatch, 16, False, pages_per_seq=128) \
         == "paged_attention_xla"                      # 2,048 mapped
     assert _dispatched(monkeypatch, 16, False, pages_per_seq=136) \

@@ -3,9 +3,7 @@ paddle_tpu/kernels/quant_matmul.py).
 
 Acceptance contract: the fused kernel matches the XLA traced-dequant
 reference to <= 1e-2 (int8) / 3e-2 (int4) across {group_size -1/64/128}
-x rectangular shapes in interpret mode; it registers as autotune
-candidates under the `quant_matmul` op (never-slower-than-XLA tie-break
-inherited from the tuner core); and `weight_only_linear` /
+x rectangular shapes in interpret mode; and `weight_only_linear` /
 `WeightOnlyLinear.forward` route through the dispatcher with zero model
 changes. The int4 pack-layout golden in tests/test_quantization.py is
 the storage format this kernel consumes."""
@@ -16,7 +14,6 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.framework import config as _config
-from paddle_tpu.kernels import autotune as at
 from paddle_tpu.kernels import quant_matmul as qm
 from paddle_tpu.nn.quant import (
     WeightOnlyLinear,
@@ -27,14 +24,18 @@ from paddle_tpu.nn.quant import (
 
 
 @pytest.fixture
-def tuner_env(tmp_path, monkeypatch):
-    monkeypatch.setattr(_config._FLAGS["FLAGS_autotune"], "value", "on")
-    monkeypatch.setattr(_config._FLAGS["FLAGS_autotune_cache_dir"],
-                        "value", str(tmp_path))
-    at.reset_tuner()
-    yield tmp_path
-    at.set_timer(None)
-    at.reset_tuner()
+def fused_calls(monkeypatch):
+    """FLAGS_quant_matmul=fused, and the calls the fused kernel gets."""
+    monkeypatch.setattr(_config._FLAGS["FLAGS_quant_matmul"], "value",
+                        "fused")
+    calls, orig = [], qm.quant_matmul_fused
+
+    def spy(*a, **kw):
+        calls.append(a)
+        return orig(*a, **kw)
+
+    monkeypatch.setattr(qm, "quant_matmul_fused", spy)
+    return calls
 
 
 def _quantized(k, n, algo, gs, seed=0):
@@ -127,8 +128,8 @@ class TestKernelParity:
 
 class TestDispatch:
     def test_default_is_xla_bit_identical(self, monkeypatch):
-        """FLAGS_quant_matmul=auto with the tuner off must produce the
-        legacy traced-dequant result bit for bit."""
+        """With no flag set the dispatch IS the traced-dequant
+        expression, bit for bit."""
         _w, qw, sc = _quantized(128, 256, "weight_only_int8", -1)
         x = jnp.asarray(np.random.RandomState(4).randn(3, 128)
                         .astype(np.float32))
@@ -161,14 +162,10 @@ class TestDispatch:
         assert np.array_equal(np.asarray(got), np.asarray(ref))
 
     def test_weight_only_linear_routes_through_dispatcher(
-            self, tuner_env, monkeypatch):
-        """The tentpole wiring: with the autotuner on and a fake timer
-        preferring the fused kernel, nn.quant.weight_only_linear picks
-        it up with zero call-site changes — and the winner lands in the
-        quant_matmul table."""
-        at.set_timer(lambda fn, args: 1.0
-                     if getattr(fn, "__name__", "") == "fused_fn"
-                     else 5.0)
+            self, fused_calls):
+        """The wiring: with the flag saying fused,
+        nn.quant.weight_only_linear reaches the kernel with zero
+        call-site changes."""
         rng = np.random.RandomState(7)
         w = rng.randn(128, 256).astype(np.float32)
         qw, sc = weight_quantize(paddle.to_tensor(w), group_size=64)
@@ -177,17 +174,11 @@ class TestDispatch:
         ref = x.numpy() @ np.asarray(weight_dequantize(
             qw, sc, group_size=64).numpy())
         np.testing.assert_allclose(y.numpy(), ref, atol=1e-2)
-        snap = at.get_tuner().snapshot()
-        keys = [k for k in snap if k.startswith("quant_matmul|")]
-        assert keys, f"no quant_matmul entry in {sorted(snap)}"
-        assert snap[keys[0]]["winner"].startswith("fused:")
+        assert len(fused_calls) == 1
 
-    def test_weight_only_layer_forward_uses_dispatch(self, tuner_env):
+    def test_weight_only_layer_forward_uses_dispatch(self, fused_calls):
         """WeightOnlyLinear.forward (the layer quantize_for_inference
         installs) flows through the same dispatcher."""
-        at.set_timer(lambda fn, args: 1.0
-                     if getattr(fn, "__name__", "") == "fused_fn"
-                     else 5.0)
         from paddle_tpu import nn
 
         rng = np.random.RandomState(8)
@@ -202,13 +193,4 @@ class TestDispatch:
         # (this test pins ROUTING, TestKernelParity pins accuracy)
         np.testing.assert_allclose(y.numpy(), ref.numpy(), rtol=0.05,
                                    atol=0.35)
-        snap = at.get_tuner().snapshot()
-        assert any(k.startswith("quant_matmul|") for k in snap)
-
-    def test_never_slower_than_xla(self, tuner_env):
-        """Inherited tuner property at the quant_matmul op: a fused
-        candidate that measures slower than XLA is never selected."""
-        at.set_timer(lambda fn, args: 0.5
-                     if getattr(fn, "__name__", "") == "xla_fn" else 2.0)
-        win = at.choose_quant_matmul(8, 256, 256, "int8", -1, "float32")
-        assert win is not None and win.meta["impl"] == "xla"
+        assert len(fused_calls) == 1

@@ -291,22 +291,6 @@ class TestRoofline:
             dp.require_kind("cpu")
         assert "require_kind" in bench_src
 
-    def test_autotune_ground_truth_rows(self, ledger_on, tmp_path,
-                                        monkeypatch):
-        from paddle_tpu.kernels import autotune as at
-
-        tuner = at.Autotuner(cache_dir=str(tmp_path))
-        tuner._mem["sdpa_fwd|v1|s=128"] = {
-            "winner": "pallas_128",
-            "timings_ms": {"xla": 2.0, "pallas_128": 1.0},
-            "op": "sdpa_fwd"}
-        tuner._loaded = True  # keep snapshot() from reloading from disk
-        monkeypatch.setattr(at, "_default_tuner", tuner)
-        rows = sl.autotune_ground_truth()
-        assert rows and rows[0]["op"] == "sdpa_fwd"
-        assert rows[0]["winner_ms"] == 1.0
-        assert rows[0]["speedup_vs_xla"] == pytest.approx(2.0)
-
 
 class TestOffPath:
     def test_begin_is_one_flag_read(self):

@@ -6,8 +6,7 @@ always-sample-on-slow escape hatch, serving requests carrying
 `FinishedRequest.trace_id` with correctly ordered/nested spans, trainer
 step spans, the FLAGS_trace_sample=0 zero-allocation fast path (same
 discipline as the metrics alloc-guard), atomic exporter writes, the
-autotune decision counter, the watchdog open-span dump, and the
-trace_report critical path.
+watchdog open-span dump, and the trace_report critical path.
 """
 import importlib.util
 import json
@@ -666,80 +665,6 @@ class TestCorrelationChannels:
         assert tr.open_spans() == []
         txt2 = open(wd.dump()).read()
         assert "(none)" in txt2
-
-    def test_autotune_decision_counter_and_event(self, tmp_path,
-                                                 monkeypatch):
-        from paddle_tpu.kernels import autotune as at
-
-        monkeypatch.setattr(_config._FLAGS["FLAGS_autotune"], "value",
-                            "on")
-        monkeypatch.setattr(_config._FLAGS["FLAGS_autotune_cache_dir"],
-                            "value", str(tmp_path))
-        at.reset_tuner()
-        rec = fr.default_recorder()
-        rec.clear()
-        reg = om.default_registry()
-
-        def fake_timer(fn, args):
-            return {"xla": 2.0, "pallas:a": 1.0}[fn.__autotune_name__]
-
-        at.set_timer(fake_timer)
-        try:
-            cands = []
-            for name, kind in (("xla", "xla"), ("pallas:a", "pallas")):
-                def fn(*a):
-                    return None
-
-                fn.__autotune_name__ = name
-                cands.append(at.Candidate(name, kind, fn, {"name": name}))
-            before = reg.value("autotune_decisions_total",
-                               op="flash_fwd", winner="pallas:a") \
-                if reg.get("autotune_decisions_total") else 0.0
-            win = at.get_tuner().pick(
-                "flash_fwd", (("sq", 128), ("dt", "float32")), cands,
-                lambda: (None,))
-            assert win.name == "pallas:a"
-            assert reg.value("autotune_decisions_total", op="flash_fwd",
-                             winner="pallas:a") == before + 1
-            evs = [(k, f) for _, k, f in rec.tail()
-                   if k == "autotune.decision"]
-            assert len(evs) == 1
-            assert evs[0][1]["winner"] == "pallas:a"
-            assert evs[0][1]["op"] == "flash_fwd"
-            assert evs[0][1]["timings_ms"] == {"xla": 2.0,
-                                               "pallas:a": 1.0}
-        finally:
-            at.set_timer(None)
-            at.reset_tuner()
-
-    def test_autotune_measure_records_span(self, tracer, tmp_path,
-                                           monkeypatch):
-        from paddle_tpu.kernels import autotune as at
-
-        monkeypatch.setattr(_config._FLAGS["FLAGS_autotune"], "value",
-                            "on")
-        monkeypatch.setattr(_config._FLAGS["FLAGS_autotune_cache_dir"],
-                            "value", str(tmp_path))
-        at.reset_tuner()
-        at.set_timer(lambda fn, args: 1.5)
-        try:
-            def fn(*a):
-                return None
-
-            fn.__autotune_name__ = "xla"
-            at.get_tuner().pick(
-                "rms_norm", (("rows", 128),),
-                [at.Candidate("xla", "xla", fn, {})], lambda: (None,))
-            spans = [e for e in tr.to_chrome_trace()
-                     if e["name"] == "autotune.measure"]
-            assert len(spans) == 1
-            # candidate timings + winner ride the span attributes
-            assert spans[0]["args"]["winner"] == "xla"
-            assert spans[0]["args"]["timings_ms"] == {"xla": 1.5}
-            assert spans[0]["args"]["op"] == "rms_norm"
-        finally:
-            at.set_timer(None)
-            at.reset_tuner()
 
 
 class TestCollectiveTracing:

@@ -232,15 +232,6 @@ then
   rc=1
 fi
 
-# autotuner smoke: measured dispatch end to end in interpret mode, cache
-# pointed at a temp dir (never the user cache); asserts the winner table
-# is written and the argmin/XLA-floor property holds at a tiny shape
-if ! timeout 600 env JAX_PLATFORMS=cpu \
-    python tools/autotune_smoke.py; then
-  echo "CI: autotune smoke FAILED" >&2
-  rc=1
-fi
-
 # fleet telemetry smoke: 2 ranks export rank shards with staggered
 # synthetic collectives AND live per-rank telemetry endpoints; the
 # smoke asserts shard layout + that the aggregator names the injected

@@ -85,11 +85,10 @@ ADVICE = {
     "canary_mismatch": (
         "the black-box canary's greedy tokens diverged from the "
         "golden reference: a replica is serving WRONG answers "
-        "(weights skew, a bad kernel winner, quantization drift) "
+        "(weights skew, a bad kernel, quantization drift) "
         "while every internal counter stays green",
         "bit-compare the replica against a reference engine "
-        "(tools/serving_parity_smoke.py), clear the autotune cache "
-        "(FLAGS_autotune_cache_dir) and re-verify the checkpoint "
+        "(tools/serving_parity_smoke.py) and re-verify the checkpoint "
         "digest before trusting this rank again"),
     "canary_timeout": (
         "the canary probe could not complete inside its deadline: "

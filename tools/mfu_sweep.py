@@ -69,8 +69,8 @@ GRIDS = {
         (64, 2048, 0, 0, 16),
     ],
     # long-context rows on the base geometry: seq >= 4096 engages the
-    # Pallas flash dispatch (KERNEL_BENCH.json: 19.8x fwd over XLA at
-    # 8192) inside the FULL train step; fused CE keeps the f32 logits
+    # Pallas flash dispatch (kernels/flash_attention.py `use_flash`)
+    # inside the FULL train step; fused CE keeps the f32 logits
     # from OOMing at 8k+ tokens x 32k vocab
     "long": [
         (8, 4096, 0, 0, 16),

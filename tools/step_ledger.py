@@ -14,10 +14,7 @@ entry point:
   measured MFU where the entry point registered its cost;
 - the top-N optimization targets, each naming the dominant bucket and
   the ROADMAP move it implicates ("collective wait 22% of step ->
-  overlap dp reduce-scatter");
-- the autotuner's measured per-kernel ground truth when its winner
-  cache has rows (in-process runs only — a .prom file carries no
-  kernel timings).
+  overlap dp reduce-scatter").
 
     python tools/step_ledger.py /tmp/ci_metrics_traced.prom
     python tools/step_ledger.py /tmp/ci_fleet --json
