@@ -501,7 +501,8 @@ def _bf16(rng, shape, scale=1.0):
 
 def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
                  flash_seq=4096, tokens=8192, decode_batch=8,
-                 pages_per_seq=160, wide=8192):
+                 pages_per_seq=160, wide=8192, expert_hidden=7680,
+                 expert_width=2048, experts_held=16):
     """Every Pallas kernel entry point of paddle_tpu/kernels/, by default
     at this model's head geometry. tests/test_kernels_compile_tpu.py
     compiles the same table ahead of time for a v5e, so a Mosaic refusal
@@ -511,6 +512,8 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
     import jax
     import jax.numpy as jnp
 
+    from paddle_tpu.incubate.distributed.models.moe import expert_share
+    from paddle_tpu.kernels import expert_hit as eh
     from paddle_tpu.kernels import flash_attention as fa
     from paddle_tpu.kernels import paged_attention as pa
     from paddle_tpu.kernels import quant_matmul as qm
@@ -614,6 +617,28 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
         return lambda q, kp, vp, ks, vs, tables, lens: impl(
             q, kp, vp, tables, lens, k_scales=ks, v_scales=vs)
 
+    # -- the expert layer of a decode step (16 rows through the held
+    # experts of openpangu-ultra-moe-ep16-l5) with 0, 1, 3/8 and all of the
+    # held experts hit; expert e's matrices are one random matrix rolled by
+    # e rows, distinct and cheap to make on the host
+    def expert_args(rng):
+        def stack(rows, cols):
+            base = _bf16(rng, (rows, cols), 0.02)
+            return np.stack([np.roll(base, 37 * e, axis=0)
+                             for e in range(experts_held)])
+        routing = (0.2 + rng.random((16, experts_held))).astype(np.float32)
+        return (_bf16(rng, (16, expert_hidden)), routing,
+                stack(expert_hidden, expert_width),
+                stack(expert_hidden, expert_width),
+                stack(expert_width, expert_hidden))
+
+    def experts_hit(n_hit, ffn):
+        """`ffn` with the routing weights of all but `n_hit` experts
+        (spread over the held ones) set to 0."""
+        hit = np.zeros(experts_held, np.float32)
+        hit[np.linspace(0, experts_held - 1, n_hit).astype(int)] = 1.0
+        return lambda x, routing, *ws: ffn(x, routing * hit, *ws)
+
     # -- rms_norm at the train step's token count, quantized matmuls
     def rms_args(cols):
         return lambda rng: (_bf16(rng, (tokens, cols)),
@@ -670,6 +695,10 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
         KernelCase("paged decode int8-KV", paged_quant,
                    paged_q8(pa.paged_attention),
                    paged_q8(pa.paged_attention_xla)),
+        *(KernelCase(f"expert hit {n_hit} of {experts_held}", expert_args,
+                     experts_hit(n_hit, eh.hit_ffn),
+                     experts_hit(n_hit, expert_share.share_ffn))
+          for n_hit in (0, 1, experts_held * 3 // 8, experts_held)),
         KernelCase(f"rms_norm {hidden} fwd", rms_hidden, rn.rms_norm,
                    rms_ref),
         KernelCase(f"rms_norm {hidden} fwd+bwd", rms_hidden,

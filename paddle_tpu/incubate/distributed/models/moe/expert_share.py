@@ -16,16 +16,22 @@ the whole layer's routed result (tests/test_latent_moe.py holds that).
 
 `SigmoidTopKGate` scores with a sigmoid in float32 and picks the `top_k`
 largest (no groups, no correction bias); `ExpertShareLayer` owns the gate
-and the held experts' stacked gated-SiLU weights. Every held expert's
-product is taken for every token and weighted by `w_e` (0 where the token
-did not pick it): at decode batch sizes the step is bound by reading the
-experts' weights either way, and nothing depends on the data's shape.
+and the held experts' stacked gated-SiLU weights. `share_ffn` takes every
+held expert's product for every token and weights it by `w_e` (0 where the
+token did not pick it). A decode step is bound by reading the experts'
+weights, and its few tokens pick few of the held experts: at decode-sized
+token counts the layer takes `kernels/expert_hit.py`'s `hit_ffn` instead,
+the same sum over the experts some live token picked, which reads those
+experts' weights and no other (`expert_hit.use_hit_path` chooses, from the
+call's shapes and types; a call that may record a gradient stays dense).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from .....autograd import tape as _tape
+from .....kernels import expert_hit as _hit
 from .....nn import initializer as I
 from .....nn.layer_base import Layer
 from .....observability import tracing as _trace
@@ -120,7 +126,10 @@ class ExpertShareLayer(Layer):
     (`held = num_experts // ep_degree`) and the router over all of them.
     forward(x [..., d_model]) -> this chip's share of the routed result,
     the same shape. `live` ([tokens] bool, optional) says which tokens
-    are real: it enters the counts only, never the result."""
+    are real: it enters the counts, and on the hit path the list of experts
+    whose weights are read. A live token's result is the same with it and
+    without; a token that is not live gets the sum over the experts that
+    live tokens hit, which nothing reads."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k, ep_rank=0,
                  ep_degree=1, routed_scaling_factor=1.0,
@@ -149,18 +158,28 @@ class ExpertShareLayer(Layer):
             dense_w = _apply_op(held_weights, picks, weights,
                                 _name="moe_held_weights", first=self.first,
                                 held=self.held)
-            self._count(dense_w, live)
+            # the kernel has no backward: a call that may record a
+            # gradient keeps the dense products (serving never records)
+            hit_path = not _tape.grad_enabled() and _hit.use_hit_path(
+                int(tokens.shape[0]), self.d_model,
+                int(self.w_gate.shape[2]), tokens._data.dtype,
+                self.w_gate._data.dtype)
+            self._count(dense_w, live, hit_path)
         with _trace.scope("experts"):
-            y = _apply_op(share_ffn, tokens, dense_w, self.w_gate, self.w_up,
-                          self.w_down, _name="moe_share_ffn")
+            ffn, kw = (_hit.hit_ffn, {"live": live}) if hit_path \
+                else (share_ffn, {})
+            y = _apply_op(ffn, tokens, dense_w, self.w_gate, self.w_up,
+                          self.w_down, _name="moe_share_ffn", **kw)
         return y.reshape(shape)
 
-    def _count(self, dense_w, live):
+    def _count(self, dense_w, live, hit_path):
         """`expert_pairs`: (live token, held expert it picked) pairs;
-        `experts_hit`: held experts some live token picked; and what each
-        is a share of, `expert_layer_steps` (1 for a call with a live
-        token) and `experts_held`. Nothing is computed where nobody
-        collects (`tracing.device_counts`)."""
+        `experts_hit`: held experts some live token picked; `experts_read`:
+        held experts whose weights the call streams (those hit on the hit
+        path, all held on the dense one); and what each is a share of,
+        `expert_layer_steps` (1 for a call with a live token) and
+        `experts_held`. Nothing is computed where nobody collects
+        (`tracing.device_counts`)."""
         if not _trace.counting():
             return
         picked = dense_w._data > 0
@@ -169,7 +188,9 @@ class ExpertShareLayer(Layer):
         any_live = jnp.int32(1) if live is None \
             else jnp.any(live).astype(jnp.int32)
         _trace.count("expert_pairs", jnp.sum(picked, dtype=jnp.int32))
-        _trace.count("experts_hit",
-                     jnp.sum(jnp.any(picked, axis=0), dtype=jnp.int32))
+        hit = jnp.sum(jnp.any(picked, axis=0), dtype=jnp.int32)
+        _trace.count("experts_hit", hit)
+        _trace.count("experts_read", hit if hit_path
+                     else any_live * self.held)
         _trace.count("expert_layer_steps", any_live)
         _trace.count("experts_held", any_live * self.held)

@@ -110,8 +110,8 @@ def _hand_made_trace_as_a_file(request):
         ["serving.prefill_batch", 45 * ms, 29 * ms],
         ["serving.kv_scatter", 70 * ms, 4 * ms],
         ["serving.emit", 41 * ms, 2 * ms,
-         {"expert_pairs": 12, "experts_hit": 9, "expert_layer_steps": 8,
-          "experts_held": 32}]]
+         {"expert_pairs": 12, "experts_hit": 9, "experts_read": 9,
+          "expert_layer_steps": 8, "experts_held": 32}]]
     directory = request.getfixturevalue("tmp_path")
     write(raw, directory)
     request.getfixturevalue("monkeypatch").setattr(

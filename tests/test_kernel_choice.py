@@ -8,7 +8,8 @@ traced under `jax.eval_shape`.
 The rows hold the three benchmark cells' own shapes and both sides of every
 boundary the choice has (`paged_attention._KERNEL_MIN_PAGE`,
 `_XLA_DECODE_MAX_CTX`, `flash_attention._PALLAS_FWD_MIN_SEQ` /
-`_PALLAS_BWD_MIN_SEQ`, the kernels' `supports`)."""
+`_PALLAS_BWD_MIN_SEQ`, `expert_hit._HIT_MAX_TOKENS`, the kernels'
+`supports`)."""
 import importlib
 
 import jax
@@ -18,6 +19,8 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.framework import config as _config
+from paddle_tpu.incubate.distributed.models.moe import expert_share
+from paddle_tpu.kernels import expert_hit as eh
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import quant_matmul as qm
@@ -138,6 +141,80 @@ def test_engine_burst_traces_one_choice_a_layer(monkeypatch, kind, page,
     model.eval()
     assert _burst_traces(monkeypatch, model, page) \
         == [want] * cfg.num_hidden_layers
+
+
+# ---------------------------------------------------------------------------
+# one chip's share of an expert layer: expert_hit.use_hit_path
+# ---------------------------------------------------------------------------
+
+HIT, DENSE = "hit_ffn", "share_ffn"
+
+# (tokens, hidden, expert width, held, type) -> off interpret mode, no grad
+EXPERTS = {
+    # openpangu-ultra-moe-ep16-l5.decode-closed: a decode step of 16 rows,
+    # and its prefills of 256 to 16 x 1,024 tokens
+    "decode-closed-step": ((16, 7680, 2048, 16, BF16), HIT),
+    "decode-closed-prefill-256": ((256, 7680, 2048, 16, BF16), DENSE),
+    "decode-closed-prefill-1024": ((1024, 7680, 2048, 16, BF16), DENSE),
+    "decode-closed-prefill-16384": ((16384, 7680, 2048, 16, BF16), DENSE),
+    "one-token": ((1, 7680, 2048, 16, BF16), HIT),
+    "tokens-64": ((64, 7680, 2048, 16, BF16), HIT),
+    "tokens-65": ((65, 7680, 2048, 16, BF16), DENSE),
+    "float32": ((16, 1024, 512, 4, F32), HIT),
+    # widths Mosaic would pad: the tiny model's
+    "hidden-48": ((16, 48, 256, 4, F32), DENSE),
+    "width-24": ((16, 128, 24, 4, F32), DENSE),
+}
+
+
+def _experts_taken(monkeypatch, row, interpret, grad=False):
+    n, d, f, held, dtype = row
+    taken = []
+    monkeypatch.setattr(eh, "_interpret", lambda: interpret)
+    record(monkeypatch, taken, eh, HIT, lambda x, *a: x)
+    record(monkeypatch, taken, expert_share, DENSE, lambda x, *a: x)
+    zeros = lambda shape, _dtype: jnp.zeros(tuple(shape), dtype)  # noqa: E731
+    paddle.nn.initializer.set_global_initializer(zeros, zeros)
+    try:
+        layer = expert_share.ExpertShareLayer(d, f, 16 * held, 8,
+                                              ep_degree=16)
+    finally:
+        paddle.nn.initializer.set_global_initializer(None, None)
+
+    def call(x, live):
+        with (paddle.enable_grad if grad else paddle.no_grad)():
+            return as_array(layer(Tensor(x), live=live))
+
+    jax.eval_shape(call, S((n, d), dtype), S((n,), jnp.bool_))
+    return taken
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["compiled", "interpret"])
+@pytest.mark.parametrize("row", sorted(EXPERTS))
+def test_expert_share_choice(monkeypatch, row, interpret):
+    args, want = EXPERTS[row]
+    # interpret mode (the CPU) takes the dense reference in every row
+    assert _experts_taken(monkeypatch, args, interpret) \
+        == [DENSE if interpret else want]
+
+
+def test_a_call_that_may_record_a_gradient_keeps_the_dense_products(
+        monkeypatch):
+    """The kernel has no backward; the engine's programs and `generate`
+    trace under `no_grad`."""
+    args, want = EXPERTS["decode-closed-step"]
+    assert want == HIT
+    assert _experts_taken(monkeypatch, args, False, grad=True) == [DENSE]
+
+
+def test_the_expert_choice_reads_no_flag(monkeypatch):
+    """Shapes, types and `_interpret()` alone: a registry without a single
+    flag chooses as the full one does."""
+    monkeypatch.setattr(eh, "_interpret", lambda: False)
+    monkeypatch.setattr(_config, "_FLAGS", {})
+    for (n, d, f, _held, dtype), want in EXPERTS.values():
+        assert eh.use_hit_path(n, d, f, dtype, dtype) == (want == HIT)
 
 
 # ---------------------------------------------------------------------------

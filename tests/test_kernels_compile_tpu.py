@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from conftest import load_repo_script
+from paddle_tpu.kernels import expert_hit as eh
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import quant_matmul as qm
@@ -53,7 +54,7 @@ def v5e():
 def mosaic(monkeypatch):
     """interpret=False in every kernel module although the default backend
     is the CPU: the programs are lowered for the topology's devices."""
-    for mod in (fa, pa, rn, qm):
+    for mod in (fa, pa, rn, qm, eh):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -251,6 +252,74 @@ def test_burst_holds_a_kernel_a_layer_and_copies_no_pool(v5e):
         n = int(np.prod([int(d) for d in dims.split(",") if d]))
         assert not (op.startswith("copy") and n * 2 >= pool_bytes), shape
         assert not (dtype == "f32" and n >= mapped), (shape, op)
+
+
+def test_latent_burst_streams_hit_experts_and_copies_no_stack(v5e,
+                                                              monkeypatch):
+    """`openpangu-ultra-moe-ep16-l5.decode-closed`'s decode burst (16 rows
+    x 16 steps, published widths, one expert layer of 16 held experts),
+    lowered for the v5e on the hit path and on the dense one: a
+    `tpu_custom_call` an expert layer whose stacked weights arrive in the
+    layout they are stored in; inside the `while` body no copy, transpose
+    or convert of an array the size of an expert stack; temporaries within
+    64 MB of the dense program's."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ServingEngine
+    from paddle_tpu.models import LatentMoEConfig, LatentMoEForCausalLM
+
+    rows, burst = 16, 16
+    zeros = lambda shape, _dtype: jnp.zeros(tuple(shape), BF16)  # noqa: E731
+    paddle.nn.initializer.set_global_initializer(zeros, zeros)
+    try:
+        model = LatentMoEForCausalLM(LatentMoEConfig(
+            vocab_size=512, num_hidden_layers=1, first_k_dense_replace=0,
+            max_position_embeddings=2048, ep_degree=16, dtype="bfloat16"))
+    finally:
+        paddle.nn.initializer.set_global_initializer(None, None)
+    model.eval()
+    experts = model.model.layers[0].mlp.experts
+    stack = tuple(experts.w_gate.shape)
+    assert stack == (16, 7680, 2048) and experts.w_gate._data.dtype == BF16
+    one = SingleDeviceSharding(v5e[0])
+
+    def compiled():
+        eng = ServingEngine(model, max_batch=rows, max_seq_len=2048,
+                            page_size=256, decode_burst=burst)
+        described = lambda tree: jax.tree.map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+        row = lambda dt: S((rows,), dt)  # noqa: E731
+        params, buffers = eng._cached_params()
+        fn = eng._get_burst_fn(True, burst)
+        return getattr(fn, "_fn", fn).lower(*described((
+            params, buffers, tuple(eng.k_pages), (), (), (),
+            row(jnp.int64), S((rows, eng.pages_per_seq), I32), row(I32),
+            row(jnp.bool_), row(I32), row(I32),
+            jax.random.key_data(jax.random.key(0)), row(jnp.bool_),
+            row(F32), row(I32), row(F32)))).compile()
+
+    hit = compiled()
+    text = hit.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(ln for ln in text.split("\n")
+                if 'custom_call_target="tpu_custom_call"' in ln)
+    assert "bf16[16,7680,2048]{2,1,0}, bf16[16,7680,2048]{2,1,0}, " \
+        "bf16[16,2048,7680]{2,1,0}" in call
+    body = re.search(r"while\(.*body=%?([\w.\-]+)", text).group(1)
+    body = text[text.index(f"%{body} ("):]
+    body = body[:body.index("\n}\n")]
+    for name, shape, op in re.findall(
+            r"%([\w.\-]+) = (\w+\[[\d,]*\])\S* ([\w\-]+)\(", body):
+        n = int(np.prod([int(d) for d in
+                         shape[:-1].split("[")[1].split(",") if d]))
+        moved = any(k in name or k in op
+                    for k in ("copy", "transpose", "convert"))
+        assert not (moved and n >= int(np.prod(stack))), (name, shape, op)
+    monkeypatch.setattr(eh, "use_hit_path", lambda *a: False)
+    dense = compiled()
+    assert "tpu_custom_call" not in dense.as_text()
+    assert hit.memory_analysis().temp_size_in_bytes \
+        <= dense.memory_analysis().temp_size_in_bytes + (64 << 20)
 
 
 def test_paged_decode_gqa(v5e):

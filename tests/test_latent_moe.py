@@ -18,6 +18,7 @@ if REPO not in sys.path:
 
 import paddle_tpu as paddle  # noqa: E402
 from paddle_tpu.inference import ServingEngine  # noqa: E402
+from paddle_tpu.kernels import expert_hit  # noqa: E402
 from paddle_tpu.kernels import paged_attention as pa  # noqa: E402
 from paddle_tpu.models import (GPTConfig, GPTForCausalLM,  # noqa: E402
                                LatentMoEConfig, LatentMoEForCausalLM,
@@ -180,7 +181,21 @@ def test_blocked_attention_is_whole_attention(cfg, block_bytes, monkeypatch):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
-def test_the_shares_add_up_to_the_uncut_layer(weights, cfg):
+PATHS = ["dense", "hit"]
+
+
+@pytest.fixture
+def expert_path(request, monkeypatch):
+    """The form the expert layer takes, whatever the shapes: `dense` is
+    `share_ffn`, `hit` the kernel of kernels/expert_hit.py (interpreted
+    here), which the choice would keep for the chip."""
+    monkeypatch.setattr(expert_hit, "use_hit_path",
+                        lambda *a: request.param == "hit")
+    return request.param
+
+
+@pytest.mark.parametrize("expert_path", PATHS, indirect=True)
+def test_the_shares_add_up_to_the_uncut_layer(weights, cfg, expert_path):
     """model-configs section 4: the routed parts that all `ep_degree`
     shares give, plus the shared expert counted once, equal what the uncut
     reference gives for the whole layer."""
@@ -210,7 +225,8 @@ def test_the_shares_add_up_to_the_uncut_layer(weights, cfg):
                 uncut[pre + "experts." + name][rank * held:(rank + 1) * held])
         layer.experts.gate.weight._rebind(uncut[pre + "experts.gate.weight"])
         assert layer.experts.first == rank * held
-        routed = np.asarray(layer.experts(paddle.to_tensor(x))._data)
+        with paddle.no_grad():   # the hit path is for calls without one
+            routed = np.asarray(layer.experts(paddle.to_tensor(x))._data)
         # the program's share is the reference's share of the same rank
         want = reference.routed_share(
             x, picks, w, *(uncut[pre + "experts." + n]
@@ -224,10 +240,13 @@ def test_the_shares_add_up_to_the_uncut_layer(weights, cfg):
                                atol=2e-6)
 
 
-def test_no_token_is_dropped_and_the_denominator_is_over_all_picks(cfg):
+@pytest.mark.parametrize("path", PATHS)
+def test_no_token_is_dropped_and_the_denominator_is_over_all_picks(cfg,
+                                                                   path):
     """16 tokens that all pick the same experts: a capacity would drop
     most; the weights of a token's picks sum to the scaling factor whether
-    its experts are held here or not."""
+    its experts are held here or not, and every one of the 16 gets the
+    whole weighted sum of its picks' products on either path."""
     from paddle_tpu.incubate.distributed.models.moe import expert_share
 
     x = jnp.tile(jnp.asarray(np.random.default_rng(5).normal(size=(1, 48)),
@@ -242,12 +261,29 @@ def test_no_token_is_dropped_and_the_denominator_is_over_all_picks(cfg):
                                2.5, rtol=1e-5)
     # every token keeps every pick: 16 tokens x 4 picks land somewhere
     assert sum(int((np.asarray(d) > 0).sum()) for d in dense) == 64
+    rng = np.random.default_rng(7)
+    w_gate, w_up = (jnp.asarray(rng.normal(size=(4, 48, 24)), jnp.float32)
+                    for _ in range(2))
+    w_down = jnp.asarray(rng.normal(size=(4, 24, 48)), jnp.float32)
+    ffn = expert_hit.hit_ffn if path == "hit" else expert_share.share_ffn
+    first = max(range(4), key=lambda r: float(np.asarray(dense[r]).sum()))
+    got = np.asarray(ffn(x, dense[first], w_gate, w_up, w_down))
+    want = sum(
+        float(dense[first][0, e]) * np.asarray(
+            (jax.nn.silu(x[:1] @ w_gate[e]) * (x[:1] @ w_up[e])) @ w_down[e])
+        for e in range(4))
+    assert np.abs(want).max() > 1e-3
+    np.testing.assert_allclose(got, np.tile(want, (16, 1)), rtol=1e-4,
+                               atol=1e-4)
 
 
-def test_the_step_counts_its_pairs_and_the_experts_hit(model, weights, cfg):
+@pytest.mark.parametrize("expert_path", PATHS, indirect=True)
+def test_the_step_counts_its_pairs_and_the_experts_hit(model, weights, cfg,
+                                                       expert_path):
     """`expert_pairs`, `experts_hit`, `expert_layer_steps` and
     `experts_held` of one decode step, against the reference's picks; a
-    dead row counts for nothing."""
+    dead row counts for nothing. `experts_read` is what the path streams:
+    the experts hit on the hit path, every held one on the dense."""
     b, page, pps = 4, 8, 2
     width = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
     L = cfg["num_hidden_layers"]
@@ -255,7 +291,7 @@ def test_the_step_counts_its_pairs_and_the_experts_hit(model, weights, cfg):
     pools = [(jnp.zeros((1, b * pps, page, width), jnp.float32),)] * L
     tok = np.asarray([[3], [17], [40], [66]])
     live = jnp.asarray([True, True, False, True])
-    with tracing.device_counts() as counts:
+    with tracing.device_counts() as counts, paddle.no_grad():
         model.forward_paged(paddle.to_tensor(tok), pools, tables,
                             jnp.zeros((b,), jnp.int32), active=live)
     pairs = hit = 0
@@ -274,14 +310,17 @@ def test_the_step_counts_its_pairs_and_the_experts_hit(model, weights, cfg):
         hit += len(seen)
     assert {k: int(v) for k, v in counts.items()} == {
         "expert_pairs": pairs, "experts_hit": hit,
+        "experts_read": hit if expert_path == "hit" else 2 * 4,
         "expert_layer_steps": 2, "experts_held": 2 * 4}
     # outside a collection nothing is counted and nothing is left behind
     assert not tracing.counting()
     tracing.count("expert_pairs", 1)
 
 
+@pytest.mark.parametrize("expert_path", PATHS, indirect=True)
 def test_the_burst_hands_its_counts_to_the_emit_phase(model, cfg,
-                                                      monkeypatch):
+                                                      monkeypatch,
+                                                      expert_path):
     seen = []
     real = tracing.phase
 
@@ -295,10 +334,13 @@ def test_the_burst_hands_its_counts_to_the_emit_phase(model, cfg,
     eng.add_request(np.arange(6), max_new_tokens=6)
     eng.run()
     assert seen and set(seen[0]) == {"expert_pairs", "experts_hit",
-                                     "expert_layer_steps", "experts_held"}
+                                     "experts_read", "expert_layer_steps",
+                                     "experts_held"}
     # one live row, 2 expert layers, bursts of 4 steps
     assert seen[0]["expert_layer_steps"] == 2 * 4
     assert seen[0]["experts_held"] == 2 * 4 * 4
+    assert seen[0]["experts_read"] == seen[0][
+        "experts_hit" if expert_path == "hit" else "experts_held"]
     assert 0 <= seen[0]["experts_hit"] <= seen[0]["expert_pairs"] <= 2 * 4 * 4
 
 
