@@ -15,7 +15,8 @@ back, is not stood in for. The shares of all `ep_degree` ranks add up to
 the whole layer's routed result (tests/test_latent_moe.py holds that).
 
 `SigmoidTopKGate` scores with a sigmoid in float32 and picks the `top_k`
-largest (no groups, no correction bias); `ExpertShareLayer` owns the gate
+largest (no groups; with `pick_bias` the router's `expert_bias` is added to
+the scores for the pick alone); `ExpertShareLayer` owns the gate
 and the held experts' stacked gated-SiLU weights. `share_ffn` takes every
 held expert's product for every token and weights it by `w_e` (0 where the
 token did not pick it). A decode step is bound by reading the experts'
@@ -57,14 +58,19 @@ def over_token_blocks(fn, block, *arrays):
     return out.reshape((-1,) + out.shape[2:])[:n]
 
 
-def sigmoid_topk(x, w_router, top_k, scale=1.0, norm_topk=True):
+def sigmoid_topk(x, w_router, top_k, scale=1.0, norm_topk=True, bias=None):
     """x [n, d], w_router [d, E] -> (picks [n, k] int32, weights [n, k]
     float32): the `top_k` largest of sigmoid(x W_r), scored in float32;
     weights are the scores, over their sum where `norm_topk`, times
-    `scale`."""
+    `scale`. `bias` [E] (a router's `expert_bias`) is added for the PICK
+    alone: the weights stay the scores of the experts picked."""
     scores = jax.nn.sigmoid(jnp.matmul(
         x, w_router.astype(x.dtype), preferred_element_type=jnp.float32))
-    top, picks = jax.lax.top_k(scores, top_k)
+    if bias is None:
+        top, picks = jax.lax.top_k(scores, top_k)
+    else:
+        _, picks = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, picks, axis=-1)
     if norm_topk:
         top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
     return picks.astype(jnp.int32), top * scale
@@ -101,11 +107,12 @@ def share_ffn(x, dense_w, w_gate, w_up, w_down):
 
 class SigmoidTopKGate(Layer):
     """The router: `weight` [d_model, num_experts] over ALL experts of the
-    deployment. forward(x [n, d]) -> (picks, weights), see
-    `sigmoid_topk`."""
+    deployment, and with `pick_bias` an `expert_bias` [num_experts] (zeros
+    until loaded) that enters the pick alone. forward(x [n, d]) -> (picks,
+    weights), see `sigmoid_topk`."""
 
     def __init__(self, d_model, num_experts, top_k, routed_scaling_factor=1.0,
-                 norm_topk_prob=True):
+                 norm_topk_prob=True, pick_bias=False):
         super().__init__()
         self.num_experts, self.top_k = num_experts, top_k
         self.routed_scaling_factor = float(routed_scaling_factor)
@@ -113,12 +120,19 @@ class SigmoidTopKGate(Layer):
         self.weight = self.create_parameter(
             shape=[d_model, num_experts],
             default_initializer=I.XavierUniform())
+        self.expert_bias = self.create_parameter(
+            shape=[num_experts], default_initializer=I.Constant(0.0)) \
+            if pick_bias else None
 
     def forward(self, x):
+        kw = dict(top_k=self.top_k, scale=self.routed_scaling_factor,
+                  norm_topk=self.norm_topk_prob)
+        if self.expert_bias is None:
+            return _apply_op(sigmoid_topk, x, self.weight,
+                             _name="moe_sigmoid_gate", **kw)
         return _apply_op(
-            sigmoid_topk, x, self.weight, _name="moe_sigmoid_gate",
-            top_k=self.top_k, scale=self.routed_scaling_factor,
-            norm_topk=self.norm_topk_prob)
+            lambda x, w, b: sigmoid_topk(x, w, bias=b, **kw), x, self.weight,
+            self.expert_bias, _name="moe_sigmoid_gate")
 
 
 class ExpertShareLayer(Layer):
@@ -133,7 +147,7 @@ class ExpertShareLayer(Layer):
 
     def __init__(self, d_model, d_hidden, num_experts, top_k, ep_rank=0,
                  ep_degree=1, routed_scaling_factor=1.0,
-                 norm_topk_prob=True):
+                 norm_topk_prob=True, pick_bias=False):
         super().__init__()
         if num_experts % ep_degree or not 0 <= ep_rank < ep_degree:
             raise ValueError(
@@ -143,7 +157,8 @@ class ExpertShareLayer(Layer):
         self.held = num_experts // ep_degree
         self.first = ep_rank * self.held
         self.gate = SigmoidTopKGate(d_model, num_experts, top_k,
-                                    routed_scaling_factor, norm_topk_prob)
+                                    routed_scaling_factor, norm_topk_prob,
+                                    pick_bias)
         for name, shape in (("w_gate", [self.held, d_model, d_hidden]),
                             ("w_up", [self.held, d_model, d_hidden]),
                             ("w_down", [self.held, d_hidden, d_model])):

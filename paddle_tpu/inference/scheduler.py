@@ -42,6 +42,23 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..framework import config as _cfg
 
+# An engine whose sequences span at most this many pages has a prefill
+# program a (batch bucket, page multiple): at most PAGE_BUCKETS_MAX x
+# (log2(max_batch) + 1) of them. Beyond it the count of programs would grow
+# with max_seq_len / page_size (36 x 4 for 9,216 tokens at pages of 256 and
+# 8 slots, each the whole unrolled depth to compile), and a batch padded to
+# its longest prompt would mostly multiply padding where prompts differ by
+# that many pages: such an engine pads a prompt to the next power of two of
+# its pages and prefills a prompt a round, log2(pages) + 2 programs at most.
+PAGE_BUCKETS_MAX = 16
+
+
+def _pow2_at_least(n: int) -> int:
+    p = 1
+    while p < n:
+        p *= 2
+    return p
+
 
 class SchedulerPolicy:
     """Base policy: the six decision hooks, default = FIFO engine
@@ -93,16 +110,21 @@ class SchedulerPolicy:
         """(batch_bucket, token_bucket) for one batched prefill of
         ``new`` = [(slot_idx, context_ids), ...]. One compiled program
         exists per bucket pair, so the policy trades padding FLOPs
-        against compile-cache pressure. Default: batch to the next
-        power of two capped at max_batch; tokens to the next page
-        multiple of the longest prompt."""
-        nb = 1
-        while nb < len(new):
-            nb *= 2
-        nb = min(nb, engine.max_batch)
-        longest = max(len(ids) for _si, ids in new)
-        bucket = -(-longest // engine.page_size) * engine.page_size
-        return nb, bucket
+        against compile-cache pressure. ``batch_bucket`` may be less than
+        ``len(new)``: the engine then prefills the first ``batch_bucket``
+        of ``new`` in this round and asks again for the rest. Default, for
+        an engine whose sequences span at most PAGE_BUCKETS_MAX pages:
+        batch to the next power of two capped at max_batch, tokens to the
+        next page multiple of the longest prompt. For a longer engine: a
+        prompt a round, its tokens to the next power of two of its pages,
+        capped at max_seq_len."""
+        page = engine.page_size
+        if engine.max_seq_len // page <= PAGE_BUCKETS_MAX:
+            nb = min(_pow2_at_least(len(new)), engine.max_batch)
+            longest = max(len(ids) for _si, ids in new)
+            return nb, -(-longest // page) * page
+        pages = _pow2_at_least(-(-len(new[0][1]) // page))
+        return 1, min(pages * page, engine.max_seq_len)
 
     # -- burst sizing -------------------------------------------------
     def burst_k(self, engine, active: Sequence[int],

@@ -392,8 +392,18 @@ class ServingEngine:
         # of absorbed-form attention, and carry the value in themselves)
         self._kv_layout = tuple(model.kv_cache_layout())
         self._one_pool = len(self._kv_layout) == 1
+        # ... and how many positions a layer keeps: every one (pages from
+        # the allocator through the block tables), or a window of them (a
+        # ring of `_rings[layer]` pages a slot in pools of the layer's own,
+        # which the allocator never sees: `CausalLMBase.kv_cache_windows`)
+        self._rings = tuple(
+            None if w is None else _pa.ring_pages(w, page_size)
+            for w in model.kv_cache_windows())
+        self._has_rings = any(self._rings)
+        # anything but (k, v) pages of every position in every layer
+        self._mixed_layout = self._one_pool or self._has_rings
         kvh = self._kv_layout[0][0]
-        self._check_latent_support(
+        self._check_mixed_layout_support(
             kv_cache_quant=kv_cache_quant, spec_decode=spec_decode,
             draft_model=draft_model, prefix_cache=prefix_cache,
             prefill_chunk=prefill_chunk)
@@ -401,11 +411,11 @@ class ServingEngine:
         # that raises leaves the caller's model where it was
         tp = int(self.mesh.shape["tp"]) if self.mesh is not None \
             and "tp" in self.mesh.axis_names else 1
-        if tp > 1 and self._one_pool:
+        if tp > 1 and self._mixed_layout:
             raise ValueError(
-                "a latent page pool has one head and cannot be sharded "
-                f"over tp={tp}: latent attention serves at tp=1 "
-                "(ROADMAP R10 keeps the sharded form)")
+                "a mixed layout (a latent page pool of one head, or window "
+                f"layers' rings) cannot be sharded over tp={tp}: it serves "
+                "at tp=1 (ROADMAP R9 and R10 keep the sharded forms)")
         if tp > 1 and kvh % tp:
             raise ValueError(
                 f"TP serving shards the {kvh} kv heads over tp={tp}; "
@@ -685,19 +695,23 @@ class ServingEngine:
 
     def _alloc_pools(self):
         """(k_pages, v_pages): empty pools for every layer, as the model's
-        cache layout shapes them; `v_pages` is [] for a layout of one."""
-        pools = [[jnp.zeros((heads, self._n_pages_total, self.page_size,
+        cache layout shapes them; `v_pages` is [] for a layout of one. A
+        layer that keeps every position has the allocator's pages, a
+        window layer its slots' rings."""
+        pools = [[jnp.zeros((heads, self._n_pages_total if ring is None
+                             else self.max_batch * ring, self.page_size,
                              width), self.kv_dtype)
-                  for _ in range(self.cfg.num_hidden_layers)]
+                  for ring in self._rings]
                  for heads, width in self._kv_layout]
         return pools[0], pools[1] if len(pools) > 1 else []
 
-    def _check_latent_support(self, **asked):
-        """A layout of one pool (latent attention) has no int8 pages, no
+    def _check_mixed_layout_support(self, **asked):
+        """A mixed layout (one pool of latent rows a layer, or window
+        layers' rings beside full layers' pages) has no int8 pages, no
         window step (speculative decoding, chunked prefill and with it
         the prefix cache and its tiers) and no draft pools yet: asking
         for one raises here, at construction, and nothing falls back."""
-        if not self._one_pool:
+        if not self._mixed_layout:
             return
         from ..framework import config as _config
 
@@ -709,9 +723,11 @@ class ServingEngine:
                 value = _config.get_flag(flags[name], 0)
             if value is not None and value != 0 and value != "":
                 raise ValueError(
-                    f"{name}={value!r} is not built for a model that caches "
-                    "a latent (one page pool a layer, decoded one token a "
-                    "row): serve it without, or see ROADMAP R10")
+                    f"{name}={value!r} is not built for a mixed layout (a "
+                    "model that caches a latent in one page pool a layer, "
+                    "or keeps a window of some layers in rings; decoded "
+                    "one token a row): serve it without, or see ROADMAP R9 "
+                    "and R10")
 
     def _pin_pages(self):
         """Lay the page pools out in the serving sharding (kv heads over
@@ -821,8 +837,14 @@ class ServingEngine:
     def _admit(self):
         with _trace.phase("serving.admit"):
             new = self._admit_slots()
-        if new:
-            self._prefill_batch(new)
+        # the policy says how many of them one round takes (all, unless
+        # its batch bucket is smaller). A round that ended in a recovery has
+        # sent every request back to the queue, and the rest with it
+        while new and all(self.slots[si].active for si, _ in new):
+            nb, _bucket = self.scheduler.prefill_bucket(self, new)
+            nb = max(1, min(int(nb), self.max_batch))
+            self._prefill_batch(new[:nb])
+            new = new[nb:]
 
     def _admit_slots(self):
         # collect ALL admissible requests first, then prefill them in ONE
@@ -1345,6 +1367,7 @@ class ServingEngine:
                              bucket=bucket, all_greedy=all_greedy,
                              which=which)
         model = self.model if which == "target" else self._draft_model
+        rings = self._rings if self._has_rings else None
         from ..jit.api import _LayerScope
         from ..models.generation import (sample_logits,
                                          sample_logits_per_row)
@@ -1358,10 +1381,9 @@ class ServingEngine:
                 # downcast implicitly
                 caches = model.init_kv_caches(
                     nb, bucket, dtype=next(iter(params.values())).dtype)
-                logits, caches = model.forward_cached(
-                    Tensor(ids), caches, 0)
                 # causal mask => position true_len-1 ignores the padding
-                last = as_array(logits)[jnp.arange(nb), true_lens - 1, :]
+                last, caches = model.forward_prefill(
+                    Tensor(ids), caches, true_lens)
                 # first token sampled ON DEVICE (round-2 verdict weak #5:
                 # the host-side sample paid a [nb, vocab] transfer),
                 # per-request params as runtime [nb] arrays
@@ -1371,6 +1393,16 @@ class ServingEngine:
                 else:
                     first, _ = sample_logits_per_row(last, key, greedy,
                                                      temp, tk, tp)
+                if rings is not None:
+                    # a layout with rings: a layer's own (k, v) an entry,
+                    # and of a window layer the part of the prompts its
+                    # ring keeps (the rest of its K/V dies with the layer,
+                    # and no stack copies what is left)
+                    return first, tuple(
+                        tuple(as_array(a) if ring is None else _pa.ring_tail(
+                            as_array(a), true_lens, ring, self.page_size)
+                            for a in c)
+                        for c, ring in zip(caches, rings))
                 # one stack per pool of the layout: (ks, vs) of [L, nb,
                 # bucket, kvh, hd] for full attention, the latent rows
                 # alone for latent attention
@@ -1415,22 +1447,46 @@ class ServingEngine:
             tag=(nb, bucket, which))
         return fn
 
-    def _write_prefill_pages(self, fn, k_pages, v_pages, k_scales,
-                             v_scales, ks, vs, tables, lens):
-        """Write every layer of a prefill's K/V ([L, nb, bucket, kvh,
-        hd]) through `fn`, re-binding each layer's donated pools to the
-        program's results. False when an OOM was absorbed by a recovery
-        (every request is back in the queue and the round is void)."""
+    def _get_layer_write_fn(self, nb, bucket, ring):
+        """The page write of a layout with rings, a compiled program a
+        (batch-bucket, token-bucket, kind of layer): ONE layer's K and V as
+        the prefill returned them land in that layer's donated pools. A
+        full layer's (`ring` None) go through the rows' block tables; a
+        window layer's are `ring_tail`s and go into the rings of the rows'
+        SLOTS ([nb]), the pages a later step can still see."""
+        fn = self._page_write_fns.get((nb, bucket, "layer", ring))
+        if fn is not None:
+            return fn
+
+        def pure_layer_write(pools, k, v, where, lens):
+            if ring is None:
+                return _pa.prefill_paged_kv_cache(*pools, k, v, where, lens)
+            return _pa.prefill_ring_kv_cache(*pools, k, v, where, lens,
+                                             ring, bucket)
+
+        fn = self._page_write_fns[(nb, bucket, "layer", ring)] = \
+            _cw.watch_jit(
+                "serving.kv_scatter",
+                jax.jit(pure_layer_write, donate_argnums=(0,)),
+                tag=(nb, bucket, "ring" if ring else "full"))
+        return fn
+
+    def _write_prefill_pages(self, write, k_pages, v_pages, k_scales,
+                             v_scales):
+        """Write every layer of a prefill's K/V into its pools:
+        `write(li, pools)` is the compiled write of layer `li` with that
+        layer's pools donated, and each layer's pools are re-bound to what
+        it returns. False when an OOM was absorbed by a recovery (every
+        request is back in the queue and the round is void)."""
         # the scales, where the pages are int8, in the order the q8 write
         # takes them: whether they exist is a fact of the tuple
-        # (a layout of one pool, latent rows, has no `v_pages`: `vs` is None)
+        # (a layout of one pool, latent rows, has no `v_pages`)
         lists = (k_pages,) if not v_pages \
             else (k_pages, v_pages) if k_scales is None \
             else (k_pages, k_scales, v_pages, v_scales)
         try:
             for li in range(len(k_pages)):
-                written = fn(tuple(pools[li] for pools in lists), ks, vs,
-                             tables, lens, np.int32(li))
+                written = write(li, tuple(pools[li] for pools in lists))
                 for pools, new in zip(lists, written):
                     pools[li] = new
         except BaseException as e:
@@ -1495,6 +1551,7 @@ class ServingEngine:
                     jax.random.key_data(sk), jnp.asarray(greedy),
                     jnp.asarray(temp), jnp.asarray(tk),
                     jnp.asarray(tp_arr))
+                # (a layout with rings: `ks` is a layer's (k, v) an entry)
                 first, ks, *vs = fn(params, buffers, *prefill_args)
                 vs = vs[0] if vs else None
             # the compiled per-layer page write (and, with a separate
@@ -1509,10 +1566,28 @@ class ServingEngine:
                 write_lens[n:] = 0
                 tables, write_lens = jnp.asarray(tables), \
                     jnp.asarray(write_lens)
+                if self._has_rings:
+                    # a layout with rings: `ks` holds a layer's own (k, v)
+                    # an entry, a window layer's cut to what its ring
+                    # keeps, which goes into the rings of the rows' slots
+                    slots = np.zeros((nb,), np.int32)
+                    slots[:n] = [si for si, _ in new]
+                    slots = jnp.asarray(slots)
+
+                    def write(li, pools):
+                        ring = self._rings[li]
+                        return self._get_layer_write_fn(nb, bucket, ring)(
+                            pools, *ks[li], tables if ring is None
+                            else slots, write_lens)
+                else:
+                    fn_w = self._get_page_write_fn(nb, bucket)
+
+                    def write(li, pools):
+                        return fn_w(pools, ks, vs, tables, write_lens,
+                                    np.int32(li))
                 if not self._write_prefill_pages(
-                        self._get_page_write_fn(nb, bucket),
-                        self.k_pages, self.v_pages, self.k_scales,
-                        self.v_scales, ks, vs, tables, write_lens):
+                        write, self.k_pages, self.v_pages, self.k_scales,
+                        self.v_scales):
                     return
                 if self._draft_model is not None:
                     # the separate draft model needs the prompt in ITS
@@ -1523,11 +1598,13 @@ class ServingEngine:
                                                 which="draft")
                     dparams, dbuffers = self._cached_draft_params()
                     _f, dks, dvs = fn_d(dparams, dbuffers, *prefill_args)
+                    fn_dw = self._get_page_write_fn(nb, bucket, "draft")
                     if not self._write_prefill_pages(
-                            self._get_page_write_fn(nb, bucket, "draft"),
+                            lambda li, pools: fn_dw(
+                                pools, dks, dvs, tables, write_lens,
+                                np.int32(li)),
                             self._draft_k_pages, self._draft_v_pages,
-                            self._draft_k_scales, self._draft_v_scales,
-                            dks, dvs, tables, write_lens):
+                            self._draft_k_scales, self._draft_v_scales):
                         return
             if self._prefix_cache is not None:
                 # cache the freshly prefilled FULL pages; the partial tail
@@ -2965,11 +3042,12 @@ class ServingEngine:
             if after <= before:  # OOM drained/preempted: no progress
                 break
 
-    def _no_latent_handoff(self):
-        if self._one_pool:
+    def _no_mixed_layout_handoff(self):
+        if self._mixed_layout:
             raise NotImplementedError(
-                "KV hand-off packs (k, v) pages: a latent page pool has no "
-                "hand-off format yet (ROADMAP R10)")
+                "KV hand-off packs (k, v) pages of every position: a mixed "
+                "layout (a latent page pool, window layers' rings) has no "
+                "hand-off format yet (ROADMAP R9 and R10)")
 
     def detach_request(self, request_id: int) -> "KVHandoff":
         """Extract a prefilled request from this engine: gather its KV
@@ -2980,7 +3058,7 @@ class ServingEngine:
         against them). The uncommitted prefill-time sample rides the
         handoff, so the first token is committed exactly once, by the
         attaching engine."""
-        self._no_latent_handoff()
+        self._no_mixed_layout_handoff()
         self._check_poisoned()
         slot_idx = next((i for i, s in enumerate(self.slots)
                          if s.active and s.request_id == request_id),
@@ -3051,7 +3129,7 @@ class ServingEngine:
         shapes are checked)."""
         self._check_poisoned()
         t_attach0 = _time_mod.perf_counter()
-        self._no_latent_handoff()
+        self._no_mixed_layout_handoff()
         if handoff.page_size != self.page_size:
             raise ValueError(
                 f"page_size mismatch: handoff {handoff.page_size} vs "

@@ -929,6 +929,143 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None,
     return jnp.swapaxes(out.reshape(b, h, s_q, d), 1, 2)
 
 
+# ---------------------------------------------------------------------------
+# grouped-query causal forward with an optional window (serving's prefill)
+# ---------------------------------------------------------------------------
+
+GQA_BLOCK_Q = 128
+GQA_BLOCK_K = 512
+# The kernel serves from this many tokens on. One layer's attention at [1, s,
+# 32 heads on 4, 128] bf16 on a v5e, the kernel against XLA's blocked
+# `gqa_attention` (PERF.md section 6, PR 31): 0.94 against 1.72 ms at 1,024,
+# 1.38 / 4.94 at 2,048, 2.78 / 16.8 at 4,096, 5.60 / 773 at 8,192 with a
+# window of 2,048, and much the same without one below 4,096. 512 tokens, the
+# one shorter length that tiles, was not measured and stays XLA's.
+GQA_MIN_SEQ = 1024
+
+
+def _gqa_key_blocks(i, block_q, block_k, window):
+    """(first, last) key block a query block `i` sees: causal, and with a
+    window no key more than `window - 1` positions behind the query."""
+    last = ((i + 1) * block_q - 1) // block_k
+    if window is None:
+        return 0, last
+    return jnp.maximum(i * block_q - window + 1, 0) // block_k, last
+
+
+def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, *, scale,
+                    block_q, block_k, n_kv, window):
+    """One (kv head, query block, key block): the `group` query heads of a
+    kv head are the rows of one [group * block_q, d] operand, so a key
+    block is read once for all of them. Operands go to the MXU in their own
+    type; scores, softmax and the accumulator are float32."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    group = q_ref.shape[1]
+    rows = group * block_q
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc[...] = jnp.zeros_like(acc)
+
+    lo, hi = _gqa_key_blocks(i, block_q, block_k, window)
+
+    @pl.when((j >= lo) & (j <= hi))
+    def _():
+        q = q_ref[0].reshape(rows, q_ref.shape[-1])
+        k, v = k_ref[0], v_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * np.float32(scale)
+        q_pos = i * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (group, block_q, block_k), 1).reshape(rows, block_k)
+        k_pos = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_k), 1)
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen = seen & (q_pos - k_pos < window)
+        s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), np.float32(0.0))
+        l_scr[:, :1] = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+        acc[...] = acc[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:, :1] = m_new
+
+    @pl.when(j == n_kv - 1)
+    def _():
+        l = l_scr[:, :1]
+        out = acc[...] / jnp.where(l == 0.0, np.float32(1.0), l)
+        o_ref[0] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
+
+
+def gqa_supports(seq, head_dim):
+    return seq % GQA_BLOCK_K == 0 and head_dim % 128 == 0
+
+
+def use_gqa_flash(seq, head_dim):
+    """The one choice a grouped-query causal prefill asks: this kernel from
+    `GQA_MIN_SEQ` tokens on, where the shape tiles; interpret mode (the
+    CPU) included, so the tests run the same body."""
+    return gqa_supports(seq, head_dim) and seq >= GQA_MIN_SEQ
+
+
+def flash_attention_gqa_bshd(q, k, v, window=None, scale=None):
+    """Causal self-attention of q [b, s, h, d] over k / v [b, s, h_kv, d]
+    (query head n reads kv head n // (h / h_kv)); with `window`, position i
+    sees j only if i - j < window, and key blocks wholly outside a query
+    block's window are neither fetched nor multiplied. Forward only."""
+    b, s, h, d = q.shape
+    h_kv = k.shape[2]
+    group = h // h_kv
+    if not gqa_supports(s, d):
+        raise ValueError(
+            f"flash_attention_gqa: unsupported shape seq={s} d={d} (need "
+            f"multiples of {GQA_BLOCK_K}/128)")
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    bq, bk = GQA_BLOCK_Q, GQA_BLOCK_K
+    n_q, n_kv = s // bq, s // bk
+    # [b * h_kv, group, s, d] and [b * h_kv, s, d]
+    qt = q.reshape(b, s, h_kv, group, d).transpose(0, 2, 3, 1, 4).reshape(
+        b * h_kv, group, s, d)
+    kt = jnp.swapaxes(k, 1, 2).reshape(b * h_kv, s, d)
+    vt = jnp.swapaxes(v, 1, 2).reshape(b * h_kv, s, d)
+
+    def kv_map(g, i, j):
+        # a block outside what the query block sees repeats the nearest one
+        # inside, so the pipeline copies nothing for it
+        lo, hi = _gqa_key_blocks(i, bq, bk, window)
+        return (g, jnp.clip(j, lo, hi), 0)
+
+    q_spec = pl.BlockSpec((1, group, bq, d), lambda g, i, j: (g, 0, i, 0))
+    kernel = functools.partial(
+        _gqa_fwd_kernel, scale=float(scale), block_q=bq, block_k=bk,
+        n_kv=n_kv, window=None if window is None else int(window))
+    with _x64_off():
+        out = _pc(
+            kernel,
+            grid=(b * h_kv, n_q, n_kv),
+            in_specs=[q_spec, pl.BlockSpec((1, bk, d), kv_map),
+                      pl.BlockSpec((1, bk, d), kv_map)],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+            scratch_shapes=[
+                pltpu.VMEM((group * bq, d), jnp.float32),
+                pltpu.VMEM((group * bq, 128), jnp.float32),
+                pltpu.VMEM((group * bq, 128), jnp.float32),
+            ],
+            interpret=_interpret(),
+        )(qt, kt, vt)
+    return out.reshape(b, h_kv, group, s, d).transpose(0, 3, 1, 2, 4).reshape(
+        b, s, h, d)
+
+
 def flash_attn_unpadded(q, k, v, cu_seqlens_q, cu_seqlens_k, max_seqlen_q,
                         max_seqlen_k, scale=None, dropout=0.0, causal=False,
                         return_softmax=False, block_q=DEFAULT_BLOCK_Q,

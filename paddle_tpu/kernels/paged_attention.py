@@ -60,25 +60,34 @@ _XLA_DECODE_MAX_CTX = 2048
 _KERNEL_MIN_PAGE = 128
 
 
+def decode_uses_kernel(page_size, mapped_tokens, quant):
+    """Whether a decode call over pools of `page_size` tokens, `quant` for
+    int8 pages, whose tables map `mapped_tokens` a row, takes the page-grid
+    kernel (else the XLA dense gather)."""
+    if _interpret():
+        return False
+    if not quant and page_size >= _KERNEL_MIN_PAGE:
+        return True
+    return mapped_tokens > _XLA_DECODE_MAX_CTX
+
+
 def paged_attention_dispatch(q, k_pages, v_pages, block_tables,
                              context_lens, scale=None, k_scales=None,
-                             v_scales=None):
+                             v_scales=None, first=None):
     """Decode attention, chosen from what the call can see: interpret mode
     takes the XLA dense gather (the Pallas path is emulation there); float
     pools at pages of `_KERNEL_MIN_PAGE` and more take the page-grid kernel
     whatever the mapped context; smaller pages and int8 pools take the
     gather up to `_XLA_DECODE_MAX_CTX` of mapped context and the kernel
-    above it."""
+    above it. `first` [batch] (a window layer's first visible position a
+    row) goes to whichever is taken."""
     page_size = k_pages.shape[2]
-    if _interpret():
-        use_xla = True
-    elif k_scales is None and page_size >= _KERNEL_MIN_PAGE:
-        use_xla = False
-    else:
-        use_xla = block_tables.shape[1] * page_size <= _XLA_DECODE_MAX_CTX
-    attend = paged_attention_xla if use_xla else paged_attention
+    attend = paged_attention if decode_uses_kernel(
+        page_size, block_tables.shape[1] * page_size,
+        k_scales is not None) else paged_attention_xla
+    kw = {} if first is None else {"first": first}
     return attend(q, k_pages, v_pages, block_tables, context_lens,
-                  scale=scale, k_scales=k_scales, v_scales=v_scales)
+                  scale=scale, k_scales=k_scales, v_scales=v_scales, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -344,10 +353,12 @@ def paged_attention_window_xla(q, k_pages, v_pages, block_tables,
 
 
 def _decode_accumulate(q, k, v, base_pos, ctx, scale, m_scr, l_scr, acc,
-                       k_col_scale=None, v_col_scale=None):
+                       k_col_scale=None, v_col_scale=None, first=None):
     """One online-softmax block update of the page-grid decode kernel:
     scores for a K/V block starting at absolute position `base_pos`,
-    masked at `ctx`, folded into the running (m, l, acc) state. q [.., rows, d] and k/v [.., tokens, d] share
+    masked at `ctx` (and below `first`, a window's first visible position,
+    where given), folded into the running (m, l, acc) state. q [.., rows,
+    d] and k/v [.., tokens, d] share
     their leading dims (the kv heads of a block), which are batch dims
     of both products; the operands go to the MXU in the type they come
     in, and everything from the scores to the accumulator is float32 (the
@@ -362,11 +373,16 @@ def _decode_accumulate(q, k, v, base_pos, ctx, scale, m_scr, l_scr, acc,
     if k_col_scale is not None:
         s = s * k_col_scale[..., None, :]
     kpos = base_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, last)
-    s = jnp.where(kpos < ctx, s, NEG_INF)
+    seen = kpos < ctx if first is None else (kpos < ctx) & (kpos >= first)
+    s = jnp.where(seen, s, NEG_INF)
     m_prev = m_scr[..., :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     pexp = jnp.exp(s - m_new)
+    if first is not None:
+        # NEG_INF is finite: a block whose every column is masked has
+        # s == m_new and exp(0) == 1 everywhere; the mask itself zeroes it
+        pexp = jnp.where(seen, pexp, np.float32(0.0))
     l_scr[..., :1] = alpha * l_scr[..., :1] + jnp.sum(pexp, axis=-1,
                                                       keepdims=True)
     pw = pexp if v_col_scale is None else pexp * v_col_scale[..., None, :]
@@ -389,10 +405,14 @@ def _decode_epilogue(l_scr, acc, dtype):
     return (acc[...] / jnp.where(l == 0.0, np.float32(1.0), l)).astype(dtype)
 
 
-def _decode_kernel(lens_ref, fetch_ref, q_ref, k_ref, v_ref, *rest,
-                   page_size, scale, n_pages, quant=False):
+def _decode_kernel(lens_ref, fetch_ref, *rest, page_size, scale, n_pages,
+                   quant=False, windowed=False):
     """Online-softmax decode over the page grid dimension, every kv head
     of the block at once. `fetch_ref` is read by the index maps alone.
+    `windowed`: a third prefetched scalar a row, the first position the row
+    sees, masked inside its page (a caller whose rows see nothing of their
+    first pages hands tables that start at the page of that position, as
+    `ring_view` does: no page is skipped for it here).
 
     One body serves both storage formats: float pages go to the MXU as
     they are (q in their type); with `quant` the pages hold int8, are
@@ -401,6 +421,10 @@ def _decode_kernel(lens_ref, fetch_ref, q_ref, k_ref, v_ref, *rest,
     the softmax weights before p·v_int8, which is algebraically exact
     dequantization (the l normalizer uses unscaled pexp in both modes).
     """
+    first_ref = None
+    if windowed:
+        first_ref, *rest = rest
+    q_ref, k_ref, v_ref, *rest = rest
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc = rest
     else:
@@ -413,6 +437,7 @@ def _decode_kernel(lens_ref, fetch_ref, q_ref, k_ref, v_ref, *rest,
         _decode_init(m_scr, l_scr, acc)
 
     ctx = lens_ref[b]
+    first = None if first_ref is None else first_ref[b]
 
     @pl.when(p * page_size < ctx)
     def _():
@@ -423,7 +448,8 @@ def _decode_kernel(lens_ref, fetch_ref, q_ref, k_ref, v_ref, *rest,
             q_ref[0].astype(k.dtype), k, v,
             p * page_size, ctx, scale, m_scr, l_scr, acc,
             k_col_scale=ks_ref[:, 0, 0, :page_size] if quant else None,
-            v_col_scale=vs_ref[:, 0, 0, :page_size] if quant else None)
+            v_col_scale=vs_ref[:, 0, 0, :page_size] if quant else None,
+            **({} if first is None else {"first": first}))
 
     @pl.when(p == n_pages - 1)
     def _():
@@ -455,7 +481,7 @@ def _live_page_ids(block_tables, context_lens, page_size):
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                    scale=None, k_scales=None, v_scales=None):
+                    scale=None, k_scales=None, v_scales=None, first=None):
     """Single-token decode attention over a paged KV cache.
 
     q: [batch, num_q_heads, head_dim]
@@ -466,6 +492,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         length 0 reads nothing and returns zeros.
     k_scales/v_scales: [num_kv_heads, n_pages, 128] f32 — present iff the
         pages hold int8 (see `alloc_page_scales`)
+    first: optional [batch] int32, a window: the row attends positions
+        first[b] .. context_lens[b] - 1. The positions below first[b] are
+        masked, not skipped: `ring_view` hands tables that start at the
+        page of the first visible position
     -> [batch, num_q_heads, head_dim]
 
     Grid (head blocks, batch, pages_per_seq), the pages innermost: a step
@@ -493,14 +523,16 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     if gpad != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gpad - group), (0, 0)))
 
+    windowed = first is not None
     kernel = functools.partial(
         _decode_kernel, page_size=page_size, scale=scale,
-        n_pages=pages_per_seq, quant=quant)
+        n_pages=pages_per_seq, quant=quant,
+        **({"windowed": True} if windowed else {}))
 
-    def row_map(h, b, p, lens, fetch):
+    def row_map(h, b, p, lens, fetch, *_):
         return (b, h, 0, 0)
 
-    def page_map(h, b, p, lens, fetch):
+    def page_map(h, b, p, lens, fetch, *_):
         return (h, fetch[b, p], 0, 0)
 
     page_spec = pl.BlockSpec((hb, 1, page_size, head_dim), page_map)
@@ -518,10 +550,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         operands += [k_scales[:, :, None, :], v_scales[:, :, None, :]]
 
     context_lens = context_lens.astype(jnp.int32)
-    fetch = _live_page_ids(block_tables, context_lens, page_size)
+    scalars = [context_lens,
+               _live_page_ids(block_tables, context_lens, page_size)]
+    if windowed:
+        scalars.append(first.astype(jnp.int32))
     with _x64_off():
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(n_kv_heads // hb, b, pages_per_seq),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, hb, gpad, head_dim), row_map),
@@ -537,12 +572,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
             out_shape=jax.ShapeDtypeStruct((b, n_kv_heads, gpad, head_dim),
                                            q.dtype),
             interpret=_interpret(),
-        )(context_lens, fetch, *operands)
+        )(*scalars, *operands)
     return out[:, :, :group, :].reshape(b, n_q_heads, head_dim)
 
 
 def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
-                        scale=None, k_scales=None, v_scales=None):
+                        scale=None, k_scales=None, v_scales=None,
+                        first=None):
     """Dense-gather reference: materialize [b, S, kv_h, d] then masked
     attention. The tests' reference, the dispatch's choice in interpret
     mode, and below the crossover for small pages and int8 pools."""
@@ -566,10 +602,129 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
     s = jnp.einsum("bhgd,hbsd->bhgs", qf,
                    k_dense.astype(jnp.float32)) * scale
     mask = jnp.arange(S)[None, :] < context_lens[:, None]  # [b, S]
+    if first is not None:
+        mask = mask & (jnp.arange(S)[None, :] >= first[:, None])
     s = jnp.where(mask[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
+    if first is not None:
+        # a row that sees nothing returns zeros, as the kernel's does
+        p = jnp.where(jnp.any(mask, -1)[:, None, None, None], p, 0.0)
     out = jnp.einsum("bhgs,hbsd->bhgd", p, v_dense.astype(jnp.float32))
     return out.reshape(b, n_q_heads, head_dim).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# window layers: a RING of pages a row. A layer that attends the last
+# `window` positions keeps `ring_pages(window, page_size)` pages a row in
+# pools of its own, [kv_heads, rows * ring, page_size, head_dim]: row b owns
+# pages b * ring .. b * ring + ring - 1 and position p lives in page
+# (p // page_size) % ring of them, at slot p % page_size. One page more than
+# the window fills, so that a window that straddles page boundaries is held
+# whole; nothing is allocated or freed as a context grows.
+# ---------------------------------------------------------------------------
+
+
+def ring_pages(window, page_size):
+    """Pages a row of a window layer keeps: ceil(window / page) + 1."""
+    return -(-window // page_size) + 1
+
+
+def ring_tables(rows, ring):
+    """[len(rows), ring] int32: the pages of each ring, in ring order."""
+    rows = jnp.asarray(rows, jnp.int32)
+    return rows[:, None] * ring + jnp.arange(ring, dtype=jnp.int32)
+
+
+def ring_view(rows, ring, page_size, context_lens, window):
+    """What a decode step over rings hands the attention: (tables [b, ring],
+    lens [b], first [b]) in coordinates that start at the first page a row
+    still sees. `context_lens` counts the positions written (0: the row
+    reads nothing); a row sees its last `window` of them. Entry k of a
+    row's table is the ring page of logical page `lo + k`, `lo` the page of
+    the first visible position, so `lens` never passes ring * page_size."""
+    n = context_lens.astype(jnp.int32)
+    first = jnp.maximum(n - window, 0)
+    lo = first // page_size
+    order = (lo[:, None] + jnp.arange(ring, dtype=jnp.int32)) % ring
+    tables = jnp.asarray(rows, jnp.int32)[:, None] * ring + order
+    return tables, n - lo * page_size, first - lo * page_size
+
+
+def update_ring_kv_cache(k_pages, v_pages, k_new, v_new, rows, context_lens,
+                         active=None):
+    """`update_paged_kv_cache` over rings: the new token of row b lands at
+    position context_lens[b] of ring `rows[b]`."""
+    ring = k_pages.shape[1] // len(rows)
+    return update_paged_kv_cache(
+        k_pages, v_pages, k_new, v_new, ring_tables(rows, ring),
+        context_lens % (ring * k_pages.shape[2]), active=active)
+
+
+def ring_tail_start(seq_lens, s, ring, page_size):
+    """(oldest, start) [batch] int32 for prompts of `seq_lens` tokens in a
+    bucket of `s`: `oldest` is the first position a ring keeps of a prompt
+    (the start of the page `ring` pages before the one its last token is
+    in; negative for a prompt the ring holds whole), `start` where the
+    `min(s, ring * page_size)` positions that hold everything kept begin."""
+    lens = seq_lens.astype(jnp.int32)
+    oldest = (-(-lens // page_size) - ring) * page_size
+    return oldest, jnp.clip(oldest, 0, s - min(s, ring * page_size))
+
+
+def ring_tail(seq, seq_lens, ring, page_size):
+    """The part of a prompt's rows a window layer keeps: of seq [batch, s,
+    kv_heads, head_dim] the `min(s, ring * page_size)` positions from
+    `ring_tail_start`'s start on, [batch, span, kv_heads, head_dim]."""
+    s = seq.shape[1]
+    _, start = ring_tail_start(seq_lens, s, ring, page_size)
+    return jax.vmap(lambda row, at: jax.lax.dynamic_slice_in_dim(
+        row, at, min(s, ring * page_size), axis=0))(seq, start)
+
+
+def prefill_ring_kv_cache(k_pages, v_pages, k_tail, v_tail, rows, seq_lens,
+                          ring, s):
+    """Write whole prompts into rings, the pages a later step can still see
+    and no other: k_tail / v_tail are `ring_tail`s of the prompts' K and V
+    in a bucket of `s` positions; row b keeps the positions from
+    `(ceil(seq_lens[b] / page) - ring) * page` to seq_lens[b] - 1, in ring
+    `rows[b]`; a row of length 0 writes nothing."""
+    b, span, kvh, hd = k_tail.shape
+    page_size = k_pages.shape[2]
+    lens = seq_lens.astype(jnp.int32)
+    oldest, start = ring_tail_start(lens, s, ring, page_size)
+    pos = start[:, None] + jnp.arange(span, dtype=jnp.int32)
+    kept = (pos < lens[:, None]) & (pos >= oldest[:, None])
+    page_ids = jnp.asarray(rows, jnp.int32)[:, None] * ring \
+        + (pos // page_size) % ring
+    page_ids = jnp.where(kept, page_ids, k_pages.shape[1]).reshape(-1)
+    slots = (pos % page_size).reshape(-1)
+
+    def put(pages, tail):
+        tail = tail.astype(pages.dtype).transpose(2, 0, 1, 3).reshape(
+            kvh, b * span, hd)
+        return pages.at[:, page_ids, slots, :].set(tail, mode="drop")
+
+    return put(k_pages, k_tail), put(v_pages, v_tail)
+
+
+def ring_pages_live(context_lens, window, page_size):
+    """Pages of a window layer that hold a position some row still sees,
+    summed over rows, from the lengths alone: those from the page of
+    max(context_lens[b] - window, 0) to the page of the last position."""
+    n = context_lens.astype(jnp.int32)
+    pages = -(-n // page_size) - jnp.maximum(n - window, 0) // page_size
+    return jnp.sum(jnp.where(n > 0, pages, 0), dtype=jnp.int32)
+
+
+def decode_pages_fetched(block_tables, context_lens, page_size):
+    """Pages of a pool that one call of the page-grid kernel copies, from
+    what the kernel itself is handed: its pipeline copies a block wherever
+    the block index (`_live_page_ids`, read in the grid's order, rows then
+    pages) differs from the step before, and the one the grid starts on. A
+    table that named more pages than are live would count them."""
+    fetch = _live_page_ids(block_tables, context_lens.astype(jnp.int32),
+                           page_size).reshape(-1)
+    return 1 + jnp.sum(fetch[1:] != fetch[:-1], dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,8 @@ from .gpt import (  # noqa: F401
     GPTModel,
 )
 from .latent_moe import (  # noqa: F401
+    AfmoeConfig,
+    AfmoeForCausalLM,
     LatentMoEConfig,
     LatentMoEForCausalLM,
     LatentMoEModel,

@@ -24,12 +24,21 @@ class CausalLMBase(nn.Layer):
     def kv_cache_layout(self):
         """((heads, width), ...): the pools one layer's cache is made of,
         in the order the layer's cache tuple holds them. Keys and values of
-        every kv head for full attention; a model that caches something
-        else (a latent) states it by overriding this, and the dense caches
-        below and the serving engine's page pools follow."""
+        every kv head for full attention; a model with a mixed layout (it
+        caches something else, a latent, or keeps only a window of some
+        layers' positions: `kv_cache_windows`) states it by overriding
+        these, and the dense caches below and the serving engine's page
+        pools follow."""
         cfg = self.config
         kv = (self._kv_heads(), cfg.hidden_size // cfg.num_attention_heads)
         return (kv, kv)
+
+    def kv_cache_windows(self):
+        """How many positions each layer keeps, a layer an entry: None for
+        every position, else the window a later step can still see (the
+        serving engine gives such a layer a ring of pages a slot and no
+        pages from the allocator)."""
+        return (None,) * self.config.num_hidden_layers
 
     def init_kv_caches(self, batch_size, max_length, dtype=None):
         """Dense per-layer caches for incremental decoding: one `[batch,
@@ -39,6 +48,19 @@ class CausalLMBase(nn.Layer):
         return [tuple(jnp.zeros((batch_size, max_length, heads, width), dt)
                       for heads, width in self.kv_cache_layout())
                 for _ in range(self.config.num_hidden_layers)]
+
+    def forward_prefill(self, input_ids, caches, true_lens):
+        """A serving prefill: prompts [batch, s] padded past `true_lens`
+        [batch] through `forward_cached` from position 0. Returns (the
+        next-token logits [batch, vocab] at each prompt's last position, an
+        array, and the filled caches). A model whose vocabulary makes the
+        logits of every position too large to hold takes the head at those
+        positions alone by overriding this."""
+        from ..tensor import as_array
+
+        logits, caches = self.forward_cached(input_ids, caches, 0)
+        rows = jnp.arange(int(input_ids.shape[0]))
+        return as_array(logits)[rows, true_lens - 1, :], caches
 
     def generate(self, input_ids, max_length=None, max_new_tokens=None,
                  decode_strategy="greedy_search", temperature=1.0,

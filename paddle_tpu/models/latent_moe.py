@@ -1,12 +1,18 @@
 """Latent-attention mixture-of-experts causal LM (the DeepSeek-V3 family's
 shape, as openPangu-Ultra-MoE publishes it: `model_type` pangu_ultra_moe).
 
-ONE decoder stack over a per-layer list of kinds (`LatentMoEConfig
-.layer_kinds()`): the mixer (`latent`: multi-head latent attention), the
-FFN (`dense`, or `routed+shared`: this chip's share of the routed experts
-plus the shared expert) and where the norms stand (`sandwich`: a norm
-before AND after each sublayer, the residual added outside both; `pre`).
-`gpt.py` and `llama.py` keep their own stacks (ROADMAP D6).
+ONE decoder stack over a per-layer list of kinds (a config's
+`layer_kinds()`): the mixer (`latent`: multi-head latent attention;
+`gqa_window` / `gqa_full`: gated grouped-query attention over the last
+`sliding_window` positions with rope, or over every position with no
+position encoding at all, as Trinity-Mini publishes it: `model_type`
+afmoe), the FFN (`dense`, or `routed+shared`: this chip's share of the
+routed experts plus the shared expert) and where the norms stand
+(`sandwich`: a norm before AND after each sublayer, the residual added
+outside both; `pre`). The stack's classes take any config that gives
+`layer_kinds()`, `moe_spec()` and the sizes a kind reads: `LatentMoEConfig`
+and `AfmoeConfig` here. `gpt.py` and `llama.py` keep their own stacks
+(ROADMAP D6).
 
 The equations (`N(x; g) = x / sqrt(mean(x^2) + eps) * g`, no biases):
 
@@ -19,7 +25,16 @@ The equations (`N(x; g) = x / sqrt(mean(x^2) + eps) * g`, no biases):
              [k_nope | v] = c W_kvb -> heads x (nope + v)
              scores_h = (q_nope,h . k_nope,h + q_rope,h . k_r) / sqrt(nope + rope)
              o = concat_h(softmax_h v_h) W_o
-    experts  s = sigmoid(x W_r) in float32; S = the top-k; w_e = scale s_e / sum_S s
+    gqa      q = x W_q -> heads x d; k = x W_k, v = x W_v -> kv heads x d
+             q = N(q; g_qn), k = N(k; g_kn) over the d of each head
+             gqa_window: rope on q and k (all d dims, rotate-half) and
+             position i sees j only if 0 <= i - j < sliding_window;
+             gqa_full: causal, no position encoding
+             query head h reads kv head h // (heads / kv heads)
+             o = (concat_h(softmax_h v) * sigmoid(x W_gate)) W_o
+    experts  s = sigmoid(x W_r) in float32; S = the top-k (of s + b where
+             the router has an `expert_bias` b: the pick alone);
+             w_e = scale s_e / sum_S s
              y = sum_{e in S & held here} w_e E_e(x) + E_shared(x)
 
 The cache holds `(c | rope(k_r))`, `kv_lora_rank + qk_rope_head_dim` numbers
@@ -47,12 +62,14 @@ import jax.numpy as jnp
 from .. import nn
 from ..incubate.distributed.models.moe.expert_share import (
     ExpertShareLayer, over_token_blocks)
+from ..kernels import flash_attention as _fa
 from ..kernels import paged_attention as _pa
 from ..nn import initializer as I
 from ..nn.functional.rope import apply_rope
 from ..observability.tracing import scope
-from ..tensor import _apply_op, as_array
+from ..tensor import Tensor, _apply_op, as_array
 from .causal_lm import CausalLMBase
+from .paged_step import paged_attention_step, window_attention_step
 
 F32 = jnp.float32
 # float32 scores one block of the decompressed attention may hold
@@ -104,6 +121,15 @@ class LatentMoEConfig:
                  else "routed+shared", norms)
                 for i in range(self.num_hidden_layers)]
 
+    def moe_spec(self):
+        """What a `routed+shared` FFN is built from."""
+        return dict(width=self.moe_intermediate_size,
+                    num_experts=self.n_routed_experts,
+                    top_k=self.num_experts_per_tok,
+                    scale=self.routed_scaling_factor,
+                    norm_topk=self.norm_topk_prob, pick_bias=False,
+                    shared=self.n_shared_experts)
+
     @staticmethod
     def tiny(vocab=96, layers=3, ep_rank=0, ep_degree=4):
         """Every width shrunk, the kinds and ratios kept: 1 dense + 2
@@ -115,6 +141,83 @@ class LatentMoEConfig:
             kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
             v_head_dim=16, n_routed_experts=16, num_experts_per_tok=4,
             first_k_dense_replace=1, max_position_embeddings=128,
+            ep_rank=ep_rank, ep_degree=ep_degree)
+
+
+@dataclass
+class AfmoeConfig:
+    """Trinity-Mini's config.json keys (`model_type` afmoe). `num_experts`
+    is the deployment's count (the router's width); `ep_rank` /
+    `ep_degree` say which contiguous block of them lives here."""
+    vocab_size: int = 200192
+    hidden_size: int = 2048
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 1024
+    num_hidden_layers: int = 32
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    layer_types: tuple = None   # None: full iff (i + 1) % every == 0
+    global_attn_every_n_layers: int = 4
+    sliding_window: int = 2048
+    num_dense_layers: int = 2
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    num_shared_experts: int = 1
+    route_norm: bool = True
+    route_scale: float = 2.826
+    mup_enabled: bool = True
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    max_position_embeddings: int = 131072
+    tie_word_embeddings: bool = False
+    ep_rank: int = 0
+    ep_degree: int = 1
+    dtype: str = "float32"
+
+    def __post_init__(self):
+        if self.layer_types is None:
+            every = self.global_attn_every_n_layers
+            self.layer_types = tuple(
+                "full_attention" if (i + 1) % every == 0
+                else "sliding_attention"
+                for i in range(self.num_hidden_layers))
+        self.layer_types = tuple(self.layer_types)
+        bad = set(self.layer_types) - {"full_attention", "sliding_attention"}
+        if bad or len(self.layer_types) != self.num_hidden_layers:
+            raise ValueError(
+                f"layer_types must name {self.num_hidden_layers} layers as "
+                "full_attention or sliding_attention, got "
+                f"{bad or len(self.layer_types)}")
+
+    @property
+    def embed_scale(self):
+        return math.sqrt(self.hidden_size) if self.mup_enabled else None
+
+    def layer_kinds(self):
+        return [("gqa_full" if t == "full_attention" else "gqa_window",
+                 "dense" if i < self.num_dense_layers else "routed+shared",
+                 "sandwich") for i, t in enumerate(self.layer_types)]
+
+    def moe_spec(self):
+        return dict(width=self.moe_intermediate_size,
+                    num_experts=self.num_experts,
+                    top_k=self.num_experts_per_tok, scale=self.route_scale,
+                    norm_topk=self.route_norm, pick_bias=True,
+                    shared=self.num_shared_experts)
+
+    @staticmethod
+    def tiny(vocab=96, layers=9, window=16, ep_rank=0, ep_degree=4):
+        """Every width shrunk, the kinds and ratios kept: 1 dense + 8
+        expert layers over two periods of three window layers and a full
+        one, 16 experts top-4 of which 4 held, 4 query heads on 2 kv
+        heads."""
+        return AfmoeConfig(
+            vocab_size=vocab, hidden_size=48, intermediate_size=96,
+            moe_intermediate_size=24, num_hidden_layers=layers,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            sliding_window=window, num_dense_layers=1, num_experts=16,
+            num_experts_per_tok=4, max_position_embeddings=512,
             ep_rank=ep_rank, ep_degree=ep_degree)
 
 
@@ -252,6 +355,62 @@ def absorbed_values(o_latent, w_kv_b, cfg):
                       ).astype(o_latent.dtype).reshape(o_latent.shape[0], -1)
 
 
+def gqa_projections(x, p, cfg, positions, rope):
+    """x [b, s, hidden] (normed) -> (q [b, s, h, d], k, v [b, s, kv, d],
+    gate [b, s, h x d]): per-head norms on q and k, then rope at
+    `positions` [b, s] where the layer has positions at all."""
+    b, s, _ = x.shape
+    d = cfg.head_dim
+    q = _mm(x, p["q_proj"]).reshape(b, s, cfg.num_attention_heads, d)
+    k = _mm(x, p["k_proj"]).reshape(b, s, cfg.num_key_value_heads, d)
+    v = _mm(x, p["v_proj"]).reshape(b, s, cfg.num_key_value_heads, d)
+    q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+    k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+    if rope:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    gate = jax.nn.sigmoid(jnp.matmul(
+        x, p["gate_proj"].astype(x.dtype), preferred_element_type=F32))
+    return q, k, v, gate
+
+
+def gqa_attention(q, k, v, window=None, offset=0):
+    """Causal grouped-query attention in plain XLA: q [b, s, h, d] are the
+    queries of positions offset .. offset + s - 1, k / v [b, t, kv, d] the
+    rows of positions 0 .. t - 1; with `window`, a query at i sees j only
+    if i - j < window. One batch row and one block of queries at a time,
+    so the float32 scores of a block stay under SCORE_BLOCK_BYTES. Returns
+    [b, s, h x d]."""
+    b, s, h, d = q.shape
+    t, kv = k.shape[1], k.shape[2]
+    scale = 1.0 / math.sqrt(d)
+    bq = s
+    while bq > 8 and h * bq * t * 4 > SCORE_BLOCK_BYTES and bq % 2 == 0:
+        bq //= 2
+    n = s // bq
+
+    def block(qb, kk, vv, q0):   # qb [bq, kv, g, d]; kk, vv [t, kv, d]
+        scores = jnp.einsum("qkgd,tkd->kgqt", qb, kk,
+                            preferred_element_type=F32) * scale
+        qpos = (offset + q0 + jnp.arange(bq))[:, None]
+        kpos = jnp.arange(t)[None, :]
+        seen = kpos <= qpos
+        if window is not None:
+            seen = seen & (qpos - kpos < window)
+        probs = jax.nn.softmax(jnp.where(seen, scores, _pa.NEG_INF), axis=-1)
+        return jnp.einsum("kgqt,tkd->qkgd", probs.astype(vv.dtype), vv,
+                          preferred_element_type=F32
+                          ).astype(qb.dtype).reshape(bq, h * d)
+
+    qg = q.reshape(b, n, bq, kv, h // kv, d)
+    if b * n == 1:
+        return block(qg[0, 0], k[0], v[0], 0)[None]
+    items = (qg.reshape((b * n,) + qg.shape[2:]),
+             jnp.repeat(jnp.arange(b), n), jnp.tile(jnp.arange(n) * bq, b))
+    out = jax.lax.map(lambda a: block(a[0], k[a[1]], v[a[1]], a[2]), items)
+    return out.reshape(b, s, h * d)
+
+
 # ---------------------------------------------------------------------------
 # layers
 # ---------------------------------------------------------------------------
@@ -269,7 +428,22 @@ class _Weight(nn.Layer):
             else I.Normal(0.0, 0.02))
 
 
-class LatentAttention(nn.Layer):
+class _Mixer(nn.Layer):
+    """A mixer's leaves are `_Weight`s named by LEAVES."""
+    LEAVES = ()
+
+    def _run(self, fn, *inputs, name):
+        """`fn(p, *arrays)` as one op, `p` the layer's leaves by name."""
+        leaves = [getattr(self, n).weight for n in self.LEAVES]
+
+        def f(*arrays):
+            p = dict(zip(self.LEAVES, arrays[:len(leaves)]))
+            return fn(p, *arrays[len(leaves):])
+
+        return _apply_op(f, *leaves, *inputs, _name=name)
+
+
+class LatentAttention(_Mixer):
     LEAVES = ("q_a_proj", "q_a_layernorm", "q_b_proj", "kv_a_proj_with_mqa",
               "kv_a_layernorm", "kv_b_proj", "o_proj")
 
@@ -286,16 +460,6 @@ class LatentAttention(nn.Layer):
         self.kv_b_proj = _Weight(
             c.kv_lora_rank, h * (c.qk_nope_head_dim + c.v_head_dim))
         self.o_proj = _Weight(h * c.v_head_dim, c.hidden_size)
-
-    def _run(self, fn, *inputs, name):
-        """`fn(p, *arrays)` as one op, `p` the layer's leaves by name."""
-        leaves = [getattr(self, n).weight for n in self.LEAVES]
-
-        def f(*arrays):
-            p = dict(zip(self.LEAVES, arrays[:len(leaves)]))
-            return fn(p, *arrays[len(leaves):])
-
-        return _apply_op(f, *leaves, *inputs, _name=name)
 
     def forward_cached(self, x, cache, cur_len):
         """x [b, s, hidden] at positions cur_len .. cur_len + s - 1; cache
@@ -359,6 +523,110 @@ class LatentAttention(nn.Layer):
         return out, (as_array(pool),)
 
 
+class GatedGQAttention(_Mixer):
+    """Grouped-query attention with per-head q / k norms and a sigmoid
+    gate on its output, taken from the same normed input as q. `window`
+    (positions a query sees, itself included) makes it a `gqa_window`
+    layer, which ropes q and k; None a `gqa_full` one, which encodes no
+    position. The cache is (k, v) rows of every kv head: every position's
+    for a full layer, a ring of the last window's pages for a window layer
+    (`kernels/paged_attention.py`)."""
+    LEAVES = ("q_proj", "k_proj", "v_proj", "gate_proj", "o_proj", "q_norm",
+              "k_norm")
+
+    def __init__(self, config, window=None):
+        super().__init__()
+        c = self.config = config
+        self.window = window
+        self.scope_name = "full" if window is None else "window"
+        h, kv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        self.q_proj = _Weight(c.hidden_size, h * d)
+        self.k_proj = _Weight(c.hidden_size, kv * d)
+        self.v_proj = _Weight(c.hidden_size, kv * d)
+        self.gate_proj = _Weight(c.hidden_size, h * d)
+        self.o_proj = _Weight(h * d, c.hidden_size)
+        self.q_norm = _Weight(d)
+        self.k_norm = _Weight(d)
+
+    def _out(self, ctx, gate, p):
+        return _mm((ctx.astype(F32) * gate).astype(ctx.dtype), p["o_proj"])
+
+    def forward_cached(self, x, cache, cur_len):
+        """x [b, s, hidden] at positions cur_len .. cur_len + s - 1; cache
+        (k rows, v rows) [b, t, kv, d] of EVERY position (the dense cache
+        of `generate` and of a prefill, whatever the layer keeps in
+        pages). Returns (out, new cache)."""
+        cfg, window = self.config, self.window
+        k_rows, v_rows = (as_array(c) for c in cache)
+        traced = hasattr(cur_len, "_data")
+        start = as_array(cur_len) if traced else cur_len
+        # a prefill from position 0 that fills the cache attends over what
+        # it has just computed, through the kernel where the length asks
+        whole = not traced and isinstance(start, int) and start == 0 \
+            and int(x.shape[1]) == k_rows.shape[1]
+
+        def f(p, x, k_rows, v_rows):
+            b, s, _ = x.shape
+            positions = jnp.broadcast_to(start + jnp.arange(s), (b, s))
+            q, k, v, gate = gqa_projections(x, p, cfg, positions,
+                                            rope=window is not None)
+            with scope("kv_write"):
+                zero = jnp.zeros((), jnp.int32)
+                at = (zero, jnp.asarray(start, jnp.int32), zero, zero)
+                k_rows = jax.lax.dynamic_update_slice(
+                    k_rows, k.astype(k_rows.dtype), at)
+                v_rows = jax.lax.dynamic_update_slice(
+                    v_rows, v.astype(v_rows.dtype), at)
+            with scope(self.scope_name):
+                if whole and _fa.use_gqa_flash(s, cfg.head_dim):
+                    ctx = _fa.flash_attention_gqa_bshd(
+                        q, k, v, window=window).reshape(b, s, -1)
+                elif whole:
+                    ctx = gqa_attention(q, k, v, window)
+                else:
+                    ctx = gqa_attention(q, k_rows.astype(q.dtype),
+                                        v_rows.astype(q.dtype), window,
+                                        offset=start)
+            return self._out(ctx, gate, p), k_rows, v_rows
+
+        out, k_rows, v_rows = self._run(f, x, k_rows, v_rows,
+                                        name="gated_gqa_attention")
+        return out, (as_array(k_rows), as_array(v_rows))
+
+    def forward(self, x):
+        c = self.config
+        empty = jnp.zeros((x.shape[0], x.shape[1], c.num_key_value_heads,
+                           c.head_dim), as_array(x).dtype)
+        return self.forward_cached(x, (empty, empty), 0)[0]
+
+    def forward_paged(self, x, cache, block_tables, context_lens,
+                      active=None, mesh=None):
+        """One new token a row over (k pages, v pages): a full layer's
+        pages are the rows' block tables' (`paged_attention_step`), a
+        window layer's its rings (`window_attention_step`), which take no
+        table."""
+        cfg, window = self.config, self.window
+        lens = as_array(context_lens)
+
+        def proj(p, x):
+            return gqa_projections(x, p, cfg, lens[:, None],
+                                   rope=window is not None)
+
+        q, k, v, gate = self._run(proj, x, name="gated_gqa_projections")
+        if window is None:
+            ctx, cache = paged_attention_step(
+                q, k, v, cache, block_tables, context_lens, active=active,
+                mesh=mesh, kv_heads=cfg.num_key_value_heads,
+                attend_scope=self.scope_name)
+        else:
+            ctx, cache = window_attention_step(
+                q, k, v, cache, context_lens, window, active=active,
+                attend_scope=self.scope_name)
+        out = self._run(lambda p, ctx, gate: self._out(ctx, gate, p), ctx,
+                        gate, name="gated_gqa_output")
+        return out, tuple(as_array(c) for c in cache)
+
+
 class GatedFFN(nn.Layer):
     def __init__(self, hidden, width):
         super().__init__()
@@ -378,16 +646,16 @@ class RoutedSharedFFN(nn.Layer):
     """This chip's share of the routed experts plus the shared expert
     (every chip computes that alike)."""
 
-    def __init__(self, config: LatentMoEConfig):
+    def __init__(self, config):
         super().__init__()
-        c = config
+        c, m = config, config.moe_spec()
         self.experts = ExpertShareLayer(
-            c.hidden_size, c.moe_intermediate_size, c.n_routed_experts,
-            c.num_experts_per_tok, ep_rank=c.ep_rank, ep_degree=c.ep_degree,
-            routed_scaling_factor=c.routed_scaling_factor,
-            norm_topk_prob=c.norm_topk_prob)
-        self.shared_experts = GatedFFN(
-            c.hidden_size, c.n_shared_experts * c.moe_intermediate_size)
+            c.hidden_size, m["width"], m["num_experts"], m["top_k"],
+            ep_rank=c.ep_rank, ep_degree=c.ep_degree,
+            routed_scaling_factor=m["scale"], norm_topk_prob=m["norm_topk"],
+            pick_bias=m["pick_bias"])
+        self.shared_experts = GatedFFN(c.hidden_size,
+                                       m["shared"] * m["width"])
 
     def forward(self, x, live=None):
         routed = self.experts(x, live=live)
@@ -396,17 +664,21 @@ class RoutedSharedFFN(nn.Layer):
 
 
 class LatentMoEDecoderLayer(nn.Layer):
-    def __init__(self, config: LatentMoEConfig, kinds):
+    def __init__(self, config, kinds):
         super().__init__()
         mixer, ffn, norms = kinds
-        if mixer != "latent" or norms not in ("sandwich", "pre") \
+        if mixer not in ("latent", "gqa_window", "gqa_full") \
+                or norms not in ("sandwich", "pre") \
                 or ffn not in ("dense", "routed+shared"):
             raise ValueError(f"unknown layer kinds {kinds}")
         self.eps = config.rms_norm_eps
         self.routed = ffn == "routed+shared"
         self.sandwich = norms == "sandwich"
         self.input_layernorm = _Weight(config.hidden_size)
-        self.self_attn = LatentAttention(config)
+        self.self_attn = LatentAttention(config) if mixer == "latent" \
+            else GatedGQAttention(
+                config, config.sliding_window if mixer == "gqa_window"
+                else None)
         self.pre_mlp_layernorm = _Weight(config.hidden_size)
         self.mlp = RoutedSharedFFN(config) if self.routed else GatedFFN(
             config.hidden_size, config.intermediate_size)
@@ -440,15 +712,15 @@ class LatentMoEDecoderLayer(nn.Layer):
             x, lambda a: self.self_attn.forward_cached(a, cache, cur_len))
 
     def forward_paged(self, x, cache, block_tables, context_lens,
-                      active=None):
+                      active=None, **kw):
         return self._block(
             x, lambda a: self.self_attn.forward_paged(
-                a, cache, block_tables, context_lens, active=active),
+                a, cache, block_tables, context_lens, active=active, **kw),
             live=None if active is None else as_array(active))
 
 
 class LatentMoEModel(nn.Layer):
-    def __init__(self, config: LatentMoEConfig):
+    def __init__(self, config):
         super().__init__()
         self.config = config
         self.embed_tokens = _Weight(config.vocab_size, config.hidden_size)
@@ -458,10 +730,16 @@ class LatentMoEModel(nn.Layer):
         self.norm = _Weight(config.hidden_size)
 
     def _embed(self, input_ids):
+        scale = getattr(self.config, "embed_scale", None)
+
+        def take(ids, w):
+            rows = jnp.take(w, ids, axis=0)
+            return rows if scale is None \
+                else (rows.astype(F32) * scale).astype(rows.dtype)
+
         with scope("embed"):
-            return _apply_op(
-                lambda ids, w: jnp.take(w, ids, axis=0), input_ids,
-                self.embed_tokens.weight, _name="embedding")
+            return _apply_op(take, input_ids, self.embed_tokens.weight,
+                             _name="embedding")
 
     def _final_norm(self, h):
         with scope("head"):
@@ -482,16 +760,24 @@ class LatentMoEModel(nn.Layer):
         new_caches = []
         for layer, cache in zip(self.layers, caches):
             h, nc = layer.forward_cached(h, cache, cur_len)
+            # The residual stream crosses from layer to layer through an
+            # optimization barrier. Without it XLA:TPU folds the residual
+            # adds into their consumers and keeps every sublayer's output
+            # of a prefill alive to the last layer: 67 MB a layer at 8,192
+            # tokens of hidden 2,048, 4.1 GB of temporaries over 32 layers
+            # against 1.0 GB behind the barrier (AOT for a v5e, PR 31).
+            h = _apply_op(jax.lax.optimization_barrier, h,
+                          _name="residual_barrier")
             new_caches.append(nc)
         return self._final_norm(h), new_caches
 
     def forward_paged(self, input_ids, paged_caches, block_tables,
-                      context_lens, active=None):
+                      context_lens, active=None, **kw):
         h = self._embed(input_ids)
         new_caches = []
         for layer, cache in zip(self.layers, paged_caches):
             h, nc = layer.forward_paged(h, cache, block_tables,
-                                        context_lens, active=active)
+                                        context_lens, active=active, **kw)
             new_caches.append(nc)
         return self._final_norm(h), new_caches
 
@@ -547,4 +833,46 @@ class LatentMoEForCausalLM(CausalLMBase):
         h, new_caches = self.model.forward_paged(
             input_ids, paged_caches, block_tables, context_lens,
             active=active)
+        return self._head(h), new_caches
+
+
+class AfmoeForCausalLM(LatentMoEForCausalLM):
+    """Trinity-Mini's shape over the same stack: window and full gated
+    grouped-query layers, dense then routed + shared FFNs, sandwich norms,
+    the embedding scaled by sqrt(hidden), an untied float32 head. Serving
+    only, like its parent."""
+
+    def kv_cache_layout(self):
+        """(k, v) of every kv head, `head_dim` wide (the config states it:
+        hidden / heads is another number here)."""
+        kv = (self.config.num_key_value_heads, self.config.head_dim)
+        return (kv, kv)
+
+    def kv_cache_windows(self):
+        cfg = self.config
+        return tuple(cfg.sliding_window if t == "sliding_attention" else None
+                     for t in cfg.layer_types)
+
+    def forward_prefill(self, input_ids, caches, true_lens):
+        """The head at each prompt's last position alone: the float32
+        logits of every position of a round (16,384 x 200,192) would be
+        13 GB."""
+        h, caches = self.model.forward_cached(input_ids, caches, 0)
+        last = as_array(h)[jnp.arange(int(input_ids.shape[0])),
+                           true_lens - 1][:, None]
+        return as_array(self._head(Tensor(last)))[:, 0], caches
+
+    def forward_paged(self, input_ids, paged_caches, block_tables,
+                      context_lens, active=None, mesh=None, limit_lens=None,
+                      max_layers=None):
+        if int(input_ids.shape[1]) != 1 or limit_lens is not None \
+                or max_layers is not None:
+            raise NotImplementedError(
+                "a mixed layout of window rings and full pages is decoded "
+                "one token a row: no window step, no shallow-exit draft "
+                "(speculative decoding and chunked prefill are not built "
+                "for it)")
+        h, new_caches = self.model.forward_paged(
+            input_ids, paged_caches, block_tables, context_lens,
+            active=active, mesh=mesh)
         return self._head(h), new_caches

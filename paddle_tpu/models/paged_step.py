@@ -23,6 +23,8 @@ Two shapes of step share this entry:
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -31,15 +33,22 @@ from ..observability.tracing import scope
 from ..tensor import Tensor, _apply_op, as_array
 
 
+def _scoped(name):
+    return scope(name) if name else contextlib.nullcontext()
+
+
 def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
                          active=None, mesh=None, kv_heads=None,
-                         rotate=None, limit_lens=None):
+                         rotate=None, limit_lens=None, attend_scope=None):
     """q: [b, s, heads, d]; k/v: [b, s, kv_heads, d] (Tensors; s == 1 is
     the classic decode step, s > 1 the speculative-verify window).
     paged_cache: (k_pages, v_pages) or (k_pages, v_pages, k_scales,
     v_scales) for int8 pages. limit_lens: optional [b] — window
-    positions at or beyond it write nothing (budget overhang). Returns
-    (out [b, s, heads*d] Tensor, new_cache tuple)."""
+    positions at or beyond it write nothing (budget overhang).
+    attend_scope: a scope name for the single-token attention itself (the
+    cache read, scores, softmax and weighted sum), for a model that reads
+    it apart from its projections. Returns (out [b, s, heads*d] Tensor,
+    new_cache tuple)."""
     from ..distributed import mesh as _mesh
     from ..distributed.sharding_utils import in_manual_region
     from ..kernels import paged_attention as _pa
@@ -99,7 +108,8 @@ def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
                 kp2, vp2 = _pa.update_paged_kv_cache(
                     kp, vp, kk[:, 0].astype(kp.dtype),
                     vv[:, 0].astype(vp.dtype), tables, lens, active=wm)
-            out = attn(qq[:, 0], kp2, vp2, tables, read)
+            with _scoped(attend_scope):
+                out = attn(qq[:, 0], kp2, vp2, tables, read)
             return out[:, None], kp2, vp2
         # window step (speculative verify): scatter the whole window,
         # then per-position causal attention over the paged prefix
@@ -161,3 +171,62 @@ def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
 
     out = reshape(out, [b, s_win, n_heads * head_dim])
     return out, new_cache
+
+
+def window_attention_step(q, k, v, paged_cache, context_lens, window,
+                          active=None, attend_scope=None):
+    """The single-token decode step of a WINDOW layer, whose pools are
+    rings (`kernels/paged_attention.py`): row b of the batch owns ring b,
+    its new token lands at position context_lens[b], and it attends the
+    last `window` positions, that one included, through the same dispatch
+    as a full layer, told the first position it sees: the kernel streams
+    the ring's live pages and nothing else. q [b, 1, heads, d]; k, v [b, 1,
+    kv_heads, d]; paged_cache (k_pages, v_pages) of [kv_heads, b x ring,
+    page, d]. Returns (out [b, 1, heads*d] Tensor, new_cache).
+
+    Counts, where someone collects: `attn_window_pages_read` (pages the
+    call streams: the copies the kernel's pipeline makes by the table of
+    block indices it is handed, `decode_pages_fetched`, or every page of
+    every live row's ring where the dense gather serves),
+    `attn_window_pages_live` (pages holding a position a live row still
+    sees, from the lengths alone) and `attn_window_pages_context` (pages a
+    layer holding every position would read)."""
+    from ..kernels import paged_attention as _pa
+
+    b, n_heads, head_dim = q.shape[0], q.shape[2], q.shape[3]
+    act = jnp.broadcast_to(
+        jnp.asarray(True if active is None else as_array(active), bool), (b,))
+    lens = as_array(context_lens)
+    k_pages, v_pages = (as_array(c) for c in paged_cache)
+    page_size = k_pages.shape[2]
+    ring = k_pages.shape[1] // b
+    rows = jnp.arange(b, dtype=jnp.int32)
+    # what a row attends over: its context and the token just written;
+    # nothing for a row that is not active
+    seen = jnp.where(act, lens + 1, 0).astype(jnp.int32)
+    tables, read, first = _pa.ring_view(rows, ring, page_size, seen, window)
+    if _trace.counting():
+        _trace.count("attn_window_pages_live",
+                     _pa.ring_pages_live(seen, window, page_size))
+        _trace.count("attn_window_pages_read", _pa.decode_pages_fetched(
+            tables, read, page_size) if _pa.decode_uses_kernel(
+            page_size, ring * page_size, False)
+            else jnp.sum(act, dtype=jnp.int32) * ring)
+        _trace.count("attn_window_pages_context", jnp.sum(
+            (seen + page_size - 1) // page_size, dtype=jnp.int32))
+
+    def step(qq, kk, vv, kp, vp):
+        with scope("kv_write"):
+            kp, vp = _pa.update_ring_kv_cache(
+                kp, vp, kk[:, 0].astype(kp.dtype), vv[:, 0].astype(vp.dtype),
+                rows, lens, active=act)
+        with _scoped(attend_scope):
+            out = _pa.paged_attention_dispatch(qq[:, 0], kp, vp, tables,
+                                               read, first=first)
+        return out[:, None], kp, vp
+
+    out, new_k, new_v = _apply_op(step, q, k, v, Tensor(k_pages),
+                                  Tensor(v_pages), _name="window_attention")
+    from ..ops.manipulation import reshape
+
+    return reshape(out, [b, 1, n_heads * head_dim]), (new_k, new_v)

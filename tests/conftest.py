@@ -101,6 +101,13 @@ def _hand_made_trace_as_a_file(request):
             ["fusion.12", 26 * ms, ms, body + "mlp/router/top_k"],
             ["fusion.13", 27 * ms, 3 * ms, body + "mlp/experts/dot_general"],
             ["fusion.14", 30 * ms, ms, body + "mlp/shared/dot_general"]]
+    # ... and window and full attention layers (the stack's `gqa_window`
+    # and `gqa_full` mixers), in the burst and in the prefill, with the
+    # burst's page counts beside the expert layer's
+    ops += [["call.15", 14 * ms, 2 * ms, body + "attn/window/pallas_call"],
+            ["call.16", 16 * ms, ms, body + "attn/full/pallas_call"],
+            ["call.17", 50 * ms, 2 * ms,
+             "jit(pure_prefill)/attn/window/pallas_call"]]
     host["lines"][0]["events"] += [
         ["serving.admit", 8 * ms, ms],
         ["serving.admitted", 8 * ms + ms // 2, 900,
@@ -111,7 +118,10 @@ def _hand_made_trace_as_a_file(request):
         ["serving.kv_scatter", 70 * ms, 4 * ms],
         ["serving.emit", 41 * ms, 2 * ms,
          {"expert_pairs": 12, "experts_hit": 9, "experts_read": 9,
-          "expert_layer_steps": 8, "experts_held": 32}]]
+          "expert_layer_steps": 8, "experts_held": 32,
+          "attn_window_pages_read": 18, "attn_window_pages_live": 18,
+          "attn_window_pages_context": 40, "attn_pages_read": 10,
+          "attn_pages_mapped": 64}]]
     directory = request.getfixturevalue("tmp_path")
     write(raw, directory)
     request.getfixturevalue("monkeypatch").setattr(

@@ -54,6 +54,11 @@ def test_phases_at_tiny_size(cs, compilewatch_on, monkeypatch):
     engine on the XLA decode path, and the spread check. The CPU allocator reports nothing, so the
     per-device bytes come from the live arrays' shards here."""
     def live_bytes(jax_):
+        # collected first: what an earlier phase of THIS test left as
+        # garbage (a one-device model in a reference cycle) would read as
+        # bytes resident on the first device, or not, by when the
+        # collector last happened to run in this process
+        gc.collect()
         held = {d: 0 for d in jax_.local_devices()}
         for a in jax_.live_arrays():
             for shard in a.addressable_shards:

@@ -5,9 +5,9 @@ table an operation; the implementation is the one TRACED, counted as
 recorder), never read back from a flag. Nothing is computed: every call is
 traced under `jax.eval_shape`.
 
-The rows hold the three benchmark cells' own shapes and both sides of every
+The rows hold the four benchmark cells' own shapes and both sides of every
 boundary the choice has (`paged_attention._KERNEL_MIN_PAGE`,
-`_XLA_DECODE_MAX_CTX`, `flash_attention._PALLAS_FWD_MIN_SEQ` /
+`_XLA_DECODE_MAX_CTX`, `flash_attention._PALLAS_FWD_MIN_SEQ` / `GQA_MIN_SEQ` /
 `_PALLAS_BWD_MIN_SEQ`, `expert_hit._HIT_MAX_TOKENS`, the kernels'
 `supports`)."""
 import importlib
@@ -49,6 +49,55 @@ def record(monkeypatch, taken, module, name, result):
 
 
 # ---------------------------------------------------------------------------
+# a grouped-query prefill: GatedGQAttention asks flash_attention.use_gqa_flash
+# ---------------------------------------------------------------------------
+
+GQA_FLASH, GQA_XLA = "flash_attention_gqa_bshd", "gqa_attention"
+
+# (rows, prompt bucket, head size, window) -> compiled and interpreted alike
+GQA_PREFILL = {
+    # trinity-mini-ep8.mixed-closed's token buckets either side of the
+    # threshold (`GQA_MIN_SEQ`, from the sweep on the chip), window and
+    # full layers
+    "mixed-closed-256-window": ((1, 256, 128, 2048), GQA_XLA),
+    "mixed-closed-512-full": ((1, 512, 128, None), GQA_XLA),
+    "mixed-closed-1024-window": ((1, 1024, 128, 2048), GQA_FLASH),
+    "mixed-closed-2048-window": ((1, 2048, 128, 2048), GQA_FLASH),
+    "mixed-closed-2048-full": ((1, 2048, 128, None), GQA_FLASH),
+    "mixed-closed-4096-window": ((1, 4096, 128, 2048), GQA_FLASH),
+    "mixed-closed-4096-full": ((1, 4096, 128, None), GQA_FLASH),
+    "mixed-closed-8192-window": ((1, 8192, 128, 2048), GQA_FLASH),
+    "cap-9216-full": ((1, 9216, 128, None), GQA_FLASH),
+    # a head size or a length the kernel does not tile
+    "head-64": ((1, 4096, 64, 2048), GQA_XLA),
+    "length-4352": ((1, 4352, 128, 2048), GQA_XLA),
+}
+
+
+@pytest.mark.parametrize("row", sorted(GQA_PREFILL))
+def test_gqa_prefill_choice(monkeypatch, row):
+    from paddle_tpu.models import AfmoeConfig, latent_moe
+
+    (b, s, d, window), want = GQA_PREFILL[row]
+    taken = []
+    record(monkeypatch, taken, fa, GQA_FLASH,
+           lambda q, *a: q)
+    record(monkeypatch, taken, latent_moe, GQA_XLA,
+           lambda q, *a: q.reshape(q.shape[0], q.shape[1], -1))
+    cfg = AfmoeConfig(num_hidden_layers=1, hidden_size=256, head_dim=d,
+                      num_attention_heads=8, num_key_value_heads=2)
+    zeros = lambda shape, _dtype: jnp.zeros(tuple(shape), BF16)  # noqa: E731
+    paddle.nn.initializer.set_global_initializer(zeros, zeros)
+    try:
+        mixer = latent_moe.GatedGQAttention(cfg, window)
+    finally:
+        paddle.nn.initializer.set_global_initializer(None, None)
+    jax.eval_shape(lambda x: as_array(mixer(Tensor(x))),
+                   S((b, s, 256), BF16))
+    assert taken == [want]
+
+
+# ---------------------------------------------------------------------------
 # decode attention over pages: paged_attention_dispatch
 # ---------------------------------------------------------------------------
 
@@ -66,11 +115,18 @@ PAGED = {
     "int8-page16-mapped2064": ((8, 16, 16, 16, 129, I8), KERNEL),
     "int8-page128-mapped2048": ((8, 16, 16, 128, 16, I8), GATHER),
     "int8-page128-mapped2176": ((8, 16, 16, 128, 17, I8), KERNEL),
+    # trinity-mini-ep8.mixed-closed: 8 slots, 32 query heads on 4 kv heads
+    # of 128, pages of 256: a full layer's 36 pages a row, a window layer's
+    # ring of 9 (with the first visible position a row)
+    "mixed-closed-full": ((8, 32, 4, 256, 36, BF16), KERNEL),
+    "mixed-closed-window": ((8, 32, 4, 256, 9, BF16, "first"), KERNEL),
+    "window-page16-ring": ((8, 32, 4, 16, 9, BF16, "first"), GATHER),
+    "window-page16-mapped2064": ((8, 32, 4, 16, 129, BF16, "first"), KERNEL),
 }
 
 
 def _paged_taken(monkeypatch, row, interpret):
-    b, qh, kvh, page, pages_per_seq, pool_dtype = row
+    b, qh, kvh, page, pages_per_seq, pool_dtype, *windowed = row
     taken = []
     monkeypatch.setattr(pa, "_interpret", lambda: interpret)
     for name in (KERNEL, GATHER):
@@ -78,6 +134,8 @@ def _paged_taken(monkeypatch, row, interpret):
     pool = S((kvh, b * pages_per_seq, page, 128), pool_dtype)
     scales = S((kvh, b * pages_per_seq, pa._SCALE_LANES), F32)
     kw = dict(k_scales=scales, v_scales=scales) if pool_dtype == I8 else {}
+    if windowed:   # the window goes to whichever is taken
+        kw["first"] = S((b,), I32)
     jax.eval_shape(
         lambda q, kp, vp, tables, lens, **kw: pa.paged_attention_dispatch(
             q, kp, vp, tables, lens, **kw),
@@ -157,6 +215,11 @@ EXPERTS = {
     "decode-closed-prefill-256": ((256, 7680, 2048, 16, BF16), DENSE),
     "decode-closed-prefill-1024": ((1024, 7680, 2048, 16, BF16), DENSE),
     "decode-closed-prefill-16384": ((16384, 7680, 2048, 16, BF16), DENSE),
+    # trinity-mini-ep8.mixed-closed: a decode step of 8 rows, and its
+    # prefills of one prompt, 256 to 8,192 tokens, experts of 2,048 x 1,024
+    "mixed-closed-step": ((8, 2048, 1024, 16, BF16), HIT),
+    "mixed-closed-prefill-256": ((256, 2048, 1024, 16, BF16), DENSE),
+    "mixed-closed-prefill-8192": ((8192, 2048, 1024, 16, BF16), DENSE),
     "one-token": ((1, 7680, 2048, 16, BF16), HIT),
     "tokens-64": ((64, 7680, 2048, 16, BF16), HIT),
     "tokens-65": ((65, 7680, 2048, 16, BF16), DENSE),

@@ -322,6 +322,43 @@ def test_latent_burst_streams_hit_experts_and_copies_no_stack(v5e,
         <= dense.memory_analysis().temp_size_in_bytes + (64 << 20)
 
 
+@pytest.mark.parametrize("pages_per_seq", [9, 36], ids=["ring", "full"])
+def test_paged_decode_with_a_window_at_the_mixed_cell_shape(v5e,
+                                                            pages_per_seq):
+    """`trinity-mini-ep8.mixed-closed`: batch 8, 32 query heads on 4 kv
+    heads of 128, pages of 256, bf16, the first visible position a row as a
+    third prefetched scalar: a window layer's ring of 9 pages, and the same
+    over a full layer's 36."""
+    def f(q, kp, vp, tables, lens, first):
+        return pa.paged_attention(q, kp, vp, tables, lens, first=first)
+
+    assert compile_for(v5e[0], f, *_paged_specs(8, 32, 4, 256, pages_per_seq,
+                                                False), S((8,), I32)) == 1
+
+
+@pytest.mark.parametrize("window", [None, 2048], ids=["full", "window"])
+@pytest.mark.parametrize("rows,seq", [(1, 8192), (1, 4096), (1, 9216),
+                                      (1, 2048), (1, 1024)])
+def test_gqa_prefill_kernel_at_the_mixed_cell_shapes(v5e, rows, seq, window):
+    """The grouped-query causal forward at the cell's prefill buckets from
+    `GQA_MIN_SEQ` on: 32 query heads on 4 kv heads of 128, bf16, eight
+    query heads a kv head as the rows of one [1024, 128] block."""
+    assert fa.use_gqa_flash(seq, 128) and not fa.use_gqa_flash(512, 128)
+    q, kv = S((rows, seq, 32, 128), BF16), S((rows, seq, 4, 128), BF16)
+    assert compile_for(v5e[0], lambda q, k, v: fa.flash_attention_gqa_bshd(
+        q, k, v, window=window), q, kv, kv) == 1
+
+
+def test_hit_ffn_at_the_mixed_cell_shape(v5e):
+    """8 rows through 16 held experts of 2,048 x 1,024 in bf16: blocks of
+    512 columns, three of them double-buffered in 12 MB of VMEM."""
+    assert eh.use_hit_path(8, 2048, 1024, BF16, BF16)
+    assert eh._block_width(2048, 1024, 2) == 512
+    w = S((16, 2048, 1024), BF16)
+    assert compile_for(v5e[0], eh.hit_ffn, S((8, 2048), BF16),
+                       S((8, 16), F32), w, w, S((16, 1024, 2048), BF16)) == 1
+
+
 def test_paged_decode_gqa(v5e):
     """32 query heads over 4 kv heads (group 8)."""
     compile_for(v5e[0], pa.paged_attention,

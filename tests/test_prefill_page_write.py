@@ -91,10 +91,11 @@ def test_compiled_write_equals_eager_loop_bit_for_bit(quant, lens):
                 jnp.asarray(tables[:n]), jnp.asarray(write_lens[:n])))
     want = [[np.asarray(a) for a in layer] for layer in want]
 
+    fn = eng._get_page_write_fn(nb, bucket)
     assert eng._write_prefill_pages(
-        eng._get_page_write_fn(nb, bucket), eng.k_pages, eng.v_pages,
-        eng.k_scales, eng.v_scales, ks, vs, jnp.asarray(tables),
-        jnp.asarray(write_lens))
+        lambda li, pools: fn(pools, ks, vs, jnp.asarray(tables),
+                             jnp.asarray(write_lens), np.int32(li)),
+        eng.k_pages, eng.v_pages, eng.k_scales, eng.v_scales)
     for li in range(L):
         got = (eng.k_pages[li], eng.k_scales[li], eng.v_pages[li],
                eng.v_scales[li]) if quant else \

@@ -227,6 +227,7 @@ def test_base_policy_prefill_bucket():
     class _E:
         max_batch = 8
         page_size = 16
+        max_seq_len = 256
 
     pol = SchedulerPolicy()
     ids = lambda n: list(range(n))  # noqa: E731
