@@ -513,6 +513,7 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
     import jax.numpy as jnp
 
     from paddle_tpu.incubate.distributed.models.moe import expert_share
+    from paddle_tpu.kernels import expert_grouped as eg
     from paddle_tpu.kernels import expert_hit as eh
     from paddle_tpu.kernels import flash_attention as fa
     from paddle_tpu.kernels import paged_attention as pa
@@ -632,6 +633,19 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
                 stack(expert_hidden, expert_width),
                 stack(expert_width, expert_hidden))
 
+    def grouped_args(picks):
+        """A prefill's 1,280 tokens (tiles of 256 rows), each on `picks`
+        of the held experts: one pair a token as the cells' routers give,
+        or 8, the most a token can make."""
+        def make(rng):
+            _, _, *stacks = expert_args(rng)
+            routing = np.zeros((1280, experts_held), np.float32)
+            for row in routing:
+                row[rng.choice(experts_held, picks, replace=False)] = \
+                    0.2 + rng.random(picks)
+            return (_bf16(rng, (1280, expert_hidden)), routing, *stacks)
+        return make
+
     def experts_hit(n_hit, ffn):
         """`ffn` with the routing weights of all but `n_hit` experts
         (spread over the held ones) set to 0."""
@@ -699,6 +713,9 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
                      experts_hit(n_hit, eh.hit_ffn),
                      experts_hit(n_hit, expert_share.share_ffn))
           for n_hit in (0, 1, experts_held * 3 // 8, experts_held)),
+        *(KernelCase(f"expert grouped {picks} a token", grouped_args(picks),
+                     eg.grouped_ffn, expert_share.share_ffn)
+          for picks in (1, 8)),
         KernelCase(f"rms_norm {hidden} fwd", rms_hidden, rn.rms_norm,
                    rms_ref),
         KernelCase(f"rms_norm {hidden} fwd+bwd", rms_hidden,

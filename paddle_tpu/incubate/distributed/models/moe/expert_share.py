@@ -17,14 +17,23 @@ the whole layer's routed result (tests/test_latent_moe.py holds that).
 `SigmoidTopKGate` scores with a sigmoid in float32 and picks the `top_k`
 largest (no groups; with `pick_bias` the router's `expert_bias` is added to
 the scores for the pick alone); `ExpertShareLayer` owns the gate
-and the held experts' stacked gated-SiLU weights. `share_ffn` takes every
-held expert's product for every token and weights it by `w_e` (0 where the
-token did not pick it). A decode step is bound by reading the experts'
-weights, and its few tokens pick few of the held experts: at decode-sized
-token counts the layer takes `kernels/expert_hit.py`'s `hit_ffn` instead,
-the same sum over the experts some live token picked, which reads those
-experts' weights and no other (`expert_hit.use_hit_path` chooses, from the
-call's shapes and types; a call that may record a gradient stays dense).
+and the held experts' stacked gated-SiLU weights, and takes the sum above
+in one of three forms, chosen from what the call can see (its token count,
+shapes and types, whether a gradient may be recorded, `_interpret()`):
+
+- `share_ffn`, the dense form and the reference: every held expert's
+  product for every token, weighted by `w_e` (0 where the token did not
+  pick it). The CPU, a call that may record a gradient and a call between
+  the two kernels' token counts take it;
+- `kernels/expert_hit.py`'s `hit_ffn` at decode-sized token counts (at most
+  `expert_hit._HIT_MAX_TOKENS`): a decode step is bound by reading the
+  experts' weights, and its few tokens pick few of the held experts, so it
+  reads the weights of the experts some live token picked and no other;
+- `kernels/expert_grouped.py`'s `grouped_ffn` from
+  `expert_grouped._GROUPED_MIN_TOKENS` tokens on (a prefill): with 8 picks
+  of 128 or 256 experts a token meets one held expert or none, so it takes
+  the products of the (live token, held expert it picked) pairs alone,
+  grouped by expert, whatever the skew.
 """
 from __future__ import annotations
 
@@ -32,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from .....autograd import tape as _tape
+from .....kernels import expert_grouped as _grouped
 from .....kernels import expert_hit as _hit
 from .....nn import initializer as I
 from .....nn.layer_base import Layer
@@ -173,28 +183,42 @@ class ExpertShareLayer(Layer):
             dense_w = _apply_op(held_weights, picks, weights,
                                 _name="moe_held_weights", first=self.first,
                                 held=self.held)
-            # the kernel has no backward: a call that may record a
-            # gradient keeps the dense products (serving never records)
-            hit_path = not _tape.grad_enabled() and _hit.use_hit_path(
-                int(tokens.shape[0]), self.d_model,
-                int(self.w_gate.shape[2]), tokens._data.dtype,
-                self.w_gate._data.dtype)
-            self._count(dense_w, live, hit_path)
+            ffn = self._form(tokens)
+            self._count(dense_w, live, ffn)
         with _trace.scope("experts"):
-            ffn, kw = (_hit.hit_ffn, {"live": live}) if hit_path \
-                else (share_ffn, {})
+            kw = {} if ffn is share_ffn else {"live": live}
             y = _apply_op(ffn, tokens, dense_w, self.w_gate, self.w_up,
                           self.w_down, _name="moe_share_ffn", **kw)
         return y.reshape(shape)
 
-    def _count(self, dense_w, live, hit_path):
+    def _form(self, tokens):
+        """The one choice: the kernels have no backward, so a call that
+        may record a gradient keeps the dense products (serving never
+        records); else `hit_ffn` or `grouped_ffn` where its module says the
+        call is its own (never both: the token count parts them), else the
+        dense reference."""
+        call = (int(tokens.shape[0]), self.d_model,
+                int(self.w_gate.shape[2]), tokens._data.dtype,
+                self.w_gate._data.dtype)
+        if _tape.grad_enabled():
+            return share_ffn
+        if _hit.use_hit_path(*call):
+            return _hit.hit_ffn
+        if _grouped.use_grouped_path(*call):
+            return _grouped.grouped_ffn
+        return share_ffn
+
+    def _count(self, dense_w, live, ffn):
         """`expert_pairs`: (live token, held expert it picked) pairs;
         `experts_hit`: held experts some live token picked; `experts_read`:
         held experts whose weights the call streams (those hit on the hit
-        path, all held on the dense one); and what each is a share of,
-        `expert_layer_steps` (1 for a call with a live token) and
-        `experts_held`. Nothing is computed where nobody collects
-        (`tracing.device_counts`)."""
+        and grouped paths, all held on the dense one); `expert_rows`: rows
+        of expert products the call takes (tokens x held on the dense
+        form, tokens x experts hit on the hit path, the pairs' rows with
+        their tiles' padding on the grouped one); and what the first three
+        are a share of, `expert_layer_steps` (1 for a call with a live
+        token) and `experts_held`. Nothing is computed where nobody
+        collects (`tracing.device_counts`)."""
         if not _trace.counting():
             return
         picked = dense_w._data > 0
@@ -205,7 +229,11 @@ class ExpertShareLayer(Layer):
         _trace.count("expert_pairs", jnp.sum(picked, dtype=jnp.int32))
         hit = jnp.sum(jnp.any(picked, axis=0), dtype=jnp.int32)
         _trace.count("experts_hit", hit)
-        _trace.count("experts_read", hit if hit_path
-                     else any_live * self.held)
+        read = any_live * self.held if ffn is share_ffn else hit
+        _trace.count("experts_read", read)
+        _trace.count("expert_rows",
+                     _grouped.grouped_rows(dense_w._data, live)
+                     if ffn is _grouped.grouped_ffn
+                     else int(picked.shape[0]) * read)
         _trace.count("expert_layer_steps", any_live)
         _trace.count("experts_held", any_live * self.held)

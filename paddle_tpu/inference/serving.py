@@ -37,6 +37,11 @@ from . import kv_fabric as _fab
 from . import prefix_cache as _pc
 from . import scheduler as _sched
 
+# what a prefill program hands on of the counts its model makes: they ride
+# on the `serving.emit` phase that commits its first tokens as `prefill_<k>`
+# (a burst's counts keep their own names on the phase after the burst)
+_PREFILL_COUNTS = ("expert_pairs", "expert_rows")
+
 
 class _EngineMetrics:
     """Serving metric handles, resolved ONCE per engine against the
@@ -670,6 +675,9 @@ class ServingEngine:
         self._recovering = False
         self._recoveries = 0
         self._retry_counts: Dict[int, int] = {}  # rid -> requeue count
+        # the counts of the prefill programs since the last commit of first
+        # tokens (`_take_prefill_counts`)
+        self._prefill_counts: Dict[str, int] = {}
         # per-(engine, tenant) accounting cells, resolved lazily at the
         # first finish for each tenant (FLAGS_requestlog; tenants are
         # dynamic, so the _tier_cells resolve-once discipline applies
@@ -1381,9 +1389,14 @@ class ServingEngine:
                 # downcast implicitly
                 caches = model.init_kv_caches(
                     nb, bucket, dtype=next(iter(params.values())).dtype)
-                # causal mask => position true_len-1 ignores the padding
-                last, caches = model.forward_prefill(
-                    Tensor(ids), caches, true_lens)
+                # causal mask => position true_len-1 ignores the padding;
+                # what the model counts of its own work while it is traced
+                # (an expert layer's pairs and rows) rides out last
+                with _trace.device_counts() as counts:
+                    last, caches = model.forward_prefill(
+                        Tensor(ids), caches, true_lens)
+                counts = {k: counts[k] for k in _PREFILL_COUNTS
+                          if k in counts}
                 # first token sampled ON DEVICE (round-2 verdict weak #5:
                 # the host-side sample paid a [nb, vocab] transfer),
                 # per-request params as runtime [nb] arrays
@@ -1402,14 +1415,14 @@ class ServingEngine:
                         tuple(as_array(a) if ring is None else _pa.ring_tail(
                             as_array(a), true_lens, ring, self.page_size)
                             for a in c)
-                        for c, ring in zip(caches, rings))
+                        for c, ring in zip(caches, rings)), counts
                 # one stack per pool of the layout: (ks, vs) of [L, nb,
                 # bucket, kvh, hd] for full attention, the latent rows
                 # alone for latent attention
                 stacks = tuple(
                     jnp.stack([as_array(c[j]) for c in caches])
                     for j in range(len(caches[0])))
-            return (first,) + stacks
+            return (first,) + stacks + (counts,)
 
         fn = self._prefill_fns[(nb, bucket, all_greedy, which)] = \
             _cw.watch_jit("serving.prefill", jax.jit(pure_prefill),
@@ -1532,7 +1545,9 @@ class ServingEngine:
                 fn = self._get_prefill_fn(nb, bucket, all_greedy)
                 params, buffers = self._cached_params()
                 padded = np.zeros((nb, bucket), np.int64)
-                true_lens = np.ones((nb,), np.int32)
+                # a padded row holds no token: length 0 (its last-position
+                # index wraps to a position nothing reads)
+                true_lens = np.zeros((nb,), np.int32)
                 greedy = np.ones((nb,), bool)
                 temp = np.ones((nb,), np.float32)
                 tk = np.zeros((nb,), np.int32)
@@ -1546,26 +1561,23 @@ class ServingEngine:
                     tk[row] = rp["top_k"]
                     tp_arr[row] = rp["top_p"]
                 self._key, sk = jax.random.split(self._key)
+                lens = jnp.asarray(true_lens)
                 prefill_args = (
-                    jnp.asarray(padded), jnp.asarray(true_lens),
+                    jnp.asarray(padded), lens,
                     jax.random.key_data(sk), jnp.asarray(greedy),
                     jnp.asarray(temp), jnp.asarray(tk),
                     jnp.asarray(tp_arr))
                 # (a layout with rings: `ks` is a layer's (k, v) an entry)
-                first, ks, *vs = fn(params, buffers, *prefill_args)
+                first, ks, *vs, counts = fn(params, buffers, *prefill_args)
                 vs = vs[0] if vs else None
             # the compiled per-layer page write (and, with a separate
             # draft model, its own prefill call and write)
             with _trace.phase("serving.kv_scatter"):
                 # padded to nb like the prefill: a padded row has a table
-                # row of zeros and a write length of 0 (true_lens says 1
-                # there for the prefill's last-position index)
+                # row of zeros and, as in the prefill, a length of 0
                 tables = np.zeros((nb, self.pages_per_seq), np.int32)
                 tables[:n] = self.block_tables[[si for si, _ in new]]
-                write_lens = true_lens.copy()
-                write_lens[n:] = 0
-                tables, write_lens = jnp.asarray(tables), \
-                    jnp.asarray(write_lens)
+                tables, write_lens = jnp.asarray(tables), lens
                 if self._has_rings:
                     # a layout with rings: `ks` holds a layer's own (k, v)
                     # an entry, a window layer's cut to what its ring
@@ -1597,7 +1609,8 @@ class ServingEngine:
                     fn_d = self._get_prefill_fn(nb, bucket, all_greedy,
                                                 which="draft")
                     dparams, dbuffers = self._cached_draft_params()
-                    _f, dks, dvs = fn_d(dparams, dbuffers, *prefill_args)
+                    _f, dks, dvs, _counts = fn_d(dparams, dbuffers,
+                                                 *prefill_args)
                     fn_dw = self._get_page_write_fn(nb, bucket, "draft")
                     if not self._write_prefill_pages(
                             lambda li, pools: fn_dw(
@@ -1614,6 +1627,11 @@ class ServingEngine:
                     self._prefix_cache.insert(ids, self.block_tables[si])
             with _trace.phase("serving.prefill.sync"):
                 first_np = np.asarray(first)  # [nb] ints — tiny transfer
+                # the program is done once its tokens are here: its counts
+                # wait for the phase that commits these first tokens
+                for k, v in counts.items():
+                    self._prefill_counts["prefill_" + k] = int(v) \
+                        + self._prefill_counts.get("prefill_" + k, 0)
             for row, (si, _) in enumerate(new):
                 self.slots[si]._first_token = int(first_np[row])
             if self._traces:
@@ -2188,6 +2206,12 @@ class ServingEngine:
                     break
         return finished
 
+    def _take_prefill_counts(self):
+        """The prefill programs' counts since the last call, as attributes
+        of the phase that commits their first tokens."""
+        counts, self._prefill_counts = self._prefill_counts, {}
+        return counts
+
     def _rem_of(self, active):
         """Remaining new-token budget per active slot — the ONE place the
         budget rule lives (k_burst sizing, page reservation, and the
@@ -2615,7 +2639,7 @@ class ServingEngine:
         # the first tokens' commit (callbacks, a finish on the first
         # token) is an emit phase of its own; a step that has none opens
         # nothing here
-        with (_trace.phase("serving.emit")
+        with (_trace.phase("serving.emit", **self._take_prefill_counts())
               if any(self.slots[i].needs_first_sample for i in active)
               else _trace.NOOP_SPAN):
             for i, s in enumerate(self.slots):

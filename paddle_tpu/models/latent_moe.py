@@ -707,9 +707,10 @@ class LatentMoEDecoderLayer(nn.Layer):
     def forward(self, x):
         return self._block(x, lambda a: (self.self_attn(a), None))[0]
 
-    def forward_cached(self, x, cache, cur_len):
+    def forward_cached(self, x, cache, cur_len, live=None):
         return self._block(
-            x, lambda a: self.self_attn.forward_cached(a, cache, cur_len))
+            x, lambda a: self.self_attn.forward_cached(a, cache, cur_len),
+            live=live)
 
     def forward_paged(self, x, cache, block_tables, context_lens,
                       active=None, **kw):
@@ -755,11 +756,14 @@ class LatentMoEModel(nn.Layer):
             h = layer(h)
         return self._final_norm(h)
 
-    def forward_cached(self, input_ids, caches, cur_len):
+    def forward_cached(self, input_ids, caches, cur_len, live=None):
+        """`live` ([batch, positions] bool, optional) says which positions
+        hold a token: the expert layers make no pair of one that does not
+        (a prefill's padding). Every position where None."""
         h = self._embed(input_ids)
         new_caches = []
         for layer, cache in zip(self.layers, caches):
-            h, nc = layer.forward_cached(h, cache, cur_len)
+            h, nc = layer.forward_cached(h, cache, cur_len, live=live)
             # The residual stream crosses from layer to layer through an
             # optimization barrier. Without it XLA:TPU folds the residual
             # adds into their consumers and keeps every sublayer's output
@@ -793,6 +797,12 @@ class _Head(_Weight):
             h, self.weight, _name="lm_head")
 
 
+def _live_positions(input_ids, true_lens):
+    """[batch, positions] bool: the positions of a padded prefill that
+    hold a prompt's token."""
+    return jnp.arange(int(input_ids.shape[1]))[None, :] < true_lens[:, None]
+
+
 class LatentMoEForCausalLM(CausalLMBase):
     """The serving contract of `GPTForCausalLM` (`forward`,
     `forward_cached`, `forward_paged`, `generate`) over `LatentMoEModel`.
@@ -817,9 +827,18 @@ class LatentMoEForCausalLM(CausalLMBase):
     def forward(self, input_ids, attn_mask=None):
         return self._head(self.model(input_ids, attn_mask))
 
-    def forward_cached(self, input_ids, caches, cur_len):
-        h, new_caches = self.model.forward_cached(input_ids, caches, cur_len)
+    def forward_cached(self, input_ids, caches, cur_len, live=None):
+        h, new_caches = self.model.forward_cached(input_ids, caches, cur_len,
+                                                  live=live)
         return self._head(h), new_caches
+
+    def forward_prefill(self, input_ids, caches, true_lens):
+        """`CausalLMBase.forward_prefill` with the prompts' padding told to
+        the expert layers: a row of `true_lens` 0 is all padding."""
+        logits, caches = self.forward_cached(
+            input_ids, caches, 0, live=_live_positions(input_ids, true_lens))
+        return as_array(logits)[jnp.arange(int(input_ids.shape[0])),
+                                jnp.maximum(true_lens - 1, 0), :], caches
 
     def forward_paged(self, input_ids, paged_caches, block_tables,
                       context_lens, active=None, mesh=None, limit_lens=None,
@@ -857,9 +876,10 @@ class AfmoeForCausalLM(LatentMoEForCausalLM):
         """The head at each prompt's last position alone: the float32
         logits of every position of a round (16,384 x 200,192) would be
         13 GB."""
-        h, caches = self.model.forward_cached(input_ids, caches, 0)
+        h, caches = self.model.forward_cached(
+            input_ids, caches, 0, live=_live_positions(input_ids, true_lens))
         last = as_array(h)[jnp.arange(int(input_ids.shape[0])),
-                           true_lens - 1][:, None]
+                           jnp.maximum(true_lens - 1, 0)][:, None]
         return as_array(self._head(Tensor(last)))[:, 0], caches
 
     def forward_paged(self, input_ids, paged_caches, block_tables,

@@ -61,6 +61,46 @@ def load_repo_script(relpath):
     return sys.modules[name]
 
 
+def check_padded_prefill(model, prompts, nb, bucket):
+    """`model.forward_prefill` of `prompts` padded to `[nb, bucket]` (rows
+    beyond them of length 0) against each prompt alone and unpadded: the
+    first token's logits and the cache rows at live positions agree, and
+    the expert layers' pairs are the unpadded prompts' own (padding makes
+    none). Returns the padded call's counts."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.tensor import as_array
+
+    ids = np.zeros((nb, bucket), np.int64)
+    for row, p in enumerate(prompts):
+        ids[row, :len(p)] = p
+    lens = jnp.asarray([len(p) for p in prompts]
+                       + [0] * (nb - len(prompts)), jnp.int32)
+    with tracing.device_counts() as counts, paddle.no_grad():
+        last, caches = model.forward_prefill(
+            paddle.to_tensor(ids), model.init_kv_caches(nb, bucket), lens)
+    pairs = 0
+    for row, p in enumerate(prompts):
+        with tracing.device_counts() as alone, paddle.no_grad():
+            last1, caches1 = model.forward_prefill(
+                paddle.to_tensor(p[None]), model.init_kv_caches(1, len(p)),
+                jnp.asarray([len(p)], jnp.int32))
+        pairs += int(alone["expert_pairs"])
+        np.testing.assert_allclose(np.asarray(last[row]),
+                                   np.asarray(last1[0]), atol=2e-5)
+        assert int(np.argmax(last[row])) == int(np.argmax(last1[0]))
+        for c, c1 in zip(caches, caches1):
+            for a, a1 in zip(c, c1):
+                np.testing.assert_allclose(
+                    np.asarray(as_array(a))[row, :len(p)],
+                    np.asarray(as_array(a1))[0], atol=2e-5)
+    assert int(counts["expert_pairs"]) == pairs > 0
+    return counts
+
+
 @pytest.fixture(autouse=True)
 def _hand_made_trace_as_a_file(request):
     """For ONE test: `test_every_reader_on_the_hand_made_trace`
@@ -121,7 +161,11 @@ def _hand_made_trace_as_a_file(request):
           "expert_layer_steps": 8, "experts_held": 32,
           "attn_window_pages_read": 18, "attn_window_pages_live": 18,
           "attn_window_pages_context": 40, "attn_pages_read": 10,
-          "attn_pages_mapped": 64}]]
+          "attn_pages_mapped": 64}],
+        # ... and the phase that commits a prefill's first tokens, with
+        # that prefill program's own counts
+        ["serving.emit", 75 * ms, ms,
+         {"prefill_expert_pairs": 20, "prefill_expert_rows": 256}]]
     directory = request.getfixturevalue("tmp_path")
     write(raw, directory)
     request.getfixturevalue("monkeypatch").setattr(

@@ -221,6 +221,22 @@ def test_the_prefill_writes_a_ring_only_what_a_later_step_sees():
     np.testing.assert_array_equal(got[3 + 1], [29, 0, 0, 0])
 
 
+@pytest.mark.parametrize("path", ["dense", "grouped"])
+def test_a_padded_prefill_is_the_unpadded_prefill(model, cfg, path,
+                                                  monkeypatch):
+    """Padded positions, and a padded row (`true_lens` 0), make no pair in
+    an expert layer on either form: the counts are the unpadded prompts'
+    own, and the first token's logits and the K/V at live positions are
+    what each prompt gives alone."""
+    from conftest import check_padded_prefill
+    from paddle_tpu.kernels import expert_grouped
+
+    monkeypatch.setattr(expert_grouped, "use_grouped_path",
+                        lambda *a: path == "grouped")
+    check_padded_prefill(
+        model, [np.arange(21) % 90, (np.arange(6) * 7 + 3) % 90], 4, 24)
+
+
 def test_the_burst_counts_the_window_layers_pages(model, cfg):
     """What rides out on `serving.emit`: pages the window layers stream,
     pages holding a position a row still sees, pages a layer holding every
@@ -230,7 +246,9 @@ def test_the_burst_counts_the_window_layers_pages(model, cfg):
     real = tracing.phase
 
     def phase(name, **attrs):
-        if name == "serving.emit" and attrs:
+        # (the phase that commits the first token carries the prefill
+        # program's own counts, `prefill_*`: not a burst's)
+        if name == "serving.emit" and "attn_window_pages_live" in attrs:
             seen.append(attrs)
         return real(name, **attrs)
 

@@ -8,8 +8,8 @@ traced under `jax.eval_shape`.
 The rows hold the four benchmark cells' own shapes and both sides of every
 boundary the choice has (`paged_attention._KERNEL_MIN_PAGE`,
 `_XLA_DECODE_MAX_CTX`, `flash_attention._PALLAS_FWD_MIN_SEQ` / `GQA_MIN_SEQ` /
-`_PALLAS_BWD_MIN_SEQ`, `expert_hit._HIT_MAX_TOKENS`, the kernels'
-`supports`)."""
+`_PALLAS_BWD_MIN_SEQ`, `expert_hit._HIT_MAX_TOKENS`,
+`expert_grouped._GROUPED_MIN_TOKENS`, the kernels' `supports`)."""
 import importlib
 
 import jax
@@ -20,6 +20,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.framework import config as _config
 from paddle_tpu.incubate.distributed.models.moe import expert_share
+from paddle_tpu.kernels import expert_grouped as eg
 from paddle_tpu.kernels import expert_hit as eh
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
@@ -202,10 +203,11 @@ def test_engine_burst_traces_one_choice_a_layer(monkeypatch, kind, page,
 
 
 # ---------------------------------------------------------------------------
-# one chip's share of an expert layer: expert_hit.use_hit_path
+# one chip's share of an expert layer: expert_hit.use_hit_path, then
+# expert_grouped.use_grouped_path
 # ---------------------------------------------------------------------------
 
-HIT, DENSE = "hit_ffn", "share_ffn"
+HIT, GROUPED, DENSE = "hit_ffn", "grouped_ffn", "share_ffn"
 
 # (tokens, hidden, expert width, held, type) -> off interpret mode, no grad
 EXPERTS = {
@@ -213,20 +215,30 @@ EXPERTS = {
     # and its prefills of 256 to 16 x 1,024 tokens
     "decode-closed-step": ((16, 7680, 2048, 16, BF16), HIT),
     "decode-closed-prefill-256": ((256, 7680, 2048, 16, BF16), DENSE),
-    "decode-closed-prefill-1024": ((1024, 7680, 2048, 16, BF16), DENSE),
-    "decode-closed-prefill-16384": ((16384, 7680, 2048, 16, BF16), DENSE),
+    "decode-closed-prefill-512": ((512, 7680, 2048, 16, BF16), GROUPED),
+    "decode-closed-prefill-1024": ((1024, 7680, 2048, 16, BF16), GROUPED),
+    "decode-closed-prefill-2048": ((2048, 7680, 2048, 16, BF16), GROUPED),
+    "decode-closed-prefill-16384": ((16384, 7680, 2048, 16, BF16), GROUPED),
     # trinity-mini-ep8.mixed-closed: a decode step of 8 rows, and its
     # prefills of one prompt, 256 to 8,192 tokens, experts of 2,048 x 1,024
     "mixed-closed-step": ((8, 2048, 1024, 16, BF16), HIT),
     "mixed-closed-prefill-256": ((256, 2048, 1024, 16, BF16), DENSE),
-    "mixed-closed-prefill-8192": ((8192, 2048, 1024, 16, BF16), DENSE),
+    "mixed-closed-prefill-512": ((512, 2048, 1024, 16, BF16), GROUPED),
+    "mixed-closed-prefill-2048": ((2048, 2048, 1024, 16, BF16), GROUPED),
+    "mixed-closed-prefill-8192": ((8192, 2048, 1024, 16, BF16), GROUPED),
     "one-token": ((1, 7680, 2048, 16, BF16), HIT),
     "tokens-64": ((64, 7680, 2048, 16, BF16), HIT),
+    # between the two kernels the dense products: every held expert is
+    # hit, and one read of their weights is all the dense form costs
     "tokens-65": ((65, 7680, 2048, 16, BF16), DENSE),
+    "tokens-511": ((511, 7680, 2048, 16, BF16), DENSE),
     "float32": ((16, 1024, 512, 4, F32), HIT),
+    "float32-prefill": ((512, 1024, 512, 4, F32), GROUPED),
     # widths Mosaic would pad: the tiny model's
     "hidden-48": ((16, 48, 256, 4, F32), DENSE),
     "width-24": ((16, 128, 24, 4, F32), DENSE),
+    "hidden-48-prefill": ((512, 48, 256, 4, F32), DENSE),
+    "width-24-prefill": ((512, 128, 24, 4, F32), DENSE),
 }
 
 
@@ -234,7 +246,9 @@ def _experts_taken(monkeypatch, row, interpret, grad=False):
     n, d, f, held, dtype = row
     taken = []
     monkeypatch.setattr(eh, "_interpret", lambda: interpret)
+    monkeypatch.setattr(eg, "_interpret", lambda: interpret)
     record(monkeypatch, taken, eh, HIT, lambda x, *a: x)
+    record(monkeypatch, taken, eg, GROUPED, lambda x, *a: x)
     record(monkeypatch, taken, expert_share, DENSE, lambda x, *a: x)
     zeros = lambda shape, _dtype: jnp.zeros(tuple(shape), dtype)  # noqa: E731
     paddle.nn.initializer.set_global_initializer(zeros, zeros)
@@ -262,22 +276,28 @@ def test_expert_share_choice(monkeypatch, row, interpret):
         == [DENSE if interpret else want]
 
 
+@pytest.mark.parametrize("row", ["decode-closed-step",
+                                 "decode-closed-prefill-1024",
+                                 "mixed-closed-prefill-8192"])
 def test_a_call_that_may_record_a_gradient_keeps_the_dense_products(
-        monkeypatch):
-    """The kernel has no backward; the engine's programs and `generate`
+        monkeypatch, row):
+    """The kernels have no backward; the engine's programs and `generate`
     trace under `no_grad`."""
-    args, want = EXPERTS["decode-closed-step"]
-    assert want == HIT
+    args, want = EXPERTS[row]
+    assert want != DENSE
     assert _experts_taken(monkeypatch, args, False, grad=True) == [DENSE]
 
 
 def test_the_expert_choice_reads_no_flag(monkeypatch):
     """Shapes, types and `_interpret()` alone: a registry without a single
-    flag chooses as the full one does."""
+    flag chooses as the full one does, and never both kernels."""
     monkeypatch.setattr(eh, "_interpret", lambda: False)
+    monkeypatch.setattr(eg, "_interpret", lambda: False)
     monkeypatch.setattr(_config, "_FLAGS", {})
     for (n, d, f, _held, dtype), want in EXPERTS.values():
         assert eh.use_hit_path(n, d, f, dtype, dtype) == (want == HIT)
+        assert eg.use_grouped_path(n, d, f, dtype, dtype) \
+            == (want == GROUPED)
 
 
 # ---------------------------------------------------------------------------

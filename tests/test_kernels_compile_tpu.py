@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from conftest import load_repo_script
+from paddle_tpu.kernels import expert_grouped as eg
 from paddle_tpu.kernels import expert_hit as eh
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
@@ -54,7 +55,7 @@ def v5e():
 def mosaic(monkeypatch):
     """interpret=False in every kernel module although the default backend
     is the CPU: the programs are lowered for the topology's devices."""
-    for mod in (fa, pa, rn, qm, eh):
+    for mod in (fa, pa, rn, qm, eh, eg):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -357,6 +358,97 @@ def test_hit_ffn_at_the_mixed_cell_shape(v5e):
     w = S((16, 2048, 1024), BF16)
     assert compile_for(v5e[0], eh.hit_ffn, S((8, 2048), BF16),
                        S((8, 16), F32), w, w, S((16, 1024, 2048), BF16)) == 1
+
+
+# (tokens, hidden, expert width): the expert cells' prefill buckets, either
+# side of the tile's step and beyond one block of tokens
+GROUPED = {"decode-closed-512": (512, 7680, 2048),
+           "decode-closed-1024": (1024, 7680, 2048),
+           "decode-closed-2048": (2048, 7680, 2048),
+           "decode-closed-16384": (16384, 7680, 2048),
+           "mixed-closed-512": (512, 2048, 1024),
+           "mixed-closed-8192": (8192, 2048, 1024)}
+
+
+@pytest.mark.parametrize("row", sorted(GROUPED))
+def test_grouped_ffn_at_the_expert_cells_prefill_shapes(v5e, row):
+    """16 held experts in bf16, tiles of 128 rows: experts of 2,048 x
+    1,024 whole in VMEM, of 7,680 x 2,048 in `hit_ffn`'s blocks of 128
+    columns; one kernel in the chunks' loop, beyond 2,048 tokens inside the
+    loop over blocks of tokens."""
+    n, d, f = GROUPED[row]
+    assert eg.use_grouped_path(n, d, f, BF16, BF16)
+    assert eg.ROW_TILE == 128 and eg.TOKEN_BLOCK == 2048
+    assert (6 * d * f * 2 <= eg._WHOLE_EXPERT_VMEM_BYTES) == (d == 2048)
+    assert eh._block_width(7680, 2048, 2) == 128
+    w = S((16, d, f), BF16)
+    assert compile_for(v5e[0], eg.grouped_ffn, S((n, d), BF16),
+                       S((n, 16), F32), w, w, S((16, f, d), BF16),
+                       S((n,), jnp.bool_)) == 1
+
+
+def test_latent_prefill_groups_its_pairs_and_copies_no_stack(v5e,
+                                                             monkeypatch):
+    """`openpangu-ultra-moe-ep16-l5.decode-closed`'s prefill of 2 x 1,024
+    tokens (published widths, one expert layer of 16 held experts), lowered
+    for the v5e on the grouped path and on the dense one: a
+    `tpu_custom_call` an expert layer whose stacked weights arrive in the
+    layout they are stored in; no copy, transpose or convert of an array
+    the size of an expert stack anywhere in the program; temporaries within
+    64 MB of the dense program's (whose own are its two float32 `[2048,
+    16 x 2048]` blocks; the grouped form's are a chunk's, and the
+    program's largest lie elsewhere)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ServingEngine
+    from paddle_tpu.models import LatentMoEConfig, LatentMoEForCausalLM
+
+    nb, bucket = 2, 1024
+    zeros = lambda shape, _dtype: jnp.zeros(tuple(shape), BF16)  # noqa: E731
+    paddle.nn.initializer.set_global_initializer(zeros, zeros)
+    try:
+        model = LatentMoEForCausalLM(LatentMoEConfig(
+            vocab_size=512, num_hidden_layers=1, first_k_dense_replace=0,
+            max_position_embeddings=2048, ep_degree=16, dtype="bfloat16"))
+    finally:
+        paddle.nn.initializer.set_global_initializer(None, None)
+    model.eval()
+    stack = tuple(model.model.layers[0].mlp.experts.w_gate.shape)
+    assert stack == (16, 7680, 2048)
+    one = SingleDeviceSharding(v5e[0])
+
+    def compiled():
+        eng = ServingEngine(model, max_batch=16, max_seq_len=2048,
+                            page_size=256, decode_burst=16)
+        described = lambda tree: jax.tree.map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+        row = lambda dt: S((nb,), dt)  # noqa: E731
+        params, buffers = eng._cached_params()
+        fn = eng._get_prefill_fn(nb, bucket, True)
+        return getattr(fn, "_fn", fn).lower(*described((
+            params, buffers, S((nb, bucket), jnp.int64), row(I32),
+            jax.random.key_data(jax.random.key(0)), row(jnp.bool_),
+            row(F32), row(I32), row(F32)))).compile()
+
+    grouped = compiled()
+    text = grouped.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(ln for ln in text.split("\n")
+                if 'custom_call_target="tpu_custom_call"' in ln)
+    assert "bf16[16,7680,2048]{2,1,0}, bf16[16,7680,2048]{2,1,0}, " \
+        "bf16[16,2048,7680]{2,1,0}" in call
+    for name, shape, op in re.findall(
+            r"%([\w.\-]+) = (\w+\[[\d,]*\])\S* ([\w\-]+)\(", text):
+        n = int(np.prod([int(d) for d in
+                         shape[:-1].split("[")[1].split(",") if d]))
+        moved = any(k in name or k in op
+                    for k in ("copy", "transpose", "convert"))
+        assert not (moved and n >= int(np.prod(stack))), (name, shape, op)
+    monkeypatch.setattr(eg, "use_grouped_path", lambda *a: False)
+    dense = compiled()
+    assert "tpu_custom_call" not in dense.as_text()
+    assert grouped.memory_analysis().temp_size_in_bytes \
+        <= dense.memory_analysis().temp_size_in_bytes + (64 << 20)
 
 
 def test_paged_decode_gqa(v5e):

@@ -18,7 +18,7 @@ if REPO not in sys.path:
 
 import paddle_tpu as paddle  # noqa: E402
 from paddle_tpu.inference import ServingEngine  # noqa: E402
-from paddle_tpu.kernels import expert_hit  # noqa: E402
+from paddle_tpu.kernels import expert_grouped, expert_hit  # noqa: E402
 from paddle_tpu.kernels import paged_attention as pa  # noqa: E402
 from paddle_tpu.models import (GPTConfig, GPTForCausalLM,  # noqa: E402
                                LatentMoEConfig, LatentMoEForCausalLM,
@@ -181,16 +181,19 @@ def test_blocked_attention_is_whole_attention(cfg, block_bytes, monkeypatch):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
-PATHS = ["dense", "hit"]
+PATHS = ["dense", "hit", "grouped"]
 
 
 @pytest.fixture
 def expert_path(request, monkeypatch):
     """The form the expert layer takes, whatever the shapes: `dense` is
-    `share_ffn`, `hit` the kernel of kernels/expert_hit.py (interpreted
-    here), which the choice would keep for the chip."""
+    `share_ffn`, `hit` the kernel of kernels/expert_hit.py and `grouped`
+    that of kernels/expert_grouped.py (interpreted here), which the choice
+    would keep for the chip."""
     monkeypatch.setattr(expert_hit, "use_hit_path",
                         lambda *a: request.param == "hit")
+    monkeypatch.setattr(expert_grouped, "use_grouped_path",
+                        lambda *a: request.param == "grouped")
     return request.param
 
 
@@ -265,7 +268,8 @@ def test_no_token_is_dropped_and_the_denominator_is_over_all_picks(cfg,
     w_gate, w_up = (jnp.asarray(rng.normal(size=(4, 48, 24)), jnp.float32)
                     for _ in range(2))
     w_down = jnp.asarray(rng.normal(size=(4, 24, 48)), jnp.float32)
-    ffn = expert_hit.hit_ffn if path == "hit" else expert_share.share_ffn
+    ffn = {"dense": expert_share.share_ffn, "hit": expert_hit.hit_ffn,
+           "grouped": expert_grouped.grouped_ffn}[path]
     first = max(range(4), key=lambda r: float(np.asarray(dense[r]).sum()))
     got = np.asarray(ffn(x, dense[first], w_gate, w_up, w_down))
     want = sum(
@@ -283,7 +287,10 @@ def test_the_step_counts_its_pairs_and_the_experts_hit(model, weights, cfg,
     """`expert_pairs`, `experts_hit`, `expert_layer_steps` and
     `experts_held` of one decode step, against the reference's picks; a
     dead row counts for nothing. `experts_read` is what the path streams:
-    the experts hit on the hit path, every held one on the dense."""
+    the experts hit on the hit and grouped paths, every held one on the
+    dense; `expert_rows` the rows of expert products taken: the step's 4
+    tokens for each expert read, on the grouped path a tile of 128 rows for
+    each expert hit."""
     b, page, pps = 4, 8, 2
     width = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
     L = cfg["num_hidden_layers"]
@@ -310,7 +317,9 @@ def test_the_step_counts_its_pairs_and_the_experts_hit(model, weights, cfg,
         hit += len(seen)
     assert {k: int(v) for k, v in counts.items()} == {
         "expert_pairs": pairs, "experts_hit": hit,
-        "experts_read": hit if expert_path == "hit" else 2 * 4,
+        "experts_read": 2 * 4 if expert_path == "dense" else hit,
+        "expert_rows": {"dense": 4 * 2 * 4, "hit": 4 * hit,
+                        "grouped": 128 * hit}[expert_path],
         "expert_layer_steps": 2, "experts_held": 2 * 4}
     # outside a collection nothing is counted and nothing is left behind
     assert not tracing.counting()
@@ -333,15 +342,42 @@ def test_the_burst_hands_its_counts_to_the_emit_phase(model, cfg,
     eng = _engine(model, cfg)
     eng.add_request(np.arange(6), max_new_tokens=6)
     eng.run()
-    assert seen and set(seen[0]) == {"expert_pairs", "experts_hit",
-                                     "experts_read", "expert_layer_steps",
-                                     "experts_held"}
+    # the phase that commits the prompt's first token carries the prefill
+    # program's counts under names of their own: 6 live tokens of a bucket
+    # of 8 in 2 expert layers, the padding in no pair
+    first, burst = seen[0], seen[1]
+    assert set(first) == {"prefill_expert_pairs", "prefill_expert_rows"}
+    assert 0 <= first["prefill_expert_pairs"] <= 2 * 6 * 4
+    if expert_path == "dense":
+        assert first["prefill_expert_rows"] == 2 * 8 * 4
+    elif expert_path == "grouped":
+        assert first["prefill_expert_rows"] % 128 == 0 \
+            and first["prefill_expert_rows"] <= 2 * 4 * 128
+    assert set(burst) == {"expert_pairs", "experts_hit", "experts_read",
+                          "expert_rows", "expert_layer_steps",
+                          "experts_held"}
     # one live row, 2 expert layers, bursts of 4 steps
-    assert seen[0]["expert_layer_steps"] == 2 * 4
-    assert seen[0]["experts_held"] == 2 * 4 * 4
-    assert seen[0]["experts_read"] == seen[0][
-        "experts_hit" if expert_path == "hit" else "experts_held"]
-    assert 0 <= seen[0]["experts_hit"] <= seen[0]["expert_pairs"] <= 2 * 4 * 4
+    assert burst["expert_layer_steps"] == 2 * 4
+    assert burst["experts_held"] == 2 * 4 * 4
+    assert burst["experts_read"] == burst[
+        "experts_held" if expert_path == "dense" else "experts_hit"]
+    assert 0 <= burst["experts_hit"] <= burst["expert_pairs"] <= 2 * 4 * 4
+
+
+@pytest.mark.parametrize("expert_path", PATHS, indirect=True)
+def test_a_padded_prefill_is_the_unpadded_prefill(model, cfg, expert_path):
+    """Padded positions, and a padded row (`true_lens` 0), make no pair in
+    an expert layer: the counts are the unpadded prompts' own, and the
+    first token's logits and the cache rows at live positions are what
+    each prompt gives alone."""
+    from conftest import check_padded_prefill
+
+    nb, bucket = 4, 16
+    counts = check_padded_prefill(
+        model, [np.arange(11) % cfg["vocab_size"],
+                (np.arange(5) * 7 + 3) % cfg["vocab_size"]], nb, bucket)
+    if expert_path == "dense":
+        assert int(counts["expert_rows"]) == 2 * nb * bucket * 4
 
 
 def test_a_gpt_engine_keeps_its_two_pools():
