@@ -502,7 +502,8 @@ def _bf16(rng, shape, scale=1.0):
 def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
                  flash_seq=4096, tokens=8192, decode_batch=8,
                  pages_per_seq=160, wide=8192, expert_hidden=7680,
-                 expert_width=2048, experts_held=16):
+                 expert_width=2048, experts_held=16, gqa_seq=2048,
+                 gqa_kv_heads=8, gqa_group=8):
     """Every Pallas kernel entry point of paddle_tpu/kernels/, by default
     at this model's head geometry. tests/test_kernels_compile_tpu.py
     compiles the same table ahead of time for a v5e, so a Mosaic refusal
@@ -618,6 +619,51 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
         return lambda q, kp, vp, ks, vs, tables, lens: impl(
             q, kp, vp, tables, lens, k_scales=ks, v_scales=vs)
 
+    # -- a window layer whose keys are wider than its values, with a sink a
+    # head in the softmax (mimo-v2.5-ep16-l11: 8 kv heads of 8 query heads,
+    # keys of 192 over values of 128, a window of 128): its prefill, and its
+    # decode step over rings of 2 pages of 256 that store a key 256 wide
+    # and score it by 192
+    sink_heads, window = gqa_kv_heads * gqa_group, 128
+
+    def sink_of(rng):
+        return rng.standard_normal(sink_heads, dtype=np.float32)
+
+    def gqa_args(rng):
+        return (_bf16(rng, (1, gqa_seq, sink_heads, 192)),
+                _bf16(rng, (1, gqa_seq, gqa_kv_heads, 192)),
+                _bf16(rng, (1, gqa_seq, gqa_kv_heads, 128)), sink_of(rng))
+
+    def gqa_window(q, k, v, sink):
+        return fa.flash_attention_gqa_bshd(q, k, v, window=window, sink=sink)
+
+    def dense_window(q, k, v, sink):
+        """The same in f32: a sink is one more column of the softmax."""
+        n = q.shape[1]
+        kk = jnp.repeat(k.astype(f32), gqa_group, axis=2)
+        vv = jnp.repeat(v.astype(f32), gqa_group, axis=2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(f32), kk) / math.sqrt(192)
+        back = jnp.arange(n)[:, None] - jnp.arange(n)[None, :]
+        s = jnp.where((back >= 0) & (back < window), s, -jnp.inf)
+        column = jnp.broadcast_to(sink[None, :, None, None], s.shape[:3] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([s, column], -1), -1)[..., :n]
+        return jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+
+    def ring_args(rng):
+        rows = jnp.arange(decode_batch)
+        seen = rng.integers(1, 3000, size=decode_batch).astype(np.int32)
+        tables, read, first = (np.array(a) for a in pa.ring_view(
+            rows, 2, 256, jnp.asarray(seen), window))
+        return (_bf16(rng, (decode_batch, sink_heads, 256)),
+                _bf16(rng, (gqa_kv_heads, decode_batch * 2, 256, 256)),
+                _bf16(rng, (gqa_kv_heads, decode_batch * 2, 256, 128)),
+                tables, read, first, sink_of(rng))
+
+    def ring_decode(impl):
+        return lambda q, kp, vp, tables, read, first, sink: impl(
+            q, kp, vp, tables, read, first=first, sink=sink,
+            scale=1.0 / math.sqrt(192))
+
     # -- the expert layer of a decode step (16 rows through the held
     # experts of openpangu-ultra-moe-ep16-l5) with 0, 1, 3/8 and all of the
     # held experts hit; expert e's matrices are one random matrix rolled by
@@ -709,6 +755,11 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
         KernelCase("paged decode int8-KV", paged_quant,
                    paged_q8(pa.paged_attention),
                    paged_q8(pa.paged_attention_xla)),
+        KernelCase("gqa prefill window + sink, key 192 / value 128",
+                   gqa_args, gqa_window, dense_window),
+        KernelCase("paged decode window + sink, key 256 / value 128",
+                   ring_args, ring_decode(pa.paged_attention),
+                   ring_decode(pa.paged_attention_xla)),
         *(KernelCase(f"expert hit {n_hit} of {experts_held}", expert_args,
                      experts_hit(n_hit, eh.hit_ffn),
                      experts_hit(n_hit, expert_share.share_ffn))

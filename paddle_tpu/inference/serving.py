@@ -305,7 +305,7 @@ class KVHandoff:
     req_params: dict
     page_size: int
     kv_cache_quant: object
-    k: list          # per layer: [kvh, n_pages, page_size, head_dim]
+    k: list          # per layer: [that layer's kvh, n_pages, page_size, width]
     v: list
     k_scales: object  # per layer or None (int8 KV only)
     v_scales: object
@@ -389,14 +389,22 @@ class ServingEngine:
         # matches the old exclusive-ownership pop/extend bit for bit).
         self._page_refs = [0] * n_pages
         L = self.cfg.num_hidden_layers
-        # what a layer caches is the model's to say: (k, v) of every kv
-        # head for full attention, ONE pool of latent rows for latent
-        # attention (`CausalLMBase.kv_cache_layout`). `k_pages` holds the
-        # first pool of each layer and `v_pages` the second; a layout of
-        # one pool leaves `v_pages` empty (its rows are the one key head
-        # of absorbed-form attention, and carry the value in themselves)
-        self._kv_layout = tuple(model.kv_cache_layout())
-        self._one_pool = len(self._kv_layout) == 1
+        # what a layer caches is the model's to say, layer by layer: (k, v)
+        # of every kv head for full attention, ONE pool of latent rows for
+        # latent attention, K and V of a head count and of widths of the
+        # layer's own where layers differ (`CausalLMBase.kv_cache_layouts`).
+        # `k_pages` holds the first pool of each layer and `v_pages` the
+        # second; layouts of one pool leave `v_pages` empty (their rows are
+        # the one key head of absorbed-form attention, and carry the value
+        # in themselves)
+        self._kv_layouts = tuple(tuple(tuple(pool) for pool in layout)
+                                 for layout in model.kv_cache_layouts())
+        if len({len(layout) for layout in self._kv_layouts}) != 1:
+            raise ValueError(
+                "every layer's cache must be made of as many pools: got "
+                f"{[len(layout) for layout in self._kv_layouts]}")
+        self._one_pool = len(self._kv_layouts[0]) == 1
+        layers_differ = len(set(self._kv_layouts)) > 1
         # ... and how many positions a layer keeps: every one (pages from
         # the allocator through the block tables), or a window of them (a
         # ring of `_rings[layer]` pages a slot in pools of the layer's own,
@@ -405,9 +413,15 @@ class ServingEngine:
             None if w is None else _pa.ring_pages(w, page_size)
             for w in model.kv_cache_windows())
         self._has_rings = any(self._rings)
-        # anything but (k, v) pages of every position in every layer
-        self._mixed_layout = self._one_pool or self._has_rings
-        kvh = self._kv_layout[0][0]
+        # a prefill hands such a layout's K and V back a layer an entry,
+        # and each is written by a program of its layer's own kind
+        self._layer_writes = self._has_rings or layers_differ
+        # anything but the same (k, v) pages of every position in every
+        # layer
+        self._mixed_layout = self._one_pool or self._layer_writes
+        # (the kv heads tp shards and int8 scales follow: every layer's
+        # alike wherever either is allowed)
+        kvh = self._kv_layouts[0][0][0]
         self._check_mixed_layout_support(
             kv_cache_quant=kv_cache_quant, spec_decode=spec_decode,
             draft_model=draft_model, prefix_cache=prefix_cache,
@@ -418,9 +432,10 @@ class ServingEngine:
             and "tp" in self.mesh.axis_names else 1
         if tp > 1 and self._mixed_layout:
             raise ValueError(
-                "a mixed layout (a latent page pool of one head, or window "
-                f"layers' rings) cannot be sharded over tp={tp}: it serves "
-                "at tp=1 (ROADMAP R9 and R10 keep the sharded forms)")
+                "a mixed layout (a latent page pool of one head, window "
+                "layers' rings, or layers whose pools differ) cannot be "
+                f"sharded over tp={tp}: it serves at tp=1 (ROADMAP R9 and "
+                "R10 keep the sharded forms)")
         if tp > 1 and kvh % tp:
             raise ValueError(
                 f"TP serving shards the {kvh} kv heads over tp={tp}; "
@@ -702,20 +717,36 @@ class ServingEngine:
         self._traces: Dict[int, object] = {}
 
     def _alloc_pools(self):
-        """(k_pages, v_pages): empty pools for every layer, as the model's
-        cache layout shapes them; `v_pages` is [] for a layout of one. A
-        layer that keeps every position has the allocator's pages, a
-        window layer its slots' rings."""
+        """(k_pages, v_pages): empty pools for every layer, each shaped by
+        that LAYER's entry of the model's cache layouts (layers may differ
+        in kv heads, and a layer's key pool in width from its value pool);
+        `v_pages` is [] for layouts of one pool. A layer that keeps every
+        position has the allocator's pages, a window layer its slots'
+        rings."""
         pools = [[jnp.zeros((heads, self._n_pages_total if ring is None
                              else self.max_batch * ring, self.page_size,
                              width), self.kv_dtype)
-                  for ring in self._rings]
-                 for heads, width in self._kv_layout]
-        return pools[0], pools[1] if len(pools) > 1 else []
+                  for heads, width in layout]
+                 for layout, ring in zip(self._kv_layouts, self._rings)]
+        return [p[0] for p in pools], \
+            [] if self._one_pool else [p[1] for p in pools]
+
+    def kv_pool_bytes(self):
+        """{"kv_pool_bytes_full", "kv_pool_bytes_window"}: what the pools
+        of the layers that keep every position hold, and what the window
+        layers' rings hold."""
+        out = {"kv_pool_bytes_full": 0, "kv_pool_bytes_window": 0}
+        for li, ring in enumerate(self._rings):
+            out["kv_pool_bytes_full" if ring is None
+                else "kv_pool_bytes_window"] += sum(
+                int(pools[li].nbytes)
+                for pools in (self.k_pages, self.v_pages) if pools)
+        return out
 
     def _check_mixed_layout_support(self, **asked):
-        """A mixed layout (one pool of latent rows a layer, or window
-        layers' rings beside full layers' pages) has no int8 pages, no
+        """A mixed layout (one pool of latent rows a layer, window layers'
+        rings beside full layers' pages, or layers whose pools differ in
+        heads or widths) has no int8 pages, no
         window step (speculative decoding, chunked prefill and with it
         the prefix cache and its tiers) and no draft pools yet: asking
         for one raises here, at construction, and nothing falls back."""
@@ -1375,7 +1406,7 @@ class ServingEngine:
                              bucket=bucket, all_greedy=all_greedy,
                              which=which)
         model = self.model if which == "target" else self._draft_model
-        rings = self._rings if self._has_rings else None
+        rings = self._rings if self._layer_writes else None
         from ..jit.api import _LayerScope
         from ..models.generation import (sample_logits,
                                          sample_logits_per_row)
@@ -1407,9 +1438,9 @@ class ServingEngine:
                     first, _ = sample_logits_per_row(last, key, greedy,
                                                      temp, tk, tp)
                 if rings is not None:
-                    # a layout with rings: a layer's own (k, v) an entry,
-                    # and of a window layer the part of the prompts its
-                    # ring keeps (the rest of its K/V dies with the layer,
+                    # a layout with rings, or whose layers differ: a
+                    # layer's own (k, v) an entry, and of a window layer
+                    # the part of the prompts its ring keeps (the rest of its K/V dies with the layer,
                     # and no stack copies what is left)
                     return first, tuple(
                         tuple(as_array(a) if ring is None else _pa.ring_tail(
@@ -1460,14 +1491,16 @@ class ServingEngine:
             tag=(nb, bucket, which))
         return fn
 
-    def _get_layer_write_fn(self, nb, bucket, ring):
-        """The page write of a layout with rings, a compiled program a
-        (batch-bucket, token-bucket, kind of layer): ONE layer's K and V as
-        the prefill returned them land in that layer's donated pools. A
-        full layer's (`ring` None) go through the rows' block tables; a
-        window layer's are `ring_tail`s and go into the rings of the rows'
-        SLOTS ([nb]), the pages a later step can still see."""
-        fn = self._page_write_fns.get((nb, bucket, "layer", ring))
+    def _get_layer_write_fn(self, nb, bucket, ring, layout):
+        """The page write of a layout with rings or whose layers differ, a
+        compiled program a (batch-bucket, token-bucket, kind of layer: its
+        ring and its pools' heads and widths): ONE layer's K and V as the
+        prefill returned them land in that layer's donated pools. A full
+        layer's (`ring` None) go through the rows' block tables; a window
+        layer's are `ring_tail`s and go into the rings of the rows' SLOTS
+        ([nb]), the pages a later step can still see."""
+        key = (nb, bucket, "layer", ring, layout)
+        fn = self._page_write_fns.get(key)
         if fn is not None:
             return fn
 
@@ -1477,11 +1510,10 @@ class ServingEngine:
             return _pa.prefill_ring_kv_cache(*pools, k, v, where, lens,
                                              ring, bucket)
 
-        fn = self._page_write_fns[(nb, bucket, "layer", ring)] = \
-            _cw.watch_jit(
-                "serving.kv_scatter",
-                jax.jit(pure_layer_write, donate_argnums=(0,)),
-                tag=(nb, bucket, "ring" if ring else "full"))
+        fn = self._page_write_fns[key] = _cw.watch_jit(
+            "serving.kv_scatter",
+            jax.jit(pure_layer_write, donate_argnums=(0,)),
+            tag=(nb, bucket, "ring" if ring else "full", layout[0][0]))
         return fn
 
     def _write_prefill_pages(self, write, k_pages, v_pages, k_scales,
@@ -1578,17 +1610,19 @@ class ServingEngine:
                 tables = np.zeros((nb, self.pages_per_seq), np.int32)
                 tables[:n] = self.block_tables[[si for si, _ in new]]
                 tables, write_lens = jnp.asarray(tables), lens
-                if self._has_rings:
-                    # a layout with rings: `ks` holds a layer's own (k, v)
-                    # an entry, a window layer's cut to what its ring
-                    # keeps, which goes into the rings of the rows' slots
+                if self._layer_writes:
+                    # a layout with rings, or whose layers differ: `ks`
+                    # holds a layer's own (k, v) an entry, a window layer's
+                    # cut to what its ring keeps, which goes into the rings
+                    # of the rows' slots
                     slots = np.zeros((nb,), np.int32)
                     slots[:n] = [si for si, _ in new]
                     slots = jnp.asarray(slots)
 
                     def write(li, pools):
                         ring = self._rings[li]
-                        return self._get_layer_write_fn(nb, bucket, ring)(
+                        return self._get_layer_write_fn(
+                            nb, bucket, ring, self._kv_layouts[li])(
                             pools, *ks[li], tables if ring is None
                             else slots, write_lens)
                 else:
@@ -2509,7 +2543,7 @@ class ServingEngine:
             # buffers, and even live ones hold KV for contexts that
             # will re-prefill anyway (mirrors __init__'s allocation)
             L = self.cfg.num_hidden_layers
-            kvh = self._kv_layout[0][0]
+            kvh = self._kv_layouts[0][0][0]
             n_pages = self._n_pages_total
             if self.kv_cache_quant == "int8":
                 self.k_scales, self.v_scales = map(list, zip(*[

@@ -953,23 +953,63 @@ def _gqa_key_blocks(i, block_q, block_k, window):
     return jnp.maximum(i * block_q - window + 1, 0) // block_k, last
 
 
-def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, *, scale,
-                    block_q, block_k, n_kv, window):
-    """One (kv head, query block, key block): the `group` query heads of a
-    kv head are the rows of one [group * block_q, d] operand, so a key
-    block is read once for all of them. Operands go to the MXU in their own
-    type; scores, softmax and the accumulator are float32."""
-    i, j = pl.program_id(1), pl.program_id(2)
+def _gqa_tiling(seq, group, window):
+    """(query heads a block, key block, key steps a query block) from the
+    shape alone. The query heads of a kv head share a block up to 1,024
+    rows of queries (8 heads: the float32 scores of 16 would not fit beside
+    their softmax), a larger group in several. A window no wider than
+    `GQA_BLOCK_K` has a key block of its own size (of 512 keys a query
+    block of 128 with a window of 128 sees 255) and walks ONLY the blocks a
+    query block can see, counted from its first (`narrow`: the grid's key
+    steps are those, not the sequence's); a wider window and none walk
+    every block of the sequence and skip the ones outside."""
+    gb = max(g for g in range(1, group + 1)
+             if group % g == 0 and g * GQA_BLOCK_Q <= 1024)
+    if window is None or window >= GQA_BLOCK_K:
+        return gb, GQA_BLOCK_K, None
+    bk = 128
+    while bk < window:
+        bk *= 2
+
+    def seen(i):
+        lo = max(i * GQA_BLOCK_Q - window + 1, 0) // bk
+        return ((i + 1) * GQA_BLOCK_Q - 1) // bk - lo + 1
+
+    return gb, bk, max(seen(i) for i in range(seq // GQA_BLOCK_Q))
+
+
+def _gqa_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k,
+                    n_kv, window, narrow=False, sunk=False):
+    """One (kv head, query block, key block): the query heads of a block
+    are the rows of one [heads * block_q, d] operand, so a key block is
+    read once for all of them. Operands go to the MXU in their own type;
+    scores, softmax and the accumulator are float32. `narrow`: grid step j
+    is the j-th key block the query block SEES (`_gqa_tiling`). `sunk`: an
+    operand after V, a sink logit a query head across 128 lanes, which the
+    softmax's state starts from (it stands in the denominator and carries
+    no value)."""
+    if sunk:
+        sink_ref, o_ref, acc, m_scr, l_scr = rest
+    else:
+        o_ref, acc, m_scr, l_scr = rest
+    i, step = pl.program_id(1), pl.program_id(2)
     group = q_ref.shape[1]
     rows = group * block_q
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        if sunk:
+            m_scr[...] = jnp.broadcast_to(
+                sink_ref[0][:, None, :], (group, block_q, 128)
+            ).reshape(rows, 128)
+            l_scr[...] = jnp.ones_like(l_scr)
+        else:
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
         acc[...] = jnp.zeros_like(acc)
 
     lo, hi = _gqa_key_blocks(i, block_q, block_k, window)
+    j = lo + step if narrow else step
 
     @pl.when((j >= lo) & (j <= hi))
     def _():
@@ -997,73 +1037,96 @@ def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, *, scale,
             preferred_element_type=jnp.float32)
         m_scr[:, :1] = m_new
 
-    @pl.when(j == n_kv - 1)
+    @pl.when(step == n_kv - 1)
     def _():
         l = l_scr[:, :1]
         out = acc[...] / jnp.where(l == 0.0, np.float32(1.0), l)
         o_ref[0] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
 
 
-def gqa_supports(seq, head_dim):
-    return seq % GQA_BLOCK_K == 0 and head_dim % 128 == 0
+def gqa_supports(seq, head_dim, value_dim=None):
+    """A key of whole or one and a half lane tiles (128, 192, 256, ...), a
+    value of whole ones."""
+    value_dim = head_dim if value_dim is None else value_dim
+    return seq % GQA_BLOCK_K == 0 and head_dim >= 128 \
+        and head_dim % 64 == 0 and value_dim % 128 == 0
 
 
-def use_gqa_flash(seq, head_dim):
+def use_gqa_flash(seq, head_dim, value_dim=None):
     """The one choice a grouped-query causal prefill asks: this kernel from
     `GQA_MIN_SEQ` tokens on, where the shape tiles; interpret mode (the
     CPU) included, so the tests run the same body."""
-    return gqa_supports(seq, head_dim) and seq >= GQA_MIN_SEQ
+    return gqa_supports(seq, head_dim, value_dim) and seq >= GQA_MIN_SEQ
 
 
-def flash_attention_gqa_bshd(q, k, v, window=None, scale=None):
-    """Causal self-attention of q [b, s, h, d] over k / v [b, s, h_kv, d]
-    (query head n reads kv head n // (h / h_kv)); with `window`, position i
-    sees j only if i - j < window, and key blocks wholly outside a query
-    block's window are neither fetched nor multiplied. Forward only."""
+def flash_attention_gqa_bshd(q, k, v, window=None, scale=None, sink=None):
+    """Causal self-attention of q [b, s, h, d] over k [b, s, h_kv, d] and
+    v [b, s, h_kv, d_v] (query head n reads kv head n // (h / h_kv); a
+    value may be narrower than a key, and the output is as wide as a
+    value); with `window`, position i sees j only if i - j < window, and
+    key blocks wholly outside a query block's window are neither fetched
+    nor multiplied; with `sink` [h], a logit a head stands in the softmax's
+    denominator and carries no value. Forward only."""
     b, s, h, d = q.shape
-    h_kv = k.shape[2]
+    h_kv, d_v = k.shape[2], v.shape[3]
     group = h // h_kv
-    if not gqa_supports(s, d):
+    if not gqa_supports(s, d, d_v):
         raise ValueError(
-            f"flash_attention_gqa: unsupported shape seq={s} d={d} (need "
-            f"multiples of {GQA_BLOCK_K}/128)")
+            f"flash_attention_gqa: unsupported shape seq={s} d={d} "
+            f"d_v={d_v} (need multiples of {GQA_BLOCK_K}/64/128)")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    bq, bk = GQA_BLOCK_Q, GQA_BLOCK_K
-    n_q, n_kv = s // bq, s // bk
-    # [b * h_kv, group, s, d] and [b * h_kv, s, d]
-    qt = q.reshape(b, s, h_kv, group, d).transpose(0, 2, 3, 1, 4).reshape(
-        b * h_kv, group, s, d)
+    bq = GQA_BLOCK_Q
+    gb, bk, steps = _gqa_tiling(s, group, window)
+    narrow = steps is not None
+    n_q, n_kv = s // bq, steps if narrow else s // bk
+    n_gb = group // gb
+    # [b * h_kv * n_gb, gb, s, d] and [b * h_kv, s, d]
+    qt = q.reshape(b, s, h_kv * n_gb, gb, d).transpose(0, 2, 3, 1, 4).reshape(
+        b * h_kv * n_gb, gb, s, d)
     kt = jnp.swapaxes(k, 1, 2).reshape(b * h_kv, s, d)
-    vt = jnp.swapaxes(v, 1, 2).reshape(b * h_kv, s, d)
+    vt = jnp.swapaxes(v, 1, 2).reshape(b * h_kv, s, d_v)
 
     def kv_map(g, i, j):
         # a block outside what the query block sees repeats the nearest one
         # inside, so the pipeline copies nothing for it
         lo, hi = _gqa_key_blocks(i, bq, bk, window)
-        return (g, jnp.clip(j, lo, hi), 0)
+        return (g if n_gb == 1 else g // n_gb,
+                jnp.minimum(lo + j, hi) if narrow else jnp.clip(j, lo, hi), 0)
 
-    q_spec = pl.BlockSpec((1, group, bq, d), lambda g, i, j: (g, 0, i, 0))
+    q_map = lambda g, i, j: (g, 0, i, 0)  # noqa: E731
     kernel = functools.partial(
         _gqa_fwd_kernel, scale=float(scale), block_q=bq, block_k=bk,
-        n_kv=n_kv, window=None if window is None else int(window))
+        n_kv=n_kv, window=None if window is None else int(window),
+        narrow=narrow, sunk=sink is not None)
+    in_specs = [pl.BlockSpec((1, gb, bq, d), q_map),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, d_v), kv_map)]
+    operands = [qt, kt, vt]
+    if sink is not None:
+        # [b * h_kv * n_gb, gb, 128]: a query head's logit across the lanes
+        # of the state it starts
+        in_specs.append(pl.BlockSpec((1, gb, 128),
+                                     lambda g, i, j: (g, 0, 0)))
+        operands.append(jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, h // gb, gb, 1),
+            (b, h // gb, gb, 128)).reshape(b * h_kv * n_gb, gb, 128))
     with _x64_off():
         out = _pc(
             kernel,
-            grid=(b * h_kv, n_q, n_kv),
-            in_specs=[q_spec, pl.BlockSpec((1, bk, d), kv_map),
-                      pl.BlockSpec((1, bk, d), kv_map)],
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+            grid=(b * h_kv * n_gb, n_q, n_kv),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, gb, bq, d_v), q_map),
+            out_shape=jax.ShapeDtypeStruct(qt.shape[:3] + (d_v,), q.dtype),
             scratch_shapes=[
-                pltpu.VMEM((group * bq, d), jnp.float32),
-                pltpu.VMEM((group * bq, 128), jnp.float32),
-                pltpu.VMEM((group * bq, 128), jnp.float32),
+                pltpu.VMEM((gb * bq, d_v), jnp.float32),
+                pltpu.VMEM((gb * bq, 128), jnp.float32),
+                pltpu.VMEM((gb * bq, 128), jnp.float32),
             ],
             interpret=_interpret(),
-        )(qt, kt, vt)
-    return out.reshape(b, h_kv, group, s, d).transpose(0, 3, 1, 2, 4).reshape(
-        b, s, h, d)
+        )(*operands)
+    return out.reshape(b, h_kv, group, s, d_v).transpose(
+        0, 3, 1, 2, 4).reshape(b, s, h, d_v)
 
 
 def flash_attn_unpadded(q, k, v, cu_seqlens_q, cu_seqlens_k, max_seqlen_q,

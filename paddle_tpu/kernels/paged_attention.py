@@ -73,19 +73,22 @@ def decode_uses_kernel(page_size, mapped_tokens, quant):
 
 def paged_attention_dispatch(q, k_pages, v_pages, block_tables,
                              context_lens, scale=None, k_scales=None,
-                             v_scales=None, first=None):
+                             v_scales=None, first=None, sink=None):
     """Decode attention, chosen from what the call can see: interpret mode
     takes the XLA dense gather (the Pallas path is emulation there); float
     pools at pages of `_KERNEL_MIN_PAGE` and more take the page-grid kernel
     whatever the mapped context; smaller pages and int8 pools take the
     gather up to `_XLA_DECODE_MAX_CTX` of mapped context and the kernel
     above it. `first` [batch] (a window layer's first visible position a
-    row) goes to whichever is taken."""
+    row) and `sink` [num_q_heads] (a logit a head in the softmax's
+    denominator) go to whichever is taken."""
     page_size = k_pages.shape[2]
     attend = paged_attention if decode_uses_kernel(
         page_size, block_tables.shape[1] * page_size,
         k_scales is not None) else paged_attention_xla
     kw = {} if first is None else {"first": first}
+    if sink is not None:
+        kw["sink"] = sink
     return attend(q, k_pages, v_pages, block_tables, context_lens,
                   scale=scale, k_scales=k_scales, v_scales=v_scales, **kw)
 
@@ -141,7 +144,7 @@ def update_paged_kv_cache(k_pages, v_pages, k_new, v_new, block_tables,
                           context_lens, active=None):
     """Write one new token per sequence into its page.
 
-    k_new/v_new: [batch, kv_heads, head_dim]; context_lens[b] is the number
+    k_new/v_new: [batch, kv_heads, the pool's width]; context_lens[b] is the number
     of tokens already present (the new token lands at that position).
     active: optional [batch] bool — False rows write nothing (their block
     table row may be stale, e.g. a retired serving slot).
@@ -153,7 +156,7 @@ def update_paged_kv_cache(k_pages, v_pages, k_new, v_new, block_tables,
     `.at[:, page_ids, slots, :]`, XLA:TPU lays each pool out with the
     indexed dimensions outermost and, inside the decode scan, copies it to
     the kernel's layout and back every step (PERF.md section 6, PR 28)."""
-    kv_heads, n_pages, page_size, head_dim = k_pages.shape
+    kv_heads, n_pages, page_size, _ = k_pages.shape
     page_ids = jnp.take_along_axis(
         block_tables, (context_lens // page_size)[:, None], axis=1)[:, 0]
     rows = (jnp.arange(kv_heads, dtype=jnp.int32)[None, :] * n_pages
@@ -165,9 +168,10 @@ def update_paged_kv_cache(k_pages, v_pages, k_new, v_new, block_tables,
                          kv_heads * n_pages * page_size)
     rows = rows.reshape(-1)  # [batch * kv_heads], as k_new's rows
 
-    def put(pages, new):
-        return pages.reshape(-1, head_dim).at[rows].set(
-            new.reshape(-1, head_dim), mode="drop").reshape(pages.shape)
+    def put(pages, new):   # a key may be wider than a value
+        width = pages.shape[-1]
+        return pages.reshape(-1, width).at[rows].set(
+            new.reshape(-1, width), mode="drop").reshape(pages.shape)
 
     return put(k_pages, k_new), put(v_pages, v_new)
 
@@ -393,9 +397,16 @@ def _decode_accumulate(q, k, v, base_pos, ctx, scale, m_scr, l_scr, acc,
     m_scr[..., :1] = m_new
 
 
-def _decode_init(m_scr, l_scr, acc):
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
+def _decode_init(m_scr, l_scr, acc, sink=None):
+    """The online softmax's state before any block: (-inf, 0, 0), or with a
+    `sink` (a logit a row that stands in the denominator and carries no
+    value) the state after that one column, (sink, 1, 0)."""
+    if sink is None:
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+    else:
+        m_scr[...] = sink
+        l_scr[...] = jnp.ones_like(l_scr)
     acc[...] = jnp.zeros_like(acc)
 
 
@@ -406,13 +417,15 @@ def _decode_epilogue(l_scr, acc, dtype):
 
 
 def _decode_kernel(lens_ref, fetch_ref, *rest, page_size, scale, n_pages,
-                   quant=False, windowed=False):
+                   quant=False, windowed=False, sunk=False):
     """Online-softmax decode over the page grid dimension, every kv head
     of the block at once. `fetch_ref` is read by the index maps alone.
     `windowed`: a third prefetched scalar a row, the first position the row
     sees, masked inside its page (a caller whose rows see nothing of their
     first pages hands tables that start at the page of that position, as
-    `ring_view` does: no page is skipped for it here).
+    `ring_view` does: no page is skipped for it here). `sunk`: one more
+    operand after V (and the scales), the sink logit of every query row
+    across 128 lanes, which the softmax's state starts from.
 
     One body serves both storage formats: float pages go to the MXU as
     they are (q in their type); with `quant` the pages hold int8, are
@@ -426,15 +439,18 @@ def _decode_kernel(lens_ref, fetch_ref, *rest, page_size, scale, n_pages,
         first_ref, *rest = rest
     q_ref, k_ref, v_ref, *rest = rest
     if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc = rest
-    else:
-        o_ref, m_scr, l_scr, acc = rest
+        ks_ref, vs_ref, *rest = rest
+    sink_ref = None
+    if sunk:
+        sink_ref, *rest = rest
+    o_ref, m_scr, l_scr, acc = rest
     b = pl.program_id(1)
     p = pl.program_id(2)
 
     @pl.when(p == 0)
     def _():
-        _decode_init(m_scr, l_scr, acc)
+        _decode_init(m_scr, l_scr, acc,
+                     None if sink_ref is None else sink_ref[...])
 
     ctx = lens_ref[b]
     first = None if first_ref is None else first_ref[b]
@@ -481,11 +497,14 @@ def _live_page_ids(block_tables, context_lens, page_size):
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                    scale=None, k_scales=None, v_scales=None, first=None):
+                    scale=None, k_scales=None, v_scales=None, first=None,
+                    sink=None):
     """Single-token decode attention over a paged KV cache.
 
     q: [batch, num_q_heads, head_dim]
-    k_pages/v_pages: [num_kv_heads, n_pages, page_size, head_dim]
+    k_pages: [num_kv_heads, n_pages, page_size, head_dim]
+    v_pages: [num_kv_heads, n_pages, page_size, value_dim] (a value may be
+        narrower than a key; the output is as wide as a value)
     block_tables: [batch, pages_per_seq] int32 (page indices)
     context_lens: [batch] int32 — tokens valid in the cache (q attends over
         these; the current token's K/V must already be written). A row of
@@ -496,7 +515,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         first[b] .. context_lens[b] - 1. The positions below first[b] are
         masked, not skipped: `ring_view` hands tables that start at the
         page of the first visible position
-    -> [batch, num_q_heads, head_dim]
+    sink: optional [num_q_heads] float, a logit a head that stands in the
+        softmax's denominator and carries no value (a row that reads
+        nothing still returns zeros)
+    -> [batch, num_q_heads, value_dim]
 
     Grid (head blocks, batch, pages_per_seq), the pages innermost: a step
     holds one page of `hb` kv heads, `hb` the most heads (a divisor of
@@ -506,6 +528,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     """
     b, n_q_heads, head_dim = q.shape
     n_kv_heads, _, page_size, _ = k_pages.shape
+    value_dim = v_pages.shape[3]
     pages_per_seq = block_tables.shape[1]
     group = n_q_heads // n_kv_heads
     if scale is None:
@@ -527,7 +550,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     kernel = functools.partial(
         _decode_kernel, page_size=page_size, scale=scale,
         n_pages=pages_per_seq, quant=quant,
-        **({"windowed": True} if windowed else {}))
+        **({"windowed": True} if windowed else {}), sunk=sink is not None)
 
     def row_map(h, b, p, lens, fetch, *_):
         return (b, h, 0, 0)
@@ -535,9 +558,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     def page_map(h, b, p, lens, fetch, *_):
         return (h, fetch[b, p], 0, 0)
 
-    page_spec = pl.BlockSpec((hb, 1, page_size, head_dim), page_map)
     in_specs = [pl.BlockSpec((1, hb, gpad, head_dim), row_map),
-                page_spec, page_spec]
+                pl.BlockSpec((hb, 1, page_size, head_dim), page_map),
+                pl.BlockSpec((hb, 1, page_size, value_dim), page_map)]
     operands = [qg, k_pages, v_pages]
     if quant:
         # Scales ride in with a singleton sublane dim: a (1, lanes) trailing
@@ -548,6 +571,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         scale_spec = pl.BlockSpec((hb, 1, 1, _SCALE_LANES), page_map)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scales[:, :, None, :], v_scales[:, :, None, :]]
+    if sink is not None:
+        # [kv_heads, gpad, 128]: a query row's logit across the lanes of
+        # the state it starts (the padded rows' outputs are dropped)
+        rows = jnp.pad(sink.astype(jnp.float32).reshape(n_kv_heads, group),
+                       ((0, 0), (0, gpad - group)))
+        in_specs.append(pl.BlockSpec(
+            (hb, gpad, 128), lambda h, b, p, *_: (h, 0, 0)))
+        operands.append(jnp.broadcast_to(rows[..., None],
+                                         (n_kv_heads, gpad, 128)))
 
     context_lens = context_lens.astype(jnp.int32)
     scalars = [context_lens,
@@ -559,31 +591,33 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
             num_scalar_prefetch=len(scalars),
             grid=(n_kv_heads // hb, b, pages_per_seq),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, hb, gpad, head_dim), row_map),
+            out_specs=pl.BlockSpec((1, hb, gpad, value_dim), row_map),
             scratch_shapes=[
                 pltpu.VMEM((hb, gpad, 128), jnp.float32),
                 pltpu.VMEM((hb, gpad, 128), jnp.float32),
-                pltpu.VMEM((hb, gpad, head_dim), jnp.float32),
+                pltpu.VMEM((hb, gpad, value_dim), jnp.float32),
             ],
         )
         out = _pc(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, n_kv_heads, gpad, head_dim),
+            out_shape=jax.ShapeDtypeStruct((b, n_kv_heads, gpad, value_dim),
                                            q.dtype),
             interpret=_interpret(),
         )(*scalars, *operands)
-    return out[:, :, :group, :].reshape(b, n_q_heads, head_dim)
+    return out[:, :, :group, :].reshape(b, n_q_heads, value_dim)
 
 
 def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
                         scale=None, k_scales=None, v_scales=None,
-                        first=None):
+                        first=None, sink=None):
     """Dense-gather reference: materialize [b, S, kv_h, d] then masked
     attention. The tests' reference, the dispatch's choice in interpret
-    mode, and below the crossover for small pages and int8 pools."""
+    mode, and below the crossover for small pages and int8 pools. A `sink`
+    is one more column of the softmax, dropped after it."""
     b, n_q_heads, head_dim = q.shape
     n_kv_heads, _, page_size, _ = k_pages.shape
+    value_dim = v_pages.shape[3]
     group = n_q_heads // n_kv_heads
     if scale is None:
         scale = 1.0 / float(np.sqrt(head_dim))
@@ -592,7 +626,7 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
     v_dense = v_pages[:, block_tables]
     S = block_tables.shape[1] * page_size
     k_dense = k_dense.reshape(n_kv_heads, b, S, head_dim)
-    v_dense = v_dense.reshape(n_kv_heads, b, S, head_dim)
+    v_dense = v_dense.reshape(n_kv_heads, b, S, value_dim)
     if k_scales is not None:  # int8 pages: dequantize the dense gather
         ks = k_scales[:, block_tables, :page_size].reshape(n_kv_heads, b, S)
         vs = v_scales[:, block_tables, :page_size].reshape(n_kv_heads, b, S)
@@ -605,12 +639,17 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
     if first is not None:
         mask = mask & (jnp.arange(S)[None, :] >= first[:, None])
     s = jnp.where(mask[:, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    if sink is not None:
+        column = jnp.broadcast_to(sink.astype(jnp.float32).reshape(
+            1, n_kv_heads, group, 1), (b, n_kv_heads, group, 1))
+        p = jax.nn.softmax(jnp.concatenate([s, column], -1), axis=-1)[..., :S]
+    else:
+        p = jax.nn.softmax(s, axis=-1)
     if first is not None:
         # a row that sees nothing returns zeros, as the kernel's does
         p = jnp.where(jnp.any(mask, -1)[:, None, None, None], p, 0.0)
     out = jnp.einsum("bhgs,hbsd->bhgd", p, v_dense.astype(jnp.float32))
-    return out.reshape(b, n_q_heads, head_dim).astype(q.dtype)
+    return out.reshape(b, n_q_heads, value_dim).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -688,7 +727,7 @@ def prefill_ring_kv_cache(k_pages, v_pages, k_tail, v_tail, rows, seq_lens,
     in a bucket of `s` positions; row b keeps the positions from
     `(ceil(seq_lens[b] / page) - ring) * page` to seq_lens[b] - 1, in ring
     `rows[b]`; a row of length 0 writes nothing."""
-    b, span, kvh, hd = k_tail.shape
+    b, span, kvh, _ = k_tail.shape
     page_size = k_pages.shape[2]
     lens = seq_lens.astype(jnp.int32)
     oldest, start = ring_tail_start(lens, s, ring, page_size)
@@ -701,7 +740,7 @@ def prefill_ring_kv_cache(k_pages, v_pages, k_tail, v_tail, rows, seq_lens,
 
     def put(pages, tail):
         tail = tail.astype(pages.dtype).transpose(2, 0, 1, 3).reshape(
-            kvh, b * span, hd)
+            kvh, b * span, -1)
         return pages.at[:, page_ids, slots, :].set(tail, mode="drop")
 
     return put(k_pages, k_tail), put(v_pages, v_tail)

@@ -16,6 +16,8 @@ from .latent_moe import (  # noqa: F401
     LatentMoEConfig,
     LatentMoEForCausalLM,
     LatentMoEModel,
+    MiMoV2Config,
+    MiMoV2ForCausalLM,
 )
 from .generation import generate, sample_logits  # noqa: F401
 from .trainer import (build_train_step, place_model,  # noqa: F401

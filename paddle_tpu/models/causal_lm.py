@@ -21,17 +21,18 @@ class CausalLMBase(nn.Layer):
         return getattr(cfg, "num_key_value_heads",
                        cfg.num_attention_heads)
 
-    def kv_cache_layout(self):
-        """((heads, width), ...): the pools one layer's cache is made of,
-        in the order the layer's cache tuple holds them. Keys and values of
-        every kv head for full attention; a model with a mixed layout (it
-        caches something else, a latent, or keeps only a window of some
-        layers' positions: `kv_cache_windows`) states it by overriding
-        these, and the dense caches below and the serving engine's page
-        pools follow."""
+    def kv_cache_layouts(self):
+        """(((heads, width), ...), ...): a layer an entry, the pools that
+        layer's cache is made of, in the order its cache tuple holds them.
+        Keys and values of every kv head in every layer for full attention;
+        a model whose layers cache something else (a latent in one pool, K
+        and V of a head count or of widths of the layer's own) or keep only
+        a window of some layers' positions (`kv_cache_windows`) states it
+        by overriding these, and the dense caches below and the serving
+        engine's page pools follow, layer by layer."""
         cfg = self.config
         kv = (self._kv_heads(), cfg.hidden_size // cfg.num_attention_heads)
-        return (kv, kv)
+        return ((kv, kv),) * cfg.num_hidden_layers
 
     def kv_cache_windows(self):
         """How many positions each layer keeps, a layer an entry: None for
@@ -42,12 +43,12 @@ class CausalLMBase(nn.Layer):
 
     def init_kv_caches(self, batch_size, max_length, dtype=None):
         """Dense per-layer caches for incremental decoding: one `[batch,
-        max_length, heads, width]` array per pool of the layout ((k, v) for
-        full attention)."""
+        max_length, heads, width]` array per pool of the layer's layout
+        ((k, v) for full attention)."""
         dt = dtype or jnp.float32
         return [tuple(jnp.zeros((batch_size, max_length, heads, width), dt)
-                      for heads, width in self.kv_cache_layout())
-                for _ in range(self.config.num_hidden_layers)]
+                      for heads, width in layout)
+                for layout in self.kv_cache_layouts()]
 
     def forward_prefill(self, input_ids, caches, true_lens):
         """A serving prefill: prompts [batch, s] padded past `true_lens`

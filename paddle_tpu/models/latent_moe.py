@@ -6,13 +6,17 @@ ONE decoder stack over a per-layer list of kinds (a config's
 `gqa_window` / `gqa_full`: gated grouped-query attention over the last
 `sliding_window` positions with rope, or over every position with no
 position encoding at all, as Trinity-Mini publishes it: `model_type`
-afmoe), the FFN (`dense`, or `routed+shared`: this chip's share of the
-routed experts plus the shared expert) and where the norms stand
-(`sandwich`: a norm before AND after each sublayer, the residual added
-outside both; `pre`). The stack's classes take any config that gives
-`layer_kinds()`, `moe_spec()` and the sizes a kind reads: `LatentMoEConfig`
-and `AfmoeConfig` here. `gpt.py` and `llama.py` keep their own stacks
-(ROADMAP D6).
+afmoe; `sink_window` / `sink_full`: ungated grouped-query attention whose
+keys are wider than its values, roped on the first dims of a head alone,
+with a kv-head count and a rope base of the kind's own, scaled values
+and, where the config says so, a learned sink in the softmax, as MiMo-V2.5
+publishes it: `model_type` mimo_v2), the FFN (`dense`; `routed+shared`:
+this chip's share of the routed experts plus the shared expert; `routed`:
+the share alone) and where the norms stand (`sandwich`: a norm before AND
+after each sublayer, the residual added outside both; `pre`). The stack's
+classes take any config that gives `layer_kinds()`, `moe_spec()` and the
+sizes a kind reads: `LatentMoEConfig`, `AfmoeConfig` and `MiMoV2Config`
+here. `gpt.py` and `llama.py` keep their own stacks (ROADMAP D6).
 
 The equations (`N(x; g) = x / sqrt(mean(x^2) + eps) * g`, no biases):
 
@@ -32,14 +36,25 @@ The equations (`N(x; g) = x / sqrt(mean(x^2) + eps) * g`, no biases):
              gqa_full: causal, no position encoding
              query head h reads kv head h // (heads / kv heads)
              o = (concat_h(softmax_h v) * sigmoid(x W_gate)) W_o
+    sink     q = x W_q -> heads x d_k; k = x W_k -> kv heads x d_k;
+             v = value_scale (x W_v) -> kv heads x d_v  (d_k 192, d_v 128)
+             rope (rotate-half) on the first `rope` dims of q and k, with
+             the kind's own base; no norm on q or k, no gate
+             sink_window: 0 <= i - j < sliding_window, and where the kind
+             has a sink b_h: P_hj = exp(s_hj) / (sum_j' exp(s_hj') +
+             exp(b_h)), the sink carrying no value; sink_full: causal
+             o = concat_h(P_h v) W_o
     experts  s = sigmoid(x W_r) in float32; S = the top-k (of s + b where
              the router has an `expert_bias` b: the pick alone);
              w_e = scale s_e / sum_S s
              y = sum_{e in S & held here} w_e E_e(x) + E_shared(x)
 
-The cache holds `(c | rope(k_r))`, `kv_lora_rank + qk_rope_head_dim` numbers
-a token a layer, never K or V: `kv_cache_layout()` says so and the serving
-engine allocates ONE pool a layer from it. Prefill and `forward` decompress
+What a layer caches is its mixer's to say (`cache_layout()`, gathered a
+layer an entry by `kv_cache_layouts()`; the serving engine shapes each
+layer's pools from its own entry): a latent layer holds `(c | rope(k_r))`,
+`kv_lora_rank + qk_rope_head_dim` numbers a token in ONE pool, never K or
+V; a grouped-query layer holds K and V of its own kv heads, a key stored
+`_pool_width` wide where it is wider than a value. Prefill and `forward` decompress
 K and V from the latent and attend in blocks (a row and a group of heads at
 a time: float32 scores of 16 x 128 x 1024 x 1024 would be 8.6 GB); decode
 over pages uses the absorbed form, multi-query attention over the one
@@ -221,6 +236,124 @@ class AfmoeConfig:
             ep_rank=ep_rank, ep_degree=ep_degree)
 
 
+@dataclass
+class MiMoV2Config:
+    """MiMo-V2.5's config.json keys (`model_type` mimo_v2): the language
+    model alone (the published vision and audio towers and MTP layers have
+    no key here and are not built). `hybrid_layer_pattern[i]` 1 makes layer
+    i a window layer (the `swa_*` sizes, a sink where
+    `add_swa_attention_sink_bias`), 0 a full one; `moe_layer_freq[i]` 1
+    gives it experts, 0 a dense FFN. `n_routed_experts` is the
+    deployment's count (the router's width); `ep_rank` / `ep_degree` say
+    which contiguous block of them lives here."""
+    vocab_size: int = 152576
+    hidden_size: int = 4096
+    intermediate_size: int = 16384
+    moe_intermediate_size: int = 2048
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 64
+    num_key_value_heads: int = 4
+    head_dim: int = 192
+    v_head_dim: int = 128
+    swa_num_attention_heads: int = 64
+    swa_num_key_value_heads: int = 8
+    swa_head_dim: int = 192
+    swa_v_head_dim: int = 128
+    hybrid_layer_pattern: tuple = None   # None: full iff i == 0 or i % 6 == 5
+    moe_layer_freq: tuple = None         # None: dense iff i == 0
+    sliding_window: int = 128
+    partial_rotary_factor: float = 0.334
+    rope_theta: float = 10000000.0
+    swa_rope_theta: float = 10000.0
+    attention_value_scale: float = 0.707
+    add_full_attention_sink_bias: bool = False
+    add_swa_attention_sink_bias: bool = True
+    n_routed_experts: int = 256
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = None
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = None
+    layernorm_epsilon: float = 1e-5
+    max_position_embeddings: int = 1048576
+    tie_word_embeddings: bool = False
+    ep_rank: int = 0
+    ep_degree: int = 1
+    dtype: str = "float32"
+
+    def __post_init__(self):
+        n = self.num_hidden_layers
+        if self.hybrid_layer_pattern is None:
+            self.hybrid_layer_pattern = tuple(
+                0 if i == 0 or i % 6 == 5 else 1 for i in range(n))
+        if self.moe_layer_freq is None:
+            self.moe_layer_freq = tuple(int(i > 0) for i in range(n))
+        self.hybrid_layer_pattern = tuple(self.hybrid_layer_pattern)
+        self.moe_layer_freq = tuple(self.moe_layer_freq)
+        for name in ("hybrid_layer_pattern", "moe_layer_freq"):
+            given = getattr(self, name)
+            if len(given) != n or set(given) - {0, 1}:
+                raise ValueError(
+                    f"{name} must give {n} layers a 0 or a 1 each, got "
+                    f"{given}")
+        if self.n_shared_experts:
+            raise ValueError(
+                "mimo_v2 publishes no shared expert (n_shared_experts "
+                f"null), got {self.n_shared_experts}")
+
+    @property
+    def rms_norm_eps(self):
+        return self.layernorm_epsilon
+
+    def attention(self, window):
+        """What a `sink_window` (`window` true) or `sink_full` mixer is
+        built from."""
+        pre = "swa_" if window else ""
+        d_k = getattr(self, pre + "head_dim")
+        return dict(
+            heads=getattr(self, pre + "num_attention_heads"),
+            kv_heads=getattr(self, pre + "num_key_value_heads"),
+            key_dim=d_k, value_dim=getattr(self, pre + "v_head_dim"),
+            # the dims of a head that carry a position, an even count
+            rope_dims=int(d_k * self.partial_rotary_factor) // 2 * 2,
+            rope_theta=self.swa_rope_theta if window else self.rope_theta,
+            window=self.sliding_window if window else None,
+            sink=self.add_swa_attention_sink_bias if window
+            else self.add_full_attention_sink_bias,
+            value_scale=self.attention_value_scale)
+
+    def layer_kinds(self):
+        return [("sink_window" if w else "sink_full",
+                 "routed" if e else "dense", "pre")
+                for w, e in zip(self.hybrid_layer_pattern,
+                                self.moe_layer_freq)]
+
+    def moe_spec(self):
+        return dict(width=self.moe_intermediate_size,
+                    num_experts=self.n_routed_experts,
+                    top_k=self.num_experts_per_tok,
+                    scale=1.0 if self.routed_scaling_factor is None
+                    else self.routed_scaling_factor,
+                    norm_topk=self.norm_topk_prob, pick_bias=True, shared=0)
+
+    @staticmethod
+    def tiny(vocab=96, layers=12, window=16, ep_rank=0, ep_degree=4):
+        """Every width shrunk, the kinds and ratios kept: 1 dense + 11
+        expert layers over two periods of the published pattern (a full
+        layer, 4 window layers, a full one, 5 window layers, a full one),
+        16 experts top-4 of which 4 held, 4 query heads on 1 kv head in a
+        full layer and on 2 in a window layer, keys 24 wide (8 of them
+        roped) over values of 16."""
+        return MiMoV2Config(
+            vocab_size=vocab, hidden_size=48, intermediate_size=96,
+            moe_intermediate_size=24, num_hidden_layers=layers,
+            num_attention_heads=4, num_key_value_heads=1, head_dim=24,
+            v_head_dim=16, swa_num_attention_heads=4,
+            swa_num_key_value_heads=2, swa_head_dim=24, swa_v_head_dim=16,
+            sliding_window=window, n_routed_experts=16,
+            num_experts_per_tok=4, max_position_embeddings=512,
+            ep_rank=ep_rank, ep_degree=ep_degree)
+
+
 # ---------------------------------------------------------------------------
 # the mathematics, on plain arrays
 # ---------------------------------------------------------------------------
@@ -374,22 +507,64 @@ def gqa_projections(x, p, cfg, positions, rope):
     return q, k, v, gate
 
 
-def gqa_attention(q, k, v, window=None, offset=0):
+def _pool_width(width):
+    """How wide a cache row of `width` numbers is STORED: itself where it
+    fills whole lane tiles (or less than one), else the next multiple of
+    128, zeros behind it. A pool of 192-wide keys at pages of 256 is laid
+    out by XLA:TPU with the page's positions minor, and copied whole to
+    the width-minor layout the decode kernel reads, every program that
+    touches it (AOT for a v5e, PR 33: 285 MB of temporaries beside a 214 MB
+    pool); in that layout a row of 192 occupies 256 lanes anyway."""
+    return width if width <= 128 or width % 128 == 0 \
+        else -(-width // 128) * 128
+
+
+def _widen(x, width):
+    """x [..., d] with zeros behind it to `width` (scores do not move)."""
+    d = x.shape[-1]
+    return x if d == width else jnp.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, width - d)])
+
+
+def sink_projections(x, p, a, positions):
+    """x [b, s, hidden] (normed) -> (q [b, s, h, d_k], k [b, s, kv, d_k],
+    v [b, s, kv, d_v]) of a `sink_*` mixer built from `a`
+    (`MiMoV2Config.attention`): the values scaled, the first `rope_dims`
+    of each head of q and k rotated at `positions` [b, s], the rest of the
+    head carrying no position."""
+    b, s, _ = x.shape
+    q = _mm(x, p["q_proj"]).reshape(b, s, a["heads"], a["key_dim"])
+    k = _mm(x, p["k_proj"]).reshape(b, s, a["kv_heads"], a["key_dim"])
+    v = _mm(x, p["v_proj"]).reshape(b, s, a["kv_heads"], a["value_dim"])
+    v = (v.astype(F32) * a["value_scale"]).astype(v.dtype)
+    r = a["rope_dims"]
+
+    def rotate(t):
+        return jnp.concatenate(
+            [_rope(t[..., :r], positions, a["rope_theta"]), t[..., r:]], -1)
+
+    return rotate(q), rotate(k), v
+
+
+def gqa_attention(q, k, v, window=None, offset=0, sink=None):
     """Causal grouped-query attention in plain XLA: q [b, s, h, d] are the
-    queries of positions offset .. offset + s - 1, k / v [b, t, kv, d] the
-    rows of positions 0 .. t - 1; with `window`, a query at i sees j only
-    if i - j < window. One batch row and one block of queries at a time,
-    so the float32 scores of a block stay under SCORE_BLOCK_BYTES. Returns
-    [b, s, h x d]."""
+    queries of positions offset .. offset + s - 1, k [b, t, kv, d] and v
+    [b, t, kv, d_v] the rows of positions 0 .. t - 1; with `window`, a
+    query at i sees j only if i - j < window; with `sink` [h], one more
+    column of the softmax, a logit a head that carries no value. One batch
+    row and one block of queries at a time, so the float32 scores of a
+    block stay under SCORE_BLOCK_BYTES. Returns [b, s, h x d_v]."""
     b, s, h, d = q.shape
-    t, kv = k.shape[1], k.shape[2]
+    t, kv, d_v = k.shape[1], k.shape[2], v.shape[3]
     scale = 1.0 / math.sqrt(d)
     bq = s
     while bq > 8 and h * bq * t * 4 > SCORE_BLOCK_BYTES and bq % 2 == 0:
         bq //= 2
     n = s // bq
+    column = None if sink is None else jnp.broadcast_to(
+        sink.astype(F32).reshape(kv, h // kv, 1, 1), (kv, h // kv, bq, 1))
 
-    def block(qb, kk, vv, q0):   # qb [bq, kv, g, d]; kk, vv [t, kv, d]
+    def block(qb, kk, vv, q0):   # qb [bq, kv, g, d]; kk [t, kv, d]
         scores = jnp.einsum("qkgd,tkd->kgqt", qb, kk,
                             preferred_element_type=F32) * scale
         qpos = (offset + q0 + jnp.arange(bq))[:, None]
@@ -397,10 +572,15 @@ def gqa_attention(q, k, v, window=None, offset=0):
         seen = kpos <= qpos
         if window is not None:
             seen = seen & (qpos - kpos < window)
-        probs = jax.nn.softmax(jnp.where(seen, scores, _pa.NEG_INF), axis=-1)
+        scores = jnp.where(seen, scores, _pa.NEG_INF)
+        if column is None:
+            probs = jax.nn.softmax(scores, axis=-1)
+        else:
+            probs = jax.nn.softmax(
+                jnp.concatenate([scores, column], -1), axis=-1)[..., :t]
         return jnp.einsum("kgqt,tkd->qkgd", probs.astype(vv.dtype), vv,
                           preferred_element_type=F32
-                          ).astype(qb.dtype).reshape(bq, h * d)
+                          ).astype(qb.dtype).reshape(bq, h * d_v)
 
     qg = q.reshape(b, n, bq, kv, h // kv, d)
     if b * n == 1:
@@ -408,7 +588,7 @@ def gqa_attention(q, k, v, window=None, offset=0):
     items = (qg.reshape((b * n,) + qg.shape[2:]),
              jnp.repeat(jnp.arange(b), n), jnp.tile(jnp.arange(n) * bq, b))
     out = jax.lax.map(lambda a: block(a[0], k[a[1]], v[a[1]], a[2]), items)
-    return out.reshape(b, s, h * d)
+    return out.reshape(b, s, h * d_v)
 
 
 # ---------------------------------------------------------------------------
@@ -429,12 +609,14 @@ class _Weight(nn.Layer):
 
 
 class _Mixer(nn.Layer):
-    """A mixer's leaves are `_Weight`s named by LEAVES."""
+    """A mixer's leaves are `_Weight`s (or bare parameters) named by
+    LEAVES."""
     LEAVES = ()
 
     def _run(self, fn, *inputs, name):
         """`fn(p, *arrays)` as one op, `p` the layer's leaves by name."""
-        leaves = [getattr(self, n).weight for n in self.LEAVES]
+        leaves = [leaf.weight if isinstance(leaf, _Weight) else leaf
+                  for leaf in (getattr(self, n) for n in self.LEAVES)]
 
         def f(*arrays):
             p = dict(zip(self.LEAVES, arrays[:len(leaves)]))
@@ -446,6 +628,11 @@ class _Mixer(nn.Layer):
 class LatentAttention(_Mixer):
     LEAVES = ("q_a_proj", "q_a_layernorm", "q_b_proj", "kv_a_proj_with_mqa",
               "kv_a_layernorm", "kv_b_proj", "o_proj")
+    window = None   # every position is kept
+
+    def cache_layout(self):
+        """One pool: one head of `(c | rope(k_r))` rows."""
+        return ((1, self.config.cache_width),)
 
     def __init__(self, config: LatentMoEConfig):
         super().__init__()
@@ -523,40 +710,33 @@ class LatentAttention(_Mixer):
         return out, (as_array(pool),)
 
 
-class GatedGQAttention(_Mixer):
-    """Grouped-query attention with per-head q / k norms and a sigmoid
-    gate on its output, taken from the same normed input as q. `window`
-    (positions a query sees, itself included) makes it a `gqa_window`
-    layer, which ropes q and k; None a `gqa_full` one, which encodes no
-    position. The cache is (k, v) rows of every kv head: every position's
-    for a full layer, a ring of the last window's pages for a window layer
-    (`kernels/paged_attention.py`)."""
-    LEAVES = ("q_proj", "k_proj", "v_proj", "gate_proj", "o_proj", "q_norm",
-              "k_norm")
+class _GQAttention(_Mixer):
+    """What the grouped-query mixers share: the dense cache of `generate`
+    and of a prefill, the decode step over pages or rings, and the choice
+    of the prefill's attention. A kind gives `_project(p, x, positions) ->
+    (q, k, v, *rest)`, `_finish(p, ctx, *rest)`, and sets `window`
+    (positions a query sees, itself included; None: every one),
+    `kv_heads`, `key_dim`, `value_dim`, `OP` (its ops' name). The cache is
+    (k, v) rows of every kv head, a key `_pool_width(key_dim)` wide: every
+    position's for a full layer, a ring of the last window's pages for a
+    window layer (`kernels/paged_attention.py`)."""
+    SINK = None   # the leaf that holds a sink, where the kind has one
 
-    def __init__(self, config, window=None):
-        super().__init__()
-        c = self.config = config
-        self.window = window
-        self.scope_name = "full" if window is None else "window"
-        h, kv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-        self.q_proj = _Weight(c.hidden_size, h * d)
-        self.k_proj = _Weight(c.hidden_size, kv * d)
-        self.v_proj = _Weight(c.hidden_size, kv * d)
-        self.gate_proj = _Weight(c.hidden_size, h * d)
-        self.o_proj = _Weight(h * d, c.hidden_size)
-        self.q_norm = _Weight(d)
-        self.k_norm = _Weight(d)
+    @property
+    def scope_name(self):
+        return "full" if self.window is None else "window"
 
-    def _out(self, ctx, gate, p):
-        return _mm((ctx.astype(F32) * gate).astype(ctx.dtype), p["o_proj"])
+    def cache_layout(self):
+        """((heads, width), (heads, width)): this layer's K and V pools."""
+        return ((self.kv_heads, _pool_width(self.key_dim)),
+                (self.kv_heads, self.value_dim))
 
     def forward_cached(self, x, cache, cur_len):
         """x [b, s, hidden] at positions cur_len .. cur_len + s - 1; cache
-        (k rows, v rows) [b, t, kv, d] of EVERY position (the dense cache
-        of `generate` and of a prefill, whatever the layer keeps in
-        pages). Returns (out, new cache)."""
-        cfg, window = self.config, self.window
+        (k rows [b, t, kv, key width], v rows [b, t, kv, d_v]) of EVERY
+        position (the dense cache of `generate` and of a prefill, whatever
+        the layer keeps in pages). Returns (out, new cache)."""
+        window, d_k, d_v = self.window, self.key_dim, self.value_dim
         k_rows, v_rows = (as_array(c) for c in cache)
         traced = hasattr(cur_len, "_data")
         start = as_array(cur_len) if traced else cur_len
@@ -568,36 +748,39 @@ class GatedGQAttention(_Mixer):
         def f(p, x, k_rows, v_rows):
             b, s, _ = x.shape
             positions = jnp.broadcast_to(start + jnp.arange(s), (b, s))
-            q, k, v, gate = gqa_projections(x, p, cfg, positions,
-                                            rope=window is not None)
+            q, k, v, *rest = self._project(p, x, positions)
+            sink = {} if self.SINK is None else {"sink": p[self.SINK]}
             with scope("kv_write"):
                 zero = jnp.zeros((), jnp.int32)
                 at = (zero, jnp.asarray(start, jnp.int32), zero, zero)
                 k_rows = jax.lax.dynamic_update_slice(
-                    k_rows, k.astype(k_rows.dtype), at)
+                    k_rows, _widen(k, k_rows.shape[-1]).astype(k_rows.dtype),
+                    at)
                 v_rows = jax.lax.dynamic_update_slice(
                     v_rows, v.astype(v_rows.dtype), at)
             with scope(self.scope_name):
-                if whole and _fa.use_gqa_flash(s, cfg.head_dim):
+                if whole and _fa.use_gqa_flash(s, d_k, d_v):
                     ctx = _fa.flash_attention_gqa_bshd(
-                        q, k, v, window=window).reshape(b, s, -1)
+                        q, k, v, window=window, **sink).reshape(b, s, -1)
                 elif whole:
-                    ctx = gqa_attention(q, k, v, window)
+                    ctx = gqa_attention(q, k, v, window, **sink)
                 else:
-                    ctx = gqa_attention(q, k_rows.astype(q.dtype),
+                    seen = k_rows if k_rows.shape[-1] == d_k \
+                        else k_rows[..., :d_k]
+                    ctx = gqa_attention(q, seen.astype(q.dtype),
                                         v_rows.astype(q.dtype), window,
-                                        offset=start)
-            return self._out(ctx, gate, p), k_rows, v_rows
+                                        offset=start, **sink)
+            return self._finish(p, ctx, *rest), k_rows, v_rows
 
         out, k_rows, v_rows = self._run(f, x, k_rows, v_rows,
-                                        name="gated_gqa_attention")
+                                        name=self.OP + "_attention")
         return out, (as_array(k_rows), as_array(v_rows))
 
     def forward(self, x):
-        c = self.config
-        empty = jnp.zeros((x.shape[0], x.shape[1], c.num_key_value_heads,
-                           c.head_dim), as_array(x).dtype)
-        return self.forward_cached(x, (empty, empty), 0)[0]
+        dtype = as_array(x).dtype
+        empty = [jnp.zeros((x.shape[0], x.shape[1], heads, width), dtype)
+                 for heads, width in self.cache_layout()]
+        return self.forward_cached(x, tuple(empty), 0)[0]
 
     def forward_paged(self, x, cache, block_tables, context_lens,
                       active=None, mesh=None):
@@ -605,26 +788,105 @@ class GatedGQAttention(_Mixer):
         pages are the rows' block tables' (`paged_attention_step`), a
         window layer's its rings (`window_attention_step`), which take no
         table."""
-        cfg, window = self.config, self.window
+        window = self.window
         lens = as_array(context_lens)
+        width = _pool_width(self.key_dim)
+        # a key stored wider than it is scores by its own width
+        kw = {} if width == self.key_dim \
+            else {"scale": 1.0 / math.sqrt(self.key_dim)}
 
         def proj(p, x):
-            return gqa_projections(x, p, cfg, lens[:, None],
-                                   rope=window is not None)
+            q, k, v, *rest = self._project(p, x, lens[:, None])
+            sink = () if self.SINK is None else (p[self.SINK],)
+            return (_widen(q, width), _widen(k, width), v) + sink \
+                + tuple(rest)
 
-        q, k, v, gate = self._run(proj, x, name="gated_gqa_projections")
+        q, k, v, *rest = self._run(proj, x, name=self.OP + "_projections")
+        if self.SINK is not None:
+            kw["sink"], rest = rest[0], rest[1:]
         if window is None:
             ctx, cache = paged_attention_step(
                 q, k, v, cache, block_tables, context_lens, active=active,
-                mesh=mesh, kv_heads=cfg.num_key_value_heads,
-                attend_scope=self.scope_name)
+                mesh=mesh, kv_heads=self.kv_heads,
+                attend_scope=self.scope_name, **kw)
         else:
             ctx, cache = window_attention_step(
                 q, k, v, cache, context_lens, window, active=active,
-                attend_scope=self.scope_name)
-        out = self._run(lambda p, ctx, gate: self._out(ctx, gate, p), ctx,
-                        gate, name="gated_gqa_output")
+                attend_scope=self.scope_name, **kw)
+        out = self._run(lambda p, ctx, *rest: self._finish(p, ctx, *rest),
+                        ctx, *rest, name=self.OP + "_output")
         return out, tuple(as_array(c) for c in cache)
+
+
+class GatedGQAttention(_GQAttention):
+    """Grouped-query attention with per-head q / k norms and a sigmoid
+    gate on its output, taken from the same normed input as q. A window
+    makes it a `gqa_window` layer, which ropes q and k; None a `gqa_full`
+    one, which encodes no position."""
+    LEAVES = ("q_proj", "k_proj", "v_proj", "gate_proj", "o_proj", "q_norm",
+              "k_norm")
+    OP = "gated_gqa"
+
+    def __init__(self, config, window=None):
+        super().__init__()
+        c = self.config = config
+        self.window = window
+        h, kv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        self.kv_heads, self.key_dim, self.value_dim = kv, d, d
+        self.q_proj = _Weight(c.hidden_size, h * d)
+        self.k_proj = _Weight(c.hidden_size, kv * d)
+        self.v_proj = _Weight(c.hidden_size, kv * d)
+        self.gate_proj = _Weight(c.hidden_size, h * d)
+        self.o_proj = _Weight(h * d, c.hidden_size)
+        self.q_norm = _Weight(d)
+        self.k_norm = _Weight(d)
+
+    def _project(self, p, x, positions):
+        return gqa_projections(x, p, self.config, positions,
+                               rope=self.window is not None)
+
+    def _finish(self, p, ctx, gate):
+        return _mm((ctx.astype(F32) * gate).astype(ctx.dtype), p["o_proj"])
+
+
+class SinkGQAttention(_GQAttention):
+    """Ungated grouped-query attention as MiMo-V2.5 publishes it: keys
+    wider than values, rope on the first dims of a head, the values
+    scaled, no norm on q or k, and where the kind has one a learned sink a
+    head in the softmax's denominator (`attention_sink_bias`, [heads]).
+    The kv-head count, the rope's base, the window and the sink are the
+    KIND's: a `sink_window` layer's differ from a `sink_full` one's
+    (`MiMoV2Config.attention`)."""
+    OP = "sink_gqa"
+
+    def __init__(self, config, window):
+        super().__init__()
+        a = self.spec = config.attention(window)
+        if a["sink"] and a["window"] is None:
+            raise NotImplementedError(
+                "a sink in a full layer's softmax is not built (the decode "
+                "step over block tables takes none): "
+                "add_full_attention_sink_bias must be false")
+        self.window = a["window"]
+        self.kv_heads = a["kv_heads"]
+        self.key_dim, self.value_dim = a["key_dim"], a["value_dim"]
+        hid = config.hidden_size
+        self.q_proj = _Weight(hid, a["heads"] * a["key_dim"])
+        self.k_proj = _Weight(hid, a["kv_heads"] * a["key_dim"])
+        self.v_proj = _Weight(hid, a["kv_heads"] * a["value_dim"])
+        self.o_proj = _Weight(a["heads"] * a["value_dim"], hid)
+        self.LEAVES = ("q_proj", "k_proj", "v_proj", "o_proj")
+        if a["sink"]:
+            self.SINK = "attention_sink_bias"
+            self.LEAVES += (self.SINK,)
+            self.attention_sink_bias = self.create_parameter(
+                shape=[a["heads"]], default_initializer=I.Constant(0.0))
+
+    def _project(self, p, x, positions):
+        return sink_projections(x, p, self.spec, positions)
+
+    def _finish(self, p, ctx):
+        return _mm(ctx, p["o_proj"])
 
 
 class GatedFFN(nn.Layer):
@@ -643,8 +905,8 @@ class GatedFFN(nn.Layer):
 
 
 class RoutedSharedFFN(nn.Layer):
-    """This chip's share of the routed experts plus the shared expert
-    (every chip computes that alike)."""
+    """This chip's share of the routed experts plus, where the config has
+    one, the shared expert (every chip computes that alike)."""
 
     def __init__(self, config):
         super().__init__()
@@ -654,31 +916,41 @@ class RoutedSharedFFN(nn.Layer):
             ep_rank=c.ep_rank, ep_degree=c.ep_degree,
             routed_scaling_factor=m["scale"], norm_topk_prob=m["norm_topk"],
             pick_bias=m["pick_bias"])
-        self.shared_experts = GatedFFN(c.hidden_size,
-                                       m["shared"] * m["width"])
+        self.shared_experts = GatedFFN(
+            c.hidden_size, m["shared"] * m["width"]) if m["shared"] else None
 
     def forward(self, x, live=None):
         routed = self.experts(x, live=live)
+        if self.shared_experts is None:
+            return routed
         with scope("shared"):
             return routed + self.shared_experts(x)
+
+
+# a mixer kind -> how it is built from a config
+MIXERS = {
+    "latent": LatentAttention,
+    "gqa_window": lambda c: GatedGQAttention(c, c.sliding_window),
+    "gqa_full": lambda c: GatedGQAttention(c, None),
+    "sink_window": lambda c: SinkGQAttention(c, True),
+    "sink_full": lambda c: SinkGQAttention(c, False),
+}
 
 
 class LatentMoEDecoderLayer(nn.Layer):
     def __init__(self, config, kinds):
         super().__init__()
         mixer, ffn, norms = kinds
-        if mixer not in ("latent", "gqa_window", "gqa_full") \
-                or norms not in ("sandwich", "pre") \
-                or ffn not in ("dense", "routed+shared"):
+        shared = config.moe_spec()["shared"]
+        if mixer not in MIXERS or norms not in ("sandwich", "pre") \
+                or ffn not in ("dense", "routed+shared", "routed") \
+                or (ffn != "dense" and (ffn == "routed") == bool(shared)):
             raise ValueError(f"unknown layer kinds {kinds}")
         self.eps = config.rms_norm_eps
-        self.routed = ffn == "routed+shared"
+        self.routed = ffn != "dense"
         self.sandwich = norms == "sandwich"
         self.input_layernorm = _Weight(config.hidden_size)
-        self.self_attn = LatentAttention(config) if mixer == "latent" \
-            else GatedGQAttention(
-                config, config.sliding_window if mixer == "gqa_window"
-                else None)
+        self.self_attn = MIXERS[mixer](config)
         self.pre_mlp_layernorm = _Weight(config.hidden_size)
         self.mlp = RoutedSharedFFN(config) if self.routed else GatedFFN(
             config.hidden_size, config.intermediate_size)
@@ -817,9 +1089,13 @@ class LatentMoEForCausalLM(CausalLMBase):
             config.hidden_size, config.vocab_size)
         self.loss_fn = nn.CrossEntropyLoss()
 
-    def kv_cache_layout(self):
-        """One pool a layer: one head of `(c | rope(k_r))` rows."""
-        return ((1, self.config.cache_width),)
+    def kv_cache_layouts(self):
+        """Each layer's mixer says what it caches."""
+        return tuple(layer.self_attn.cache_layout()
+                     for layer in self.model.layers)
+
+    def kv_cache_windows(self):
+        return tuple(layer.self_attn.window for layer in self.model.layers)
 
     def _backbone_embed_weight(self):
         return self.model.embed_tokens.weight
@@ -861,17 +1137,6 @@ class AfmoeForCausalLM(LatentMoEForCausalLM):
     the embedding scaled by sqrt(hidden), an untied float32 head. Serving
     only, like its parent."""
 
-    def kv_cache_layout(self):
-        """(k, v) of every kv head, `head_dim` wide (the config states it:
-        hidden / heads is another number here)."""
-        kv = (self.config.num_key_value_heads, self.config.head_dim)
-        return (kv, kv)
-
-    def kv_cache_windows(self):
-        cfg = self.config
-        return tuple(cfg.sliding_window if t == "sliding_attention" else None
-                     for t in cfg.layer_types)
-
     def forward_prefill(self, input_ids, caches, true_lens):
         """The head at each prompt's last position alone: the float32
         logits of every position of a round (16,384 x 200,192) would be
@@ -896,3 +1161,11 @@ class AfmoeForCausalLM(LatentMoEForCausalLM):
             input_ids, paged_caches, block_tables, context_lens,
             active=active, mesh=mesh)
         return self._head(h), new_caches
+
+
+class MiMoV2ForCausalLM(AfmoeForCausalLM):
+    """MiMo-V2.5's shape over the same stack: `sink_window` and
+    `sink_full` layers whose kv-head counts differ, a dense then routed
+    FFNs with no shared expert, norms before the sublayers only, an
+    untied float32 head taken at each prompt's last position. Serving
+    only, like its parents."""

@@ -24,6 +24,7 @@ Two shapes of step share this entry:
 from __future__ import annotations
 
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -39,16 +40,19 @@ def _scoped(name):
 
 def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
                          active=None, mesh=None, kv_heads=None,
-                         rotate=None, limit_lens=None, attend_scope=None):
+                         rotate=None, limit_lens=None, attend_scope=None,
+                         scale=None):
     """q: [b, s, heads, d]; k/v: [b, s, kv_heads, d] (Tensors; s == 1 is
-    the classic decode step, s > 1 the speculative-verify window).
+    the classic decode step, s > 1 the speculative-verify window; a value
+    may be narrower than a key, and the output is as wide as a value).
     paged_cache: (k_pages, v_pages) or (k_pages, v_pages, k_scales,
     v_scales) for int8 pages. limit_lens: optional [b] — window
     positions at or beyond it write nothing (budget overhang).
     attend_scope: a scope name for the single-token attention itself (the
     cache read, scores, softmax and weighted sum), for a model that reads
-    it apart from its projections. Returns (out [b, s, heads*d] Tensor,
-    new_cache tuple)."""
+    it apart from its projections. scale: the scores' factor where it is
+    not 1 / sqrt(d) (a key stored wider than it is). Returns (out [b, s,
+    heads * value width] Tensor, new_cache tuple)."""
     from ..distributed import mesh as _mesh
     from ..distributed.sharding_utils import in_manual_region
     from ..kernels import paged_attention as _pa
@@ -56,7 +60,7 @@ def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
     b = q.shape[0]
     s_win = int(q.shape[1])
     n_heads = q.shape[2]
-    head_dim = q.shape[3]
+    head_dim = v.shape[3]
     if kv_heads is None:
         kv_heads = k.shape[2]
     kv_quant = len(paged_cache) == 4
@@ -85,7 +89,9 @@ def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
         if rotate is not None:
             qq, kk = rotate(qq, kk, lens)
         if s_win == 1:
-            attn = _pa.paged_attention_dispatch
+            attn = _pa.paged_attention_dispatch if scale is None \
+                else functools.partial(_pa.paged_attention_dispatch,
+                                       scale=scale)
             # a row at/past its limit writes NOTHING: the draft scan of
             # a row that exhausted its budget would otherwise write
             # through stale (or zero) block-table entries into pages
@@ -174,7 +180,8 @@ def paged_attention_step(q, k, v, paged_cache, block_tables, context_lens,
 
 
 def window_attention_step(q, k, v, paged_cache, context_lens, window,
-                          active=None, attend_scope=None):
+                          active=None, attend_scope=None, scale=None,
+                          sink=None):
     """The single-token decode step of a WINDOW layer, whose pools are
     rings (`kernels/paged_attention.py`): row b of the batch owns ring b,
     its new token lands at position context_lens[b], and it attends the
@@ -182,7 +189,9 @@ def window_attention_step(q, k, v, paged_cache, context_lens, window,
     as a full layer, told the first position it sees: the kernel streams
     the ring's live pages and nothing else. q [b, 1, heads, d]; k, v [b, 1,
     kv_heads, d]; paged_cache (k_pages, v_pages) of [kv_heads, b x ring,
-    page, d]. Returns (out [b, 1, heads*d] Tensor, new_cache).
+    page, d]. `scale`: the scores' factor where it is not 1 / sqrt(d);
+    `sink` [heads]: a logit a head in the softmax's denominator. Returns
+    (out [b, 1, heads * value width] Tensor, new_cache).
 
     Counts, where someone collects: `attn_window_pages_read` (pages the
     call streams: the copies the kernel's pipeline makes by the table of
@@ -193,7 +202,7 @@ def window_attention_step(q, k, v, paged_cache, context_lens, window,
     layer holding every position would read)."""
     from ..kernels import paged_attention as _pa
 
-    b, n_heads, head_dim = q.shape[0], q.shape[2], q.shape[3]
+    b, n_heads, head_dim = q.shape[0], q.shape[2], v.shape[3]
     act = jnp.broadcast_to(
         jnp.asarray(True if active is None else as_array(active), bool), (b,))
     lens = as_array(context_lens)
@@ -215,18 +224,22 @@ def window_attention_step(q, k, v, paged_cache, context_lens, window,
         _trace.count("attn_window_pages_context", jnp.sum(
             (seen + page_size - 1) // page_size, dtype=jnp.int32))
 
-    def step(qq, kk, vv, kp, vp):
+    kw = {} if scale is None else {"scale": scale}
+
+    def step(qq, kk, vv, kp, vp, *sunk):
         with scope("kv_write"):
             kp, vp = _pa.update_ring_kv_cache(
                 kp, vp, kk[:, 0].astype(kp.dtype), vv[:, 0].astype(vp.dtype),
                 rows, lens, active=act)
         with _scoped(attend_scope):
-            out = _pa.paged_attention_dispatch(qq[:, 0], kp, vp, tables,
-                                               read, first=first)
+            out = _pa.paged_attention_dispatch(
+                qq[:, 0], kp, vp, tables, read, first=first, **kw,
+                **({"sink": sunk[0]} if sunk else {}))
         return out[:, None], kp, vp
 
-    out, new_k, new_v = _apply_op(step, q, k, v, Tensor(k_pages),
-                                  Tensor(v_pages), _name="window_attention")
+    out, new_k, new_v = _apply_op(
+        step, q, k, v, Tensor(k_pages), Tensor(v_pages),
+        *(() if sink is None else (sink,)), _name="window_attention")
     from ..ops.manipulation import reshape
 
     return reshape(out, [b, 1, n_heads * head_dim]), (new_k, new_v)

@@ -147,7 +147,9 @@ def _hand_made_trace_as_a_file(request):
     ops += [["call.15", 14 * ms, 2 * ms, body + "attn/window/pallas_call"],
             ["call.16", 16 * ms, ms, body + "attn/full/pallas_call"],
             ["call.17", 50 * ms, 2 * ms,
-             "jit(pure_prefill)/attn/window/pallas_call"]]
+             "jit(pure_prefill)/attn/window/pallas_call"],
+            ["call.18", 53 * ms, ms,
+             "jit(pure_prefill)/attn/full/pallas_call"]]
     host["lines"][0]["events"] += [
         ["serving.admit", 8 * ms, ms],
         ["serving.admitted", 8 * ms + ms // 2, 900,

@@ -102,7 +102,7 @@ def test_it_is_the_one_stack_with_other_kinds(model):
                for layer in layers)
     assert [layer.self_attn.window for layer in layers] \
         == [16, 16, 16, None, 16, 16, 16, None, 16]
-    assert model.kv_cache_layout() == ((2, 16), (2, 16))
+    assert model.kv_cache_layouts() == (((2, 16), (2, 16)),) * 9
     assert model.kv_cache_windows() == (16, 16, 16, None) * 2 + (16,)
 
 

@@ -98,6 +98,52 @@ def test_gqa_prefill_choice(monkeypatch, row):
     assert taken == [want]
 
 
+# (prompt bucket, window layer?) -> (what attends, its window, a sink?):
+# mimo-v2.5-ep16-l11.long-closed's token buckets, 64 query heads of 192
+# over values of 128, on 8 kv heads with a window of 128 and a sink or on 4
+# with neither; and a bucket under the kernel's first length
+SINK_PREFILL = {
+    "long-closed-2048-window": ((2048, True), (GQA_FLASH, 128, True)),
+    "long-closed-2048-full": ((2048, False), (GQA_FLASH, None, False)),
+    "long-closed-4096-window": ((4096, True), (GQA_FLASH, 128, True)),
+    "long-closed-8192-full": ((8192, False), (GQA_FLASH, None, False)),
+    "long-closed-16384-window": ((16384, True), (GQA_FLASH, 128, True)),
+    "long-closed-16384-full": ((16384, False), (GQA_FLASH, None, False)),
+    "under-the-kernel-512-window": ((512, True), (GQA_XLA, 128, True)),
+    "under-the-kernel-512-full": ((512, False), (GQA_XLA, None, False)),
+}
+
+
+@pytest.mark.parametrize("row", sorted(SINK_PREFILL))
+def test_sink_gqa_prefill_choice(monkeypatch, row):
+    from paddle_tpu.models import MiMoV2Config, latent_moe
+
+    (s, window), want = SINK_PREFILL[row]
+    taken = []
+
+    def attend(name):
+        def f(q, k, v, window=None, offset=0, sink=None, **kw):
+            assert (q.shape[-1], k.shape[-1], v.shape[-1]) == (192,
+                                                               192, 128)
+            assert k.shape[2] == (8 if window else 4)
+            taken.append((name, window, sink is not None))
+            return q[..., :128].reshape(q.shape[0], q.shape[1], -1)
+        return f
+
+    monkeypatch.setattr(fa, GQA_FLASH, attend(GQA_FLASH))
+    monkeypatch.setattr(latent_moe, GQA_XLA, attend(GQA_XLA))
+    cfg = MiMoV2Config(num_hidden_layers=1, hidden_size=256)
+    zeros = lambda shape, _dtype: jnp.zeros(tuple(shape), BF16)  # noqa: E731
+    paddle.nn.initializer.set_global_initializer(zeros, zeros)
+    try:
+        mixer = latent_moe.SinkGQAttention(cfg, window)
+    finally:
+        paddle.nn.initializer.set_global_initializer(None, None)
+    jax.eval_shape(lambda x: as_array(mixer(Tensor(x))),
+                   S((1, s, 256), BF16))
+    assert taken == [want]
+
+
 # ---------------------------------------------------------------------------
 # decode attention over pages: paged_attention_dispatch
 # ---------------------------------------------------------------------------
@@ -123,24 +169,50 @@ PAGED = {
     "mixed-closed-window": ((8, 32, 4, 256, 9, BF16, "first"), KERNEL),
     "window-page16-ring": ((8, 32, 4, 16, 9, BF16, "first"), GATHER),
     "window-page16-mapped2064": ((8, 32, 4, 16, 129, BF16, "first"), KERNEL),
+    # mimo-v2.5-ep16-l11.long-closed: 8 slots, 64 query heads, keys of 192
+    # stored 256 wide over values of 128 ("wide"), pages of 256: a full
+    # layer's 68 pages a row on 4 kv heads, a window layer's ring of 2 on 8
+    # with the first visible position and a sink a head
+    "long-closed-full": ((8, 64, 4, 256, 68, BF16, "wide"), KERNEL),
+    "long-closed-full-sink": ((8, 64, 4, 256, 68, BF16, "wide", "sink"),
+                              KERNEL),
+    "long-closed-window": ((8, 64, 8, 256, 2, BF16, "wide", "first", "sink"),
+                           KERNEL),
+    "long-closed-window-no-sink": ((8, 64, 8, 256, 2, BF16, "wide", "first"),
+                                   KERNEL),
+    "wide-page16-ring": ((8, 64, 8, 16, 9, BF16, "wide", "first", "sink"),
+                         GATHER),
 }
 
 
 def _paged_taken(monkeypatch, row, interpret):
-    b, qh, kvh, page, pages_per_seq, pool_dtype, *windowed = row
+    b, qh, kvh, page, pages_per_seq, pool_dtype, *more = row
     taken = []
     monkeypatch.setattr(pa, "_interpret", lambda: interpret)
+    def recorder(name):
+        def attend(q, *a, **kw):
+            # whichever is taken is handed the window and the sink
+            assert {k for k in kw if k in ("first", "sink")} \
+                == set(more) - {"wide"}
+            taken.append(name)
+            return q
+        return attend
+
     for name in (KERNEL, GATHER):
-        record(monkeypatch, taken, pa, name, lambda q, *a: q)
-    pool = S((kvh, b * pages_per_seq, page, 128), pool_dtype)
+        monkeypatch.setattr(pa, name, recorder(name))
+    key = 256 if "wide" in more else 128
+    pool = S((kvh, b * pages_per_seq, page, key), pool_dtype)
+    values = S((kvh, b * pages_per_seq, page, 128), pool_dtype)
     scales = S((kvh, b * pages_per_seq, pa._SCALE_LANES), F32)
     kw = dict(k_scales=scales, v_scales=scales) if pool_dtype == I8 else {}
-    if windowed:   # the window goes to whichever is taken
+    if "first" in more:
         kw["first"] = S((b,), I32)
+    if "sink" in more:
+        kw["sink"] = S((qh,), F32)
     jax.eval_shape(
         lambda q, kp, vp, tables, lens, **kw: pa.paged_attention_dispatch(
             q, kp, vp, tables, lens, **kw),
-        S((b, qh, 128), BF16), pool, pool, S((b, pages_per_seq), I32),
+        S((b, qh, key), BF16), pool, values, S((b, pages_per_seq), I32),
         S((b,), I32), **kw)
     return taken
 
@@ -226,6 +298,11 @@ EXPERTS = {
     "mixed-closed-prefill-512": ((512, 2048, 1024, 16, BF16), GROUPED),
     "mixed-closed-prefill-2048": ((2048, 2048, 1024, 16, BF16), GROUPED),
     "mixed-closed-prefill-8192": ((8192, 2048, 1024, 16, BF16), GROUPED),
+    # mimo-v2.5-ep16-l11.long-closed: a decode step of 8 rows, and its
+    # prefills of one prompt, 2,048 to 16,384 tokens, experts of 4,096 x 2,048
+    "long-closed-step": ((8, 4096, 2048, 16, BF16), HIT),
+    "long-closed-prefill-2048": ((2048, 4096, 2048, 16, BF16), GROUPED),
+    "long-closed-prefill-16384": ((16384, 4096, 2048, 16, BF16), GROUPED),
     "one-token": ((1, 7680, 2048, 16, BF16), HIT),
     "tokens-64": ((64, 7680, 2048, 16, BF16), HIT),
     # between the two kernels the dense products: every held expert is

@@ -350,6 +350,66 @@ def test_gqa_prefill_kernel_at_the_mixed_cell_shapes(v5e, rows, seq, window):
         q, k, v, window=window), q, kv, kv) == 1
 
 
+@pytest.mark.parametrize("kv_heads,pages_per_seq,extra", [
+    (4, 68, ()), (4, 68, ("sink",)), (8, 2, ("first",)),
+    (8, 2, ("first", "sink"))],
+    ids=["full", "full-sink", "ring", "ring-sink"])
+@pytest.mark.parametrize("key", [192, 256], ids=["key192", "stored256"])
+def test_paged_decode_with_a_wide_key_at_the_long_cell_shape(
+        v5e, kv_heads, pages_per_seq, extra, key):
+    """`mimo-v2.5-ep16-l11.long-closed`: batch 8, 64 query heads of 192
+    over values of 128, pages of 256, bf16: a full layer's 68 pages a row
+    on 4 kv heads (16 query heads a block's row group) and a window layer's
+    ring of 2 on 8, with the first visible position a row and a sink a
+    head; at the key's own 192 and at the 256 its pool stores."""
+    def f(q, kp, vp, tables, lens, *rest):
+        kw = dict(zip(extra, rest))
+        return pa.paged_attention(q, kp, vp, tables, lens,
+                                  scale=192 ** -0.5, **kw)
+
+    n_pages = 8 * pages_per_seq
+    more = {"first": S((8,), I32), "sink": S((64,), F32)}
+    assert compile_for(
+        v5e[0], f, S((8, 64, key), BF16),
+        S((kv_heads, n_pages, 256, key), BF16),
+        S((kv_heads, n_pages, 256, 128), BF16), S((8, pages_per_seq), I32),
+        S((8,), I32), *(more[e] for e in extra)) == 1
+
+
+@pytest.mark.parametrize("kv_heads,window,sink", [
+    (4, None, False), (4, None, True), (8, 128, True), (8, 128, False)],
+    ids=["full", "full-sink", "window-sink", "window"])
+@pytest.mark.parametrize("seq", [2048, 16384])
+def test_gqa_prefill_kernel_at_the_long_cell_shapes(v5e, seq, kv_heads,
+                                                    window, sink):
+    """The grouped-query causal forward at the cell's smallest and largest
+    prefill buckets: 64 query heads of 192 over values of 128, on 4 kv heads
+    (16 query heads a kv head, two blocks of 8) or on 8 with a window of 128
+    (key blocks of 128, two a query block) and a sink a head."""
+    assert fa.use_gqa_flash(seq, 192, 128)
+    assert fa._gqa_tiling(seq, 64 // kv_heads, window) \
+        == ((8, 128, 2) if window else (8, 512, None))
+
+    def f(q, k, v, *rest):
+        return fa.flash_attention_gqa_bshd(q, k, v, window=window,
+                                           sink=rest[0] if rest else None)
+
+    assert compile_for(
+        v5e[0], f, S((1, seq, 64, 192), BF16), S((1, seq, kv_heads, 192), BF16),
+        S((1, seq, kv_heads, 128), BF16),
+        *((S((64,), F32),) if sink else ())) == 1
+
+
+def test_hit_ffn_at_the_long_cell_shape(v5e):
+    """8 rows through 16 held experts of 4,096 x 2,048 in bf16: blocks of
+    256 columns."""
+    assert eh.use_hit_path(8, 4096, 2048, BF16, BF16)
+    assert eh._block_width(4096, 2048, 2) == 256
+    w = S((16, 4096, 2048), BF16)
+    assert compile_for(v5e[0], eh.hit_ffn, S((8, 4096), BF16),
+                       S((8, 16), F32), w, w, S((16, 2048, 4096), BF16)) == 1
+
+
 def test_hit_ffn_at_the_mixed_cell_shape(v5e):
     """8 rows through 16 held experts of 2,048 x 1,024 in bf16: blocks of
     512 columns, three of them double-buffered in 12 MB of VMEM."""
@@ -367,7 +427,9 @@ GROUPED = {"decode-closed-512": (512, 7680, 2048),
            "decode-closed-2048": (2048, 7680, 2048),
            "decode-closed-16384": (16384, 7680, 2048),
            "mixed-closed-512": (512, 2048, 1024),
-           "mixed-closed-8192": (8192, 2048, 1024)}
+           "mixed-closed-8192": (8192, 2048, 1024),
+           "long-closed-2048": (2048, 4096, 2048),
+           "long-closed-16384": (16384, 4096, 2048)}
 
 
 @pytest.mark.parametrize("row", sorted(GROUPED))

@@ -100,7 +100,8 @@ def _engine(model, cfg, **kw):
 def test_the_engine_allocates_one_latent_pool_a_layer(model, cfg):
     eng = _engine(model, cfg)
     width = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
-    assert model.kv_cache_layout() == ((1, width),)
+    assert model.kv_cache_layouts() == (((1, width),),) * len(
+        model.model.layers)
     assert len(eng.k_pages) == cfg["num_hidden_layers"] and not eng.v_pages
     assert {p.shape for p in eng.k_pages} == {(1, 4 * 8, 8, width)}
     assert family.cache_bytes_per_token(cfg, 4) \
@@ -386,7 +387,8 @@ def test_a_gpt_engine_keeps_its_two_pools():
     m.eval()
     eng = ServingEngine(m, max_batch=2, max_seq_len=32, page_size=8,
                         decode_burst=4)
-    assert m.kv_cache_layout() == ((2, 16), (2, 16))
+    assert m.kv_cache_layouts() == (((2, 16), (2, 16)),) * len(
+        m.kv_cache_windows())
     assert len(eng.k_pages) == len(eng.v_pages) == 2
     assert eng.k_pages[0].shape == eng.v_pages[0].shape == (2, 8, 8, 16)
     k, v = m.init_kv_caches(3, 5)[0]
