@@ -6,7 +6,10 @@ Layout: paddle bshd [batch, seq, heads, head_dim]. Forward is the online-
 softmax streaming kernel (never materializes [s, s]); backward recomputes
 p-blocks from the saved row logsumexp (standard flash backward, two kernels:
 dk/dv then dq). Grids put the contraction dim innermost so accumulators live
-in VMEM scratch across grid steps; blocks are MXU-aligned (128).
+in VMEM scratch across grid steps; the blocks of each pass come from the
+shape (`_flash_tiling`), operands go to the MXU in their own type with
+float32 scores and accumulators, and a causal block in the future is
+neither fetched nor stepped into.
 
 On the CPU the same kernels run in interpreter mode so CPU CI exercises
 identical code paths (SURVEY.md §7 "interpret-mode fallback").
@@ -34,6 +37,10 @@ NEG_INF = np.float32(-1e30)  # f32 scalar: x64 mode must not leak f64 into kerne
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+
+# the `jax.named_scope` of the kernels and the backward's prologue, inside a
+# model's `attn` (`tracing.SCOPES`): a device trace books them as `attn/flash`
+SCOPE = "flash"
 
 
 # ---------------------------------------------------------------------------
@@ -74,13 +81,15 @@ def _threefry2x32(k0, k1, c0, c1):
     return x0
 
 
-def _dropout_keep(seed, bh, i, j, block_q, block_k, rate):
-    """Boolean keep-mask for one (block_q, block_k) attention tile.
-    Counters are the global (q, k) token positions, key is (seed, bh)."""
+def _dropout_keep(seed, bh, i, j, block_q, block_k, rate, transposed=False):
+    """Boolean keep-mask for one (block_q, block_k) attention tile, or with
+    `transposed` for the same tile as (block_k, block_q). Counters are the
+    global (q, k) token positions, key is (seed, bh)."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
     rows = i * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+        jnp.int32, shape, 1 if transposed else 0)
     cols = j * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+        jnp.int32, shape, 0 if transposed else 1)
     bits = _threefry2x32(seed, bh, rows, cols)
     # low 23 bits -> uniform [0, 1): non-negative regardless of sign bit
     u = (bits & np.int32(0x7FFFFF)).astype(jnp.float32) * np.float32(
@@ -89,9 +98,153 @@ def _dropout_keep(seed, bh, i, j, block_q, block_k, rate):
 
 
 # ---------------------------------------------------------------------------
-# forward
+# blocks and visibility
 # ---------------------------------------------------------------------------
 
+# What the three passes take where the microbench measured them: heads of
+# 128 in a two-byte type, (queries, keys) a grid step. One layer's causal
+# attention at [4, 2,048, 16, 128] bf16 on a v5e, ms a call (PERF.md section
+# 6, PR 34; 1,024 and 4,096 at the same 8,192 tokens rank the same way):
+#
+#   blocks      128x128  256x512  256x1024  512x512  512x1024  1024x512  1024x1024
+#   forward      6.07     1.81     1.26      1.50     1.10      1.76      1.03
+#   dK/dV pass   5.62     1.94     1.75      1.42     1.51      1.50      1.44
+#   dQ pass      5.18     1.53     1.32      1.21     1.17      1.19      1.11
+#
+# A grid step has a fixed cost (the state's rescale, the pipeline's turn)
+# that a tile of 128 x 128 x 128 products cannot carry; the dK/dV pass holds
+# two accumulators and four products a tile and is as fast at 512 x 512.
+_FWD_BLOCKS = (1024, 1024)
+_DKV_BLOCKS = (512, 512)
+_DQ_BLOCKS = (1024, 1024)
+_SMALL_BLOCKS = ((DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K),) * 3
+
+
+def _flash_tiling(seq_q, seq_kv, head_dim, dtype):
+    """((block_q, block_k) of the forward, of the dK/dV pass, of the dQ
+    pass) from the shape and the operands' type alone: the measured blocks,
+    halved to the largest measured ones (256 queries, 512 keys at the
+    least) that divide the lengths; 128 x 128 for all three where none
+    does, and for a head or a type the microbench did not see (float32
+    operands, heads of 256), so that every shape the kernels took before
+    PR 34 they still take, as they took it."""
+    if head_dim != 128 or jnp.dtype(dtype).itemsize != 2 \
+            or seq_q % 256 or seq_kv % 512:
+        return _SMALL_BLOCKS
+
+    def fit(block, seq):
+        while seq % block:
+            block //= 2
+        return block
+
+    return tuple((fit(bq, seq_q), fit(bk, seq_kv))
+                 for bq, bk in (_FWD_BLOCKS, _DKV_BLOCKS, _DQ_BLOCKS))
+
+
+def _last_kv_block(i, block_q, block_k, offset):
+    """The last key block a causal query block `i` sees (bottom-right
+    aligned: query row r sees keys up to r + offset); 0 for a block of
+    rows that see nothing, whose steps the kernels skip."""
+    return jnp.maximum(((i + 1) * block_q - 1 + offset) // block_k, 0)
+
+
+def _first_q_block(j, block_q, block_k, offset):
+    """The first query block that sees any key of causal key block `j`."""
+    return jnp.maximum((j * block_k - offset) // block_q, 0)
+
+
+def _kv_index_map(causal, block_q, block_k, offset):
+    """Index map of a K / V block in a grid (bh, i, j), key blocks
+    innermost: a causal block in the future repeats the last visible one,
+    so the pipeline copies nothing for the steps the kernel skips."""
+    def index(b, i, j):
+        if causal:
+            j = jnp.minimum(j, _last_kv_block(i, block_q, block_k, offset))
+        return (b, j, 0)
+    return index
+
+
+def _q_index_map(causal, block_q, block_k, offset, rows=False):
+    """Index map of a query-side block in the dK/dV grid (bh, j, i), query
+    blocks innermost: a causal block before the first that sees key block j
+    repeats that first one. `rows`: the [bh, 8, s_q] layout of lse / delta."""
+    def index(b, j, i):
+        if causal:
+            i = jnp.maximum(i, _first_q_block(j, block_q, block_k, offset))
+        return (b, 0, i) if rows else (b, i, 0)
+    return index
+
+
+def _unpack(refs, n_in, seg, drop):
+    """(the first n_in refs, seg_q_ref, seg_k_ref, seed_ref, the rest) of a
+    kernel's positional refs: the segment ids and the seed are operands
+    only where the call has them."""
+    head, refs = refs[:n_in], refs[n_in:]
+    seg_q_ref = seg_k_ref = seed_ref = None
+    if seg:
+        seg_q_ref, seg_k_ref, *refs = refs
+    if drop:
+        seed_ref, *refs = refs
+    return head, seg_q_ref, seg_k_ref, seed_ref, refs
+
+
+def _on_tiles(causal, i, j, block_q, block_k, offset, tile):
+    """Run `tile(cut)` for the grid step's tile: not at all where a causal
+    tile lies wholly in the future, with `cut` (the causal mask applied)
+    only where the diagonal crosses it."""
+    if not causal:
+        tile(False)
+        return
+    seen = j * block_k <= (i + 1) * block_q - 1 + offset
+    cut = (j + 1) * block_k - 1 > i * block_q + offset
+    pl.when(seen & cut)(lambda: tile(True))
+    pl.when(seen & jnp.logical_not(cut))(lambda: tile(False))
+
+
+def _compiler_params(block_q, block_k, head_dim):
+    """The grid's semantics, and the scoped VMEM a call may take: Mosaic
+    keeps a handful of float32 score tiles beside the double-buffered
+    operand blocks (1,024 x 1,024 tiles need more than the default 16 MiB)."""
+    tiles = 8 * block_q * block_k * 4
+    blocks = 16 * max(block_q, block_k) * head_dim * 4
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=int(min(max(tiles + blocks, 16 << 20), 96 << 20)))
+
+
+def _tile_mask(cut, i, j, block_q, block_k, offset, seg_q_ref, seg_k_ref,
+               transposed=False):
+    """The pairs of a tile that may attend, or None where all may: the
+    causal mask where the diagonal crosses the tile (`cut`), the segment
+    ids' equality where the call has them; (block_q, block_k), or with
+    `transposed` (block_k, block_q)."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    q_axis = 1 if transposed else 0
+    mask = None
+    if cut:
+        q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                       q_axis)
+        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                       1 - q_axis)
+        mask = q_pos + offset >= k_pos
+    if seg_q_ref is not None:
+        sq, sk = seg_q_ref[0, 0], seg_k_ref[0, 0]
+        seg_m = sk[:, None] == sq[None, :] if transposed \
+            else sq[:, None] == sk[None, :]
+        mask = seg_m if mask is None else (mask & seg_m)
+    return mask
+
+
+def _dims(q, k, block_q, block_k):
+    """(bh, s_q, s_kv, d, query blocks, key blocks, causal offset)."""
+    bh, s_q, d = q.shape
+    s_kv = k.shape[1]
+    return bh, s_q, s_kv, d, s_q // block_q, s_kv // block_k, s_kv - s_q
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
 
 
 def _sds(shape, dtype, like):
@@ -104,9 +257,14 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
-                scale, causal, block_q, block_k, n_kv, offset,
-                seg_q_ref=None, seg_k_ref=None, dropout=0.0, seed_ref=None):
+def _fwd_kernel(*refs, scale, causal, block_q, block_k, n_kv, offset,
+                seg=False, dropout=0.0):
+    """One (batch-head, query block, key block). Operands go to the MXU in
+    their own type; scores, softmax state and the accumulator are
+    float32."""
+    (q_ref, k_ref, v_ref), seg_q_ref, seg_k_ref, seed_ref, rest = _unpack(
+        refs, 3, seg, dropout)
+    o_ref, lse_ref, acc, m_scr, l_scr = rest
     bh = pl.program_id(0)
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -117,30 +275,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc[:] = jnp.zeros_like(acc)
 
-    run = True
-    if causal:
-        # block fully in the future -> skip (bottom-right aligned)
-        run = j * block_k <= (i + 1) * block_q - 1 + offset
-
-    @pl.when(run)
-    def _():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
+    def tile(cut):
+        v = v_ref[0]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * np.float32(scale)
-        mask = None
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            mask = q_pos + offset >= k_pos
-        if seg_q_ref is not None:
-            sq = seg_q_ref[0, 0]
-            sk = seg_k_ref[0, 0]
-            seg_m = sq[:, None] == sk[None, :]
-            mask = seg_m if mask is None else (mask & seg_m)
+        mask = _tile_mask(cut, i, j, block_q, block_k, offset, seg_q_ref,
+                          seg_k_ref)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[:, :1]
@@ -148,13 +289,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        if mask is not None:
-            # NEG_INF is finite: a fully-masked row has s == m_new == NEG_INF
-            # and exp(0) == 1 everywhere — zero p by the mask itself so l
-            # stays 0 and the epilogue's safe_l emits a zero output row
+        if mask is not None and (seg or offset < 0):
+            # NEG_INF is finite: a row that has seen nothing yet has
+            # s == m_new == NEG_INF and exp(0) == 1 everywhere — zero p by
+            # the mask itself so l stays 0 and the epilogue's safe_l emits a
+            # zero output row. (A causal row with offset >= 0 sees key 0 in
+            # the first block it visits, and exp(NEG_INF - m) is 0.)
             p = jnp.where(mask, p, np.float32(0.0))
         l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)
         p_v = p
         if dropout:
             # dropout hits the (eventually l-normalized) weights feeding
@@ -165,11 +307,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
             p_v = jnp.where(keep, p, np.float32(0.0)) * np.float32(
                 1.0 / (1.0 - dropout))
         pv = jax.lax.dot_general(
-            p_v, v, (((1,), (0,)), ((), ())),
+            p_v.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         acc[:] = acc[:] * alpha + pv
         m_scr[:, :1] = m_new
         l_scr[:, :1] = l_new
+
+    _on_tiles(causal, i, j, block_q, block_k, offset, tile)
 
     @pl.when(j == n_kv - 1)
     def _():
@@ -181,84 +325,100 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
         lse_ref[0] = jnp.broadcast_to(lse_row[None, :], lse_ref.shape[1:])
 
 
-def _fwd_kernel_seg(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, o_ref,
-                    lse_ref, acc, m_scr, l_scr, **params):
-    _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
-                seg_q_ref=seg_q_ref, seg_k_ref=seg_k_ref, **params)
-
-
-def _fwd_kernel_drop(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref, acc,
-                     m_scr, l_scr, **params):
-    _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
-                seed_ref=seed_ref, **params)
-
-
-def _fwd_kernel_seg_drop(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
-                         seed_ref, o_ref, lse_ref, acc, m_scr, l_scr,
-                         **params):
-    _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
-                seg_q_ref=seg_q_ref, seg_k_ref=seg_k_ref,
-                seed_ref=seed_ref, **params)
-
-
 def _seed_arg(seed):
     return jnp.asarray(seed, jnp.int32).reshape(1)
 
 
+def _side_operands(seg_q, seg_k, seed, heads, block_q, block_k, q_major):
+    """(in_specs, operands) of the segment ids and the dropout seed (int32
+    [1], or None) behind
+    a kernel's main operands. The seg arrays are [batch, 8, s] (NOT
+    replicated per head): the index map folds the head dim of the [b*h]
+    grid axis away. `q_major`: the grid is (bh, i, j), else (bh, j, i)."""
+    in_specs, operands = [], []
+    if seg_q is not None:
+        h_ = heads
+        if q_major:
+            qi, ki = (lambda b, i, j: (b // h_, 0, i)), \
+                (lambda b, i, j: (b // h_, 0, j))
+        else:
+            qi, ki = (lambda b, j, i: (b // h_, 0, i)), \
+                (lambda b, j, i: (b // h_, 0, j))
+        in_specs += [pl.BlockSpec((1, 8, block_q), qi),
+                     pl.BlockSpec((1, 8, block_k), ki)]
+        operands += [seg_q, seg_k]
+    if seed is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.append(seed)
+    return in_specs, operands
+
+
+_STATIC = ("scale", "causal", "block_q", "block_k", "heads", "dropout",
+           "interpret")
+
+
+def _call(fn):
+    """One trace and ONE Mosaic lowering for each (shapes, blocks) a program
+    meets, however many layers call it: a `pallas_call` met bare is traced
+    and lowered again at every call site (0.2 s each, 48 a train step of
+    twelve layers: ten seconds of every set-up, which no compile cache
+    holds). `interpret` is an argument so that a cached trace never
+    outlives a change of `_interpret`."""
+    return functools.partial(jax.jit, static_argnames=_STATIC)(fn)
+
+
+@_call
+def _fwd_call(q, k, v, seg_q, seg_k, seed, *, scale, causal, block_q,
+              block_k, heads, dropout, interpret):
+    bh, s_q, s_kv, d, n_q, n_kv, offset = _dims(q, k, block_q, block_k)
+    kernel = functools.partial(
+        _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, n_kv=n_kv, offset=offset, seg=seg_q is not None,
+        dropout=dropout)
+    kv_map = _kv_index_map(causal, block_q, block_k, offset)
+    side_specs, side = _side_operands(seg_q, seg_k, seed, heads, block_q,
+                                      block_k, q_major=True)
+    with _x64_off():
+        return _pc(
+            kernel,
+            grid=(bh, n_q, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_k, d), kv_map),
+                pl.BlockSpec((1, block_k, d), kv_map),
+            ] + side_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
+            ],
+            out_shape=[
+                _sds((bh, s_q, d), q.dtype, q),
+                _sds((bh, 8, s_q), jnp.float32, q),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+            ],
+            compiler_params=_compiler_params(block_q, block_k, d),
+            interpret=interpret,
+            name="flash_fwd",
+        )(q, k, v, *side)
+
+
+def _statics(scale, causal, block_q, block_k, heads, dropout):
+    return dict(scale=float(scale), causal=bool(causal), block_q=block_q,
+                block_k=block_k, heads=heads, dropout=float(dropout),
+                interpret=_interpret())
+
+
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, seg_q=None,
                seg_k=None, heads=1, dropout=0.0, seed=None):
-    bh, s_q, d = q.shape
-    s_kv = k.shape[1]
-    n_q = s_q // block_q
-    n_kv = s_kv // block_k
-    seg = seg_q is not None
-    drop = dropout > 0.0
-    params = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, n_kv=n_kv, offset=s_kv - s_q,
-                  dropout=float(dropout))
-    kern_fn = {(False, False): _fwd_kernel,
-               (True, False): _fwd_kernel_seg,
-               (False, True): _fwd_kernel_drop,
-               (True, True): _fwd_kernel_seg_drop}[(seg, drop)]
-    kernel = functools.partial(kern_fn, **params)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-    ]
-    args = [q, k, v]
-    if seg:
-        # seg arrays are [batch, 8, s] (NOT replicated per head); the index
-        # map folds the head dim of the [b*h] grid axis away
-        h_ = heads
-        in_specs += [
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b // h_, 0, i)),
-            pl.BlockSpec((1, 8, block_k), lambda b, i, j: (b // h_, 0, j)),
-        ]
-        args += [seg_q, seg_k]
-    if drop:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.append(_seed_arg(seed))
-    with _x64_off():
-        out, lse = _pc(
-        kernel,
-        grid=(bh, n_q, n_kv),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-        ],
-        out_shape=[
-            _sds((bh, s_q, d), q.dtype, q),
-            _sds((bh, 8, s_q), jnp.float32, q),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(*args)
+    with jax.named_scope(SCOPE):
+        out, lse = _fwd_call(
+            q, k, v, seg_q, seg_k,
+            _seed_arg(seed) if dropout > 0.0 else None,
+            **_statics(scale, causal, block_q, block_k, heads, dropout))
     return out, lse[:, 0, :]
 
 
@@ -267,11 +427,16 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, seg_q=None,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                    block_q, block_k, n_q, offset,
-                    seg_q_ref=None, seg_k_ref=None, dropout=0.0,
-                    seed_ref=None):
+def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, n_q, offset,
+                    seg=False, dropout=0.0):
+    """One (batch-head, key block, query block), the tile TRANSPOSED: keys
+    down the sublanes, queries across the lanes, so that lse and delta
+    broadcast as the rows they are stored as and both accumulating
+    products (P^T dO, dS^T Q) are plain row-by-column ones. Operands go to
+    the MXU in their own type."""
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), seg_q_ref, \
+        seg_k_ref, seed_ref, rest = _unpack(refs, 6, seg, dropout)
+    dk_ref, dv_ref, dk_acc, dv_acc = rest
     bh = pl.program_id(0)
     j = pl.program_id(1)
     i = pl.program_id(2)
@@ -281,72 +446,61 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = True
-    if causal:
-        run = j * block_k <= (i + 1) * block_q - 1 + offset
-
-    @pl.when(run)
-    def _():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
+    def tile(cut):
+        q, do = q_ref[0], do_ref[0]
+        lse = lse_ref[0, 0:1, :]      # [1, block_q]
+        delta = delta_ref[0, 0:1, :]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            k_ref[0], q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * np.float32(scale)
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            cmask = q_pos + offset >= k_pos
-            s = jnp.where(cmask, s, NEG_INF)
+        mask = _tile_mask(cut, i, j, block_q, block_k, offset, seg_q_ref,
+                          seg_k_ref, transposed=True)
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse)
-        if causal:
-            p = jnp.where(cmask, p, np.float32(0.0))
-        if seg_q_ref is not None:
-            seg_m = seg_q_ref[0, 0][:, None] == seg_k_ref[0, 0][None, :]
+        if mask is not None and (seg or offset < 0):
             # mask p (not just s): fully-masked rows have lse == NEG_INF and
             # exp(s - lse) == 1, which would leak garbage into dk/dv
-            p = jnp.where(seg_m, p, np.float32(0.0))
+            p = jnp.where(mask, p, np.float32(0.0))
         # regenerate the forward's dropout tile: dv sees the DROPPED
         # normalized weights; the softmax-grad dot product folds into the
         # SAME delta = rowsum(do*o), so only dp gets masked in ds
         p_d = p
         dp_mask = None
         if dropout:
-            keep = _dropout_keep(seed_ref[0], bh, i, j,
-                                 block_q, block_k, dropout)
+            keep = _dropout_keep(seed_ref[0], bh, i, j, block_q, block_k,
+                                 dropout, transposed=True)
             inv = np.float32(1.0 / (1.0 - dropout))
             p_d = jnp.where(keep, p, np.float32(0.0)) * inv
             dp_mask = (keep, inv)
         # dv += p^T do
         dv_acc[:] += jax.lax.dot_general(
-            p_d, do, (((0,), (0,)), ((), ())),
+            p_d.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        # dp = do v^T ; ds = p * (dp - delta) * scale
+        # dp = do v^T ; ds = p * (dp - delta), the scale on the way out
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+            v_ref[0], do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if dp_mask is not None:
             dp = jnp.where(dp_mask[0], dp, np.float32(0.0)) * dp_mask[1]
-        ds = p * (dp - delta) * scale
+        ds = p * (dp - delta)
         dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _on_tiles(causal, i, j, block_q, block_k, offset, tile)
 
     @pl.when(i == n_q - 1)
     def _():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * np.float32(scale)).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, scale, causal, block_q, block_k, n_kv, offset,
-                   seg_q_ref=None, seg_k_ref=None, dropout=0.0,
-                   seed_ref=None):
+def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, n_kv, offset,
+                   seg=False, dropout=0.0):
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), seg_q_ref, \
+        seg_k_ref, seed_ref, rest = _unpack(refs, 6, seg, dropout)
+    dq_ref, dq_acc = rest
     bh = pl.program_id(0)
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -355,95 +509,38 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = True
-    if causal:
-        run = j * block_k <= (i + 1) * block_q - 1 + offset
-
-    @pl.when(run)
-    def _():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+    def tile(cut):
+        k = k_ref[0]
         lse = lse_ref[0, 0][:, None]
         delta = delta_ref[0, 0][:, None]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q_ref[0], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * np.float32(scale)
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            cmask = q_pos + offset >= k_pos
-            s = jnp.where(cmask, s, NEG_INF)
+        mask = _tile_mask(cut, i, j, block_q, block_k, offset, seg_q_ref,
+                          seg_k_ref)
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse)
-        if causal:
-            p = jnp.where(cmask, p, np.float32(0.0))
-        if seg_q_ref is not None:
-            seg_m = seg_q_ref[0, 0][:, None] == seg_k_ref[0, 0][None, :]
-            p = jnp.where(seg_m, p, np.float32(0.0))
+        if mask is not None and (seg or offset < 0):
+            p = jnp.where(mask, p, np.float32(0.0))
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if dropout:
             keep = _dropout_keep(seed_ref[0], bh, i, j,
                                  block_q, block_k, dropout)
             dp = jnp.where(keep, dp, np.float32(0.0)) * np.float32(
                 1.0 / (1.0 - dropout))
-        ds = p * (dp - delta) * scale
+        ds = p * (dp - delta)
         dq_acc[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _on_tiles(causal, i, j, block_q, block_k, offset, tile)
 
     @pl.when(j == n_kv - 1)
     def _():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel_seg(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        seg_q_ref, seg_k_ref, dk_ref, dv_ref, dk_acc,
-                        dv_acc, **params):
-    _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc,
-                    seg_q_ref=seg_q_ref, seg_k_ref=seg_k_ref, **params)
-
-
-def _bwd_dq_kernel_seg(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       seg_q_ref, seg_k_ref, dq_ref, dq_acc, **params):
-    _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, seg_q_ref=seg_q_ref, seg_k_ref=seg_k_ref,
-                   **params)
-
-
-def _bwd_dkv_kernel_drop(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         seed_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                         **params):
-    _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, seed_ref=seed_ref,
-                    **params)
-
-
-def _bwd_dq_kernel_drop(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        seed_ref, dq_ref, dq_acc, **params):
-    _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, seed_ref=seed_ref, **params)
-
-
-def _bwd_dkv_kernel_seg_drop(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                             delta_ref, seg_q_ref, seg_k_ref, seed_ref,
-                             dk_ref, dv_ref, dk_acc, dv_acc, **params):
-    _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, seg_q_ref=seg_q_ref,
-                    seg_k_ref=seg_k_ref, seed_ref=seed_ref, **params)
-
-
-def _bwd_dq_kernel_seg_drop(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                            delta_ref, seg_q_ref, seg_k_ref, seed_ref,
-                            dq_ref, dq_acc, **params):
-    _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, seg_q_ref=seg_q_ref, seg_k_ref=seg_k_ref,
-                   seed_ref=seed_ref, **params)
+        dq_ref[0] = (dq_acc[:] * np.float32(scale)).astype(dq_ref.dtype)
 
 
 def _bwd_delta(res, g, d_lse=None):
@@ -464,65 +561,92 @@ def _bwd_delta(res, g, d_lse=None):
     return do, lse8, delta8
 
 
+@_call
+def _dkv_call(q, k, v, do, lse8, delta8, seg_q, seg_k, seed, *, scale,
+              causal, block_q, block_k, heads, dropout, interpret):
+    bh, s_q, s_kv, d, n_q, n_kv, offset = _dims(q, k, block_q, block_k)
+    kernel = functools.partial(
+        _bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, n_q=n_q, offset=offset, seg=seg_q is not None,
+        dropout=dropout)
+    q_map = _q_index_map(causal, block_q, block_k, offset)
+    row_map = _q_index_map(causal, block_q, block_k, offset, rows=True)
+    side_specs, side = _side_operands(seg_q, seg_k, seed, heads, block_q,
+                                      block_k, q_major=False)
+    with _x64_off():
+        return _pc(
+            kernel,
+            grid=(bh, n_kv, n_q),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), q_map),
+                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, block_q, d), q_map),
+                pl.BlockSpec((1, 8, block_q), row_map),
+                pl.BlockSpec((1, 8, block_q), row_map),
+            ] + side_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            ],
+            out_shape=[
+                _sds((bh, s_kv, d), q.dtype, q),
+                _sds((bh, s_kv, d), q.dtype, q),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ],
+            compiler_params=_compiler_params(block_q, block_k, d),
+            interpret=interpret,
+            name="flash_bwd_dkv",
+        )(q, k, v, do, lse8, delta8, *side)
+
+
+@_call
+def _dq_call(q, k, v, do, lse8, delta8, seg_q, seg_k, seed, *, scale,
+             causal, block_q, block_k, heads, dropout, interpret):
+    bh, s_q, s_kv, d, n_q, n_kv, offset = _dims(q, k, block_q, block_k)
+    kernel = functools.partial(
+        _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, n_kv=n_kv, offset=offset, seg=seg_q is not None,
+        dropout=dropout)
+    kv_map = _kv_index_map(causal, block_q, block_k, offset)
+    side_specs, side = _side_operands(seg_q, seg_k, seed, heads, block_q,
+                                      block_k, q_major=True)
+    with _x64_off():
+        return _pc(
+            kernel,
+            grid=(bh, n_q, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_k, d), kv_map),
+                pl.BlockSpec((1, block_k, d), kv_map),
+                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
+                pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
+            ] + side_specs,
+            out_specs=pl.BlockSpec((1, block_q, d),
+                                   lambda b, i, j: (b, i, 0)),
+            out_shape=_sds((bh, s_q, d), q.dtype, q),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=_compiler_params(block_q, block_k, d),
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(q, k, v, do, lse8, delta8, *side)
+
+
 def _run_dkv_pass(q, k, v, do, lse8, delta8, scale, causal, block_q,
                   block_k, seg_q=None, seg_k=None, heads=1, dropout=0.0,
                   seed=None):
     """dkv backward pass: grid parallel over k blocks (contraction over q
     blocks innermost, accumulators in VMEM scratch) with its OWN
     block_q/block_k choice, independent of the dq pass."""
-    bh, s_q, d = q.shape
-    s_kv = k.shape[1]
-    n_q = s_q // block_q
-    n_kv = s_kv // block_k
-    seg = seg_q is not None
-    drop = dropout > 0.0
-    dkv_params = dict(scale=scale, causal=causal, block_q=block_q,
-                      block_k=block_k, n_q=n_q, offset=s_kv - s_q,
-                      dropout=float(dropout))
-    dkv_fn = {(False, False): _bwd_dkv_kernel,
-              (True, False): _bwd_dkv_kernel_seg,
-              (False, True): _bwd_dkv_kernel_drop,
-              (True, True): _bwd_dkv_kernel_seg_drop}[(seg, drop)]
-    dkv_kernel = functools.partial(dkv_fn, **dkv_params)
-    dkv_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-        pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i)),
-        pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i)),
-    ]
-    dkv_args = [q, k, v, do, lse8, delta8]
-    h_ = heads
-    if seg:
-        dkv_in_specs += [
-            pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b // h_, 0, i)),
-            pl.BlockSpec((1, 8, block_k), lambda b, j, i: (b // h_, 0, j)),
-        ]
-        dkv_args += [seg_q, seg_k]
-    if drop:
-        dkv_in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        dkv_args.append(_seed_arg(seed))
-    with _x64_off():
-        dk, dv = _pc(
-        dkv_kernel,
-        grid=(bh, n_kv, n_q),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            _sds((bh, s_kv, d), q.dtype, q),
-            _sds((bh, s_kv, d), q.dtype, q),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(*dkv_args)
-    return dk, dv
+    with jax.named_scope(SCOPE):
+        return _dkv_call(
+            q, k, v, do, lse8, delta8, seg_q, seg_k,
+            _seed_arg(seed) if dropout > 0.0 else None,
+            **_statics(scale, causal, block_q, block_k, heads, dropout))
 
 
 def _run_dq_pass(q, k, v, do, lse8, delta8, scale, causal, block_q,
@@ -530,50 +654,11 @@ def _run_dq_pass(q, k, v, do, lse8, delta8, scale, causal, block_q,
                  seed=None):
     """dq backward pass: grid parallel over q blocks (contraction over k
     blocks innermost) with its OWN block_q/block_k choice."""
-    bh, s_q, d = q.shape
-    s_kv = k.shape[1]
-    n_q = s_q // block_q
-    n_kv = s_kv // block_k
-    seg = seg_q is not None
-    drop = dropout > 0.0
-    h_ = heads
-    dq_params = dict(scale=scale, causal=causal, block_q=block_q,
-                     block_k=block_k, n_kv=n_kv, offset=s_kv - s_q,
-                     dropout=float(dropout))
-    dq_fn = {(False, False): _bwd_dq_kernel,
-             (True, False): _bwd_dq_kernel_seg,
-             (False, True): _bwd_dq_kernel_drop,
-             (True, True): _bwd_dq_kernel_seg_drop}[(seg, drop)]
-    dq_kernel = functools.partial(dq_fn, **dq_params)
-    dq_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-        pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-    ]
-    dq_args = [q, k, v, do, lse8, delta8]
-    if seg:
-        dq_in_specs += [
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b // h_, 0, i)),
-            pl.BlockSpec((1, 8, block_k), lambda b, i, j: (b // h_, 0, j)),
-        ]
-        dq_args += [seg_q, seg_k]
-    if drop:
-        dq_in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        dq_args.append(_seed_arg(seed))
-    with _x64_off():
-        dq = _pc(
-        dq_kernel,
-        grid=(bh, n_q, n_kv),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=_sds((bh, s_q, d), q.dtype, q),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret(),
-    )(*dq_args)
-    return dq
+    with jax.named_scope(SCOPE):
+        return _dq_call(
+            q, k, v, do, lse8, delta8, seg_q, seg_k,
+            _seed_arg(seed) if dropout > 0.0 else None,
+            **_statics(scale, causal, block_q, block_k, heads, dropout))
 
 
 def _flash_bwd_split(res, g, scale, causal, dq_blocks=(DEFAULT_BLOCK_Q,
@@ -585,7 +670,8 @@ def _flash_bwd_split(res, g, scale, causal, dq_blocks=(DEFAULT_BLOCK_Q,
     (block_q, block_k). Dropout regenerates the forward's threefry mask
     from GLOBAL (q, k) coordinates, so the mask is bit-identical
     regardless of either pass's block choice."""
-    do, lse8, delta8 = _bwd_delta(res, g, d_lse)
+    with jax.named_scope(SCOPE):
+        do, lse8, delta8 = _bwd_delta(res, g, d_lse)
     q, k, v = res[0], res[1], res[2]
     dk, dv = _run_dkv_pass(q, k, v, do, lse8, delta8, scale, causal,
                            dkv_blocks[0], dkv_blocks[1], seg_q=seg_q,
@@ -598,61 +684,82 @@ def _flash_bwd_split(res, g, scale, causal, dq_blocks=(DEFAULT_BLOCK_Q,
     return dq, dk, dv
 
 
-def _flash_bwd(res, g, scale, causal, block_q, block_k, **kw):
-    """The backward every custom VJP here takes: both passes at the
-    forward's blocks."""
-    return _flash_bwd_split(res, g, scale, causal,
-                            dq_blocks=(block_q, block_k),
-                            dkv_blocks=(block_q, block_k), **kw)
-
-
 # ---------------------------------------------------------------------------
 # public entry (custom VJP over [bh, s, d])
+#
+# `blocks` is what `_flash_tiling` answers: the (block_q, block_k) of the
+# forward, of the dK/dV pass and of the dQ pass.
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bhsd(q, k, v, scale, causal, block_q, block_k):
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_bhsd(q, k, v, scale, causal, blocks):
+    out, _ = _flash_fwd(q, k, v, scale, causal, *blocks[0])
     return out
 
 
-def _flash_bhsd_fwd(q, k, v, scale, causal, block_q, block_k):
-    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+def _flash_bhsd_fwd(q, k, v, scale, causal, blocks):
+    out, lse = _flash_fwd(q, k, v, scale, causal, *blocks[0])
     return out, (q, k, v, out, lse)
 
 
-# From this sequence length on attention takes the Pallas kernels: the
-# backward below (streamed passes against XLA's recompute grad, which
-# materializes the O(s^2) scores) and `use_flash` for the forward. Both are
-# taken from v5e readings that predate the ledger (the flash forward crossed
-# XLA's fused attention near 4,096; the streamed backward was the
-# memory-safe choice from there). No cell of the benchmark stands on either
-# side: `train-2k` trains at 2,048, under both (ROADMAP S3 decides them).
-_PALLAS_BWD_MIN_SEQ = 4096
-_PALLAS_FWD_MIN_SEQ = 4096
+# From this sequence length on attention takes the Pallas kernels: `use_flash`
+# for the call, and the backward below (streamed passes against XLA's
+# recompute grad, which materializes the O(s^2) scores). One layer's
+# attention over 8,192 tokens of 16 heads x 128 in bf16 on a v5e, ms, XLA
+# (`_sdpa_reference`) / the kernels at `_flash_tiling`'s large blocks (PERF.md
+# section 6, PR 34's two microbenches; a training call is the forward + the
+# forward recomputed under `jax.checkpoint` + the backward):
+#
+#   sequence                      1,024         2,048         4,096
+#   causal, training           6.05 / 3.73  10.46 / 5.46  19.82 / 8.00
+#   causal, forward alone      1.42 / 0.71   2.54 / 1.15   4.85 / 1.64
+#   not causal, training       5.92 / 4.23  10.34 / 6.59  19.51 / 11.19
+#   not causal, forward alone  1.42 / 0.88   2.54 / 1.36   4.78 / 2.29
+#
+# (1,024 queries on 4,096 keys, not causal: 10.77 / 6.87 and 2.79 / 1.31; one
+# row of 16 heads, causal: 2.90 / 1.42 at 2,048, a tie of 0.70 / 0.72 at
+# 1,024.) So at the large blocks every mode starts at 1,024, the shortest
+# length measured (512 was not, and stays XLA's). At 128 x 128 blocks XLA is
+# the faster at every length measured (bf16: 13.37 / 23.41 / 43.41 ms
+# training; float32 operands at [2, 4,096, 16, 128]: XLA 39.5, the kernels
+# 47.7), and such a shape takes the kernels from 4,096 on, as it did before
+# PR 34, for the memory: that float32 layer's temporaries are 4.52 GB
+# through XLA and 0.40 GB through the kernels (`memory_analysis()` on the
+# chip), and XLA's grow with s^2.
+_PALLAS_MIN_SEQ = 1024
+_PALLAS_SMALL_BLOCK_MIN_SEQ = 4096
 
 
-def use_flash(seq_q, seq_kv, head_dim, training, dropout=0.0):
+def _min_seq(blocks):
+    """The shortest query length at which a call at `blocks` takes the
+    kernels (tests replace it to force them at small shapes)."""
+    if (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K) in blocks:
+        return _PALLAS_SMALL_BLOCK_MIN_SEQ
+    return _PALLAS_MIN_SEQ
+
+
+def use_flash(seq_q, seq_kv, head_dim, dropout=0.0, dtype=jnp.bfloat16):
     """The one choice `scaled_dot_product_attention` asks for an unmasked
-    call: the flash kernel where it `supports` the shape at the default
-    blocks and the sequence reaches the threshold of its mode; with
-    dropout only under FLAGS_flash_dropout_kernel (ROADMAP D2 decides
-    the in-kernel dropout path)."""
+    call, training or not, causal or not: the flash kernel where it
+    `supports` the shape and the sequence reaches the threshold of the
+    blocks `_flash_tiling` answers; with dropout only under
+    FLAGS_flash_dropout_kernel (ROADMAP D2 decides the in-kernel dropout
+    path)."""
     from ..framework import config as _config
 
-    min_seq = _PALLAS_BWD_MIN_SEQ if training else _PALLAS_FWD_MIN_SEQ
-    return (supports(seq_q, seq_kv, head_dim) and seq_q >= min_seq
+    blocks = _flash_tiling(seq_q, seq_kv, head_dim, dtype)
+    return (supports(seq_q, seq_kv, head_dim)
+            and seq_q >= _min_seq(blocks)
             and (dropout == 0.0
                  or bool(_config.get_flag("FLAGS_flash_dropout_kernel",
                                           False))))
 
 
-def _bwd_use_xla(s_q):
-    """XLA recompute grad below the threshold, streamed Pallas kernels from
-    it on (tests monkeypatch the constant to force the streamed path at
-    small seq)."""
-    return s_q < _PALLAS_BWD_MIN_SEQ
+def _bwd_use_xla(s_q, blocks):
+    """XLA recompute grad below the threshold of the call's blocks,
+    streamed Pallas kernels from it on."""
+    return s_q < _min_seq(blocks)
 
 
 def _xla_ref_fwd(q_, k_, v_, scale, causal, seg_q=None, seg_k=None,
@@ -688,12 +795,10 @@ def _xla_ref_fwd(q_, k_, v_, scale, causal, seg_q=None, seg_k=None,
 
 def _xla_ref_bwd(res, g, scale, causal, seg_q=None, seg_k=None, heads=1,
                  d_lse=None):
-    """XLA-fused backward via recompute: at short sequence the O(s^2)
-    score matrix fits comfortably and XLA's fused softmax-grad beats the
-    streamed kernels; the Pallas backward takes over for long sequences
-    where s^2 memory is the binding constraint. The ONE reference
-    implementation also serves the lse-returning variant (d_lse is the lse
-    cotangent, zeros when the caller only differentiates the output)."""
+    """XLA-fused backward via recompute, below the blocks' `_min_seq`, where
+    the O(s^2) score matrix is small. The ONE reference implementation
+    also serves the lse-returning variant (d_lse is the lse cotangent,
+    zeros when the caller only differentiates the output)."""
     q, k, v, _, _ = res
 
     def ref(q_, k_, v_):
@@ -706,16 +811,17 @@ def _xla_ref_bwd(res, g, scale, causal, seg_q=None, seg_k=None, heads=1,
     return vjp((g, d_lse.astype(jnp.float32)))
 
 
-def _dispatch_bwd(res, g, scale, causal, block_q, block_k, d_lse=None):
-    """Backward of the plain (non-seg, non-dropout) path."""
-    if _bwd_use_xla(res[0].shape[1]):
-        return _xla_ref_bwd(res, g, scale, causal, d_lse=d_lse)
-    return _flash_bwd(res, g, scale, causal, block_q, block_k,
-                      d_lse=d_lse)
+def _dispatch_bwd(res, g, scale, causal, blocks, d_lse=None, **seg):
+    """Backward of the paths without dropout: `seg` is the segment ids'
+    (seg_q, seg_k, heads), or nothing."""
+    if _bwd_use_xla(res[0].shape[1], blocks):
+        return _xla_ref_bwd(res, g, scale, causal, d_lse=d_lse, **seg)
+    return _flash_bwd_split(res, g, scale, causal, dq_blocks=blocks[2],
+                            dkv_blocks=blocks[1], d_lse=d_lse, **seg)
 
 
-def _flash_bhsd_bwd(scale, causal, block_q, block_k, res, g):
-    return _dispatch_bwd(res, g, scale, causal, block_q, block_k)
+def _flash_bhsd_bwd(scale, causal, blocks, res, g):
+    return _dispatch_bwd(res, g, scale, causal, blocks)
 
 
 _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
@@ -726,31 +832,24 @@ _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
 # four kernels (fwd, dkv, dq, and the short-seq XLA fallback backward)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash_bhsd_seg(q, k, v, seg_q8, seg_k8, scale, causal, block_q,
-                    block_k, heads):
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_bhsd_seg(q, k, v, seg_q8, seg_k8, scale, causal, blocks, heads):
+    out, _ = _flash_fwd(q, k, v, scale, causal, *blocks[0],
                         seg_q=seg_q8, seg_k=seg_k8, heads=heads)
     return out
 
 
-def _flash_bhsd_seg_fwd(q, k, v, seg_q8, seg_k8, scale, causal, block_q,
-                        block_k, heads):
-    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
+def _flash_bhsd_seg_fwd(q, k, v, seg_q8, seg_k8, scale, causal, blocks,
+                        heads):
+    out, lse = _flash_fwd(q, k, v, scale, causal, *blocks[0],
                           seg_q=seg_q8, seg_k=seg_k8, heads=heads)
     return out, (q, k, v, out, lse, seg_q8, seg_k8)
 
 
-def _flash_bhsd_seg_bwd(scale, causal, block_q, block_k, heads, res, g):
-    q, k, v, out, lse, seg_q8, seg_k8 = res
-    s_q = q.shape[1]
-    if _bwd_use_xla(s_q):
-        dq, dk, dv = _xla_ref_bwd((q, k, v, out, lse), g, scale, causal,
-                                  seg_q=seg_q8, seg_k=seg_k8, heads=heads)
-    else:
-        dq, dk, dv = _flash_bwd((q, k, v, out, lse), g, scale, causal,
-                                block_q, block_k, seg_q=seg_q8,
-                                seg_k=seg_k8, heads=heads)
+def _flash_bhsd_seg_bwd(scale, causal, blocks, heads, res, g):
+    *res, seg_q8, seg_k8 = res
+    dq, dk, dv = _dispatch_bwd(tuple(res), g, scale, causal, blocks,
+                               seg_q=seg_q8, seg_k=seg_k8, heads=heads)
     return dq, dk, dv, None, None
 
 
@@ -762,54 +861,53 @@ _flash_bhsd_seg.defvjp(_flash_bhsd_seg_fwd, _flash_bhsd_seg_bwd)
 # short-seq fallback cannot do.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_bhsd_drop(q, k, v, seed, scale, causal, block_q, block_k,
-                     dropout):
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_bhsd_drop(q, k, v, seed, scale, causal, blocks, dropout):
+    out, _ = _flash_fwd(q, k, v, scale, causal, *blocks[0],
                         dropout=dropout, seed=seed)
     return out
 
 
-def _flash_bhsd_drop_fwd(q, k, v, seed, scale, causal, block_q, block_k,
-                         dropout):
-    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
+def _flash_bhsd_drop_fwd(q, k, v, seed, scale, causal, blocks, dropout):
+    out, lse = _flash_fwd(q, k, v, scale, causal, *blocks[0],
                           dropout=dropout, seed=seed)
     return out, (q, k, v, out, lse, seed)
 
 
-def _flash_bhsd_drop_bwd(scale, causal, block_q, block_k, dropout, res, g):
-    q, k, v, out, lse, seed = res
-    dq, dk, dv = _flash_bwd((q, k, v, out, lse), g, scale, causal, block_q,
-                            block_k, dropout=dropout, seed=seed)
+def _flash_bhsd_drop_bwd(scale, causal, blocks, dropout, res, g):
+    *res, seed = res
+    dq, dk, dv = _flash_bwd_split(
+        tuple(res), g, scale, causal, dq_blocks=blocks[2],
+        dkv_blocks=blocks[1], dropout=dropout, seed=seed)
     return dq, dk, dv, None
 
 
 _flash_bhsd_drop.defvjp(_flash_bhsd_drop_fwd, _flash_bhsd_drop_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def _flash_bhsd_seg_drop(q, k, v, seg_q8, seg_k8, seed, scale, causal,
-                         block_q, block_k, heads, dropout):
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
+                         blocks, heads, dropout):
+    out, _ = _flash_fwd(q, k, v, scale, causal, *blocks[0],
                         seg_q=seg_q8, seg_k=seg_k8, heads=heads,
                         dropout=dropout, seed=seed)
     return out
 
 
 def _flash_bhsd_seg_drop_fwd(q, k, v, seg_q8, seg_k8, seed, scale, causal,
-                             block_q, block_k, heads, dropout):
-    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
+                             blocks, heads, dropout):
+    out, lse = _flash_fwd(q, k, v, scale, causal, *blocks[0],
                           seg_q=seg_q8, seg_k=seg_k8, heads=heads,
                           dropout=dropout, seed=seed)
     return out, (q, k, v, out, lse, seg_q8, seg_k8, seed)
 
 
-def _flash_bhsd_seg_drop_bwd(scale, causal, block_q, block_k, heads,
-                             dropout, res, g):
-    q, k, v, out, lse, seg_q8, seg_k8, seed = res
-    dq, dk, dv = _flash_bwd((q, k, v, out, lse), g, scale, causal, block_q,
-                            block_k, seg_q=seg_q8, seg_k=seg_k8,
-                            heads=heads, dropout=dropout, seed=seed)
+def _flash_bhsd_seg_drop_bwd(scale, causal, blocks, heads, dropout, res, g):
+    *res, seg_q8, seg_k8, seed = res
+    dq, dk, dv = _flash_bwd_split(
+        tuple(res), g, scale, causal, dq_blocks=blocks[2],
+        dkv_blocks=blocks[1], seg_q=seg_q8, seg_k=seg_k8, heads=heads,
+        dropout=dropout, seed=seed)
     return dq, dk, dv, None, None, None
 
 
@@ -817,52 +915,69 @@ _flash_bhsd_seg_drop.defvjp(_flash_bhsd_seg_drop_fwd,
                             _flash_bhsd_seg_drop_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bhsd_lse(q, k, v, scale, causal, block_q, block_k):
-    return _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_bhsd_lse(q, k, v, scale, causal, blocks):
+    return _flash_fwd(q, k, v, scale, causal, *blocks[0])
 
 
-def _flash_bhsd_lse_fwd(q, k, v, scale, causal, block_q, block_k):
-    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+def _flash_bhsd_lse_fwd(q, k, v, scale, causal, blocks):
+    out, lse = _flash_fwd(q, k, v, scale, causal, *blocks[0])
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_bhsd_lse_bwd(scale, causal, block_q, block_k, res, g):
+def _flash_bhsd_lse_bwd(scale, causal, blocks, res, g):
     g_out, g_lse = g
-    q, k, v, out, lse = res
-    return _dispatch_bwd((q, k, v, out, lse), g_out, scale, causal,
-                         block_q, block_k, d_lse=g_lse)
+    return _dispatch_bwd(res, g_out, scale, causal, blocks, d_lse=g_lse)
 
 
 _flash_bhsd_lse.defvjp(_flash_bhsd_lse_fwd, _flash_bhsd_lse_bwd)
 
 
+def _blocks_for(q, k, block_q, block_k):
+    """The three passes' blocks of a call over q / k [b, s, h, d]: the
+    caller's at all three where it names them (a varlen call pads to its
+    own), `_flash_tiling`'s otherwise; ValueError where they do not tile
+    the shape."""
+    s_q, d, s_kv = q.shape[1], q.shape[3], k.shape[1]
+    if block_q is None and block_k is None:
+        blocks = _flash_tiling(s_q, s_kv, d, q.dtype)
+    else:
+        blocks = ((block_q or DEFAULT_BLOCK_Q,
+                   block_k or DEFAULT_BLOCK_K),) * 3
+    if not all(supports(s_q, s_kv, d, bq, bk) for bq, bk in blocks):
+        raise ValueError(
+            f"flash_attention: unsupported shape seq_q={s_q} seq_kv={s_kv} "
+            f"d={d} (need multiples of {blocks[0][0]}/{blocks[0][1]}/128)")
+    return blocks
+
+
+def _to_bhsd(x):
+    b, s, h, d = x.shape
+    return jnp.swapaxes(x, 1, 2).reshape(b * h, s, d)
+
+
 def flash_attention_with_lse_bshd(q, k, v, causal=False, scale=None,
-                                  block_q=DEFAULT_BLOCK_Q,
-                                  block_k=DEFAULT_BLOCK_K):
+                                  block_q=None, block_k=None):
     """Like flash_attention_bshd but also returns the row logsumexp
     ([b, h, s_q], f32) — the merge statistic ring attention accumulates
     across KV blocks. Both outputs are differentiable (the lse cotangent
     folds into the flash backward's delta term)."""
     b, s_q, h, d = q.shape
-    s_kv = k.shape[1]
-    if not supports(s_q, s_kv, d, block_q, block_k):
-        raise ValueError(
-            f"flash_attention: unsupported shape seq_q={s_q} seq_kv={s_kv} "
-            f"d={d} (need multiples of {block_q}/{block_k}/128)")
+    blocks = _blocks_for(q, k, block_q, block_k)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    qt = jnp.swapaxes(q, 1, 2).reshape(b * h, s_q, d)
-    kt = jnp.swapaxes(k, 1, 2).reshape(b * h, s_kv, d)
-    vt = jnp.swapaxes(v, 1, 2).reshape(b * h, s_kv, d)
-    out, lse = _flash_bhsd_lse(qt, kt, vt, float(scale), bool(causal),
-                               block_q, block_k)
+    out, lse = _flash_bhsd_lse(_to_bhsd(q), _to_bhsd(k), _to_bhsd(v),
+                               float(scale), bool(causal), blocks)
     return (jnp.swapaxes(out.reshape(b, h, s_q, d), 1, 2),
             lse.reshape(b, h, s_q))
 
 
-def supports(seq_q, seq_kv, head_dim, block_q=DEFAULT_BLOCK_Q,
-             block_k=DEFAULT_BLOCK_K):
+def supports(seq_q, seq_kv, head_dim, block_q=None, block_k=None):
+    """The kernels take the shape: at the caller's blocks where it names
+    them, else at the smallest (`_flash_tiling` answers those for a length
+    its larger ones do not divide)."""
+    block_q = block_q or DEFAULT_BLOCK_Q
+    block_k = block_k or DEFAULT_BLOCK_K
     return (seq_q % block_q == 0 and seq_kv % block_k == 0
             and head_dim % 128 == 0 and seq_q >= block_q
             and seq_kv >= block_k)
@@ -876,10 +991,13 @@ def _seg8(seg, b, s):
 
 
 def flash_attention_bshd(q, k, v, causal=False, scale=None,
-                         block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                         block_q=None, block_k=None,
                          segment_ids_q=None, segment_ids_k=None,
                          dropout=0.0, dropout_seed=None):
     """q/k/v: [batch, seq, heads, head_dim] (paddle layout) -> same shape.
+
+    The blocks of the three passes come from `_flash_tiling` (the shape and
+    the type alone); `block_q` / `block_k` name them for all three.
 
     segment_ids_q/k ([batch, seq] int32) activate varlen masking: tokens
     attend only within equal segment ids (the packed-sequence contract of
@@ -895,37 +1013,29 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None,
     """
     b, s_q, h, d = q.shape
     s_kv = k.shape[1]
-    if not supports(s_q, s_kv, d, block_q, block_k):
-        raise ValueError(
-            f"flash_attention: unsupported shape seq_q={s_q} seq_kv={s_kv} "
-            f"d={d} (need multiples of {block_q}/{block_k}/128)"
-        )
+    blocks = _blocks_for(q, k, block_q, block_k)
     if dropout and dropout_seed is None:
         raise ValueError("flash_attention: dropout requires dropout_seed")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    # bshd -> (b*h, s, d)
-    qt = jnp.swapaxes(q, 1, 2).reshape(b * h, s_q, d)
-    kt = jnp.swapaxes(k, 1, 2).reshape(b * h, s_kv, d)
-    vt = jnp.swapaxes(v, 1, 2).reshape(b * h, s_kv, d)
+    qt, kt, vt = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
     if segment_ids_q is not None:
         sq8 = _seg8(segment_ids_q, b, s_q)
         sk8 = _seg8(segment_ids_k, b, s_kv)
         if dropout:
             out = _flash_bhsd_seg_drop(qt, kt, vt, sq8, sk8,
                                        _seed_arg(dropout_seed),
-                                       float(scale), bool(causal), block_q,
-                                       block_k, h, float(dropout))
+                                       float(scale), bool(causal), blocks,
+                                       h, float(dropout))
         else:
             out = _flash_bhsd_seg(qt, kt, vt, sq8, sk8, float(scale),
-                                  bool(causal), block_q, block_k, h)
+                                  bool(causal), blocks, h)
     elif dropout:
         out = _flash_bhsd_drop(qt, kt, vt, _seed_arg(dropout_seed),
-                               float(scale), bool(causal), block_q,
-                               block_k, float(dropout))
+                               float(scale), bool(causal), blocks,
+                               float(dropout))
     else:
-        out = _flash_bhsd(qt, kt, vt, float(scale), bool(causal), block_q,
-                          block_k)
+        out = _flash_bhsd(qt, kt, vt, float(scale), bool(causal), blocks)
     return jnp.swapaxes(out.reshape(b, h, s_q, d), 1, 2)
 
 

@@ -63,7 +63,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
         qa = as_array(query)
         if fa.use_flash(qa.shape[1], as_array(key).shape[1], qa.shape[3],
-                        training, eff_dropout):
+                        eff_dropout, qa.dtype):
             def f(q, k, v):
                 if eff_dropout > 0.0:
                     # in-kernel threefry dropout; a fresh per-step

@@ -74,8 +74,10 @@ from . import metrics as _metrics
 # what the models call their parts in `op_name` (HLO metadata only, the
 # compiled programs do not change). Finer names nest under these:
 # `attn/kv_write` (the cache write), `attn/latent` (scores, softmax and
-# weighted sum over latent pages), `mlp/router`, `mlp/experts`, `mlp/shared`
-# (an expert layer's parts), `head/sample` (the sampler).
+# weighted sum over latent pages), `attn/flash` (the flash-attention kernels
+# of a training step, forward and backward: `kernels/flash_attention.SCOPE`),
+# `mlp/router`, `mlp/experts`, `mlp/shared` (an expert layer's parts),
+# `head/sample` (the sampler).
 SCOPES = ("embed", "attn", "mlp", "head", "optimizer")
 
 # span record ring entry: (ph, name, t0, t1, tid, trace_id, attrs)

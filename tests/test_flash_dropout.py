@@ -136,7 +136,7 @@ class TestDropoutBackward:
         import paddle_tpu.nn.functional as F
         from paddle_tpu.framework import config as _config
 
-        monkeypatch.setattr(fa, "_PALLAS_BWD_MIN_SEQ", 0)
+        monkeypatch.setattr(fa, "_min_seq", lambda blocks: 0)
         # the in-kernel dropout route is opt-in (default off) until
         # validated under real Mosaic — ADVICE.md round-5 policy
         monkeypatch.setattr(

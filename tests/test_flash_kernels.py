@@ -56,7 +56,7 @@ class TestFlashBackward:
     @pytest.mark.parametrize("causal", [False, True])
     def test_pallas_bwd_matches_dense_autodiff(self, causal, monkeypatch):
         # force the hand-written Pallas backward (not the XLA fallback)
-        monkeypatch.setattr(fa, "_PALLAS_BWD_MIN_SEQ", 0)
+        monkeypatch.setattr(fa, "_min_seq", lambda blocks: 0)
         b, s, h, d = 1, 256, 2, 128
         q, k, v = (_rand((b, s, h, d), i + 10) for i in range(3))
         do = _rand((b, s, h, d), 99)
@@ -102,7 +102,7 @@ class TestVarlen:
             np.testing.assert_allclose(out[sl], ref, atol=2e-3, rtol=2e-3)
 
     def test_varlen_causal_fwd_and_grad(self, monkeypatch):
-        monkeypatch.setattr(fa, "_PALLAS_BWD_MIN_SEQ", 0)
+        monkeypatch.setattr(fa, "_min_seq", lambda blocks: 0)
         h, d = 1, 128
         lens = [120, 136]
         total = sum(lens)
@@ -187,7 +187,7 @@ class TestMaskedRowEdgeCases:
         np.testing.assert_array_equal(np.asarray(gv), 0.0)
 
     def test_fully_masked_rows_pallas_bwd(self, monkeypatch):
-        monkeypatch.setattr(fa, "_PALLAS_BWD_MIN_SEQ", 0)
+        monkeypatch.setattr(fa, "_min_seq", lambda blocks: 0)
         self.test_fully_masked_rows_emit_zero()
 
     def test_causal_mismatched_packing_rejected(self):
@@ -247,7 +247,7 @@ class TestLseVariant:
         lse cotangent folds into delta on BOTH backward branches (the
         Pallas d_lse path is forced via the threshold monkeypatch)."""
         if force_pallas_bwd:
-            monkeypatch.setattr(fa, "_PALLAS_BWD_MIN_SEQ", 0)
+            monkeypatch.setattr(fa, "_min_seq", lambda blocks: 0)
         b, s, h, d = 1, 256, 2, 128
         q, k, v = (_rand((b, s, h, d), i + 60) for i in range(3))
         do = _rand((b, s, h, d), 61)

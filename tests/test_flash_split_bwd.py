@@ -1,8 +1,8 @@
 """Split dq/dkv flash-attention backward (ISSUE 2 tentpole, second half).
 
 The backward is two Pallas passes, dq and dkv, with INDEPENDENT block
-choices (kernels/flash_attention.py `_flash_bwd_split`; `_flash_bwd`, what
-every custom VJP takes, is both passes at the forward's blocks). Acceptance:
+choices (kernels/flash_attention.py `_flash_bwd_split`, which every custom
+VJP takes at the blocks `_flash_tiling` answers for its shape). Acceptance:
 grad-check against the XLA recompute vjp to <= 1e-3 rel error in
 interpret mode across causal / GQA / dropout variants, matching the
 rigor of tests/test_flash_dropout.py (finite differences for the dropout
@@ -90,16 +90,19 @@ class TestSplitVsXlaVjp:
         np.testing.assert_array_equal(np.asarray(dk), np.asarray(dk2))
         np.testing.assert_array_equal(np.asarray(dv), np.asarray(dv2))
 
-    def test_split_equals_fused_at_shared_blocks(self):
-        """With both passes at the caller's shared blocks the split path
-        IS the legacy fused pair — bit-identical."""
+    def test_the_custom_vjp_is_the_split_at_its_blocks(self, monkeypatch):
+        """What every custom VJP takes IS the split pair at the blocks it
+        was handed (forward's, dK/dV pass's, dQ pass's) — bit-identical."""
+        monkeypatch.setattr(fa, "_min_seq", lambda blocks: 0)
         b, s, h, d = 1, 256, 2, 128
         res, g, scale = _make_res(b, s, h, d, True)
-        fused = fa._flash_bwd(res, g, scale, True, 128, 128)
+        blocks = ((128, 128), (256, 128), (128, 256))
+        _, vjp = jax.vjp(lambda q, k, v: fa._flash_bhsd(
+            q, k, v, scale, True, blocks), *res[:3])
         split = fa._flash_bwd_split(res, g, scale, True,
-                                    dq_blocks=(128, 128),
-                                    dkv_blocks=(128, 128))
-        for a, b_ in zip(fused, split):
+                                    dq_blocks=(128, 256),
+                                    dkv_blocks=(256, 128))
+        for a, b_ in zip(vjp(g), split):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
 
     def test_rectangular_seq_kv(self):
@@ -188,8 +191,8 @@ class TestSplitDropout:
         for the same seed."""
         b, s, h, d = 1, 256, 2, 128
         res, g, scale = _make_res(b, s, h, d, True)
-        fused = fa._flash_bwd(res, g, scale, True, 128, 128,
-                              dropout=0.3, seed=7)
+        fused = fa._flash_bwd_split(res, g, scale, True, dropout=0.3,
+                                    seed=7)
         split = fa._flash_bwd_split(res, g, scale, True,
                                     dq_blocks=(256, 128),
                                     dkv_blocks=(128, 256),
@@ -221,3 +224,101 @@ class TestSegmentedSplit:
                                   seg_q=seg8, seg_k=seg8, heads=h)
         for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
             assert _rel_err(a, b_) <= 1e-3, name
+
+
+# ---------------------------------------------------------------------------
+# the blocks `_flash_tiling` answers (PR 34): the cell's 512-wide ones at a
+# length they divide, bf16 and float32 operands
+# ---------------------------------------------------------------------------
+
+
+def _sdpa32(q, k, v, causal):
+    from paddle_tpu.nn.functional.attention import _sdpa_reference
+    return _sdpa_reference(*(x.astype(jnp.float32) for x in (q, k, v)),
+                           causal=causal)
+
+
+class TestChosenBlocks:
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bf16", "f32"])
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("s_q,s_kv", [(1024, 1024), (512, 1024)])
+    def test_fwd_and_grads_match_sdpa_reference(self, monkeypatch, dtype,
+                                                causal, s_q, s_kv):
+        """Operands go to the MXU in their own type; scores, softmax state
+        and accumulators are float32. Against `_sdpa_reference` over the
+        same (rounded) operands in float32: float32 operands to 2e-3 of the
+        largest value as the split passes above, bf16 to 2e-2 (outputs,
+        probabilities and dS are rounded to 8 bits of mantissa once)."""
+        monkeypatch.setattr(fa, "_min_seq", lambda blocks: 0)
+        b, h, d = 1, 2, 128
+        # bf16 takes the blocks the shape answers; float32 operands, which
+        # `_flash_tiling` keeps at 128 x 128, are NAMED 512 x 512 ones so
+        # that the large tiles meet both types
+        named = {}
+        if dtype == jnp.float32:
+            assert fa._flash_tiling(s_q, s_kv, d, dtype) == ((128, 128),) * 3
+            named = dict(block_q=512, block_k=512)
+        else:
+            blocks = fa._flash_tiling(s_q, s_kv, d, dtype)
+            assert min(min(pair) for pair in blocks) >= 512
+        q = _rand((b, s_q, h, d), 0).astype(dtype)
+        k = _rand((b, s_kv, h, d), 1).astype(dtype)
+        v = _rand((b, s_kv, h, d), 2).astype(dtype)
+        g = _rand((b, s_q, h, d), 3)
+
+        def run(attn):
+            out, vjp = jax.vjp(lambda *a: attn(*a).astype(jnp.float32),
+                               q, k, v)
+            return (out,) + vjp(g)
+
+        got = run(lambda q_, k_, v_: fa.flash_attention_bshd(
+            q_, k_, v_, causal=causal, **named))
+        want = run(lambda q_, k_, v_: _sdpa32(q_, k_, v_, causal))
+        tol = 2e-3 if dtype == jnp.float32 else 2e-2
+        for name, a, b_ in zip(("out", "dq", "dk", "dv"), got, want):
+            assert a.shape == b_.shape
+            assert _rel_err(a, b_) <= tol, (name, _rel_err(a, b_))
+
+
+GRIDS = [  # (s_q, s_kv, block_q, block_k)
+    (2048, 2048, 512, 512), (2048, 2048, 1024, 512),
+    (2048, 2048, 256, 1024), (2048, 2048, 128, 128),
+    (512, 1024, 256, 512), (1024, 512, 256, 256)]
+
+
+@pytest.mark.parametrize("s_q,s_kv,bq,bk", GRIDS)
+def test_a_future_block_is_never_fetched(s_q, s_kv, bq, bk):
+    """The clamped index maps over the whole grid: a step whose tile lies
+    in the causal future names the block of the nearest step that runs, so
+    the pipeline copies nothing for it; a step that runs names its own."""
+    offset = s_kv - s_q
+    n_q, n_kv = s_q // bq, s_kv // bk
+
+    def seen(i, j):
+        return j * bk <= (i + 1) * bq - 1 + offset
+
+    kv_map = fa._kv_index_map(True, bq, bk, offset)
+    q_map = fa._q_index_map(True, bq, bk, offset)
+    row_map = fa._q_index_map(True, bq, bk, offset, rows=True)
+    fetched_kv, fetched_q = set(), set()
+    for i in range(n_q):
+        for j in range(n_kv):
+            _, jj, _ = (int(x) for x in kv_map(0, i, j))
+            _, ii, _ = (int(x) for x in q_map(0, j, i))
+            assert int(row_map(0, j, i)[2]) == ii
+            if seen(i, j):
+                assert (ii, jj) == (i, j)
+            # a row of queries that sees no key at all (s_q > s_kv) names
+            # block 0 and skips every step
+            assert seen(i, jj) or (jj == 0 and not seen(i, 0))
+            assert seen(ii, j)
+            fetched_kv.add((i, jj))
+            fetched_q.add((ii, j))
+    visible = {(i, j) for i in range(n_q) for j in range(n_kv) if seen(i, j)}
+    assert fetched_q == visible
+    assert fetched_kv - visible == {(i, 0) for i in range(n_q)
+                                    if not seen(i, 0)}
+    # and without a mask every block is its own
+    assert tuple(fa._kv_index_map(False, bq, bk, offset)(3, 1, 2)) \
+        == (3, 2, 0)

@@ -7,8 +7,8 @@ traced under `jax.eval_shape`.
 
 The rows hold the four benchmark cells' own shapes and both sides of every
 boundary the choice has (`paged_attention._KERNEL_MIN_PAGE`,
-`_XLA_DECODE_MAX_CTX`, `flash_attention._PALLAS_FWD_MIN_SEQ` / `GQA_MIN_SEQ` /
-`_PALLAS_BWD_MIN_SEQ`, `expert_hit._HIT_MAX_TOKENS`,
+`_XLA_DECODE_MAX_CTX`, `flash_attention._PALLAS_MIN_SEQ` /
+`_PALLAS_SMALL_BLOCK_MIN_SEQ` / `GQA_MIN_SEQ`, `expert_hit._HIT_MAX_TOKENS`,
 `expert_grouped._GROUPED_MIN_TOKENS`, the kernels' `supports`)."""
 import importlib
 
@@ -381,17 +381,28 @@ def test_the_expert_choice_reads_no_flag(monkeypatch):
 # scaled_dot_product_attention: flash_attention.use_flash
 # ---------------------------------------------------------------------------
 
-# (batch, s_q, s_kv, heads, head_dim, training, dropout_p, mask) -> traced
+# (batch, s_q, s_kv, heads, head_dim, training, dropout_p, mask) -> traced;
+# a ninth entry is the operands' type where it is not bf16
 SDPA = {
     # gpt3-1.3b-l12.train-2k: 4 x 2,048 tokens, 16 heads of 128, training
-    "train-2k": ((4, 2048, 2048, 16, 128, True, 0.0, False), XLA),
-    "train-3968": ((1, 3968, 3968, 2, 128, True, 0.0, False), XLA),
-    "train-4095": ((1, 4095, 4095, 2, 128, True, 0.0, False), XLA),
-    "train-4096": ((1, 4096, 4096, 2, 128, True, 0.0, False), FLASH),
-    "eval-3968": ((1, 3968, 3968, 2, 128, False, 0.0, False), XLA),
-    "eval-4095": ((1, 4095, 4095, 2, 128, False, 0.0, False), XLA),
-    "eval-4096": ((1, 4096, 4096, 2, 128, False, 0.0, False), FLASH),
+    "train-2k": ((4, 2048, 2048, 16, 128, True, 0.0, False), FLASH),
+    # both sides of the crossover at `_flash_tiling`'s large blocks (PR 34's
+    # microbenches: 1,024 is the shortest length measured, training and not,
+    # causal and not)
+    "train-512": ((1, 512, 512, 2, 128, True, 0.0, False), XLA),
+    "train-1023": ((1, 1023, 1023, 2, 128, True, 0.0, False), XLA),
+    "train-1024": ((1, 1024, 1024, 2, 128, True, 0.0, False), FLASH),
+    "eval-512": ((1, 512, 512, 2, 128, False, 0.0, False), XLA),
+    "eval-1023": ((1, 1023, 1023, 2, 128, False, 0.0, False), XLA),
+    "eval-1024": ((1, 1024, 1024, 2, 128, False, 0.0, False), FLASH),
     "eval-8192": ((1, 8192, 8192, 2, 128, False, 0.0, False), FLASH),
+    # where only 128 x 128 blocks serve (a length 256 x 512 does not divide,
+    # float32 operands) the kernels start at 4,096, as before PR 34
+    "train-2176": ((1, 2176, 2176, 2, 128, True, 0.0, False), XLA),
+    "train-4224": ((1, 4224, 4224, 2, 128, True, 0.0, False), FLASH),
+    "train-2k-f32": ((1, 2048, 2048, 2, 128, True, 0.0, False, F32), XLA),
+    "eval-4096-f32": ((1, 4096, 4096, 2, 128, False, 0.0, False, F32),
+                      FLASH),
     # fa.supports false: a head Mosaic cannot tile, a ragged key length
     "head-dim-64": ((1, 4096, 4096, 2, 64, True, 0.0, False), XLA),
     "kv-4100": ((1, 4096, 4100, 2, 128, False, 0.0, False), XLA),
@@ -403,8 +414,9 @@ SDPA = {
 }
 
 
-def _sdpa_traced(monkeypatch, row):
-    b, s_q, s_kv, h, d, training, dropout_p, masked = row
+def _sdpa_traced(monkeypatch, row, causal=True):
+    b, s_q, s_kv, h, d, training, dropout_p, masked = row[:8]
+    dtype = row[8] if len(row) > 8 else BF16
     taken = []
     record(monkeypatch, taken, fa, FLASH, lambda q, *a: q)
     record(monkeypatch, taken, sdpa_mod, XLA, lambda q, *a: q)
@@ -413,11 +425,12 @@ def _sdpa_traced(monkeypatch, row):
         return as_array(sdpa_mod.scaled_dot_product_attention(
             Tensor(q), Tensor(k), Tensor(v),
             attn_mask=Tensor(mask[0]) if mask else None,
-            dropout_p=dropout_p, is_causal=not masked, training=training))
+            dropout_p=dropout_p, is_causal=causal and not masked,
+            training=training))
 
-    kv = S((b, s_kv, h, d), BF16)
+    kv = S((b, s_kv, h, d), dtype)
     mask = (S((1, 1, s_q, s_kv), jnp.bool_),) if masked else ()
-    jax.eval_shape(call, S((b, s_q, h, d), BF16), kv, kv, *mask)
+    jax.eval_shape(call, S((b, s_q, h, d), dtype), kv, kv, *mask)
     return taken
 
 
@@ -427,14 +440,63 @@ def test_sdpa_choice(monkeypatch, row):
     assert _sdpa_traced(monkeypatch, args) == [want]
 
 
+@pytest.mark.parametrize("row", ["train-2k", "train-1023", "train-1024",
+                                 "eval-1024", "train-2176", "eval-4096-f32"])
+def test_sdpa_choice_is_the_same_without_the_causal_mask(monkeypatch, row):
+    """The non-causal call was measured too (encoders, cross-attention, a
+    ring's blocks): the kernels lead from 1,024 on there as well, so the
+    choice does not read `is_causal`."""
+    args, want = SDPA[row]
+    assert _sdpa_traced(monkeypatch, args, causal=False) == [want]
+
+
 @pytest.mark.parametrize("flag,value,row,want", [
     ("FLAGS_flash_dropout_kernel", True, "train-4096-dropout", FLASH),
-    ("FLAGS_use_pallas_kernels", False, "train-4096", XLA),
+    ("FLAGS_use_pallas_kernels", False, "train-2k", XLA),
     ("FLAGS_use_pallas_kernels", False, "eval-8192", XLA)])
 def test_sdpa_choice_under_the_two_flags_left(monkeypatch, flag, value, row,
                                               want):
     monkeypatch.setattr(_config._FLAGS[flag], "value", value)
     assert _sdpa_traced(monkeypatch, SDPA[row][0]) == [want]
+
+
+# (s_q, s_kv, head_dim, dtype) -> the blocks of the forward, the dK/dV pass
+# and the dQ pass: `flash_attention._flash_tiling`, a row a shape class
+LARGE = ((1024, 1024), (512, 512), (1024, 1024))
+SMALL = ((128, 128),) * 3
+TILING = {
+    "train-2k": ((2048, 2048, 128, BF16), LARGE),
+    "long-8192": ((8192, 8192, 128, BF16), LARGE),
+    "fp16-2048": ((2048, 2048, 128, jnp.float16), LARGE),
+    # the measured blocks halved to the largest that divide the lengths
+    "halved-1536": ((1536, 1536, 128, BF16), ((512, 512),) * 3),
+    "halved-1280": ((1280, 1536, 128, BF16), ((256, 512),) * 3),
+    # cross-attention: the query and the key side fit their own length
+    "cross-512x1024": ((512, 1024, 128, BF16),
+                       ((512, 1024), (512, 512), (512, 1024))),
+    "cross-4096x512": ((4096, 512, 128, BF16),
+                       ((1024, 512), (512, 512), (1024, 512))),
+    # a length that 256 queries x 512 keys do not divide: today's blocks
+    "ragged-2176": ((2176, 2176, 128, BF16), SMALL),
+    "ragged-keys-2304": ((2048, 2304, 128, BF16), SMALL),
+    "short-128": ((128, 128, 128, BF16), SMALL),
+    # what the microbench did not see: today's blocks
+    "f32-2048": ((2048, 2048, 128, F32), SMALL),
+    "head-256": ((2048, 2048, 256, BF16), SMALL),
+}
+
+
+@pytest.mark.parametrize("row", sorted(TILING))
+def test_flash_tiling(row):
+    (s_q, s_kv, d, dtype), want = TILING[row]
+    assert fa._flash_tiling(s_q, s_kv, d, dtype) == want
+    # every shape the kernels took at 128 x 128 they still take, and the
+    # blocks answered tile it
+    assert fa.supports(s_q, s_kv, d)
+    assert all(fa.supports(s_q, s_kv, d, bq, bk) for bq, bk in want)
+    # the streamed backward starts where the forward's choice does
+    assert fa._bwd_use_xla(s_q, want) \
+        == (not fa.use_flash(s_q, s_kv, d, 0.0, dtype))
 
 
 # ---------------------------------------------------------------------------

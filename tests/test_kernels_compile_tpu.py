@@ -135,7 +135,7 @@ def test_rms_norm_decode_rows(v5e):
 
 @pytest.mark.parametrize("head_dim,block", [(128, 512), (256, 128),
                                             (256, 512)])
-def test_flash_block_and_head_dim_corners(v5e, head_dim, block):
+def test_flash_block_and_head_dim_corners(v5e, monkeypatch, head_dim, block):
     """The largest blocks and a 256-wide head, fwd and bwd."""
     s = 1024
     assert fa.supports(s, s, head_dim, block, block)
@@ -146,12 +146,38 @@ def test_flash_block_and_head_dim_corners(v5e, head_dim, block):
                                        block_k=block)
 
     compile_for(v5e[0], f, q, q, q)
-    # force the streamed backward below its 4096 threshold
-    saved, fa._PALLAS_BWD_MIN_SEQ = fa._PALLAS_BWD_MIN_SEQ, 0
-    try:
-        assert compile_for(v5e[0], _sum_grad(f, 3), q, q, q) == 3
-    finally:
-        fa._PALLAS_BWD_MIN_SEQ = saved
+    # force the streamed backward below the threshold of 128 x 128 blocks
+    monkeypatch.setattr(fa, "_min_seq", lambda blocks: 0)
+    assert compile_for(v5e[0], _sum_grad(f, 3), q, q, q) == 3
+
+
+def test_flash_train_cell_shape_under_checkpoint(v5e):
+    """gpt3-1.3b-l12.train-2k's attention, [4, 2,048, 16, 128] bf16, as the
+    trainer runs it: Mosaic takes the forward, the forward recomputed under
+    `jax.checkpoint` and both backward passes at the blocks `_flash_tiling`
+    answers (1,024 x 1,024 float32 score tiles need more than the default
+    16 MiB of scoped VMEM: `_compiler_params`)."""
+    q = S((4, 2048, 16, 128), BF16)
+    assert fa.use_flash(2048, 2048, 128, 0.0, BF16)
+    assert fa._flash_tiling(2048, 2048, 128, BF16) \
+        == ((1024, 1024), (512, 512), (1024, 1024))
+
+    def loss(q, k, v):
+        out = jax.checkpoint(lambda *a: fa.flash_attention_bshd(
+            *a, causal=True))(q, k, v)
+        return jnp.sum(out.astype(F32) ** 2)   # the primal output is used
+
+    sharding = SingleDeviceSharding(v5e[0])
+    specs = [jax.ShapeDtypeStruct(q.shape, q.dtype, sharding=sharding)] * 3
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        *specs).compile().as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]+)"', text)
+    assert sorted(c.split("/")[-2] for c in calls) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"]
+    # the scope a trace books, bare or inside a transform's name
+    assert all(re.search(r"[/(]flash[/)]", c) for c in calls)
+    assert any("rematted_computation" in c for c in calls)
 
 
 def test_flash_cross_attention_lengths(v5e):

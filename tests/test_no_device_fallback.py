@@ -71,7 +71,8 @@ class TestKernelBoundary:
 
         q = paddle.to_tensor(np.ones((1, 128, 1, 128), np.float32))
         assert fa.supports(128, 128, 128)
-        monkeypatch.setattr(fa, "_PALLAS_FWD_MIN_SEQ", 128)
+        # the choice takes the kernel at this small shape
+        monkeypatch.setattr(fa, "_min_seq", lambda blocks: 128)
         monkeypatch.setattr(fa, "flash_attention_bshd", refuse)
         with pytest.raises(RuntimeError, match="Mosaic refused"):
             F.scaled_dot_product_attention(q, q, q, is_causal=True,
