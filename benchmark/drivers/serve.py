@@ -23,8 +23,8 @@ class build:
 def warm(system, log):
     """Compile and run once every program the mix's lengths can meet: one
     prefill per (requests admitted together, token bucket) the engine's own
-    policy returns — the eager page scatter after a prefill is shaped by
-    the number of requests, not by the batch bucket — then the engine's own
+    policy returns — the page write after a prefill is shaped by the
+    number of requests, not by the batch bucket — then the engine's own
     `warmup()` for the burst and single-step decode programs."""
     engine, mix = system.engine, system.cell.mix
     lo, hi = traffic.length_support(mix["prompt_tokens"])

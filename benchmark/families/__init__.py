@@ -7,8 +7,10 @@ needs from shapes alone. A configuration without the key is a GPT one,
 whose table is `benchmark/flops.py` (and whose build is
 `benchmark/program.py`'s). `needs(cfg)` gives the readers one face for
 both: `prefill_flops(cfg, prompt_len)`, `decode_flops(cfg, context_len)`
-and `decode_bytes(cfg, kv_tokens, rows)`: the test that walks EVERY reader
-over a hand-made trace hands each a GPT cell.
+and `decode_bytes(cfg, kv_tokens, rows)`, which is all that `mfu.serve`,
+`mfu.prefill`, `decode_roofline` and `prefill_roofline` ask of it. What
+only some families have (`page_bytes`, `window_attn_flops`,
+`full_attn_flops`) is asked by readers that list those families' cells.
 """
 from __future__ import annotations
 

@@ -63,9 +63,11 @@ def cache_bytes_per_token(cfg, itemsize=BF16) -> int:
     return itemsize * 2 * cfg["num_key_value_heads"] * cfg["head_dim"]
 
 
-def page_bytes(cfg, page_size, itemsize=BF16) -> int:
+def page_bytes(cfg, page_size, kind=None, itemsize=BF16) -> int:
     """K and V of one page of one layer, every kv head: what the decode
-    attention reads for a live page."""
+    attention reads for a live page. The window and the full layers share
+    one layout, so `kind` ("window" / "full") changes nothing; it is taken
+    so that a reader asks every family the same way."""
     return page_size * cache_bytes_per_token(cfg, itemsize)
 
 

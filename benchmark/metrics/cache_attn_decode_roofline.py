@@ -1,25 +1,18 @@
 """The decode attention's share of its roofline, window and full layers
-together: the least time to read the LIVE pages of both kinds at the HBM
-peak (the burst's own counts on `serving.emit`: `attn_window_pages_live`
+together: the least time to read the LIVE window pages and the full pages
+read (the burst's own counts on `serving.emit`: `attn_window_pages_live`
 for the window layers' rings, `attn_pages_read` for the full layers'
-tables; the bytes of a page by the family's table) over the device time
-under `attn/window` + `attn/full` in the burst program. HBM-bound: 8
-query heads a kv head make 16 operations a byte of K or V."""
-from benchmark import (families, flops, program_subscopes, program_trace,
+tables), each at its kind's `page_bytes` by the family's table (the TRUE
+widths of a key and a value, whatever a pool pads a key to; a family whose
+layers share one layout says one size for both), at the HBM peak, over
+the device time under `attn/window` + `attn/full` in the burst program.
+HBM-bound: 8 or 16 query heads a kv head make 16 or 32 operations a byte
+of K or V."""
+from benchmark import (families, program_subscopes, program_trace,
                        trace_reduce)
 
 MODULE = r"pure_burst"
 PATHS = ("attn/window", "attn/full")
-
-
-def page_bytes(cfg, page_size):
-    """K and V of one page of one layer: by the family's table, or for a
-    configuration without one (GPT: every head its own K and V, as
-    `flops.decode_bytes` counts a token) 2 x hidden numbers a token."""
-    need = families.needs(cfg)
-    if hasattr(need, "page_bytes"):
-        return need.page_bytes(cfg, page_size)
-    return page_size * 2 * cfg["hidden_size"] * flops.BF16
 
 
 def read(trace, host, cell):
@@ -32,9 +25,12 @@ def read(trace, host, cell):
     _, runs = trace_reduce.module_seconds(trace, MODULE)
     seconds = sum(per_step) * runs * engine["decode_burst"] / 1e3
     emits = program_trace.marks(program_trace.current(trace), "serving.emit")
-    pages = sum(a.get("attn_window_pages_live", 0)
-                + a.get("attn_pages_read", 0) for a in emits)
-    if not pages or seconds <= 0:
+    window = sum(a.get("attn_window_pages_live", 0) for a in emits)
+    full = sum(a.get("attn_pages_read", 0) for a in emits)
+    if not window + full or seconds <= 0:
         return None
-    return 100.0 * pages * page_bytes(cell.config, engine["page_size"]) \
-        / cell.peaks["hbm_bytes_per_s"] / seconds
+    size, need = engine["page_size"], families.needs(cell.config)
+    least = (window * need.page_bytes(cell.config, size, "window")
+             + full * need.page_bytes(cell.config, size, "full")) \
+        / cell.peaks["hbm_bytes_per_s"]
+    return 100.0 * least / seconds

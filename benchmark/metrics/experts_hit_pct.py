@@ -6,5 +6,4 @@ from benchmark import program_subscopes
 
 
 def read(trace, host, cell):
-    ratio = program_subscopes.emit_ratio(trace, "experts_hit", "experts_held")
-    return None if ratio is None else 100.0 * ratio
+    return program_subscopes.emit_pct(trace, "experts_hit", "experts_held")

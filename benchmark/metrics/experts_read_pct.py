@@ -6,11 +6,4 @@ from benchmark import program_subscopes
 
 
 def read(trace, host, cell):
-    try:
-        ratio = program_subscopes.emit_ratio(trace, "experts_read",
-                                             "experts_held")
-    except KeyError:
-        # a burst that counts the experts it holds and not the ones it
-        # reads (a commit before the count existed): no value
-        return None
-    return None if ratio is None else 100.0 * ratio
+    return program_subscopes.emit_pct(trace, "experts_read", "experts_held")

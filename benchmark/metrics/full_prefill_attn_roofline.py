@@ -13,23 +13,13 @@ from benchmark import families, program_subscopes, trace_reduce
 MODULE = r"pure_prefill"
 
 
-def full_attn_flops(cfg, prompt_len):
-    """By the family's table; a configuration without one (GPT) is full
-    attention in every layer: both products of n (n + 1) / 2 pairs, 2 x
-    hidden multiply-adds a pair."""
-    need = families.needs(cfg)
-    if hasattr(need, "full_attn_flops"):
-        return need.full_attn_flops(cfg, prompt_len)
-    return 4 * cfg["hidden_size"] * cfg["num_hidden_layers"] \
-        * prompt_len * (prompt_len + 1) // 2
-
-
 def read(trace, host, cell):
     per_run = program_subscopes.path_ms(trace, MODULE, "attn/full")
     prompts = [v[1] for v in host.samples.get("prefill", [])]
     if per_run is None or not prompts or per_run <= 0:
         return None
     _, runs = trace_reduce.module_seconds(trace, MODULE)
-    ops = sum(full_attn_flops(cell.config, n) for n in prompts)
+    need = families.needs(cell.config)
+    ops = sum(need.full_attn_flops(cell.config, n) for n in prompts)
     return 100.0 * ops / cell.peaks["bf16_flops_per_s"] \
         / (per_run * runs / 1e3)

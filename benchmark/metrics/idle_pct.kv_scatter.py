@@ -1,7 +1,7 @@
 """Share of the traced part in which the device was idle while the host was
-dispatching the eager per-layer page scatters after a prefill, and the re-
-pin (`serving.kv_scatter`). The five `idle_pct.*` sum to
-`device_idle_pct.serve`."""
+dispatching the page write after a prefill, one compiled call a layer with
+that layer's pools donated (`serving.kv_scatter`). The five `idle_pct.*`
+sum to `device_idle_pct.serve`."""
 from benchmark import program_trace
 
 SPANS = ("serving.kv_scatter",)

@@ -9,8 +9,5 @@ from benchmark import program_subscopes
 
 
 def read(trace, host, cell):
-    try:
-        return program_subscopes.emit_ratio(trace, "prefill_expert_rows",
-                                            "prefill_expert_pairs")
-    except KeyError:
-        return None
+    return program_subscopes.emit_ratio(trace, "prefill_expert_rows",
+                                        "prefill_expert_pairs")

@@ -1,7 +1,9 @@
-"""The prefill programs' share of their roofline: operations of the TRUE
-prompt tokens (padding to the batch and token buckets shows as loss) over
-the chip's peak, against the prefill modules' device time. Compute-bound."""
-from benchmark import flops, trace_reduce
+"""The prefill programs' share of their roofline, by the table of the
+configuration's family: operations of the TRUE prompt tokens (padding to
+the batch and token buckets, and a routed expert's product taken for a
+token that did not pick it, show as loss) over the chip's peak, against
+the prefill modules' device time. Compute-bound."""
+from benchmark import families, trace_reduce
 
 MODULE = r"pure_prefill"
 
@@ -13,6 +15,6 @@ def read(trace, host, cell):
     prompts = [v[1] for v in host.samples.get("prefill", [])]
     if not runs or not prompts or seconds <= 0:
         return None
-    need = sum(flops.prefill_flops(cell.config, n) for n in prompts) \
-        / cell.peaks["bf16_flops_per_s"]
-    return 100.0 * need / seconds
+    need = families.needs(cell.config)
+    return 100.0 * sum(need.prefill_flops(cell.config, n) for n in prompts) \
+        / cell.peaks["bf16_flops_per_s"] / seconds

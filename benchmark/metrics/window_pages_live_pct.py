@@ -7,9 +7,5 @@ from benchmark import program_subscopes
 
 
 def read(trace, host, cell):
-    try:
-        ratio = program_subscopes.emit_ratio(
-            trace, "attn_window_pages_live", "attn_window_pages_context")
-    except KeyError:
-        return None
-    return None if ratio is None else 100.0 * ratio
+    return program_subscopes.emit_pct(
+        trace, "attn_window_pages_live", "attn_window_pages_context")

@@ -10,8 +10,10 @@ of itself in the same `.xplane.pb`:
 - its device scopes: the `jax.named_scope` names (`SCOPES`) that the
   models put into each operation's `op_name`.
 
-and gives the per-layer readers three things, all inside the
-`bench.traced_window` annotation:
+and gives the per-layer readers four things, all inside the
+`bench.traced_window` annotation (`run.read_trace` parses the file once
+and puts them beside the reduced trace, under `program`; a reader asks
+`current(reduced)` for them):
 
 `spans`          the program's spans and marks, nested by containment on
                  their thread: {"name", "start_s", "end_s", "attrs",
@@ -28,6 +30,9 @@ and gives the per-layer readers three things, all inside the
                  top-level scope in its `op_name`; a fusion is named by its
                  root's `op_name`, an operation the compiler made without
                  metadata has none and is booked to no scope.
+`path_seconds`   the same self time by the scope and the name right behind
+                 it (`mlp/experts`, `attn/latent`: `program_subscopes`),
+                 from the same pass over the operations.
 
 A trace of a program without phases or scopes (an older commit's) gives
 empty spans and no scope: every reader then returns None. Nothing here
@@ -37,27 +42,15 @@ constants below, pinned by tests on both sides.
 from __future__ import annotations
 
 import bisect
-import functools
-import glob
-import os
 import re
 
-from benchmark import manifest, trace_reduce
+from benchmark import trace_reduce
 
-TRACE_DIR = os.path.join(manifest.ROOT, ".bench_trace")
 PROGRAM_PREFIXES = ("serving.", "train.", "jit.")
 HARNESS_PREFIX = "bench."
 SCOPES = ("embed", "attn", "mlp", "head", "optimizer")
-OUTSIDE = "(outside)"
+OUTSIDE = "(outside)"   # idle time inside no span of the program
 _WRAPPED = re.compile(r"^(?:\w+\()*([\w.\-]+)\)*$")
-
-
-def newest(directory=None):
-    """The newest `*.xplane.pb` a traced run left (under TRACE_DIR), or
-    None."""
-    found = glob.glob(os.path.join(directory or TRACE_DIR, "plugins",
-                                   "profile", "*", "*.xplane.pb"))
-    return max(found, key=os.path.getmtime) if found else None
 
 
 def split_attrs(event_name: str, stats: dict):
@@ -184,9 +177,12 @@ def _program_id(module_event_name: str):
 
 
 def load(path: str) -> dict:
-    """The trace as plain data, `trace_reduce.load`'s shape with a fourth
-    element per event: the attributes of a host span, or the `op_name` of
-    a device operation ("" where the compiler gave it none)."""
+    """The trace as plain data, read with nothing but jax
+    (`jax.profiler.ProfileData`) and `op_names`: the shape
+    `trace_reduce.reduce` takes, with a fourth element per event: the
+    attributes of a host span (the harness's `bench.*` and the program's
+    own), or the `op_name` of a device operation ("" where the compiler
+    gave it none)."""
     from jax.profiler import ProfileData
 
     names = op_names(path)
@@ -232,16 +228,31 @@ def _name_operations(lines, names):
         ev[0] = trace_reduce.short_name(ev[0])
 
 
-def scope_of(op_name: str):
-    """The top-level scope of an `op_name`: the first path component that
-    is one of SCOPES, bare or inside a transform's name —
-    `jit(pure_step)/transpose(jvp(attn))/dot_general` is `attn`,
-    `.../while/body/closed_call/attn/kv_write/scatter` too."""
-    for part in op_name.split(";")[0].split("/"):
+def scope_and_path(op_name: str):
+    """(top-level scope, finer path) of an `op_name`. The scope is the
+    first path component that is one of SCOPES, bare or inside a
+    transform's name: `jit(pure_step)/transpose(jvp(attn))/dot_general` is
+    `attn`, `.../while/body/closed_call/attn/kv_write/scatter` too. The
+    path is the scope and the name right behind it
+    (`.../mlp/experts/dot_general` -> `mlp/experts`); None where nothing
+    named follows the scope."""
+    parts = op_name.split(";")[0].split("/")
+    for i, part in enumerate(parts):
         m = _WRAPPED.match(part)
         if m and m.group(1) in SCOPES:
-            return m.group(1)
-    return None
+            nxt = _WRAPPED.match(parts[i + 1]) if i + 1 < len(parts) \
+                else None
+            return m.group(1), \
+                f"{m.group(1)}/{nxt.group(1)}" if nxt else None
+    return None, None
+
+
+def scope_of(op_name: str):
+    return scope_and_path(op_name)[0]
+
+
+def path_of(op_name: str):
+    return scope_and_path(op_name)[1]
 
 
 def nest(events):
@@ -315,55 +326,58 @@ def _host_spans(host, lo, hi):
 
 
 def _charge(gaps, spans):
-    """{span name: idle seconds}: each gap [start_s, length_s] divided
-    among the innermost spans by overlap, the rest to OUTSIDE."""
+    """({span name: idle seconds}, [[start_s, length_s], ...]): each gap
+    [start_s, length_s] divided among the innermost spans by overlap; the
+    stretches no span covers are summed under OUTSIDE and returned."""
     segments = _innermost_segments(
         [(s["start_s"], s["end_s"], s["name"]) for s in spans
          if not s["mark"]])
     starts = [seg[0] for seg in segments]
-    idle = {}
+    idle, outside = {}, []
     for g0, length in sorted(gaps):
-        g1, covered = g0 + length, 0.0
+        g1, at = g0 + length, g0
         i = max(0, bisect.bisect_right(starts, g0) - 1)
         while i < len(segments) and segments[i][0] < g1:
             a, b, name = segments[i]
-            part = min(b, g1) - max(a, g0)
-            if part > 0:
-                idle[name] = idle.get(name, 0.0) + part
-                covered += part
+            lo, hi = max(a, g0), min(b, g1)
+            if hi > lo:
+                if lo > at:
+                    outside.append([at, lo - at])
+                idle[name] = idle.get(name, 0.0) + hi - lo
+                at = hi
             i += 1
-        if length > covered:
-            idle[OUTSIDE] = idle.get(OUTSIDE, 0.0) + length - covered
-    return idle
+        if g1 > at:
+            outside.append([at, g1 - at])
+    if outside:
+        idle[OUTSIDE] = sum(length for _, length in outside)
+    return idle, outside
 
 
-def _scope_seconds(lines, lo, hi):
-    """{module: {scope or None: seconds}} from the operations' self time
-    inside [lo, hi); modules none of whose operations carries a scope are
-    left out."""
+def self_seconds(lines, lo, hi, key):
+    """{module: {key(op_name): seconds}} from the operations' self time
+    inside [lo, hi), by the module each ran in; an operation without an
+    `op_name` is booked under None."""
     modules = sorted((ev[1], ev[1] + ev[2], trace_reduce.module_name(ev[0]))
                      for ev in lines.get(trace_reduce.MODULE_LINE, []))
     module_starts = [m[0] for m in modules]
     clipped = [(max(ev[1], lo), min(ev[1] + ev[2], hi), ev)
                for ev in lines.get(trace_reduce.OP_LINE, [])
                if min(ev[1] + ev[2], hi) > max(ev[1], lo)]
-    seconds, scoped = {}, set()
+    seconds = {}
     for start, _end, ev, _depth, self_ns in nest(clipped):
         i = bisect.bisect_right(module_starts, start) - 1
         if i < 0 or modules[i][1] <= start:
             continue
-        module = modules[i][2]
-        scope = scope_of(ev[3]) if len(ev) > 3 and ev[3] else None
-        per = seconds.setdefault(module, {})
-        per[scope] = per.get(scope, 0.0) + self_ns / 1e9
-        if scope is not None:
-            scoped.add(module)
-    return {m: per for m, per in seconds.items() if m in scoped}
+        per = seconds.setdefault(modules[i][2], {})
+        name = key(ev[3]) if len(ev) > 3 and ev[3] else None
+        per[name] = per.get(name, 0.0) + self_ns / 1e9
+    return seconds
 
 
-def program_side(raw: dict) -> dict:
-    """The part of `parse` that needs only the file: the program's spans
-    and the scopes' seconds, inside the `bench.traced_window` annotation."""
+def traced_part(raw: dict):
+    """(the first device's lines by name, the host's (line, event)s, the
+    window's bounds in ns) of `load`'s data; None without a
+    `bench.traced_window` annotation or a device plane."""
     device_planes = [p for p in raw["planes"]
                      if trace_reduce.DEVICE_PLANE.match(p["name"])]
     host = [(ln["name"], ev) for p in raw["planes"]
@@ -371,44 +385,70 @@ def program_side(raw: dict) -> dict:
             for ev in ln["events"]]
     window = [ev for _, ev in host if ev[0] == trace_reduce.WINDOW_SPAN]
     if not window or not device_planes:
-        return {"spans": [], "scope_seconds": {}}
-    lo, hi = window[0][1], window[0][1] + window[0][2]
+        return None
     lines = {ln["name"]: ln["events"] for ln in device_planes[0]["lines"]}
-    return {"spans": _host_spans(host, lo, hi),
-            "scope_seconds": _scope_seconds(lines, lo, hi)}
+    return lines, host, window[0][1], window[0][1] + window[0][2]
+
+
+def program_side(raw: dict) -> dict:
+    """The part of `parse` that needs only the file, inside the
+    `bench.traced_window` annotation: the program's spans, and from ONE
+    pass over the operations (`self_seconds` keyed by `scope_and_path`)
+    the seconds by top-level scope (`scope_seconds`; modules none of whose
+    operations carries a scope left out) and by finer path
+    (`path_seconds`: {module: {path: seconds}}, operations without a path
+    and modules without any left out)."""
+    part = traced_part(raw)
+    if part is None:
+        return {"spans": [], "scope_seconds": {}, "path_seconds": {}}
+    lines, host, lo, hi = part
+    by_scope, by_path = {}, {}
+    for module, per in self_seconds(lines, lo, hi, scope_and_path).items():
+        scopes, paths = {}, {}
+        for key, seconds in per.items():
+            scope, path = key or (None, None)
+            scopes[scope] = scopes.get(scope, 0.0) + seconds
+            if path is not None:
+                paths[path] = paths.get(path, 0.0) + seconds
+        if any(k is not None for k in scopes):
+            by_scope[module] = scopes
+        if paths:
+            by_path[module] = paths
+    return {"spans": _host_spans(host, lo, hi), "scope_seconds": by_scope,
+            "path_seconds": by_path}
 
 
 def parse(raw: dict, reduced: dict) -> dict:
     """What the readers use (module docstring), from `load`'s data and
-    `trace_reduce.reduce`'s view of the same trace, as a reader is handed
-    it: the window, the idle stretches and the modules' time are that
-    view's own, so that what is charged here sums to what it reports."""
+    `trace_reduce.reduce`'s view of the same data: the window, the idle
+    stretches and the modules' time are that view's own, so that what is
+    charged here sums to what it reports. `idle_outside` holds the
+    stretches charged to OUTSIDE (`idle_charge`)."""
     return _join(program_side(raw), reduced)
 
 
 def _join(side, reduced):
     device = reduced["devices"][0] if reduced["devices"] else None
-    return dict(side, window_s=reduced["window_s"],
-                idle_by_span=_charge(device["gaps"], side["spans"])
-                if device else {},
+    idle, outside = _charge(device["gaps"], side["spans"]) if device \
+        else ({}, [])
+    return dict(side, window_s=reduced["window_s"], idle_by_span=idle,
+                idle_outside=outside,
                 modules=device["modules"] if device else {})
 
 
-@functools.lru_cache(maxsize=2)
-def _side_of(path, _mtime):
-    return program_side(load(path))
+def idle_charge(parsed):
+    """(charged, stretches) as `trace_reduce.attribute_gaps` takes them:
+    the idle seconds by the program's span names, and the stretches that no
+    span of the program covers, which are the harness's spans' to name."""
+    return ({n: s for n, s in parsed["idle_by_span"].items()
+             if n != OUTSIDE}, parsed["idle_outside"])
 
 
-def current(reduced, directory=None):
+def current(reduced):
     """The program's side of the traced run whose reduced trace a reader
-    was handed: the newest trace under `directory` (TRACE_DIR), read once
-    per process and file. None where the reader was handed no trace or
-    one without a device, or no traced run has left a file."""
-    if not reduced or not reduced.get("devices"):
-        return None
-    path = newest(directory)
-    return _join(_side_of(path, os.path.getmtime(path)), reduced) \
-        if path else None
+    was handed, as `run.read_trace` put it there; None where the reader was
+    handed no trace or one without it."""
+    return reduced.get("program") if reduced else None
 
 
 # -- what the readers in benchmark/metrics/ ask for --------------------------

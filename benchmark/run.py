@@ -29,7 +29,7 @@ import sys  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmark import manifest, trace_reduce  # noqa: E402
+from benchmark import manifest, program_trace, trace_reduce  # noqa: E402
 from benchmark.hostlog import HostLog  # noqa: E402
 from benchmark.tracer import Tracer  # noqa: E402
 
@@ -60,6 +60,18 @@ def take_devices(cell):
 def memory_peak(devices) -> int:
     return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)
+
+
+def read_trace(path):
+    """The ONE parse of the file a traced run left: the reduced trace
+    (`trace_reduce.reduce`) with, under `program`, what the program says
+    of itself in the same data (`program_trace.parse`: its spans, the idle
+    time charged to them, and ONE pass over the operations for the scopes'
+    and the finer paths' seconds). Every reader is handed this."""
+    raw = program_trace.load(path)
+    reduced = trace_reduce.reduce(raw)
+    reduced["program"] = program_trace.parse(raw, reduced)
+    return reduced
 
 
 def measure(cell, seed, seconds, trace, devices, t_start=T_START,
@@ -100,7 +112,7 @@ def measure(cell, seed, seconds, trace, devices, t_start=T_START,
     result = {"correct": bool(correct), "attempted": int(attempted),
               "failed": int(failed)}
     if tracer:
-        reduced = trace_reduce.reduce(trace_reduce.load(tracer.xplane_path()))
+        reduced = read_trace(tracer.xplane_path())
         host = log.between(tracer.t0, tracer.t1)
         metrics = {}
         for m in cell.per_layer:
@@ -109,8 +121,12 @@ def measure(cell, seed, seconds, trace, devices, t_start=T_START,
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         device["busy_s"] = trace_reduce.busy_seconds(reduced)
         device["window_s"] = reduced["window_s"]
+        # the idle time under the program's own span names, the rest under
+        # the harness's: the `idle_pct.*` readers' charge, listed
         result.update(metrics=metrics, device=device,
-                      breakdown=trace_reduce.breakdown(reduced))
+                      breakdown=trace_reduce.breakdown(
+                          reduced,
+                          *program_trace.idle_charge(reduced["program"])))
     else:
         result.update(
             metrics={m["name"]: {"value": values[m["name"]],

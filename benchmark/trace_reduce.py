@@ -2,10 +2,11 @@
 
 Two steps, so that the arithmetic is testable on a small recorded file:
 
-`load(path)` reads the trace with nothing but jax
-(`jax.profiler.ProfileData`) into plain data:
+`program_trace.load(path)` reads the trace ONCE into plain data:
     {"planes": [{"name": str, "lines": [{"name": str,
-                 "events": [[name, start_ns, duration_ns], ...]}]}]}
+                 "events": [[name, start_ns, duration_ns, ...], ...]}]}]}
+(a fourth element, a span's attributes or an operation's `op_name`, is
+`program_trace`'s to read and is stepped over here);
 `reduce(raw)` turns that into the reduced trace:
     window_s      length of the traced part (the `bench.traced_window`
                   annotation; without it, the extent of the device events)
@@ -16,7 +17,6 @@ Two steps, so that the arithmetic is testable on a small recorded file:
                   (idle stretches, longest first)
     host_spans    [[name, start_s, end_s], ...] of the harness's own
                   `bench.*` annotations, on the same clock, window-relative
-
 On a TPU the device planes are `/device:TPU:<n>`; their `XLA Ops` line
 holds one event per executed operation and `XLA Modules` one per executed
 program (`jit_<function>(<fingerprint>)`).
@@ -29,28 +29,6 @@ import re
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 MODULE_LINE, OP_LINE = "XLA Modules", "XLA Ops"
 WINDOW_SPAN = "bench.traced_window"
-
-
-def load(path: str):
-    from jax.profiler import ProfileData
-
-    data = ProfileData.from_file(path)
-    planes = []
-    for plane in data.planes:
-        device = bool(DEVICE_PLANE.match(plane.name))
-        lines = []
-        for line in plane.lines:
-            if device and line.name not in (MODULE_LINE, OP_LINE):
-                continue
-            events = [[short_name(ev.name), int(ev.start_ns),
-                       int(ev.duration_ns)]
-                      for ev in line.events
-                      if device or ev.name.startswith("bench.")]
-            if events:
-                lines.append({"name": line.name, "events": events})
-        if lines:
-            planes.append({"name": plane.name, "lines": lines})
-    return {"planes": planes}
 
 
 def short_name(event_name: str) -> str:
@@ -81,7 +59,7 @@ def _union(intervals):
 
 
 def _clip(events, lo, hi):
-    for name, start, dur in events:
+    for name, start, dur, *_ in events:
         a, b = max(start, lo), min(start + dur, hi)
         if b > a:
             yield name, a, b
@@ -157,18 +135,23 @@ def module_seconds(reduced: dict, pattern: str) -> tuple:
     return best
 
 
-def attribute_gaps(reduced: dict, top=10) -> list:
-    """[[what the host was doing, idle seconds], ...]: EVERY idle stretch of
-    the first device (there are thousands: the eager page scatters after a
-    prefill leave one before each small program) is charged to the harness
-    span that covers its middle — the latest-starting one, `(none)` where
-    no span does — and summed by span name."""
+def attribute_gaps(reduced: dict, charged=None, stretches=None,
+                   top=10) -> list:
+    """[[what the host was doing, idle seconds], ...], longest first.
+    `charged` is idle time that the caller has a name for already ({name:
+    seconds}; none by default); `stretches` are the idle stretches
+    [[start_s, length_s], ...] left to name here (by default every gap of
+    the first device). Each stretch goes whole to the harness span that
+    covers its middle: the latest-starting one, `(none)` where no span
+    does."""
     if not reduced["devices"]:
         return []
+    charged = dict(charged or {})
+    if stretches is None:
+        stretches = reduced["devices"][0]["gaps"]
     spans = sorted(reduced["host_spans"], key=lambda s: s[1])
     starts = [s[1] for s in spans]
-    charged = {}
-    for start, length in reduced["devices"][0]["gaps"]:
+    for start, length in stretches:
         mid = start + length / 2
         i = bisect.bisect_right(starts, mid) - 1
         while i >= 0 and spans[i][2] <= mid:
@@ -179,6 +162,9 @@ def attribute_gaps(reduced: dict, top=10) -> list:
                   key=lambda g: -g[1])[:top]
 
 
-def breakdown(reduced: dict) -> dict:
+def breakdown(reduced: dict, charged=None, stretches=None) -> dict:
+    """The result line's `breakdown`; `charged` and `stretches` as
+    `attribute_gaps` takes them."""
     ops = reduced["devices"][0]["ops"][:10] if reduced["devices"] else []
-    return {"device_ops": ops, "idle_gaps": attribute_gaps(reduced)}
+    return {"device_ops": ops,
+            "idle_gaps": attribute_gaps(reduced, charged, stretches)}

@@ -4,23 +4,31 @@ trace whose answers are known, on a small trace recorded on the chip
 of each cell, PR 25, cut down as its `how` says), and on the parent's
 recorded trace, which has no span and no scope of the program's.
 
-A reader is handed the reduced trace and takes the program's side from the
-FILE the traced run left. The tests that call a reader therefore write
-their trace as such a file (xplane_writer.py) where the readers look, and
-go the whole way: `current`, `newest`, `load`, `op_names`, `_join`."""
+A reader is handed the reduced trace with the program's side under
+`program`, both from ONE parse of the file the traced run left
+(`run.read_trace`). The tests that call a reader therefore write their
+trace as such a file (xplane_writer.py) and go the whole way: `load`,
+`op_names`, `reduce`, `parse`, `current`."""
 import json
 import os
 
 import pytest
 
 from benchmark_suite_helpers import DATA, REPO
+from benchmark_suite_helpers import traced as traced_run  # noqa: F401
 from xplane_writer import write
 
-from benchmark import manifest, program_trace, run, trace_reduce
+from benchmark import manifest, program_trace, trace_reduce
 
 MS = 1_000_000  # ns
-SERVE_CELL, TRAIN_CELL = (w["name"] for w in
-                          manifest.load_manifest(REPO)["workloads"][:2])
+
+
+def _a_cell_of_kind(kind):
+    return next(w["name"] for w in manifest.load_manifest(REPO)["workloads"]
+                if manifest.load_cell(w["name"]).mix["kind"] == kind)
+
+
+SERVE_CELL, TRAIN_CELL = _a_cell_of_kind("serve"), _a_cell_of_kind("train")
 # every per-layer metric of BENCHMARK.json that reads the program's own
 # spans and scopes, with the cell of the fixture it is read from
 NEW = {m["name"]: ("serve" if SERVE_CELL in m["workloads"] else "train")
@@ -117,22 +125,6 @@ def _parent():
     return raw
 
 
-@pytest.fixture
-def traced_run(tmp_path, monkeypatch):
-    """`traced_run(raw)`: leave `raw` behind as a traced run's file, where
-    the readers look, and return what `run.py` hands a reader beside it:
-    the same file, loaded and reduced by trace_reduce."""
-    monkeypatch.setattr(program_trace, "TRACE_DIR", str(tmp_path))
-    count = iter(range(100))
-
-    def leave(raw, **kw):
-        path = write(raw, tmp_path, stamp=f"run_{next(count):02d}", **kw)
-        os.utime(path, (next(count), next(count)))   # the newest so far
-        return trace_reduce.reduce(trace_reduce.load(path))
-
-    return leave
-
-
 def test_spans_nest_by_containment_on_their_thread():
     spans = _parse(_raw())["spans"]
     depth = {(s["name"], round(1e3 * s["start_s"])): s["depth"]
@@ -204,7 +196,12 @@ def test_idle_is_charged_to_the_innermost_span_by_overlap():
     # only the harness's span covers 8-10 ms: that is outside the program
     gap_in_bench_step_only = program_trace._charge(
         [[0.008, 0.002]], parsed["spans"])
-    assert gap_in_bench_step_only == {"(outside)": pytest.approx(0.002)}
+    assert gap_in_bench_step_only == ({"(outside)": pytest.approx(0.002)},
+                                      [[0.008, pytest.approx(0.002)]])
+    # the stretches no span of the program covers are handed on whole
+    assert sorted([round(1e3 * a, 6), round(1e3 * n, 6)]
+                  for a, n in parsed["idle_outside"]) == [
+        [0, 6], [8, 2], [40, 2], [60, 40]]
 
 
 def test_scope_seconds_are_self_time_by_module():
@@ -255,7 +252,7 @@ def test_a_file_reads_back_as_the_trace_it_was_written_from(which,
     event metadata, found by the program it ran in."""
     raw = _raw() if which == "hand-made" else _recorded(which)
     reduced = traced_run(raw)
-    path = program_trace.newest()
+    path = traced_run.path
     assert program_trace.load(path) == raw
     table = program_trace.op_names(path)
     named = [ev for p in raw["planes"] for ln in p["lines"]
@@ -266,7 +263,8 @@ def test_a_file_reads_back_as_the_trace_it_was_written_from(which,
     assert all(isinstance(program, int) and line.startswith("%")
                for program, line in table)
     # and what a reader is given is what `parse` makes of the same data
-    assert reduced == _reduced(raw)
+    assert {k: v for k, v in reduced.items() if k != "program"} \
+        == _reduced(raw)
     assert program_trace.current(reduced) == _parse(raw)
 
 
@@ -287,12 +285,10 @@ def test_an_op_name_that_moved_is_an_error_not_an_empty_scope(traced_run):
     """Where libtpu keeps `op_name` is read off a raw trace, not promised:
     operations with metadata and no `tf_op` among it must not read as a
     program without scopes."""
-    reduced = traced_run(_raw(), tf_op=False)
     with pytest.raises(RuntimeError, match="tf_op"):
-        program_trace.op_names(program_trace.newest())
+        traced_run(_raw(), tf_op=False)
     with pytest.raises(RuntimeError, match="tf_op"):
-        manifest.load_reader("decode_ms.attn")(
-            reduced, None, manifest.load_cell(SERVE_CELL))
+        program_trace.op_names(traced_run.path)
 
 
 def test_the_recorded_trace_keeps_both_sums(traced_run):
@@ -344,10 +340,13 @@ def test_reader_on_the_recorded_trace_and_on_the_parents(metric,
     assert read(of_parent, None, cell) is None
 
 
-def test_no_traced_run_no_value(tmp_path, monkeypatch):
-    monkeypatch.setattr(program_trace, "TRACE_DIR", str(tmp_path))
+def test_without_the_programs_side_no_value():
+    """A reduced trace that `run.read_trace` did not make (trace_reduce's
+    view alone) has no `program`: every reader of the program's spans and
+    scopes reads nothing, and looks for no file."""
     reduced = _reduced(_recorded("serve"))
     assert program_trace.current(reduced) is None
+    assert program_trace.current(None) is None
     for metric, which in NEW.items():
         cell = manifest.load_cell(SERVE_CELL if which == "serve"
                                   else TRAIN_CELL)
@@ -362,26 +361,90 @@ def test_a_build_in_the_traced_part_is_counted(traced_run):
         == 1.5
 
 
-def test_the_trace_is_found_where_the_run_writes_it(tmp_path):
-    assert program_trace.TRACE_DIR == run.TRACE_DIR
-    assert program_trace.newest(str(tmp_path)) is None
-    assert program_trace.current(_reduced(_raw()), str(tmp_path)) is None
+def test_the_programs_side_rides_on_the_reduced_trace(traced_run):
+    reduced = traced_run(_raw())
+    assert program_trace.current(reduced) is reduced["program"]
+    assert set(reduced["program"]) == {
+        "spans", "scope_seconds", "path_seconds", "window_s",
+        "idle_by_span", "idle_outside", "modules"}
+    assert reduced["program"]["window_s"] == reduced["window_s"]
+    assert reduced["program"]["modules"] == reduced["devices"][0]["modules"]
+    assert not hasattr(program_trace, "newest")
 
 
-def test_the_newest_file_is_read_and_each_file_once(traced_run,
-                                                    monkeypatch):
+def test_a_traced_run_parses_its_file_once(traced_run, monkeypatch):
+    """`run.read_trace` loads the file; no reader of the cell loads it
+    again, or any other."""
     loads = []
     real = program_trace.load
     monkeypatch.setattr(program_trace, "load",
                         lambda path: loads.append(path) or real(path))
-    older = traced_run(_recorded("serve"))
-    newer = traced_run(_raw())
-    assert older != newer
-    assert program_trace.newest().endswith(
-        os.path.join("run_03", "host.xplane.pb"))
-    for _ in range(2):
-        assert program_trace.current(newer) == _parse(_raw())
-    assert len(loads) == 1
+    reduced = traced_run(_recorded("serve"))
+    cell = manifest.load_cell(SERVE_CELL)
+    values = {m["name"]: manifest.load_reader(m["name"])(reduced, None, cell)
+              for m in cell.per_layer if m["name"] in NEW}
+    assert len(values) >= 11 and None not in values.values()
+    assert loads == [traced_run.path]
+    assert not hasattr(trace_reduce, "load")
+
+
+IDLE_SPANS = {
+    "idle_pct.prefill": ("serving.prefill_batch", "serving.prefill.launch",
+                         "serving.prefill.sync"),
+    "idle_pct.kv_scatter": ("serving.kv_scatter",),
+    "idle_pct.decode_launch": ("serving.admit", "serving.decode.launch"),
+    "idle_pct.emit": ("serving.decode.sync", "serving.emit",
+                      "serving.close"),
+    "idle_pct.outside": ("step", "idle", "add_request", "(none)")}
+
+
+@pytest.mark.parametrize("metric", sorted(IDLE_SPANS))
+@pytest.mark.parametrize("which", ["hand-made", "serve"])
+def test_idle_gaps_and_the_idle_shares_are_two_views_of_one_charge(
+        metric, which, traced_run):
+    """`breakdown.idle_gaps` names the program's spans: the seconds it
+    lists under a metric's spans are that `idle_pct.*` x the window. What
+    no span of the program covers is the harness's: `idle` while the
+    engine is empty, `step`, `add_request`, `(none)`."""
+    reduced = traced_run(_raw() if which == "hand-made"
+                         else _recorded("serve"))
+    charge = program_trace.idle_charge(reduced["program"])
+    listed = trace_reduce.breakdown(reduced, *charge)["idle_gaps"]
+    assert len(listed) <= 10 and listed == sorted(listed,
+                                                  key=lambda g: -g[1])
+    gaps = dict(trace_reduce.attribute_gaps(reduced, *charge, top=None))
+    assert listed == [[n, s] for n, s in gaps.items()][:10]
+    assert sum(gaps.values()) == pytest.approx(
+        reduced["window_s"] - reduced["devices"][0]["busy_s"])
+    assert "(outside)" not in gaps
+    share = manifest.load_reader(metric)(reduced, None,
+                                         manifest.load_cell(SERVE_CELL))
+    assert sum(gaps.get(n, 0.0) for n in IDLE_SPANS[metric]) \
+        == pytest.approx(share * reduced["window_s"] / 100, abs=1e-9)
+    assert set(gaps) <= {n for names in IDLE_SPANS.values() for n in names}
+    if which == "hand-made":
+        # the stretches outside the program's spans, each charged whole
+        # to the harness span at its middle: 60-100 ms to `idle` (62-90),
+        # 8-10 and 40-42 to `step`, 0-6 to none (`step` starts at 5)
+        assert gaps["idle"] == pytest.approx(0.040)
+        assert gaps["step"] == pytest.approx(0.004)
+        assert gaps["(none)"] == pytest.approx(0.006)
+
+
+def test_a_program_without_phases_reads_as_the_harness_alone(traced_run):
+    """The train cell's program opens no phase: every idle stretch goes to
+    the harness's spans, as before the program had any."""
+    reduced = traced_run(_recorded("train"))
+    assert reduced["program"]["spans"] == []
+    charged, stretches = program_trace.idle_charge(reduced["program"])
+    assert charged == {}
+    both = [trace_reduce.breakdown(reduced, charged, stretches),
+            trace_reduce.breakdown(reduced)]
+    assert both[0]["device_ops"] == both[1]["device_ops"]
+    assert [n for n, _ in both[0]["idle_gaps"]] \
+        == [n for n, _ in both[1]["idle_gaps"]] != []
+    assert [s for _, s in both[0]["idle_gaps"]] == pytest.approx(
+        [s for _, s in both[1]["idle_gaps"]])
 
 
 def test_op_names_come_from_the_event_metadata_of_a_real_xplane(tmp_path):

@@ -1,37 +1,21 @@
-"""benchmark/trace_reduce.py and every reader in benchmark/metrics/ on a
-hand-made trace whose answers are known, and on a small trace recorded on
-the chip (data/recorded_trace.json: `trace_reduce.load()` of a traced run
-of the train cell, PR 24, cut to its first events)."""
+"""benchmark/trace_reduce.py and the readers of the harness's own counts on
+a hand-made trace whose answers are known (benchmark_suite_helpers.py
+`gpt_raw`), and on a small trace recorded on the chip
+(data/recorded_trace.json: a traced run of the train cell, PR 24, cut to
+its first events). Which reader reads a value in which cell is
+test_benchmark_manifest.py's to ask, of the manifest."""
 import json
 import os
 
 import pytest
 
 from benchmark_suite_helpers import DATA, REPO, TEST_PEAKS, tiny_cell
+from benchmark_suite_helpers import gpt_host as _host
+from benchmark_suite_helpers import gpt_raw as _raw
 
 from benchmark import manifest, trace_reduce
-from benchmark.hostlog import HostLog
 
 MS = 1_000_000  # ns
-
-
-def _raw():
-    """Window 0..100 ms. Device 0: a burst module 10-40 ms made of two ops
-    (10-25, 25-40), a prefill module 50-70 ms (one op), an op that starts
-    before the window (-5..5 ms) and one that ends after it (95..105)."""
-    dev_ops = [["fusion.1", 10 * MS, 15 * MS], ["fusion.2", 25 * MS, 15 * MS],
-               ["convolution.3", 50 * MS, 20 * MS],
-               ["fusion.1", -5 * MS, 10 * MS], ["copy.4", 95 * MS, 10 * MS]]
-    modules = [["jit_pure_burst(123)", 10 * MS, 30 * MS],
-               ["jit_pure_prefill(456)", 50 * MS, 20 * MS]]
-    host = [["bench.traced_window", 0, 100 * MS],
-            ["bench.step", 8 * MS, 34 * MS], ["bench.add_request", 43 * MS, MS],
-            ["bench.step", 45 * MS, 30 * MS], ["bench.idle", 76 * MS, 18 * MS]]
-    return {"planes": [
-        {"name": "/device:TPU:0", "lines": [
-            {"name": "XLA Modules", "events": modules},
-            {"name": "XLA Ops", "events": dev_ops}]},
-        {"name": "/host:CPU", "lines": [{"name": "python", "events": host}]}]}
 
 
 def test_busy_is_the_union_of_op_intervals_inside_the_window():
@@ -75,49 +59,6 @@ def test_without_the_window_span_the_device_events_bound_the_window():
     r = trace_reduce.reduce(raw)
     assert r["window_s"] == pytest.approx(0.110)
     assert trace_reduce.reduce({"planes": []})["devices"] == []
-
-
-def _host():
-    log = HostLog()
-    log.spans = [("step", 0.0, 0.004), ("step", 0.01, 0.016),
-                 ("add_request", 0.02, 0.021)]
-    log.samples = {
-        "gen_lag_s": [(0.0, 0.001), (0.0, 0.003)],
-        "occupancy": [(0.0, 0.5), (0.0, 0.75)],
-        "pages_used": [(0.0, 0.25), (0.0, 0.35)],
-        "prefill": [(0.0, 20), (0.0, 30)],
-        "decode": [(0.0, 8, 2, 50), (0.0, 4, 1, 30)]}
-    log.counts = {"compiles_in_window": 0}
-    return log
-
-
-def _every_reader():
-    names = sorted(f[:-3] for f in os.listdir(
-        os.path.join(REPO, "benchmark", "metrics")) if f.endswith(".py"))
-    assert len(names) >= 14
-    return names
-
-
-@pytest.mark.parametrize("name", _every_reader())
-def test_every_reader_on_the_hand_made_trace(name):
-    read = manifest.load_reader(name, os.path.join(REPO, "benchmark"))
-    serve = tiny_cell("tiny-gpt.tiny-open")
-    train = tiny_cell("tiny-gpt.tiny-train")
-    raw = _raw()
-    raw["planes"][0]["lines"][0]["events"].append(
-        ["jit_pure_step(9)", 75 * MS, 10 * MS])
-    reduced = trace_reduce.reduce(raw)
-    cell = train if name.endswith("train") or name.startswith("train") \
-        else serve
-    value = read(reduced, _host(), cell)
-    assert value is not None and value >= 0
-    if name.endswith("_roofline") or "mfu" in name:
-        assert 0 < value
-    # a reader that finds nothing to read returns nothing, never 0
-    empty = trace_reduce.reduce({"planes": []})
-    nothing = read(empty, HostLog(), cell)
-    assert nothing is None or name == "compiles_in_window"
-    assert read(None, HostLog(), cell) is None
 
 
 def test_known_values_of_the_readers():
