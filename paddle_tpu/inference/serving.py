@@ -1556,12 +1556,20 @@ class ServingEngine:
             raise
         return True
 
+    def _rows_held(self):
+        """Rows a prefill round keeps from their next step: slots that
+        have emitted a token and have more due. A slot whose first token
+        is pending is not one (its wait is time to first token)."""
+        return sum(s.active and not s.prefilling
+                   and not s.needs_first_sample for s in self.slots)
+
     def _prefill_batch(self, new):
         """new: list of (slot_idx, prompt_ids) — ONE compiled forward for
         all admitted prompts + ONE compiled page write per layer into
         that layer's donated pools."""
         n = len(new)
-        with _trace.phase("serving.prefill_batch"):
+        with _trace.phase("serving.prefill_batch",
+                          rows_held=self._rows_held()):
             t0_prefill = _time_mod.perf_counter() if self._traces else 0.0
             # packing is the scheduler policy's call (default: next-pow2
             # batch capped at max_batch, token bucket = next page multiple)
@@ -1573,7 +1581,9 @@ class ServingEngine:
             bucket = max(-(-bucket // self.page_size) * self.page_size,
                          -(-longest // self.page_size) * self.page_size)
             all_greedy = all(self.slots[si].greedy for si, _ in new)
-            with _trace.phase("serving.prefill.launch"):
+            with _trace.phase("serving.prefill.launch",
+                              prompt_tokens=sum(len(ids) for _, ids in new),
+                              padded_tokens=int(nb * bucket)):
                 fn = self._get_prefill_fn(nb, bucket, all_greedy)
                 params, buffers = self._cached_params()
                 padded = np.zeros((nb, bucket), np.int64)
@@ -2657,7 +2667,8 @@ class ServingEngine:
         pf = [i for i, s in enumerate(self.slots)
               if s.active and s.prefilling]
         if pf:
-            with _trace.phase("serving.prefill_batch"):
+            with _trace.phase("serving.prefill_batch",
+                              rows_held=self._rows_held()):
                 self._prefill_chunk_round(pf)
         # prefilling slots are excluded from decode (their context is
         # partial and they have no last token yet)
@@ -2830,6 +2841,9 @@ class ServingEngine:
                         args += (jax.random.key_data(sk),
                                  jnp.asarray(greedy), jnp.asarray(temp),
                                  jnp.asarray(tk), jnp.asarray(tp_arr))
+                        # the arguments are copied: what follows is the
+                        # compiled call's own dispatch
+                        _trace.mark("serving.dispatch")
                         if burst:
                             (toks, emits, nk, nv, nks, nvs, *_carry,
                              counts) = fn(*args)
@@ -2879,10 +2893,15 @@ class ServingEngine:
         if self.k_scales is not None:
             self.k_scales, self.v_scales = list(nks), list(nvs)
         # intentional sync: the sampled tokens must reach the host to be
-        # appended/streamed — the one read per burst or step, not a stray
-        # transfer
-        with _trace.phase("serving.decode.sync"):
+        # appended/streamed — the one wait per burst or step, not a stray
+        # transfer. Every read inside it blocks: the tokens, a burst's
+        # `emits`, and one `int(v)` for each of the program's counts
+        with _trace.phase("serving.decode.sync",
+                          fetches=(2 if burst else 1) + len(counts)):
             toks = np.asarray(toks)  # tpu-lint: disable=sync-transfer-in-step-loop
+            # the program has ended and its tokens are here; what is left
+            # of this phase is the reads that follow
+            _trace.mark("serving.fetched")
             if burst:
                 emits = np.asarray(emits)  # tpu-lint: disable=sync-transfer-in-step-loop
             # the program is done once its tokens are here: no second wait
@@ -2917,10 +2936,10 @@ class ServingEngine:
         """Per-step telemetry close-out: ZERO registry allocations —
         handle attribute reads + float ops only (the overhead guard test
         pins this)."""
-        with _trace.phase("serving.close"):
+        n_tok = self._m.tokens.value - tok0
+        with _trace.phase("serving.close", tokens=int(n_tok)):
             t1 = _time_mod.perf_counter()
             dt = t1 - t0
-            n_tok = self._m.tokens.value - tok0
             ex = None
             if self._traces:
                 # decode-step exemplar: one traced rider of this batched

@@ -99,76 +99,3 @@ def check_padded_prefill(model, prompts, nb, bucket):
                     np.asarray(as_array(a1))[0], atol=2e-5)
     assert int(counts["expert_pairs"]) == pairs > 0
     return counts
-
-
-@pytest.fixture(autouse=True)
-def _hand_made_trace_as_a_file(request):
-    """For ONE test: `test_every_reader_on_the_hand_made_trace`
-    (tests/benchmark_suite/test_benchmark_trace_reduce.py, which a PR
-    that adds readers may not edit) hands every reader under
-    benchmark/metrics/ its hand-made trace, reduced, and wants a value
-    from each. The readers of the program's own spans and scopes take
-    those from the FILE a traced run left (benchmark/program_trace.py).
-    So that test's own trace is written where they look, as a program
-    with phases and scopes would have left it: they read the trace they
-    are handed, through the whole path from the file. (Here and not in a
-    conftest.py of that directory, which would take this module's name
-    from the tests that import it.)"""
-    if getattr(request.node, "originalname", None) != \
-            "test_every_reader_on_the_hand_made_trace":
-        return
-    from xplane_writer import write
-
-    from benchmark import program_trace
-
-    ms = 1_000_000
-    raw = request.module._raw()
-    device, host = raw["planes"]
-    modules, ops = (ln["events"] for ln in device["lines"])
-    # the burst's first operation is attention's, its second the
-    # compiler's own; the prefill's is the MLP's; and the train step that
-    # the test appends to its trace holds one operation of attention's
-    ops[0].append("jit(pure_burst)/while/body/closed_call/attn/dot_general")
-    ops[2].append("jit(pure_prefill)/mlp/dot_general")
-    modules.append(["jit_pure_step(9)", 75 * ms, 10 * ms])
-    ops.append(["fusion.5", 76 * ms, 4 * ms,
-                "jit(pure_step)/transpose(jvp(attn))/dot_general"])
-    # a model that names parts of its scopes (models/latent_moe.py): inside
-    # the burst's two operations run the latent attention's and an expert
-    # layer's, and the step's counts ride on `serving.emit`
-    body = "jit(pure_burst)/while/body/closed_call/"
-    ops += [["fusion.11", 12 * ms, 2 * ms, body + "attn/latent/dot_general"],
-            ["fusion.12", 26 * ms, ms, body + "mlp/router/top_k"],
-            ["fusion.13", 27 * ms, 3 * ms, body + "mlp/experts/dot_general"],
-            ["fusion.14", 30 * ms, ms, body + "mlp/shared/dot_general"]]
-    # ... and window and full attention layers (the stack's `gqa_window`
-    # and `gqa_full` mixers), in the burst and in the prefill, with the
-    # burst's page counts beside the expert layer's
-    ops += [["call.15", 14 * ms, 2 * ms, body + "attn/window/pallas_call"],
-            ["call.16", 16 * ms, ms, body + "attn/full/pallas_call"],
-            ["call.17", 50 * ms, 2 * ms,
-             "jit(pure_prefill)/attn/window/pallas_call"],
-            ["call.18", 53 * ms, ms,
-             "jit(pure_prefill)/attn/full/pallas_call"]]
-    host["lines"][0]["events"] += [
-        ["serving.admit", 8 * ms, ms],
-        ["serving.admitted", 8 * ms + ms // 2, 900,
-         {"rid": 1, "queued_us": 250, "requeue": 0}],
-        ["serving.decode.launch", 9 * ms, 2 * ms],
-        ["serving.decode.sync", 11 * ms, 30 * ms],
-        ["serving.prefill_batch", 45 * ms, 29 * ms],
-        ["serving.kv_scatter", 70 * ms, 4 * ms],
-        ["serving.emit", 41 * ms, 2 * ms,
-         {"expert_pairs": 12, "experts_hit": 9, "experts_read": 9,
-          "expert_layer_steps": 8, "experts_held": 32,
-          "attn_window_pages_read": 18, "attn_window_pages_live": 18,
-          "attn_window_pages_context": 40, "attn_pages_read": 10,
-          "attn_pages_mapped": 64}],
-        # ... and the phase that commits a prefill's first tokens, with
-        # that prefill program's own counts
-        ["serving.emit", 75 * ms, ms,
-         {"prefill_expert_pairs": 20, "prefill_expert_rows": 256}]]
-    directory = request.getfixturevalue("tmp_path")
-    write(raw, directory)
-    request.getfixturevalue("monkeypatch").setattr(
-        program_trace, "TRACE_DIR", str(directory))

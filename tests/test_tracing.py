@@ -59,14 +59,18 @@ def _tiny_engine(**kw):
 
 
 # the engine's host phases (tracing.phase), as serving.py opens them in
-# one step() that admits, and the zero-length mark, one per request. No
-# name is shared with the per-request spans (serving.prefill, ...)
+# one step() that admits, and the zero-length marks: one per request
+# admitted, one where a launch has copied its arguments, one where a sync
+# has its tokens. No name is shared with the per-request spans
+# (serving.prefill, ...)
 SERVING_PHASES = ("serving.admit", "serving.prefill_batch",
                   "serving.prefill.launch", "serving.kv_scatter",
                   "serving.prefill.sync", "serving.decode.launch",
                   "serving.decode.sync", "serving.emit", "serving.close")
-SERVING_MARKS = ("serving.admitted",)
+SERVING_MARKS = ("serving.admitted", "serving.dispatch", "serving.fetched")
 INSIDE = {"serving.admitted": "serving.admit",
+          "serving.dispatch": "serving.decode.launch",
+          "serving.fetched": "serving.decode.sync",
           "serving.prefill.launch": "serving.prefill_batch",
           "serving.kv_scatter": "serving.prefill_batch",
           "serving.prefill.sync": "serving.prefill_batch"}
@@ -367,12 +371,14 @@ class TestPhases:
     """tracing.phase / mark: the profiler's annotations, whatever the
     flags; the ring only when tracing is enabled."""
 
-    def _count(self, monkeypatch):
+    def _count(self, monkeypatch, attrs=False):
+        """Every annotation opened from here on: its name, or with
+        `attrs` (name, attributes)."""
         opened = []
 
         class Counting(tr.TraceAnnotation):
             def __init__(self, name, **kw):
-                opened.append(name)
+                opened.append((name, kw) if attrs else name)
                 super().__init__(name, **kw)
 
         monkeypatch.setattr(tr, "TraceAnnotation", Counting)
@@ -390,9 +396,10 @@ class TestPhases:
         tr.mark("x.z", seconds=0.5)
         assert opened == ["x.y", "x.z"]
         del opened[:]
-        eng.step()                      # decode only
+        eng.step()                      # decode only: five spans, two marks
         assert opened == ["serving.admit", "serving.decode.launch",
-                          "serving.decode.sync", "serving.emit",
+                          "serving.dispatch", "serving.decode.sync",
+                          "serving.fetched", "serving.emit",
                           "serving.close"]
         del opened[:]
         eng.add_request(np.arange(5), max_new_tokens=8)
@@ -402,7 +409,8 @@ class TestPhases:
         spans = [n for n in opened if n not in SERVING_MARKS]
         assert set(spans) == set(SERVING_PHASES)
         assert len(spans) <= 10
-        # one mark per request admitted, none per token or layer
+        # one mark per request admitted, per launch and per sync, none per
+        # token or layer
         assert [n for n in opened if n in SERVING_MARKS] == \
             list(SERVING_MARKS)
         assert tracer_off.spans_created == c0 and len(tracer_off) == 0
@@ -444,16 +452,125 @@ class TestPhases:
         assert all(set(a) == {"rid", "queued_us", "requeue"}
                    and a["queued_us"] >= 0 and a["requeue"] == 0
                    for a in admitted)
-        # the phases carry no attribute but the burst's own counts of the
-        # pages its decode attention reads and maps, on `serving.emit`
-        carried = [(e[0], set(e[3])) for e in evs
-                   if e[0] in SERVING_PHASES and e[3]]
-        assert carried and all(
-            c == ("serving.emit", {"attn_pages_read", "attn_pages_mapped"})
-            for c in carried)
+        # a phase carries what a reader reads and nothing else: a round the
+        # rows it holds up, its launch its true and padded tokens, a sync
+        # its blocking reads, a close-out its tokens, and `serving.emit` the
+        # burst's own counts of the pages its decode attention reads and
+        # maps
+        carried = {(e[0], frozenset(e[3])) for e in evs
+                   if e[0] in SERVING_PHASES and e[3]}
+        assert carried == {
+            ("serving.prefill_batch", frozenset({"rows_held"})),
+            ("serving.prefill.launch",
+             frozenset({"prompt_tokens", "padded_tokens"})),
+            ("serving.decode.sync", frozenset({"fetches"})),
+            ("serving.close", frozenset({"tokens"})),
+            ("serving.emit",
+             frozenset({"attn_pages_read", "attn_pages_mapped"}))}
+        # exactly one mark inside each sync and each launch
+        for mark, outer in (("serving.fetched", "serving.decode.sync"),
+                            ("serving.dispatch", "serving.decode.launch")):
+            for o in (o for o in evs if o[0] == outer):
+                assert sum(e[0] == mark and o[1] <= e[1] and e[2] <= o[2]
+                           for e in evs) == 1, outer
         # one prefill round per request, each around its three parts
         assert sum(e[0] == "serving.prefill_batch" for e in evs) == 2
         assert tracer_off.spans_created == 0
+
+    def test_a_round_says_the_rows_it_holds_up_and_its_padding(
+            self, tracer_off, monkeypatch):
+        # 20 pages a sequence is past the policy's PAGE_BUCKETS_MAX: a
+        # prompt a round, padded to the next power of two of its pages
+        eng, _ = _tiny_engine(max_batch=4, max_seq_len=40, page_size=2,
+                              decode_burst=4)
+        opened = self._count(monkeypatch, attrs=True)
+
+        def rounds():
+            found = [kw for name, kw in opened
+                     if name == "serving.prefill_batch"]
+            launches = [kw for name, kw in opened
+                        if name == "serving.prefill.launch"]
+            del opened[:]
+            return found, launches
+
+        eng.add_request(np.arange(5), max_new_tokens=30)
+        eng.add_request(np.arange(7), max_new_tokens=30)
+        eng.step()
+        # the engine was empty; the second round finds the first prompt's
+        # slot with its first token pending, which is no row held up
+        found, launches = rounds()
+        assert found == [{"rows_held": 0}] * 2
+        assert launches == [{"prompt_tokens": 5, "padded_tokens": 8},
+                            {"prompt_tokens": 7, "padded_tokens": 8}]
+        eng.step()
+        assert rounds() == ([], [])
+        # both rows decode: a third prompt holds up two
+        eng.add_request(np.arange(3), max_new_tokens=30)
+        eng.step()
+        found, launches = rounds()
+        assert found == [{"rows_held": 2}]
+        assert launches == [{"prompt_tokens": 3, "padded_tokens": 4}]
+
+    def test_a_batched_round_beside_a_decoding_row(self, tracer_off,
+                                                   monkeypatch):
+        eng, _ = _tiny_engine(max_batch=4, decode_burst=4)
+        eng.add_request(np.arange(6), max_new_tokens=20)
+        eng.step()
+        opened = self._count(monkeypatch, attrs=True)
+        for n in (9, 4, 11):
+            eng.add_request(np.arange(n), max_new_tokens=20)
+        eng.step()
+        by_name = {}
+        for name, kw in opened:
+            by_name.setdefault(name, []).append(kw)
+        # one round of three prompts beside one decoding row; the batch
+        # pads to 4 rows of 2 pages
+        assert by_name["serving.prefill_batch"] == [{"rows_held": 1}]
+        launch, = by_name["serving.prefill.launch"]
+        assert launch == {"prompt_tokens": 24, "padded_tokens": 4 * 16}
+        assert launch["prompt_tokens"] <= launch["padded_tokens"]
+
+    @pytest.mark.parametrize("decode_burst", [4, 1])
+    def test_a_sync_counts_its_reads_and_a_close_its_tokens(
+            self, tracer_off, monkeypatch, decode_burst):
+        eng, _ = _tiny_engine(decode_burst=decode_burst)
+        streamed = []
+        eng.add_request(np.arange(6), max_new_tokens=10,
+                        on_token=lambda rid, tok: streamed.append(tok))
+        eng.add_request(np.arange(4), max_new_tokens=6,
+                        on_token=lambda rid, tok: streamed.append(tok))
+        opened = self._count(monkeypatch, attrs=True)
+        asked = []                      # the program each step launched
+        for which in ("_get_burst_fn", "_get_decode_fn"):
+            monkeypatch.setattr(
+                eng, which, lambda *a, _get=getattr(eng, which), _w=which:
+                (asked.append(_w), _get(*a))[1])
+        while eng.has_work():
+            n0 = len(streamed)
+            del opened[:]
+            eng.step()
+            by_name = {}
+            for name, kw in opened:
+                by_name.setdefault(name, []).append(kw)
+            sync, = by_name["serving.decode.sync"]
+            close, = by_name["serving.close"]
+            counts = [kw for kw in by_name["serving.emit"]
+                      if "attn_pages_read" in kw][-1]
+            # the tokens, a burst's emits, and a read for each count; the
+            # single-step program (every row on its last token, or no
+            # bursts at all) has no emits
+            burst = asked[-1] == "_get_burst_fn"
+            assert sync == {"fetches": (2 if burst else 1) + len(counts)}
+            # the first tokens a step commits before its launch are not
+            # the burst's
+            firsts = 2 if any("attn_pages_read" not in kw
+                              for kw in by_name["serving.emit"]) else 0
+            assert close["tokens"] == len(streamed) - n0 - firsts
+            assert len(by_name["serving.fetched"]) == 1
+            assert len(by_name["serving.dispatch"]) == 1
+        assert len(streamed) == 10 + 6
+        assert set(asked) == ({"_get_burst_fn", "_get_decode_fn"}
+                              if decode_burst > 1 else {"_get_decode_fn"})
 
     def test_second_prefill_of_a_bucket_builds_nothing_in_the_page_write(
             self, tracer_off, tmp_path):
