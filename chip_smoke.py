@@ -503,7 +503,8 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
                  flash_seq=4096, tokens=8192, decode_batch=8,
                  pages_per_seq=160, wide=8192, expert_hidden=7680,
                  expert_width=2048, experts_held=16, gqa_seq=2048,
-                 gqa_kv_heads=8, gqa_group=8):
+                 gqa_kv_heads=8, gqa_group=8, scan_seq=4096,
+                 scan_inner=5120):
     """Every Pallas kernel entry point of paddle_tpu/kernels/, by default
     at this model's head geometry. tests/test_kernels_compile_tpu.py
     compiles the same table ahead of time for a v5e, so a Mosaic refusal
@@ -520,6 +521,7 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
     from paddle_tpu.kernels import paged_attention as pa
     from paddle_tpu.kernels import quant_matmul as qm
     from paddle_tpu.kernels import rms_norm as rn
+    from paddle_tpu.kernels import selective_scan as ss
 
     f32 = jnp.float32
     scale = 1.0 / math.sqrt(head_dim)
@@ -736,6 +738,39 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
         return lambda x, qw, sc: jnp.matmul(
             x.astype(f32), qm.dequantize(qw, sc, weight_dtype, f32))
 
+    def scan_args(rng):
+        """A Mamba layer's scan over one prompt whose last 100 positions
+        are padding, from a state that is not zero: u' in bf16, dt after
+        its softplus, A = -(1 .. 16) in every channel, B and C, D, the
+        state, the prompt's true length."""
+        n = 16
+        dt = np.log1p(np.exp(rng.standard_normal(
+            (1, scan_seq, scan_inner), dtype=np.float32) - 3.0))
+        a = -np.tile(np.arange(1, n + 1, dtype=np.float32)[:, None],
+                     (1, scan_inner))
+        return (_bf16(rng, (1, scan_seq, scan_inner)), dt, a,
+                rng.standard_normal((1, scan_seq, n), dtype=np.float32),
+                rng.standard_normal((1, scan_seq, n), dtype=np.float32),
+                np.ones((scan_inner,), np.float32),
+                rng.standard_normal((1, n, scan_inner), dtype=np.float32),
+                np.array([scan_seq - 100], np.int32))
+
+    def scan_ref(u, dt, a, b, c, d, h0, lens):
+        """The recurrence a token at a time; a row's tokens past its
+        length hold the state."""
+        live = jnp.arange(u.shape[1])[None, :] < lens[:, None]
+        dt = jnp.where(live[..., None], dt, 0.0)
+
+        def step(h, xs):
+            u_t, dt_t, b_t, c_t = xs
+            h = jnp.exp(dt_t[:, None, :] * a) * h \
+                + (dt_t * u_t)[:, None, :] * b_t[..., None]
+            return h, jnp.sum(h * c_t[..., None], axis=1) + d * u_t
+
+        h, y = jax.lax.scan(step, h0, tuple(
+            jnp.swapaxes(x, 0, 1) for x in (u.astype(f32), dt, b, c)))
+        return jnp.swapaxes(y, 0, 1).astype(u.dtype), h
+
     # one make_args per operand set: its fwd and fwd+bwd cases share it
     flash_args, drop_args = qkv(flash_seq), qkv(drop_seq)
     paged, paged_quant = paged_args(False), paged_args(True)
@@ -777,6 +812,8 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
                    qmm_ref("int8")),
         KernelCase("quant_matmul_fused int4", qmm_args("int4"), qmm("int4"),
                    qmm_ref("int4")),
+        KernelCase(f"selective scan {scan_seq} x {scan_inner}", scan_args,
+                   ss.selective_scan, scan_ref),
     ]
 
 

@@ -22,6 +22,7 @@ import numpy as np
 from .. import faults as _faults
 from ..autograd import tape as _tape
 from ..kernels import paged_attention as _pa
+from ..models.causal_lm import StatePool
 from ..observability import compilewatch as _cw
 from ..observability import fleet as _fleet
 from ..observability import flight_recorder as _flight
@@ -397,8 +398,10 @@ class ServingEngine:
         # second; layouts of one pool leave `v_pages` empty (their rows are
         # the one key head of absorbed-form attention, and carry the value
         # in themselves)
-        self._kv_layouts = tuple(tuple(tuple(pool) for pool in layout)
-                                 for layout in model.kv_cache_layouts())
+        self._kv_layouts = tuple(
+            tuple(pool if isinstance(pool, StatePool) else tuple(pool)
+                  for pool in layout)
+            for layout in model.kv_cache_layouts())
         if len({len(layout) for layout in self._kv_layouts}) != 1:
             raise ValueError(
                 "every layer's cache must be made of as many pools: got "
@@ -413,15 +416,22 @@ class ServingEngine:
             None if w is None else _pa.ring_pages(w, page_size)
             for w in model.kv_cache_windows())
         self._has_rings = any(self._rings)
+        # ... or none: a layer that keeps a state of fixed size a sequence
+        # (`StatePool`s) has pools of `max_batch` states indexed by slot,
+        # which the allocator never sees either
+        self._states = tuple(isinstance(layout[0], StatePool)
+                             for layout in self._kv_layouts)
         # a prefill hands such a layout's K and V back a layer an entry,
         # and each is written by a program of its layer's own kind
-        self._layer_writes = self._has_rings or layers_differ
+        self._layer_writes = self._has_rings or layers_differ \
+            or any(self._states)
         # anything but the same (k, v) pages of every position in every
         # layer
         self._mixed_layout = self._one_pool or self._layer_writes
         # (the kv heads tp shards and int8 scales follow: every layer's
         # alike wherever either is allowed)
-        kvh = self._kv_layouts[0][0][0]
+        kvh = next((layout[0][0] for layout, state in zip(
+            self._kv_layouts, self._states) if not state), None)
         self._check_mixed_layout_support(
             kv_cache_quant=kv_cache_quant, spec_decode=spec_decode,
             draft_model=draft_model, prefix_cache=prefix_cache,
@@ -433,7 +443,8 @@ class ServingEngine:
         if tp > 1 and self._mixed_layout:
             raise ValueError(
                 "a mixed layout (a latent page pool of one head, window "
-                "layers' rings, or layers whose pools differ) cannot be "
+                "layers' rings, layers that keep a state, or layers whose "
+                "pools differ) cannot be "
                 f"sharded over tp={tp}: it serves at tp=1 (ROADMAP R9 and "
                 "R10 keep the sharded forms)")
         if tp > 1 and kvh % tp:
@@ -722,11 +733,16 @@ class ServingEngine:
         in kv heads, and a layer's key pool in width from its value pool);
         `v_pages` is [] for layouts of one pool. A layer that keeps every
         position has the allocator's pages, a window layer its slots'
-        rings."""
-        pools = [[jnp.zeros((heads, self._n_pages_total if ring is None
-                             else self.max_batch * ring, self.page_size,
-                             width), self.kv_dtype)
-                  for heads, width in layout]
+        rings, a layer that keeps a state one state a slot."""
+        def pool(shape, ring):
+            if isinstance(shape, StatePool):
+                return shape.zeros(self.max_batch, self.kv_dtype)
+            heads, width = shape
+            return jnp.zeros((heads, self._n_pages_total if ring is None
+                              else self.max_batch * ring, self.page_size,
+                              width), self.kv_dtype)
+
+        pools = [[pool(shape, ring) for shape in layout]
                  for layout, ring in zip(self._kv_layouts, self._rings)]
         return [p[0] for p in pools], \
             [] if self._one_pool else [p[1] for p in pools]
@@ -734,10 +750,14 @@ class ServingEngine:
     def kv_pool_bytes(self):
         """{"kv_pool_bytes_full", "kv_pool_bytes_window"}: what the pools
         of the layers that keep every position hold, and what the window
-        layers' rings hold."""
+        layers' rings hold; with "kv_pool_bytes_state" where layers keep a
+        state, what their slots' states hold."""
         out = {"kv_pool_bytes_full": 0, "kv_pool_bytes_window": 0}
+        if any(self._states):
+            out["kv_pool_bytes_state"] = 0
         for li, ring in enumerate(self._rings):
-            out["kv_pool_bytes_full" if ring is None
+            out["kv_pool_bytes_state" if self._states[li]
+                else "kv_pool_bytes_full" if ring is None
                 else "kv_pool_bytes_window"] += sum(
                 int(pools[li].nbytes)
                 for pools in (self.k_pages, self.v_pages) if pools)
@@ -745,8 +765,9 @@ class ServingEngine:
 
     def _check_mixed_layout_support(self, **asked):
         """A mixed layout (one pool of latent rows a layer, window layers'
-        rings beside full layers' pages, or layers whose pools differ in
-        heads or widths) has no int8 pages, no
+        rings beside full layers' pages, layers that keep a state of fixed
+        size a sequence, or layers whose pools differ in heads or widths)
+        has no int8 pages, no
         window step (speculative decoding, chunked prefill and with it
         the prefix cache and its tiers) and no draft pools yet: asking
         for one raises here, at construction, and nothing falls back."""
@@ -764,9 +785,9 @@ class ServingEngine:
                 raise ValueError(
                     f"{name}={value!r} is not built for a mixed layout (a "
                     "model that caches a latent in one page pool a layer, "
-                    "or keeps a window of some layers in rings; decoded "
-                    "one token a row): serve it without, or see ROADMAP R9 "
-                    "and R10")
+                    "keeps a window of some layers in rings, or a state of "
+                    "some layers a slot; decoded one token a row): serve it "
+                    "without, or see ROADMAP R9, R10 and R11")
 
     def _pin_pages(self):
         """Lay the page pools out in the serving sharding (kv heads over
@@ -1516,6 +1537,31 @@ class ServingEngine:
             tag=(nb, bucket, "ring" if ring else "full", layout[0][0]))
         return fn
 
+    def _get_state_write_fn(self, nb, layout):
+        """The state write after a prefill, a compiled program a
+        (batch-bucket, layout of states): the states a prefill handed back
+        for its rows ([nb, ...] each, at each row's last real token) land
+        in the rows' SLOTS of that layer's donated pools, which replaces
+        whatever a slot's last owner left; a padded row (length 0) writes
+        nothing."""
+        key = (nb, "state", layout)
+        fn = self._page_write_fns.get(key)
+        if fn is not None:
+            return fn
+
+        def pure_state_write(pools, *states_slots_lens):
+            *states, slots, lens = states_slots_lens
+            where = jnp.where(lens > 0, slots, self.max_batch)
+            return tuple(pool.at[where].set(state.astype(pool.dtype),
+                                            mode="drop")
+                         for pool, state in zip(pools, states))
+
+        fn = self._page_write_fns[key] = _cw.watch_jit(
+            "serving.kv_scatter",
+            jax.jit(pure_state_write, donate_argnums=(0,)),
+            tag=(nb, "state"))
+        return fn
+
     def _write_prefill_pages(self, write, k_pages, v_pages, k_scales,
                              v_scales):
         """Write every layer of a prefill's K/V into its pools:
@@ -1630,9 +1676,12 @@ class ServingEngine:
                     slots = jnp.asarray(slots)
 
                     def write(li, pools):
-                        ring = self._rings[li]
+                        ring, layout = self._rings[li], self._kv_layouts[li]
+                        if self._states[li]:
+                            return self._get_state_write_fn(nb, layout)(
+                                pools, *ks[li], slots, write_lens)
                         return self._get_layer_write_fn(
-                            nb, bucket, ring, self._kv_layouts[li])(
+                            nb, bucket, ring, layout)(
                             pools, *ks[li], tables if ring is None
                             else slots, write_lens)
                 else:
@@ -3123,8 +3172,9 @@ class ServingEngine:
         if self._mixed_layout:
             raise NotImplementedError(
                 "KV hand-off packs (k, v) pages of every position: a mixed "
-                "layout (a latent page pool, window layers' rings) has no "
-                "hand-off format yet (ROADMAP R9 and R10)")
+                "layout (a latent page pool, window layers' rings, layers' "
+                "states) has no hand-off format yet (ROADMAP R9, R10 and "
+                "R11)")
 
     def detach_request(self, request_id: int) -> "KVHandoff":
         """Extract a prefilled request from this engine: gather its KV

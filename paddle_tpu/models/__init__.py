@@ -13,6 +13,8 @@ from .gpt import (  # noqa: F401
 from .latent_moe import (  # noqa: F401
     AfmoeConfig,
     AfmoeForCausalLM,
+    JambaConfig,
+    JambaForCausalLM,
     LatentMoEConfig,
     LatentMoEForCausalLM,
     LatentMoEModel,

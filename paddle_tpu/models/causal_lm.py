@@ -4,10 +4,26 @@ dense KV-cache allocation and the generate() entry — one implementation
 so the two models cannot drift."""
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax.numpy as jnp
 
 from .. import nn
 from ..observability.tracing import scope
+
+
+class StatePool(NamedTuple):
+    """A pool of a layer that keeps a state of fixed size a sequence (a
+    state-space layer's conv tail or scan state) in place of K/V rows:
+    `shape` a sequence, `dtype` its own or None for the cache's."""
+    shape: tuple
+    dtype: str = None
+
+    def zeros(self, batch, default_dtype):
+        """`batch` zero states, in `default_dtype` where the pool names
+        none."""
+        return jnp.zeros((batch,) + tuple(self.shape),
+                         self.dtype or default_dtype)
 
 
 class CausalLMBase(nn.Layer):
@@ -29,7 +45,9 @@ class CausalLMBase(nn.Layer):
         and V of a head count or of widths of the layer's own) or keep only
         a window of some layers' positions (`kv_cache_windows`) states it
         by overriding these, and the dense caches below and the serving
-        engine's page pools follow, layer by layer."""
+        engine's page pools follow, layer by layer. A layer that keeps no
+        K/V at all answers `StatePool`s: a state a sequence, whatever its
+        length, which the engine keeps a slot apiece."""
         cfg = self.config
         kv = (self._kv_heads(), cfg.hidden_size // cfg.num_attention_heads)
         return ((kv, kv),) * cfg.num_hidden_layers
@@ -44,10 +62,17 @@ class CausalLMBase(nn.Layer):
     def init_kv_caches(self, batch_size, max_length, dtype=None):
         """Dense per-layer caches for incremental decoding: one `[batch,
         max_length, heads, width]` array per pool of the layer's layout
-        ((k, v) for full attention)."""
+        ((k, v) for full attention), a zero `[batch, *shape]` state per
+        `StatePool`."""
         dt = dtype or jnp.float32
-        return [tuple(jnp.zeros((batch_size, max_length, heads, width), dt)
-                      for heads, width in layout)
+
+        def empty(pool):
+            if isinstance(pool, StatePool):
+                return pool.zeros(batch_size, dt)
+            heads, width = pool
+            return jnp.zeros((batch_size, max_length, heads, width), dt)
+
+        return [tuple(empty(pool) for pool in layout)
                 for layout in self.kv_cache_layouts()]
 
     def forward_prefill(self, input_ids, caches, true_lens):

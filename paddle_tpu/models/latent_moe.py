@@ -1,5 +1,7 @@
-"""Latent-attention mixture-of-experts causal LM (the DeepSeek-V3 family's
-shape, as openPangu-Ultra-MoE publishes it: `model_type` pangu_ultra_moe).
+"""The one decoder stack of the serving families: latent, grouped-query
+and Mamba mixers, dense and expert FFNs (openPangu-Ultra-MoE: `model_type`
+pangu_ultra_moe; Trinity-Mini: afmoe; MiMo-V2.5: mimo_v2; AI21-Jamba2-3B:
+jamba).
 
 ONE decoder stack over a per-layer list of kinds (a config's
 `layer_kinds()`): the mixer (`latent`: multi-head latent attention;
@@ -10,15 +12,19 @@ afmoe; `sink_window` / `sink_full`: ungated grouped-query attention whose
 keys are wider than its values, roped on the first dims of a head alone,
 with a kv-head count and a rope base of the kind's own, scaled values
 and, where the config says so, a learned sink in the softmax, as MiMo-V2.5
-publishes it: `model_type` mimo_v2), the FFN (`dense`; `routed+shared`:
+publishes it: `model_type` mimo_v2; `mamba`: Mamba-1's selective
+state-space mixer, which caches no K/V but a state of fixed size a
+sequence, as AI21-Jamba2-3B publishes it beside `sink_full` layers with
+every option off), the FFN (`dense`; `routed+shared`:
 this chip's share of the routed experts plus the shared expert; `routed`:
 the share alone) and where the norms stand (`sandwich`: a norm before AND
 after each sublayer, the residual added outside both; `pre`). The stack's
 classes take any config that gives `layer_kinds()`, `moe_spec()` and the
-sizes a kind reads: `LatentMoEConfig`, `AfmoeConfig` and `MiMoV2Config`
-here. `gpt.py` and `llama.py` keep their own stacks (ROADMAP D6).
+sizes a kind reads: `LatentMoEConfig`, `AfmoeConfig`, `MiMoV2Config` and
+`JambaConfig` here. `gpt.py` and `llama.py` keep their own stacks (ROADMAP D6).
 
-The equations (`N(x; g) = x / sqrt(mean(x^2) + eps) * g`, no biases):
+The equations (`N(x; g) = x / sqrt(mean(x^2) + eps) * g`, no biases but a
+Mamba mixer's two):
 
     block    x' = x + N(Attn(N(x; g_in)); g_post_attn)
              y  = x' + N(FFN(N(x'; g_pre_mlp)); g_post_mlp)
@@ -48,15 +54,25 @@ The equations (`N(x; g) = x / sqrt(mean(x^2) + eps) * g`, no biases):
              the router has an `expert_bias` b: the pick alone);
              w_e = scale s_e / sum_S s
              y = sum_{e in S & held here} w_e E_e(x) + E_shared(x)
+    mamba    [u | z] = x W_in (d_inner E each); a depthwise causal conv of
+             d_conv K taps with a bias: u'_t = silu(b_c + sum_k w_c[k] *
+             u_{t-K+1+k}), u_{<0} = 0; [d | B | C] = u' W_x, each RMS-normed
+             (dt_rank R, d_state N, N); D_t = softplus(d W_dt + b_dt) [E];
+             A = -exp(A_log) [E, N];
+             h_t = exp(D_t (x) A) h_{t-1} + (D_t u'_t) (x) B_t,  h_{-1} = 0
+             y_t = h_t C_t + D_skip u'_t;  o = (y * silu(z)) W_out
 
 What a layer caches is its mixer's to say (`cache_layout()`, gathered a
 layer an entry by `kv_cache_layouts()`; the serving engine shapes each
 layer's pools from its own entry): a latent layer holds `(c | rope(k_r))`,
 `kv_lora_rank + qk_rope_head_dim` numbers a token in ONE pool, never K or
 V; a grouped-query layer holds K and V of its own kv heads, a key stored
-`_pool_width` wide where it is wider than a value. Prefill and `forward` decompress
-K and V from the latent and attend in blocks (a row and a group of heads at
-a time: float32 scores of 16 x 128 x 1024 x 1024 would be 8.6 GB); decode
+`_pool_width` wide where it is wider than a value; a Mamba layer holds
+two `StatePool`s, the conv's last K - 1 inputs in the served type and the
+scan's [N, E] state in float32, whatever the context. Prefill and
+`forward` decompress K and V from the latent and attend in blocks (a row
+and a group of heads at a time: float32 scores of 16 x 128 x 1024 x 1024
+would be 8.6 GB); decode
 over pages uses the absorbed form, multi-query attention over the one
 latent key head: `q~_h = q_nope,h W_kvb[K,h]^T`, `scores_h = (q~_h . c +
 q_rope,h . k_r) / sqrt(nope + rope)`, `o_h = (P_h c) W_kvb[V,h]`. The same
@@ -73,17 +89,19 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import nn
 from ..incubate.distributed.models.moe.expert_share import (
     ExpertShareLayer, over_token_blocks)
 from ..kernels import flash_attention as _fa
 from ..kernels import paged_attention as _pa
+from ..kernels import selective_scan as _ss
 from ..nn import initializer as I
 from ..nn.functional.rope import apply_rope
 from ..observability.tracing import scope
 from ..tensor import Tensor, _apply_op, as_array
-from .causal_lm import CausalLMBase
+from .causal_lm import CausalLMBase, StatePool
 from .paged_step import paged_attention_step, window_attention_step
 
 F32 = jnp.float32
@@ -354,6 +372,90 @@ class MiMoV2Config:
             ep_rank=ep_rank, ep_degree=ep_degree)
 
 
+@dataclass
+class JambaConfig:
+    """AI21-Jamba2-3B's config.json keys (`model_type` jamba): layer i is
+    attention where `i % attn_layer_period == attn_layer_offset` and a
+    Mamba-1 mixer everywhere else; every layer's FFN is the dense SwiGLU
+    (`num_experts` 1: the published modelling code builds a layer of one
+    expert as that dense MLP). Attention is grouped-query over every
+    position with no position encoding, gate, q/k norm, sink or value
+    scale (the Mamba layers carry position); the head is tied."""
+    vocab_size: int = 65536
+    hidden_size: int = 2560
+    intermediate_size: int = 8192
+    num_hidden_layers: int = 28
+    num_attention_heads: int = 20
+    num_key_value_heads: int = 1
+    attn_layer_period: int = 14
+    attn_layer_offset: int = 7
+    expert_layer_period: int = 2
+    expert_layer_offset: int = 1
+    num_experts: int = 1
+    num_experts_per_tok: int = 1
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 160
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    rms_norm_eps: float = 1e-6
+    max_position_embeddings: int = 262144
+    sliding_window: int = None
+    tie_word_embeddings: bool = True
+    dtype: str = "float32"
+
+    def __post_init__(self):
+        refused = {"num_experts": (self.num_experts, 1),
+                   "num_experts_per_tok": (self.num_experts_per_tok, 1),
+                   "sliding_window": (self.sliding_window, None),
+                   "mamba_conv_bias": (self.mamba_conv_bias, True),
+                   "mamba_proj_bias": (self.mamba_proj_bias, False)}
+        for key, (given, built) in refused.items():
+            if given != built:
+                raise NotImplementedError(
+                    f"jamba is built for {key}={built!r} (AI21-Jamba2-3B's "
+                    f"value), got {given!r}")
+
+    @property
+    def head_dim(self):
+        return self.hidden_size // self.num_attention_heads
+
+    def attention(self, window):
+        """What a `sink_full` mixer is built from: every option off."""
+        d = self.head_dim
+        return dict(heads=self.num_attention_heads,
+                    kv_heads=self.num_key_value_heads, key_dim=d,
+                    value_dim=d, rope_dims=0, rope_theta=None, window=None,
+                    sink=False, value_scale=1.0)
+
+    def mamba(self):
+        """What a `mamba` mixer is built from."""
+        return dict(d_inner=self.mamba_expand * self.hidden_size,
+                    d_state=self.mamba_d_state, d_conv=self.mamba_d_conv,
+                    dt_rank=self.mamba_dt_rank)
+
+    def layer_kinds(self):
+        return [("sink_full" if i % self.attn_layer_period
+                 == self.attn_layer_offset else "mamba", "dense", "pre")
+                for i in range(self.num_hidden_layers)]
+
+    def moe_spec(self):
+        return dict(shared=0)
+
+    @staticmethod
+    def tiny(vocab=96, layers=8):
+        """Every width shrunk, the kinds kept: a period of 4 with attention
+        at offset 2 (layers 2 and 6), 2 query heads on 1 kv head of 32,
+        d_inner 128 (one lane tile of the scan's kernel), d_state 8,
+        dt_rank 6, d_conv 4."""
+        return JambaConfig(
+            vocab_size=vocab, hidden_size=64, intermediate_size=64,
+            num_hidden_layers=layers, num_attention_heads=2,
+            num_key_value_heads=1, attn_layer_period=4, attn_layer_offset=2,
+            mamba_d_state=8, mamba_dt_rank=6, max_position_embeddings=512)
+
+
 # ---------------------------------------------------------------------------
 # the mathematics, on plain arrays
 # ---------------------------------------------------------------------------
@@ -529,15 +631,19 @@ def _widen(x, width):
 def sink_projections(x, p, a, positions):
     """x [b, s, hidden] (normed) -> (q [b, s, h, d_k], k [b, s, kv, d_k],
     v [b, s, kv, d_v]) of a `sink_*` mixer built from `a`
-    (`MiMoV2Config.attention`): the values scaled, the first `rope_dims`
-    of each head of q and k rotated at `positions` [b, s], the rest of the
-    head carrying no position."""
+    (`MiMoV2Config.attention`, `JambaConfig.attention`): the values scaled
+    where the kind scales them, the first `rope_dims` of each head of q and
+    k rotated at `positions` [b, s], the rest of the head (all of it where
+    `rope_dims` is 0) carrying no position."""
     b, s, _ = x.shape
     q = _mm(x, p["q_proj"]).reshape(b, s, a["heads"], a["key_dim"])
     k = _mm(x, p["k_proj"]).reshape(b, s, a["kv_heads"], a["key_dim"])
     v = _mm(x, p["v_proj"]).reshape(b, s, a["kv_heads"], a["value_dim"])
-    v = (v.astype(F32) * a["value_scale"]).astype(v.dtype)
+    if a["value_scale"] != 1.0:
+        v = (v.astype(F32) * a["value_scale"]).astype(v.dtype)
     r = a["rope_dims"]
+    if not r:
+        return q, k, v
 
     def rotate(t):
         return jnp.concatenate(
@@ -610,7 +716,9 @@ class _Weight(nn.Layer):
 
 class _Mixer(nn.Layer):
     """A mixer's leaves are `_Weight`s (or bare parameters) named by
-    LEAVES."""
+    LEAVES. Each `forward_cached` is handed `live` ([b, s] bool, a
+    prefill's real tokens first, or None): attention, causal, need not read
+    it; a mixer that carries a state from token to token must."""
     LEAVES = ()
 
     def _run(self, fn, *inputs, name):
@@ -648,7 +756,7 @@ class LatentAttention(_Mixer):
             c.kv_lora_rank, h * (c.qk_nope_head_dim + c.v_head_dim))
         self.o_proj = _Weight(h * c.v_head_dim, c.hidden_size)
 
-    def forward_cached(self, x, cache, cur_len):
+    def forward_cached(self, x, cache, cur_len, live=None):
         """x [b, s, hidden] at positions cur_len .. cur_len + s - 1; cache
         `(latent rows [b, t, 1, width],)`. Decompressed attention over the
         whole cache. Returns (out, new cache)."""
@@ -731,7 +839,7 @@ class _GQAttention(_Mixer):
         return ((self.kv_heads, _pool_width(self.key_dim)),
                 (self.kv_heads, self.value_dim))
 
-    def forward_cached(self, x, cache, cur_len):
+    def forward_cached(self, x, cache, cur_len, live=None):
         """x [b, s, hidden] at positions cur_len .. cur_len + s - 1; cache
         (k rows [b, t, kv, key width], v rows [b, t, kv, d_v]) of EVERY
         position (the dense cache of `generate` and of a prefill, whatever
@@ -889,6 +997,155 @@ class SinkGQAttention(_GQAttention):
         return _mm(ctx, p["o_proj"])
 
 
+class MambaMixer(_Mixer):
+    """Mamba-1's selective state-space mixer as AI21-Jamba2-3B publishes it
+    (the module docstring's `mamba` equations): the four projections in,
+    x, dt and out without a bias, a depthwise causal conv of `d_conv` taps
+    with one, RMS norms on dt's input, B and C, a diagonal A = -exp(A_log)
+    and a skip D. It caches no K/V: a sequence's state is the conv's last
+    `d_conv - 1` inputs (the served type) and the scan's [d_state, d_inner]
+    (float32), `StatePool`s of the engine's. `scope("ssm")` holds the conv
+    and the scan with their state's reads and writes; the projections stay
+    in the layer's `attn`."""
+    LEAVES = ("in_proj", "conv_weight", "conv_bias", "x_proj",
+              "dt_layernorm", "b_layernorm", "c_layernorm", "dt_proj",
+              "dt_bias", "A_log", "D", "out_proj")
+    window = None     # no positions are kept at all
+
+    def __init__(self, config):
+        super().__init__()
+        m = self.spec = config.mamba()
+        self.eps = config.rms_norm_eps
+        hid, e, n = config.hidden_size, m["d_inner"], m["d_state"]
+        self.in_proj = _Weight(hid, 2 * e)
+        # [d_conv, d_inner]: tap k weighs the input k - d_conv + 1 back
+        self.conv_weight = self.create_parameter(
+            shape=[m["d_conv"], e], default_initializer=I.Normal(0.0, 0.02))
+        self.conv_bias = self.create_parameter(
+            shape=[e], default_initializer=I.Constant(0.0))
+        self.x_proj = _Weight(e, m["dt_rank"] + 2 * n)
+        self.dt_layernorm = _Weight(m["dt_rank"])
+        self.b_layernorm = _Weight(n)
+        self.c_layernorm = _Weight(n)
+        self.dt_proj = _Weight(m["dt_rank"], e)
+        # Mamba's initialisation: softplus(dt_bias) spread geometrically
+        # over [0.001, 0.1] across the channels, A = -(1 .. d_state), D = 1
+        dt = np.exp(np.linspace(np.log(1e-3), np.log(1e-1), e))
+        self.dt_bias = self.create_parameter(
+            shape=[e], default_initializer=I.Assign(
+                dt + np.log(-np.expm1(-dt))))
+        self.A_log = self.create_parameter(
+            shape=[e, n], default_initializer=I.Assign(
+                np.log(np.tile(np.arange(1.0, n + 1), (e, 1)))))
+        self.D = self.create_parameter(
+            shape=[e], default_initializer=I.Constant(1.0))
+        self.out_proj = _Weight(e, hid)
+
+    def cache_layout(self):
+        m = self.spec
+        return (StatePool((m["d_conv"] - 1, m["d_inner"])),
+                StatePool((m["d_state"], m["d_inner"]), "float32"))
+
+    def _inputs(self, p, x):
+        """x [b, s, hidden] (normed) -> (u, z) [b, s, d_inner] each."""
+        u, z = jnp.split(_mm(x, p["in_proj"]), 2, axis=-1)
+        return u, z
+
+    def _scan_inputs(self, p, u):
+        """u' [b, s, d_inner] -> (dt float32 [b, s, d_inner], B, C float32
+        [b, s, d_state], A float32 [d_state, d_inner])."""
+        m, eps = self.spec, self.eps
+        r, n = m["dt_rank"], m["d_state"]
+        d, b, c = jnp.split(_mm(u, p["x_proj"]), [r, r + n], axis=-1)
+        d = rms_norm(d, p["dt_layernorm"], eps)
+        b = rms_norm(b.astype(F32), p["b_layernorm"], eps)
+        c = rms_norm(c.astype(F32), p["c_layernorm"], eps)
+        dt = jax.nn.softplus(
+            jnp.matmul(d, p["dt_proj"].astype(d.dtype),
+                       preferred_element_type=F32)
+            + p["dt_bias"].astype(F32))
+        a = -jnp.exp(p["A_log"].astype(F32)).T
+        return dt, b, c, a
+
+    def _out(self, p, y, z):
+        gate = jax.nn.silu(z.astype(F32))
+        return _mm((y.astype(F32) * gate).astype(z.dtype), p["out_proj"])
+
+    def forward_cached(self, x, cache, cur_len, live=None):
+        """x [b, s, hidden] from the state `cache` (conv tail [b, d_conv -
+        1, d_inner], scan state [b, d_state, d_inner]); `cur_len` is not
+        read (the state is the position). With `live` ([b, s] bool, a
+        prefill's real tokens first), a row's tokens past its real ones
+        leave the state as it was: the state handed back is the one at the
+        row's last real token, and the conv tail its last d_conv - 1 real
+        inputs. Returns (out, new cache)."""
+        conv, ssm = (as_array(a) for a in cache)
+        lens = None if live is None else jnp.sum(
+            as_array(live), axis=-1, dtype=jnp.int32)
+
+        def f(p, x, conv, ssm):
+            b, s, _ = x.shape
+            n = jnp.full((b,), s, jnp.int32) if lens is None else lens
+            u, z = self._inputs(p, x)
+            with scope("ssm"):
+                k = conv.shape[1]
+                ext = jnp.concatenate([conv.astype(u.dtype), u], axis=1)
+                acc = p["conv_bias"].astype(F32) + sum(
+                    ext[:, i:i + s].astype(F32)
+                    * p["conv_weight"][i].astype(F32) for i in range(k + 1))
+                u = jax.nn.silu(acc).astype(u.dtype)
+                # the last d_conv - 1 real inputs: ext's positions n .. n +
+                # d_conv - 2 (the old tail's where a row has fewer)
+                at = n[:, None] + jnp.arange(k)
+                conv = jnp.take_along_axis(ext, at[..., None], axis=1
+                                           ).astype(conv.dtype)
+            dt, bm, cm, a = self._scan_inputs(p, u)
+            with scope("ssm"):
+                y, ssm = _ss.selective_scan(u, dt, a, bm, cm, p["D"], ssm, n)
+            return self._out(p, y, z), conv, ssm
+
+        out, conv, ssm = self._run(f, x, conv, ssm, name="mamba_mixer")
+        return out, (as_array(conv), as_array(ssm))
+
+    def forward(self, x):
+        b, dtype = x.shape[0], as_array(x).dtype
+        empty = tuple(pool.zeros(b, dtype) for pool in self.cache_layout())
+        return self.forward_cached(x, empty, 0)[0]
+
+    def forward_paged(self, x, cache, block_tables, context_lens,
+                      active=None, mesh=None):
+        """One new token a row (x [b, 1, hidden]) over the state pools
+        `(conv [b, d_conv - 1, d_inner], scan [b, d_state, d_inner])`,
+        indexed by the row's slot: a row that is not `active` keeps its
+        state as it was. The step's update is XLA's fusion."""
+        conv, ssm = (as_array(a) for a in cache)
+        act = None if active is None else as_array(active)
+
+        def f(p, x, conv, ssm):
+            u, z = self._inputs(p, x[:, 0])
+            with scope("ssm"):
+                ext = jnp.concatenate([conv.astype(u.dtype), u[:, None]],
+                                      axis=1)
+                acc = p["conv_bias"].astype(F32) + jnp.sum(
+                    ext.astype(F32) * p["conv_weight"].astype(F32), axis=1)
+                u = jax.nn.silu(acc).astype(u.dtype)
+                new_conv = ext[:, 1:].astype(conv.dtype)
+            dt, bm, cm, a = self._scan_inputs(p, u)
+            with scope("ssm"):
+                uf = u.astype(F32)
+                h = jnp.exp(dt[:, None, :] * a) * ssm \
+                    + (dt * uf)[:, None, :] * bm[..., None]
+                y = jnp.sum(h * cm[..., None], axis=1) \
+                    + p["D"].astype(F32) * uf
+                if act is not None:
+                    new_conv = jnp.where(act[:, None, None], new_conv, conv)
+                    h = jnp.where(act[:, None, None], h, ssm)
+            return self._out(p, y.astype(u.dtype), z)[:, None], new_conv, h
+
+        out, conv, ssm = self._run(f, x, conv, ssm, name="mamba_mixer")
+        return out, (as_array(conv), as_array(ssm))
+
+
 class GatedFFN(nn.Layer):
     def __init__(self, hidden, width):
         super().__init__()
@@ -934,6 +1191,7 @@ MIXERS = {
     "gqa_full": lambda c: GatedGQAttention(c, None),
     "sink_window": lambda c: SinkGQAttention(c, True),
     "sink_full": lambda c: SinkGQAttention(c, False),
+    "mamba": MambaMixer,
 }
 
 
@@ -981,7 +1239,8 @@ class LatentMoEDecoderLayer(nn.Layer):
 
     def forward_cached(self, x, cache, cur_len, live=None):
         return self._block(
-            x, lambda a: self.self_attn.forward_cached(a, cache, cur_len),
+            x, lambda a: self.self_attn.forward_cached(a, cache, cur_len,
+                                                       live),
             live=live)
 
     def forward_paged(self, x, cache, block_tables, context_lens,
@@ -1100,6 +1359,17 @@ class LatentMoEForCausalLM(CausalLMBase):
     def _backbone_embed_weight(self):
         return self.model.embed_tokens.weight
 
+    @scope("head")
+    def _head(self, h):
+        """float32 logits whatever the model's dtype, tied or not (a bf16
+        logit near 10 moves in steps of 1/16)."""
+        if self.lm_head is not None:
+            return self.lm_head(h)
+        return _apply_op(
+            lambda h, w: jnp.einsum("...h,vh->...v", h, w.astype(h.dtype),
+                                    preferred_element_type=F32),
+            h, self.model.embed_tokens.weight, _name="lm_head")
+
     def forward(self, input_ids, attn_mask=None):
         return self._head(self.model(input_ids, attn_mask))
 
@@ -1169,3 +1439,13 @@ class MiMoV2ForCausalLM(AfmoeForCausalLM):
     FFNs with no shared expert, norms before the sublayers only, an
     untied float32 head taken at each prompt's last position. Serving
     only, like its parents."""
+
+
+class JambaForCausalLM(AfmoeForCausalLM):
+    """AI21-Jamba2-3B's shape over the same stack: Mamba layers beside
+    grouped-query attention layers with every option off, the dense FFN
+    in every layer, norms before the sublayers only, a tied float32 head
+    taken at each prompt's last real position. A prefill's padding leaves
+    every Mamba layer's state where the prompt's last token left it (the
+    stack tells the mixer which positions are live). Serving only: the
+    scan has no backward."""

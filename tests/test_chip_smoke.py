@@ -117,7 +117,8 @@ def test_kernel_references_agree_with_the_kernels_in_interpret_mode(cs):
                             flash_seq=256, tokens=64, decode_batch=2,
                             pages_per_seq=8, wide=512, expert_hidden=128,
                             expert_width=256, experts_held=8, gqa_seq=512,
-                            gqa_kv_heads=1, gqa_group=2)
+                            gqa_kv_heads=1, gqa_group=2, scan_seq=256,
+                            scan_inner=1024)
     assert len(cases) == len(cs.kernel_cases())
     for case in cases:
         args = tuple(jnp.asarray(a) for a in

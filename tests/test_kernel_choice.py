@@ -9,7 +9,8 @@ The rows hold the four benchmark cells' own shapes and both sides of every
 boundary the choice has (`paged_attention._KERNEL_MIN_PAGE`,
 `_XLA_DECODE_MAX_CTX`, `flash_attention._PALLAS_MIN_SEQ` /
 `_PALLAS_SMALL_BLOCK_MIN_SEQ` / `GQA_MIN_SEQ`, `expert_hit._HIT_MAX_TOKENS`,
-`expert_grouped._GROUPED_MIN_TOKENS`, the kernels' `supports`)."""
+`expert_grouped._GROUPED_MIN_TOKENS`, the kernels' `supports`, and
+`selective_scan.scan_block`)."""
 import importlib
 
 import jax
@@ -26,6 +27,7 @@ from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import quant_matmul as qm
 from paddle_tpu.kernels import rms_norm as rn
+from paddle_tpu.kernels import selective_scan as ss
 from paddle_tpu.tensor import Tensor, as_array
 
 sdpa_mod = importlib.import_module("paddle_tpu.nn.functional.attention")
@@ -497,6 +499,76 @@ def test_flash_tiling(row):
     # the streamed backward starts where the forward's choice does
     assert fa._bwd_use_xla(s_q, want) \
         == (not fa.use_flash(s_q, s_kv, d, 0.0, dtype))
+
+
+# ---------------------------------------------------------------------------
+# a Mamba mixer's scan: the kernel for every prefill, selective_scan.scan_block
+# ---------------------------------------------------------------------------
+
+# (prompt bucket, hidden size) -> the block of d_inner a prefill's scan
+# kernel takes, or None where it refuses the shape: jamba2-3b.docs-closed's
+# token buckets at d_inner 5,120 and d_state 16, a prompt no cell sends, a
+# d_inner no block of 1,024 divides, and one of no whole lane tiles
+SCAN = {
+    "docs-closed-4096": ((4096, 2560), 1024),
+    "docs-closed-8192": ((8192, 2560), 1024),
+    "docs-closed-16384": ((16384, 2560), 1024),
+    "a-short-prompt": ((128, 2560), 1024),
+    "d_inner-1536": ((4096, 768), 768),
+    "d_inner-4000": ((4096, 2000), None),
+}
+
+
+def _mamba_mixer(hidden):
+    from paddle_tpu.models import JambaConfig, latent_moe
+
+    zeros = lambda shape, _dtype: jnp.zeros(tuple(shape), BF16)  # noqa: E731
+    paddle.nn.initializer.set_global_initializer(zeros, zeros)
+    try:
+        return latent_moe.MambaMixer(JambaConfig(num_hidden_layers=1,
+                                                 hidden_size=hidden))
+    finally:
+        paddle.nn.initializer.set_global_initializer(None, None)
+
+
+def _record_scans(monkeypatch, taken):
+    def recorder(lens, u, dt, bt, ct, a, d, h0, **kw):
+        taken.append(kw["block"])
+        return u, h0
+
+    monkeypatch.setattr(ss, "_kernel_call", recorder)
+
+
+@pytest.mark.parametrize("row", sorted(SCAN))
+def test_mamba_prefill_scan_choice(monkeypatch, row):
+    (s, hidden), want = SCAN[row]
+    taken = []
+    _record_scans(monkeypatch, taken)
+    mixer = _mamba_mixer(hidden)
+
+    def trace():
+        jax.eval_shape(lambda x: as_array(mixer(Tensor(x))),
+                       S((1, s, hidden), BF16))
+
+    if want is None:
+        with pytest.raises(ValueError, match="whole tiles of 128 lanes"):
+            trace()
+        assert taken == []
+    else:
+        trace()
+        assert taken == [want]
+
+
+def test_a_mamba_decode_step_leaves_its_update_to_xla(monkeypatch):
+    """docs-closed's burst: 8 rows, one token each, over the state pools."""
+    taken = []
+    _record_scans(monkeypatch, taken)
+    mixer = _mamba_mixer(2560)
+    jax.eval_shape(
+        lambda x, conv, ssm: as_array(mixer.forward_paged(
+            Tensor(x), (conv, ssm), None, None)[0]),
+        S((8, 1, 2560), BF16), S((8, 3, 5120), BF16), S((8, 16, 5120), F32))
+    assert taken == []
 
 
 # ---------------------------------------------------------------------------

@@ -34,6 +34,7 @@ from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import quant_matmul as qm
 from paddle_tpu.kernels import rms_norm as rn
+from paddle_tpu.kernels import selective_scan as ss
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -55,7 +56,7 @@ def v5e():
 def mosaic(monkeypatch):
     """interpret=False in every kernel module although the default backend
     is the CPU: the programs are lowered for the topology's devices."""
-    for mod in (fa, pa, rn, qm, eh, eg):
+    for mod in (fa, pa, rn, qm, eh, eg, ss):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -424,6 +425,23 @@ def test_gqa_prefill_kernel_at_the_long_cell_shapes(v5e, seq, kv_heads,
         v5e[0], f, S((1, seq, 64, 192), BF16), S((1, seq, kv_heads, 192), BF16),
         S((1, seq, kv_heads, 128), BF16),
         *((S((64,), F32),) if sink else ())) == 1
+
+
+@pytest.mark.parametrize("rows,seq", [(1, 4096), (1, 16384), (2, 1000)])
+def test_selective_scan_at_the_docs_cell_shapes(v5e, rows, seq):
+    """A Mamba layer's prefill scan at jamba2-3b.docs-closed's smallest and
+    largest buckets (d_inner 5,120 in 5 blocks of 1,024, d_state 16), and a
+    length no chunk divides over two rows: one kernel, state in VMEM."""
+    e, n = 5120, 16
+    assert ss.scan_block(e) == ss.SCAN_BLOCK_E
+
+    def f(u, dt, a, b, c, d, h0, lens):
+        return ss.selective_scan(u, dt, a, b, c, d, h0, lens)
+
+    assert compile_for(
+        v5e[0], f, S((rows, seq, e), BF16), S((rows, seq, e), F32),
+        S((n, e), F32), S((rows, seq, n), F32), S((rows, seq, n), F32),
+        S((e,), F32), S((rows, n, e), F32), S((rows,), I32)) == 1
 
 
 def test_hit_ffn_at_the_long_cell_shape(v5e):
