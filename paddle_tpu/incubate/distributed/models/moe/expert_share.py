@@ -17,8 +17,10 @@ the whole layer's routed result (tests/test_latent_moe.py holds that).
 `SigmoidTopKGate` scores with a sigmoid in float32 and picks the `top_k`
 largest (no groups; with `pick_bias` the router's `expert_bias` is added to
 the scores for the pick alone); `ExpertShareLayer` owns the gate
-and the held experts' stacked gated-SiLU weights, and takes the sum above
-in one of three forms, chosen from what the call can see (its token count,
+and the held experts' stacked gated weights, whose activation is SiLU or,
+where the layer is built with one, PolyNorm (`polynorm`: per expert its
+own three weights and bias, the statistics over the whole expert width),
+and takes the sum above in one of three forms, chosen from what the call can see (its token count,
 shapes and types, whether a gradient may be recorded, `_interpret()`):
 
 - `share_ffn`, the dense form and the reference: every held expert's
@@ -94,11 +96,32 @@ def held_weights(picks, weights, first, held):
     return jnp.sum(jnp.where(local, weights[..., None], 0.0), axis=1)
 
 
-def share_ffn(x, dense_w, w_gate, w_up, w_down):
+def polynorm(u, w, b, scale, clamp, eps):
+    """PolyNorm of u [..., f] float32 over its last axis: `scale (w_0 N(u^3)
+    + w_1 N(u^2) + w_2 N(u)) + clip(b, -clamp, clamp)`, `N(t) = t /
+    sqrt(mean(t^2) + eps)`; w [..., 3] and b [..., 1] broadcast against u's
+    leading axes (an expert's own in a stack of them)."""
+    def n(t):
+        return t * jax.lax.rsqrt(jnp.mean(jnp.square(t), -1, keepdims=True)
+                                 + eps)
+
+    w, b = w.astype(jnp.float32), b.astype(jnp.float32)
+    return scale * (w[..., 0:1] * n(u * u * u) + w[..., 1:2] * n(u * u)
+                    + w[..., 2:3] * n(u)) + jnp.clip(b, -clamp, clamp)
+
+
+def activate(gate, act):
+    """An FFN's activation of its float32 gate [..., f]: SiLU where `act` is
+    None, else PolyNorm with `act` = (w, b, scale, clamp, eps)."""
+    return jax.nn.silu(gate) if act is None else polynorm(gate, *act)
+
+
+def share_ffn(x, dense_w, w_gate, w_up, w_down, act=None):
     """sum_e dense_w[:, e] * E_e(x) over the held experts: x [n, d],
-    dense_w [n, held], w_gate / w_up [held, d, f], w_down [held, f, d].
-    The weight goes onto the hidden activations, so the sum over experts
-    is the down projection's own float32 accumulation."""
+    dense_w [n, held], w_gate / w_up [held, d, f], w_down [held, f, d];
+    `act` None (SiLU) or (w [held, 3], b [held, 1], scale, clamp, eps) for
+    PolyNorm. The weight goes onto the hidden activations, so the sum over
+    experts is the down projection's own float32 accumulation."""
     held, _, f = w_gate.shape
 
     def block(xb, wb):
@@ -106,7 +129,7 @@ def share_ffn(x, dense_w, w_gate, w_up, w_down):
                           preferred_element_type=jnp.float32)
         up = jnp.einsum("nd,edf->nef", xb, w_up.astype(xb.dtype),
                         preferred_element_type=jnp.float32)
-        hidden = (jax.nn.silu(gate) * up * wb[..., None]).astype(xb.dtype)
+        hidden = (activate(gate, act) * up * wb[..., None]).astype(xb.dtype)
         return jnp.matmul(hidden.reshape(-1, held * f),
                           w_down.astype(xb.dtype).reshape(held * f, -1),
                           preferred_element_type=jnp.float32
@@ -153,11 +176,14 @@ class ExpertShareLayer(Layer):
     are real: it enters the counts, and on the hit path the list of experts
     whose weights are read. A live token's result is the same with it and
     without; a token that is not live gets the sum over the experts that
-    live tokens hit, which nothing reads."""
+    live tokens hit, which nothing reads. `act` None is SiLU; a dict
+    (`scale`, `clamp`, `eps`) makes every expert's activation PolyNorm,
+    with its own `act_weight` [3] and `act_bias` [1] in the stacks
+    `act_weight` [held, 3] and `act_bias` [held, 1]."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k, ep_rank=0,
                  ep_degree=1, routed_scaling_factor=1.0,
-                 norm_topk_prob=True, pick_bias=False):
+                 norm_topk_prob=True, pick_bias=False, act=None):
         super().__init__()
         if num_experts % ep_degree or not 0 <= ep_rank < ep_degree:
             raise ValueError(
@@ -174,6 +200,13 @@ class ExpertShareLayer(Layer):
                             ("w_down", [self.held, d_hidden, d_model])):
             setattr(self, name, self.create_parameter(
                 shape=shape, default_initializer=I.XavierUniform()))
+        self.act = act
+        if act is not None:
+            self.act_weight = self.create_parameter(
+                shape=[self.held, 3],
+                default_initializer=I.Constant(1.0 / 3.0))
+            self.act_bias = self.create_parameter(
+                shape=[self.held, 1], default_initializer=I.Constant(0.0))
 
     def forward(self, x, live=None):
         shape = [int(s) for s in x.shape]
@@ -187,8 +220,17 @@ class ExpertShareLayer(Layer):
             self._count(dense_w, live, ffn)
         with _trace.scope("experts"):
             kw = {} if ffn is share_ffn else {"live": live}
-            y = _apply_op(ffn, tokens, dense_w, self.w_gate, self.w_up,
-                          self.w_down, _name="moe_share_ffn", **kw)
+            if self.act is None:
+                y = _apply_op(ffn, tokens, dense_w, self.w_gate, self.w_up,
+                              self.w_down, _name="moe_share_ffn", **kw)
+            else:
+                a = self.act
+                y = _apply_op(
+                    lambda x, dw, wg, wu, wd, aw, ab: ffn(
+                        x, dw, wg, wu, wd, act=(aw, ab, a["scale"],
+                                                a["clamp"], a["eps"]), **kw),
+                    tokens, dense_w, self.w_gate, self.w_up, self.w_down,
+                    self.act_weight, self.act_bias, _name="moe_share_ffn")
         return y.reshape(shape)
 
     def _form(self, tokens):

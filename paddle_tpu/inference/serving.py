@@ -750,7 +750,7 @@ class ServingEngine:
     def kv_pool_bytes(self):
         """{"kv_pool_bytes_full", "kv_pool_bytes_window"}: what the pools
         of the layers that keep every position hold, and what the window
-        layers' rings hold; with "kv_pool_bytes_state" where layers keep a
+        layers' rings hold (K and V, or latent rows); with "kv_pool_bytes_state" where layers keep a
         state, what their slots' states hold."""
         out = {"kv_pool_bytes_full": 0, "kv_pool_bytes_window": 0}
         if any(self._states):
@@ -1515,21 +1515,32 @@ class ServingEngine:
     def _get_layer_write_fn(self, nb, bucket, ring, layout):
         """The page write of a layout with rings or whose layers differ, a
         compiled program a (batch-bucket, token-bucket, kind of layer: its
-        ring and its pools' heads and widths): ONE layer's K and V as the
-        prefill returned them land in that layer's donated pools. A full
-        layer's (`ring` None) go through the rows' block tables; a window
-        layer's are `ring_tail`s and go into the rings of the rows' SLOTS
-        ([nb]), the pages a later step can still see."""
+        ring and its pools' heads and widths): ONE layer's K and V (or, in
+        a layout of one pool, its latent rows) as the prefill returned them
+        land in that layer's donated pools. A full layer's (`ring` None) go
+        through the rows' block tables; a window layer's are `ring_tail`s
+        and go into the rings of the rows' SLOTS ([nb]), the pages a later
+        step can still see."""
         key = (nb, bucket, "layer", ring, layout)
         fn = self._page_write_fns.get(key)
         if fn is not None:
             return fn
 
-        def pure_layer_write(pools, k, v, where, lens):
-            if ring is None:
-                return _pa.prefill_paged_kv_cache(*pools, k, v, where, lens)
-            return _pa.prefill_ring_kv_cache(*pools, k, v, where, lens,
-                                             ring, bucket)
+        if len(layout) == 1:
+            # a layout of one pool (latent rows): the rows alone
+            def pure_layer_write(pools, rows, where, lens):
+                if ring is None:
+                    return (_pa.prefill_paged_pool(pools[0], rows, where,
+                                                   lens),)
+                return (_pa.prefill_ring_pool(pools[0], rows, where, lens,
+                                              ring, bucket),)
+        else:
+            def pure_layer_write(pools, k, v, where, lens):
+                if ring is None:
+                    return _pa.prefill_paged_kv_cache(*pools, k, v, where,
+                                                      lens)
+                return _pa.prefill_ring_kv_cache(*pools, k, v, where, lens,
+                                                 ring, bucket)
 
         fn = self._page_write_fns[key] = _cw.watch_jit(
             "serving.kv_scatter",

@@ -21,8 +21,15 @@ any skew, and the temporaries are a chunk's, whatever the token count.
 Which form an expert layer takes is `use_grouped_path`'s choice (beside
 `expert_hit.use_hit_path`), from the token count, the shapes, the dtypes
 and `_interpret()` alone.
+
+With a PolyNorm activation a tile walks its expert's width twice
+(`expert_hit`'s two passes: gate and up of the whole width into VMEM with
+the statistics, then the activation and the down products a block at a
+time); SiLU's kernel is left as it was.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +38,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret as _interpret
 from . import x64_off as _x64_off
-from .expert_hit import _block_width
+from .expert_hit import (_block_width, poly_hidden, poly_params,
+                         poly_phase1)
 
 _pc = pl.pallas_call
 
@@ -131,6 +139,75 @@ def _grouped_kernel(eid_ref, x_ref, w_ref, wg_ref, wu_ref, wd_ref, o_ref):
                           preferred_element_type=jnp.float32)
 
 
+def _grouped_poly_kernel(eid_ref, x_ref, w_ref, p_ref, wg_ref, wu_ref,
+                         wd_ref, o_ref, g_scr, u_scr, st, *, blocks, width,
+                         eps):
+    """`_grouped_kernel` with PolyNorm: grid step j < blocks is the first
+    pass over block j of the tile's expert, j >= blocks the second over
+    block j - blocks."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(j < blocks)
+    def _():
+        poly_phase1(j, x_ref[...], wg_ref, wu_ref, g_scr, u_scr, st)
+
+    @pl.when(j >= blocks)
+    def _():
+        x = x_ref[...]
+        hidden = (poly_hidden(j - blocks, p_ref, g_scr, u_scr, st, width,
+                              eps) * w_ref[...]).astype(x.dtype)
+        o_ref[...] += jnp.dot(hidden, wd_ref[0].astype(x.dtype),
+                              preferred_element_type=jnp.float32)
+
+
+def _poly_tile_products(xs, ws, eids, n_tiles, w_gate, w_up, w_down, act):
+    """`_tile_products` with PolyNorm: a grid of (tiles, two passes of the
+    expert width's blocks)."""
+    rows, d = xs.shape
+    tm = ROW_TILE
+    f = w_gate.shape[2]
+    itemsize = jnp.dtype(w_gate.dtype).itemsize
+    bf = f if 6 * d * f * itemsize <= _WHOLE_EXPERT_VMEM_BYTES \
+        else _block_width(d, f, itemsize)
+    blocks = f // bf
+    vmem = 6 * d * bf * itemsize + 2 * tm * d * (xs.dtype.itemsize + 4) \
+        + 2 * tm * 512 + 4 * tm * bf * 4 + 2 * tm * f * 4 + 3 * tm * 512 \
+        + (2 << 20)
+    with _x64_off():
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_tiles, 2 * blocks),
+            in_specs=[
+                pl.BlockSpec((tm, d), lambda t, j, e: (t, 0)),
+                pl.BlockSpec((tm, 1), lambda t, j, e: (t, 0)),
+                pl.BlockSpec((1, 1, 128), lambda t, j, e: (e[t], 0, 0)),
+                pl.BlockSpec((1, d, bf), lambda t, j, e: (
+                    e[t], 0, jnp.minimum(j, blocks - 1))),
+                pl.BlockSpec((1, d, bf), lambda t, j, e: (
+                    e[t], 0, jnp.minimum(j, blocks - 1))),
+                pl.BlockSpec((1, bf, d), lambda t, j, e: (
+                    e[t], jnp.maximum(j - blocks, 0), 0))],
+            out_specs=pl.BlockSpec((tm, d), lambda t, j, e: (t, 0)),
+            scratch_shapes=[pltpu.VMEM((blocks, tm, bf), jnp.float32),
+                            pltpu.VMEM((blocks, tm, bf), jnp.float32),
+                            pltpu.VMEM((3, tm, 128), jnp.float32)],
+        )
+        return _pc(
+            functools.partial(_grouped_poly_kernel, blocks=blocks, width=f,
+                              eps=act[4]),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=vmem),
+            interpret=_interpret(),
+        )(eids, xs, ws, poly_params(act), w_gate, w_up, w_down)
+
+
 def _tile_products(xs, ws, eids, n_tiles, w_gate, w_up, w_down):
     """[rows, d] float32: row r's `ws[r] * E_e(xs[r])` for the expert
     `eids[r // ROW_TILE]`, of the first `n_tiles` tiles (traced; the rest
@@ -169,7 +246,7 @@ def _tile_products(xs, ws, eids, n_tiles, w_gate, w_up, w_down):
         )(eids, xs, ws, w_gate, w_up, w_down)
 
 
-def grouped_ffn(x, dense_w, w_gate, w_up, w_down, live=None):
+def grouped_ffn(x, dense_w, w_gate, w_up, w_down, live=None, act=None):
     """sum over the held experts e of `dense_w[:, e] * E_e(x)` for the
     pairs with a weight above 0 of live tokens: x [n, d], dense_w [n, held]
     float32, w_gate / w_up [held, d, f], w_down [held, f, d], live [n] bool
@@ -177,18 +254,19 @@ def grouped_ffn(x, dense_w, w_gate, w_up, w_down, live=None):
     would erase left out: per pair `silu(x Wg) * (x Wu) * w` in float32,
     cast to x's type, the down product in float32, the pairs of a token
     summed in float32 and cast once. A token that is not live, or picked
-    no held expert, gets zeros."""
+    no held expert, gets zeros. `act` None is SiLU, else (w [held, 3], b
+    [held, 1], scale, clamp, eps) for PolyNorm."""
     n, d = x.shape
     if n <= TOKEN_BLOCK:
         return _grouped_block(x, _picked(dense_w, live), dense_w, w_gate,
-                              w_up, w_down)
+                              w_up, w_down, act)
     out = jax.lax.map(
-        lambda block: _grouped_block(*block, w_gate, w_up, w_down),
+        lambda block: _grouped_block(*block, w_gate, w_up, w_down, act),
         _token_blocks(n, x, _picked(dense_w, live), dense_w))
     return out.reshape(-1, d)[:n]
 
 
-def _grouped_block(x, picked, dense_w, w_gate, w_up, w_down):
+def _grouped_block(x, picked, dense_w, w_gate, w_up, w_down, act=None):
     """`grouped_ffn` of one block of tokens, `picked` [n, held] its pairs."""
     n, d = x.shape
     held = w_gate.shape[0]
@@ -223,7 +301,8 @@ def _grouped_block(x, picked, dense_w, w_gate, w_up, w_down):
             tile_eid, (i * _CHUNK_TILES,), (_CHUNK_TILES,)), held - 1)
         tok = jax.lax.dynamic_slice(row_tok, (i * chunk,), (chunk,))
         ws = jax.lax.dynamic_slice(row_w, (i * chunk,), (chunk,))
-        ys = _tile_products(
+        ys = (_tile_products if act is None else functools.partial(
+            _poly_tile_products, act=act))(
             x[jnp.minimum(tok, n - 1)], ws[:, None], eids,
             jnp.minimum(n_tiles - i * _CHUNK_TILES, _CHUNK_TILES),
             w_gate, w_up, w_down)

@@ -18,8 +18,20 @@ experts HIT, whatever the chip holds.
 
 Which form an expert layer takes is `use_hit_path`'s choice, from the token
 count, the shapes, the dtypes and `_interpret()` alone.
+
+A PolyNorm activation (`expert_share.polynorm`) normalises the gate's
+cube, square and itself by statistics over the WHOLE expert width, so it
+cannot be applied a block of the width at a time: its kernel walks each
+hit expert twice over the blocks, `poly_grid`. The first pass takes gate
+and up of every block into VMEM (float32, [n, f] each) and sums the three
+statistics; the second applies the activation a block at a time and takes
+the down products. The gate and up blocks are read in the first pass and
+the down blocks in the second, each once; a step whose block index does
+not change copies nothing, as above. SiLU's kernel is left as it was.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -116,7 +128,82 @@ def _hit_kernel(ids_ref, n_ref, x_ref, w_ref, wg_ref, wu_ref, wd_ref, o_ref,
         o_ref[...] = acc[...].astype(o_ref.dtype)
 
 
-def hit_ffn(x, dense_w, w_gate, w_up, w_down, live=None):
+def poly_params(act):
+    """[held, 1, 128] float32 from `act` = (w [held, 3], b [held, 1],
+    scale, clamp, eps): an expert's row `scale w_0, scale w_1, scale w_2,
+    clip(b)` in its first four lanes, the block a PolyNorm kernel reads for
+    the expert it is on."""
+    w, b, scale, clamp = act[:4]
+    row = jnp.concatenate([scale * w.astype(jnp.float32),
+                           jnp.clip(b.astype(jnp.float32), -clamp, clamp)],
+                          axis=-1)
+    return jnp.pad(row, ((0, 0), (0, 124)))[:, None, :]
+
+
+def poly_phase1(j, x, wg_ref, wu_ref, g_scr, u_scr, st):
+    """The first pass of a PolyNorm kernel over block `j` of the width: gate
+    and up into VMEM, sum(g^2), sum(g^4), sum(g^6) a row into `st` (reset at
+    the first block)."""
+    gate = jnp.dot(x, wg_ref[0].astype(x.dtype),
+                   preferred_element_type=jnp.float32)
+    g_scr[j] = gate
+    u_scr[j] = jnp.dot(x, wu_ref[0].astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+
+    @pl.when(j == 0)
+    def _():
+        st[...] = jnp.zeros_like(st)
+
+    g2 = gate * gate
+    for i, t in enumerate((g2, g2 * g2, g2 * g2 * g2)):
+        st[i] += jnp.broadcast_to(jnp.sum(t, axis=-1, keepdims=True),
+                                  st.shape[1:])
+
+
+def poly_hidden(k, p_ref, g_scr, u_scr, st, width, eps):
+    """The second pass's activation of block `k` times its up block
+    (float32): the statistics of the whole width in `st`, the expert's
+    scaled weights and clipped bias in the first lanes of `p_ref`."""
+    g, u = g_scr[k], u_scr[k]
+    p = p_ref[0]
+
+    def inv(i):
+        return jax.lax.rsqrt(st[i][:, :1] * (1.0 / width) + eps)
+
+    act = p[:, 0:1] * (g * g * g) * inv(2) + p[:, 1:2] * (g * g) * inv(1) \
+        + p[:, 2:3] * g * inv(0) + p[:, 3:4]
+    return act * u
+
+
+def _hit_poly_kernel(ids_ref, n_ref, x_ref, w_ref, p_ref, wg_ref, wu_ref,
+                     wd_ref, o_ref, acc, g_scr, u_scr, st, *, blocks, width,
+                     eps):
+    """`_hit_kernel` with PolyNorm: grid step j < blocks is the first pass
+    over block j, j >= blocks the second over block j - blocks."""
+    s, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((s == 0) & (j == 0))
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    @pl.when((s < n_ref[0]) & (j < blocks))
+    def _():
+        poly_phase1(j, x_ref[...], wg_ref, wu_ref, g_scr, u_scr, st)
+
+    @pl.when((s < n_ref[0]) & (j >= blocks))
+    def _():
+        x = x_ref[...]
+        hidden = (poly_hidden(j - blocks, p_ref, g_scr, u_scr, st, width,
+                              eps) * w_ref[s]).astype(x.dtype)
+        acc[...] += jnp.dot(hidden, wd_ref[0].astype(x.dtype),
+                            preferred_element_type=jnp.float32)
+
+    @pl.when((s == pl.num_programs(0) - 1) & (j == pl.num_programs(1) - 1))
+    def _():
+        o_ref[...] = acc[...].astype(o_ref.dtype)
+
+
+def hit_ffn(x, dense_w, w_gate, w_up, w_down, live=None, act=None):
     """sum over the HIT experts e of `dense_w[:, e] * E_e(x)`: x [n, d],
     dense_w [n, held] float32, w_gate / w_up [held, d, f], w_down [held, f,
     d], live [n] bool or None. `share_ffn`'s mathematics with the products
@@ -124,7 +211,11 @@ def hit_ffn(x, dense_w, w_gate, w_up, w_down, live=None):
     Wu) * w` in float32, cast to x's type, the down products summed in
     float32 over blocks and experts and cast once. A token that is not live
     adds no expert to the table; its own row of the result holds the sum
-    over the experts that live tokens hit."""
+    over the experts that live tokens hit. `act` None is SiLU, else
+    (w [held, 3], b [held, 1], scale, clamp, eps) for PolyNorm, taken in
+    two passes over each hit expert's blocks."""
+    if act is not None:
+        return _hit_poly(x, dense_w, w_gate, w_up, w_down, live, act)
     n, d = x.shape
     held, _, f = w_gate.shape
     ids, n_hit = hit_table(dense_w, live)
@@ -173,4 +264,65 @@ def hit_ffn(x, dense_w, w_gate, w_up, w_down, live=None):
                 vmem_limit_bytes=vmem),
             interpret=_interpret(),
         )(ids, n_hit.reshape(1), x, w_slots, w_gate, w_up, w_down)
+    return out[:n]
+
+
+def _hit_poly(x, dense_w, w_gate, w_up, w_down, live, act):
+    """`hit_ffn` with PolyNorm: a grid of (held slots, two passes of the
+    width's blocks)."""
+    n, d = x.shape
+    held, _, f = w_gate.shape
+    ids, n_hit = hit_table(dense_w, live)
+    w_slots = dense_w.astype(jnp.float32).T[ids][..., None]
+    rows = -n % 16
+    if rows:
+        x = jnp.pad(x, ((0, rows), (0, 0)))
+        w_slots = jnp.pad(w_slots, ((0, 0), (0, rows), (0, 0)))
+    np_ = n + rows
+    bf = _block_width(d, f, jnp.dtype(w_gate.dtype).itemsize)
+    blocks = f // bf
+    # beside `hit_ffn`'s: gate and up of the whole width, float32, and the
+    # three statistics a row across a lane tile
+    vmem = _WEIGHT_VMEM_BYTES + np_ * d * (4 * x.dtype.itemsize + 4) \
+        + 2 * held * np_ * 512 + 4 * np_ * bf * 4 + 2 * np_ * f * 4 \
+        + 3 * np_ * 512 + (2 << 20)
+
+    def col_map(s, j, ids, n_hit):   # the first pass's
+        return (ids[s], 0, jnp.where(s < n_hit[0],
+                                     jnp.minimum(j, blocks - 1), blocks - 1))
+
+    def row_map(s, j, ids, n_hit):   # the second pass's
+        return (ids[s], jnp.where(s < n_hit[0], jnp.maximum(j - blocks, 0),
+                                  blocks - 1), 0)
+
+    whole2 = lambda s, j, ids, n_hit: (0, 0)        # noqa: E731
+    whole3 = lambda s, j, ids, n_hit: (0, 0, 0)     # noqa: E731
+    with _x64_off():
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(held, 2 * blocks),
+            in_specs=[pl.BlockSpec((np_, d), whole2),
+                      pl.BlockSpec((held, np_, 1), whole3),
+                      pl.BlockSpec((1, 1, 128),
+                                   lambda s, j, ids, n_hit: (ids[s], 0, 0)),
+                      pl.BlockSpec((1, d, bf), col_map),
+                      pl.BlockSpec((1, d, bf), col_map),
+                      pl.BlockSpec((1, bf, d), row_map)],
+            out_specs=pl.BlockSpec((np_, d), whole2),
+            scratch_shapes=[pltpu.VMEM((np_, d), jnp.float32),
+                            pltpu.VMEM((blocks, np_, bf), jnp.float32),
+                            pltpu.VMEM((blocks, np_, bf), jnp.float32),
+                            pltpu.VMEM((3, np_, 128), jnp.float32)],
+        )
+        out = _pc(
+            functools.partial(_hit_poly_kernel, blocks=blocks, width=f,
+                              eps=act[4]),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((np_, d), x.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=vmem),
+            interpret=_interpret(),
+        )(ids, n_hit.reshape(1), x, w_slots, poly_params(act), w_gate, w_up,
+          w_down)
     return out[:n]

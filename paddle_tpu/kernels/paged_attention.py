@@ -727,23 +727,39 @@ def prefill_ring_kv_cache(k_pages, v_pages, k_tail, v_tail, rows, seq_lens,
     in a bucket of `s` positions; row b keeps the positions from
     `(ceil(seq_lens[b] / page) - ring) * page` to seq_lens[b] - 1, in ring
     `rows[b]`; a row of length 0 writes nothing."""
-    b, span, kvh, _ = k_tail.shape
-    page_size = k_pages.shape[2]
+    page_ids, slots = _ring_slots(k_pages, k_tail, rows, seq_lens, ring, s)
+    return _ring_put(k_pages, k_tail, page_ids, slots), \
+        _ring_put(v_pages, v_tail, page_ids, slots)
+
+
+def prefill_ring_pool(pages, tail, rows, seq_lens, ring, s):
+    """`prefill_ring_kv_cache` for a layout of one pool (latent rows):
+    tail [batch, span, heads, width] a `ring_tail` of the prompts' rows."""
+    page_ids, slots = _ring_slots(pages, tail, rows, seq_lens, ring, s)
+    return _ring_put(pages, tail, page_ids, slots)
+
+
+def _ring_slots(pages, tail, rows, seq_lens, ring, s):
+    """(page ids, slots) [batch x span] where a prefill's `ring_tail`
+    lands in the rings of `rows`; a position the ring does not keep (or
+    past a prompt's end) names the page past the pool, and is dropped."""
+    b, span = tail.shape[:2]
+    page_size = pages.shape[2]
     lens = seq_lens.astype(jnp.int32)
     oldest, start = ring_tail_start(lens, s, ring, page_size)
     pos = start[:, None] + jnp.arange(span, dtype=jnp.int32)
     kept = (pos < lens[:, None]) & (pos >= oldest[:, None])
     page_ids = jnp.asarray(rows, jnp.int32)[:, None] * ring \
         + (pos // page_size) % ring
-    page_ids = jnp.where(kept, page_ids, k_pages.shape[1]).reshape(-1)
-    slots = (pos % page_size).reshape(-1)
+    page_ids = jnp.where(kept, page_ids, pages.shape[1]).reshape(-1)
+    return page_ids, (pos % page_size).reshape(-1)
 
-    def put(pages, tail):
-        tail = tail.astype(pages.dtype).transpose(2, 0, 1, 3).reshape(
-            kvh, b * span, -1)
-        return pages.at[:, page_ids, slots, :].set(tail, mode="drop")
 
-    return put(k_pages, k_tail), put(v_pages, v_tail)
+def _ring_put(pages, tail, page_ids, slots):
+    b, span, kvh, _ = tail.shape
+    tail = tail.astype(pages.dtype).transpose(2, 0, 1, 3).reshape(
+        kvh, b * span, -1)
+    return pages.at[:, page_ids, slots, :].set(tail, mode="drop")
 
 
 def ring_pages_live(context_lens, window, page_size):
@@ -803,15 +819,16 @@ def prefill_paged_pool(pages, seq, block_tables, seq_lens):
 
 
 def paged_latent_attention_xla(q, pages, block_tables, context_lens,
-                               value_width, scale):
+                               value_width, scale, first=None):
     """Absorbed-form decode attention over latent pages (dense gather).
 
     q: [batch, heads, width] (the absorbed query | the rope query); pages:
     [1, n_pages, page_size, width]. Returns [batch, heads, value_width]:
-    softmax(q . row * scale) over the first context_lens[b] rows, weighted
-    sum of the rows' first `value_width` columns. The rows stay in the
-    pool's dtype: both products accumulate in float32 without a float32
-    copy of the mapped context."""
+    softmax(q . row * scale) over the first context_lens[b] rows (from
+    `first[b]` on where given: a ring's `ring_view`), weighted sum of the
+    rows' first `value_width` columns. The rows stay in the pool's dtype:
+    both products accumulate in float32 without a float32 copy of the
+    mapped context."""
     b = q.shape[0]
     page_size = pages.shape[2]
     S = block_tables.shape[1] * page_size
@@ -819,6 +836,8 @@ def paged_latent_attention_xla(q, pages, block_tables, context_lens,
     s = jnp.einsum("bhw,bsw->bhs", q.astype(rows.dtype), rows,
                    preferred_element_type=jnp.float32) * scale
     mask = jnp.arange(S)[None, :] < context_lens[:, None]
+    if first is not None:
+        mask = mask & (jnp.arange(S)[None, :] >= first[:, None])
     p = jax.nn.softmax(jnp.where(mask[:, None, :], s, NEG_INF), axis=-1)
     out = jnp.einsum("bhs,bsv->bhv", p.astype(rows.dtype),
                      rows[..., :value_width],

@@ -1,7 +1,7 @@
 """The one decoder stack of the serving families: latent, grouped-query
-and Mamba mixers, dense and expert FFNs (openPangu-Ultra-MoE: `model_type`
-pangu_ultra_moe; Trinity-Mini: afmoe; MiMo-V2.5: mimo_v2; AI21-Jamba2-3B:
-jamba).
+and Mamba mixers, dense and expert FFNs, one residual stream or four
+(openPangu-Ultra-MoE: `model_type` pangu_ultra_moe; Trinity-Mini: afmoe;
+MiMo-V2.5: mimo_v2; AI21-Jamba2-3B: jamba; Motif-3-Beta: Motif).
 
 ONE decoder stack over a per-layer list of kinds (a config's
 `layer_kinds()`): the mixer (`latent`: multi-head latent attention;
@@ -15,13 +15,19 @@ and, where the config says so, a learned sink in the softmax, as MiMo-V2.5
 publishes it: `model_type` mimo_v2; `mamba`: Mamba-1's selective
 state-space mixer, which caches no K/V but a state of fixed size a
 sequence, as AI21-Jamba2-3B publishes it beside `sink_full` layers with
-every option off), the FFN (`dense`; `routed+shared`:
+every option off; `gdla_window` / `gdla_full`: grouped differential
+attention on latent rows, as Motif-3-Beta publishes it, over a window or
+every position), the FFN (`dense`; `routed+shared`:
 this chip's share of the routed experts plus the shared expert; `routed`:
-the share alone) and where the norms stand (`sandwich`: a norm before AND
-after each sublayer, the residual added outside both; `pre`). The stack's
-classes take any config that gives `layer_kinds()`, `moe_spec()` and the
-sizes a kind reads: `LatentMoEConfig`, `AfmoeConfig`, `MiMoV2Config` and
-`JambaConfig` here. `gpt.py` and `llama.py` keep their own stacks (ROADMAP D6).
+the share alone; the activation SiLU, or PolyNorm where the config's
+`moe_spec()` names one), where the norms stand (`sandwich`: a norm before
+AND after each sublayer, the residual added outside both; `pre`) and, as
+an optional fourth entry, the residual (`plain`, the default: one stream
+and an add; `hyper`: manifold-constrained hyper-connections over n
+streams). The stack's classes take any config that gives `layer_kinds()`,
+`moe_spec()` and the sizes a kind reads: `LatentMoEConfig`, `AfmoeConfig`,
+`MiMoV2Config`, `JambaConfig` and `MotifConfig` here. `gpt.py` and
+`llama.py` keep their own stacks (ROADMAP D6).
 
 The equations (`N(x; g) = x / sqrt(mean(x^2) + eps) * g`, no biases but a
 Mamba mixer's two):
@@ -61,6 +67,35 @@ Mamba mixer's two):
              A = -exp(A_log) [E, N];
              h_t = exp(D_t (x) A) h_{t-1} + (D_t u'_t) (x) B_t,  h_{-1} = 0
              y_t = h_t C_t + D_skip u'_t;  o = (y * silu(z)) W_out
+    hyper    (mHC, n streams X [n, C] a token; each sublayer F, attention
+             and FFN, has its own phi [nC, 2n + n^2], alpha [3], b [2n +
+             n^2]): x~ = N(vec X) over n C with no gain; h = x~ phi;
+             H_pre = sigmoid(alpha_0 h[:n] + b[:n])             [1, n]
+             H_post = 2 sigmoid(alpha_1 h[n:2n] + b[n:2n])      [1, n]
+             H_res = Sinkhorn_I(alpha_2 mat(h[2n:]) + b[2n:])   [n, n]
+             (exp, then rows and columns normalised in turn, I times,
+             float32); X' = clip(H_res X + H_post^T F(H_pre X), +-clamp),
+             F holding the sublayer's pre-norm. The embedding's row is
+             copied into every stream; the final norm takes their sum
+    gdla     latent projections as above with G = heads / kv heads query
+             heads a kv head: [k_nope | v] = c W_kvb -> kv heads x (nope +
+             v); query head h reads kv head h // G; in each group of G the
+             first G - 1 heads are signal and the last noise;
+             A_h = softmax(q_h . k_{h//G} / sqrt(nope + rope)) v_{h//G},
+             causal (gdla_window: 0 <= i - j < window);
+             lambda = sigmoid(x W_lambda) [(G - 1) kv heads];
+             o_{g,k} = A_{Gg+k} - lambda_{(G-1)g+k} A_{Gg+G-1}, k < G - 1;
+             out = (concat o * sigmoid(x W_gate)) W_o; gdla_full ropes with
+             YaRN, gdla_window with plain rope at its own base
+    polynorm PolyNorm(u) = s (w_0 N(u^3) + w_1 N(u^2) + w_2 N(u))
+             + clip(b, +-c), N over the FFN's width (an expert's 1,280),
+             w and b an FFN's (an expert's) own; an FFN is then
+             W_down(PolyNorm(x W_gate) * x W_up)
+    yarn     DeepSeek-V3's ramp over the rope's frequency pairs: pair i
+             keeps theta^(-2i/d) below the correction dim of beta_fast
+             rotations over the original length, takes it over `factor`
+             above that of beta_slow, and blends linearly between; the
+             cos and sin are not rescaled (mscale 1)
 
 What a layer caches is its mixer's to say (`cache_layout()`, gathered a
 layer an entry by `kv_cache_layouts()`; the serving engine shapes each
@@ -77,6 +112,11 @@ over pages uses the absorbed form, multi-query attention over the one
 latent key head: `q~_h = q_nope,h W_kvb[K,h]^T`, `scores_h = (q~_h . c +
 q_rope,h . k_r) / sqrt(nope + rope)`, `o_h = (P_h c) W_kvb[V,h]`. The same
 mathematics; tests/test_latent_moe.py holds the two forms to each other.
+A `gdla` layer caches the same `(c | rope(k_r))` rows, in pages where it
+keeps every position and in a ring of pages a slot where it keeps a window
+(`kernels/paged_attention.py`); its decode takes the absorbed form with
+`q~_h = q_nope,h W_kvb[K, h // G]^T`, its prefill the grouped-query kernel
+over K and V decompressed a kv head at a time.
 
 Config fields carry the source's key names; `n_routed_experts` is the
 deployment's count (the router's width) and `ep_rank` / `ep_degree` say
@@ -93,12 +133,13 @@ import numpy as np
 
 from .. import nn
 from ..incubate.distributed.models.moe.expert_share import (
-    ExpertShareLayer, over_token_blocks)
+    ExpertShareLayer, activate, over_token_blocks)
 from ..kernels import flash_attention as _fa
 from ..kernels import paged_attention as _pa
 from ..kernels import selective_scan as _ss
 from ..nn import initializer as I
 from ..nn.functional.rope import apply_rope
+from ..observability import tracing as _trace
 from ..observability.tracing import scope
 from ..tensor import Tensor, _apply_op, as_array
 from .causal_lm import CausalLMBase, StatePool
@@ -456,6 +497,128 @@ class JambaConfig:
             mamba_d_state=8, mamba_dt_rank=6, max_position_embeddings=512)
 
 
+@dataclass
+class MotifConfig:
+    """Motif-3-Beta's config.json keys (`model_type` Motif): GDLA, grouped
+    differential attention on latent rows (`num_noise_heads` of the
+    `num_attention_heads` query heads are noise heads, one a kv head),
+    with an elementwise sigmoid gate on its output; a window of
+    `sliding_window` on every layer but the last of each
+    `sliding_window_period`, which is full; `n_dense_first_layers` dense
+    FFNs, then `num_experts` routed experts (the deployment's count, the
+    router's width; `ep_rank` / `ep_degree` say which block lives here),
+    top-`experts_top_k` by sigmoid with the shared expert beside them;
+    PolyNorm the activation of every FFN; `mhc_expansion_rate` residual
+    streams mixed by mHC. `first_layer` is the published index of this
+    stack's first layer (a pipeline stage's): it places the dense layers
+    and the window pattern. Full layers rope with YaRN (`rope_scaling`),
+    window layers with plain rope at `swa_rope_theta`."""
+    vocab_size: int = 220160
+    hidden_size: int = 4096
+    intermediate_size: int = 12288
+    moe_intermediate_size: int = 1280
+    num_hidden_layers: int = 53
+    num_attention_heads: int = 80
+    num_key_value_heads: int = 16
+    num_noise_heads: int = 16
+    head_dim: int = 192
+    q_lora_rank: int = 1024
+    kv_lora_rank: int = 512
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    first_layer: int = 0
+    sliding_window: int = 128
+    sliding_window_period: int = 4
+    n_dense_first_layers: int = 2
+    num_experts: int = 384
+    experts_top_k: int = 8
+    num_shared_experts: int = 1
+    route_norm: bool = True
+    route_scale: float = 2.0
+    mhc_expansion_rate: int = 4
+    mhc_sinkhorn_iters: int = 20
+    polynorm_output_scale: float = 0.5
+    polynorm_bias_clamp: float = 0.5
+    polynorm_eps: float = 1e-6
+    hidden_clamp: float = 1e6
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    swa_rope_theta: float = 10000.0
+    rope_scaling: dict = None   # None: YaRN as published
+    max_position_embeddings: int = 262144
+    tie_word_embeddings: bool = False
+    ep_rank: int = 0
+    ep_degree: int = 1
+    dtype: str = "float32"
+
+    def __post_init__(self):
+        if self.rope_scaling is None:
+            self.rope_scaling = dict(factor=64.0,
+                                     original_max_position_embeddings=4096,
+                                     beta_fast=32.0, beta_slow=1.0)
+        if self.num_attention_heads % self.num_key_value_heads \
+                or self.num_noise_heads != self.num_key_value_heads:
+            raise NotImplementedError(
+                "gdla is built for one noise head a kv head (the last of "
+                "its group): got "
+                f"{self.num_attention_heads}/{self.num_key_value_heads} "
+                f"heads and {self.num_noise_heads} noise heads")
+
+    @property
+    def qk_nope_head_dim(self):
+        return self.head_dim - self.qk_rope_head_dim
+
+    @property
+    def cache_width(self):
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def yarn(self):
+        """The full layers' frequency ramp (`_rope`'s `yarn`)."""
+        r = self.rope_scaling
+        return (float(r["factor"]),
+                int(r["original_max_position_embeddings"]),
+                float(r["beta_fast"]), float(r["beta_slow"]))
+
+    def layer_kinds(self):
+        period, first = self.sliding_window_period, self.first_layer
+        return [("gdla_full" if (first + i) % period == period - 1
+                 else "gdla_window",
+                 "dense" if first + i < self.n_dense_first_layers
+                 else "routed+shared", "pre", "hyper")
+                for i in range(self.num_hidden_layers)]
+
+    def moe_spec(self):
+        return dict(width=self.moe_intermediate_size,
+                    num_experts=self.num_experts, top_k=self.experts_top_k,
+                    scale=self.route_scale, norm_topk=self.route_norm,
+                    pick_bias=False, shared=self.num_shared_experts,
+                    act=dict(scale=self.polynorm_output_scale,
+                             clamp=self.polynorm_bias_clamp,
+                             eps=self.polynorm_eps))
+
+    @staticmethod
+    def tiny(vocab=96, layers=7, window=8, first_layer=1, ep_rank=0,
+             ep_degree=4):
+        """Every width shrunk, the kinds and ratios kept: from published
+        layer 1 on, a dense window layer, then expert layers window, full,
+        window, window, window, full; 10 query heads on 2 kv heads (4
+        signal and 1 noise head each), heads of 16 + 8 rope over values of
+        16, 16 experts top-4 of which 4 held, 4 streams; YaRN's factor 4
+        over 16 original positions, so that its ramp bites at test
+        lengths."""
+        return MotifConfig(
+            vocab_size=vocab, hidden_size=48, intermediate_size=96,
+            moe_intermediate_size=24, num_hidden_layers=layers,
+            num_attention_heads=10, num_key_value_heads=2,
+            num_noise_heads=2, head_dim=24, q_lora_rank=24,
+            kv_lora_rank=32, qk_rope_head_dim=8, v_head_dim=16,
+            first_layer=first_layer, sliding_window=window, num_experts=16,
+            experts_top_k=4, max_position_embeddings=512,
+            rope_scaling=dict(factor=4, original_max_position_embeddings=16,
+                              beta_fast=32, beta_slow=1),
+            ep_rank=ep_rank, ep_degree=ep_degree)
+
+
 # ---------------------------------------------------------------------------
 # the mathematics, on plain arrays
 # ---------------------------------------------------------------------------
@@ -472,32 +635,58 @@ def _mm(x, w):
                       preferred_element_type=F32).astype(x.dtype)
 
 
-def gated_ffn(x, w_gate, w_up, w_down):
-    """W_down(silu(x W_gate) * x W_up) on [n, d] tokens, a block of tokens
-    at a time beyond TOKEN_BLOCK."""
+def gated_ffn(x, w_gate, w_up, w_down, act=None):
+    """W_down(act(x W_gate) * x W_up) on [n, d] tokens, a block of tokens
+    at a time beyond TOKEN_BLOCK; `act` None is SiLU, else PolyNorm's (w
+    [3], b [1], scale, clamp, eps)."""
     def block(xb):
         gate = jnp.matmul(xb, w_gate.astype(xb.dtype),
                           preferred_element_type=F32)
         up = jnp.matmul(xb, w_up.astype(xb.dtype),
                         preferred_element_type=F32)
-        return _mm((jax.nn.silu(gate) * up).astype(xb.dtype), w_down)
+        return _mm((activate(gate, act) * up).astype(xb.dtype), w_down)
 
     return over_token_blocks(block, TOKEN_BLOCK, x)
 
 
-def _rope(x, positions, theta):
-    """x [b, s, heads, d] rotated at `positions` [b, s], rotate-half."""
+def yarn_inv_freq(inv_freq, d, theta, yarn):
+    """DeepSeek-V3's YaRN ramp over `inv_freq` [d / 2]: `yarn` = (factor,
+    original length, beta_fast, beta_slow)."""
+    factor, original, fast, slow = yarn
+
+    def dim(rotations):
+        return d * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(dim(fast)), 0)
+    hi = min(math.ceil(dim(slow)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=F32) - lo)
+                    / max(hi - lo, 1e-3), 0.0, 1.0)
+    return inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
+
+
+def _rope(x, positions, theta, yarn=None):
+    """x [b, s, heads, d] rotated at `positions` [b, s], rotate-half; with
+    `yarn` (factor, original length, beta_fast, beta_slow) the frequencies
+    take YaRN's ramp."""
     d = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
+    if yarn is not None:
+        inv_freq = yarn_inv_freq(inv_freq, d, theta, yarn)
     freqs = positions.astype(F32)[..., None] * inv_freq
     return apply_rope(x.astype(F32), jnp.cos(freqs), jnp.sin(freqs),
                       neox=True).astype(x.dtype)
 
 
-def latent_projections(x, p, cfg, positions):
+def latent_projections(x, p, cfg, positions, rotate=None):
     """x [b, s, hidden] (normed) -> (q_nope [b, s, h, nope], q_rope [b, s,
     h, rope], latent [b, s, rank + rope]): the queries, and the row the
-    cache keeps for each token, `(c | rope(k_r))`."""
+    cache keeps for each token, `(c | rope(k_r))`; `rotate(t)` ropes [b,
+    s, heads, rope] at `positions` (plain rope at `cfg.rope_theta` where
+    None)."""
+    if rotate is None:
+        def rotate(t):
+            return _rope(t, positions, cfg.rope_theta)
     b, s, _ = x.shape
     h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
     c_q = rms_norm(_mm(x, p["q_a_proj"]), p["q_a_layernorm"],
@@ -507,15 +696,15 @@ def latent_projections(x, p, cfg, positions):
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
     kv = _mm(x, p["kv_a_proj_with_mqa"])
     c = rms_norm(kv[..., :rank], p["kv_a_layernorm"], cfg.rms_norm_eps)
-    k_r = _rope(kv[..., None, rank:], positions, cfg.rope_theta)[:, :, 0]
-    q_rope = _rope(q_rope, positions, cfg.rope_theta)
+    k_r = rotate(kv[..., None, rank:])[:, :, 0]
+    q_rope = rotate(q_rope)
     return q_nope, q_rope, jnp.concatenate([c, k_r], axis=-1)
 
 
-def _split_kv_b(w_kv_b, cfg):
+def _split_kv_b(w_kv_b, cfg, heads=None):
     """W_kvb [rank, h x (nope + v)] -> (W_K [rank, h, nope], W_V [rank, h,
-    v])."""
-    w = w_kv_b.reshape(cfg.kv_lora_rank, cfg.num_attention_heads,
+    v]), h `heads` (the query heads where None)."""
+    w = w_kv_b.reshape(cfg.kv_lora_rank, heads or cfg.num_attention_heads,
                        cfg.qk_nope_head_dim + cfg.v_head_dim)
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
@@ -697,6 +886,56 @@ def gqa_attention(q, k, v, window=None, offset=0, sink=None):
     return out.reshape(b, s, h * d_v)
 
 
+def diff_combine(ctx, lam, kv_heads, value_dim):
+    """The differential combination: ctx [..., h x v] of `kv_heads` groups
+    of G heads (G - 1 signal, the last noise), lam [..., kv_heads x (G -
+    1)] -> [..., kv_heads x (G - 1) x v] float32, each signal head less
+    its lambda times its group's noise head."""
+    lead = ctx.shape[:-1]
+    a = ctx.astype(F32).reshape(lead + (kv_heads, -1, value_dim))
+    g = a.shape[-2]
+    lam = lam.astype(F32).reshape(lead + (kv_heads, g - 1, 1))
+    return (a[..., :g - 1, :] - lam * a[..., g - 1:, :]).reshape(
+        lead + (-1,))
+
+
+def sinkhorn(logits, iters):
+    """[..., n, n] float32 logits -> a doubly stochastic [..., n, n]: exp,
+    then rows and columns normalised in turn, `iters` times each."""
+    m = jnp.exp(logits - jnp.max(logits, axis=(-2, -1), keepdims=True))
+    for _ in range(iters):
+        m = m / jnp.sum(m, axis=-1, keepdims=True)
+        m = m / jnp.sum(m, axis=-2, keepdims=True)
+    return m
+
+
+def hyper_in(streams, phi, alpha, bias, iters, eps):
+    """mHC's read of a sublayer's input: streams [..., n, C] -> (x [..., C]
+    in the streams' type, H_res [..., n, n], H_post [..., n], both
+    float32)."""
+    n, c = streams.shape[-2:]
+    flat = streams.reshape(streams.shape[:-2] + (n * c,)).astype(F32)
+    flat = flat * jax.lax.rsqrt(jnp.mean(jnp.square(flat), -1,
+                                         keepdims=True) + eps)
+    h = jnp.matmul(flat.astype(streams.dtype), phi.astype(streams.dtype),
+                   preferred_element_type=F32)
+    a, b = alpha.astype(F32), bias.astype(F32)
+    pre = jax.nn.sigmoid(a[0] * h[..., :n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(a[1] * h[..., n:2 * n] + b[n:2 * n])
+    res = sinkhorn((a[2] * h[..., 2 * n:] + b[2 * n:]).reshape(
+        h.shape[:-1] + (n, n)), iters)
+    x = jnp.einsum("...n,...nc->...c", pre, streams.astype(F32))
+    return x.astype(streams.dtype), res, post
+
+
+def hyper_out(streams, res, post, y, clamp):
+    """mHC's write: clip(H_res X + H_post^T y, +-clamp), y [..., C] the
+    sublayer's output, in the streams' type."""
+    out = jnp.einsum("...nm,...mc->...nc", res, streams.astype(F32)) \
+        + post[..., :, None] * y.astype(F32)[..., None, :]
+    return jnp.clip(out, -clamp, clamp).astype(streams.dtype)
+
+
 # ---------------------------------------------------------------------------
 # layers
 # ---------------------------------------------------------------------------
@@ -815,6 +1054,167 @@ class LatentAttention(_Mixer):
             return _mm(ctx, p["o_proj"])[:, None], pool
 
         out, pool = self._run(f, x, as_array(pool), name="latent_attention")
+        return out, (as_array(pool),)
+
+
+class DiffLatentAttention(LatentAttention):
+    """GDLA, grouped differential attention on latent rows, as Motif-3-Beta
+    publishes it (the module docstring's `gdla` equations): the latent
+    projections of `LatentAttention` with `num_key_value_heads` kv heads
+    decompressed from a row, a group of query heads a kv head whose last
+    is a noise head that each signal head subtracts, scaled by a lambda a
+    token and signal head, and a sigmoid gate on the combined output. A
+    window layer (`gdla_window`) keeps its rows in a ring of pages a slot,
+    ropes at `swa_rope_theta` and runs its attention under `attn/window`;
+    a full one (`gdla_full`) keeps every row in pages, ropes with YaRN and
+    runs under `attn/latent`."""
+    LEAVES = LatentAttention.LEAVES + ("lambda_proj", "gate_proj")
+
+    def __init__(self, config, window=None):
+        _Mixer.__init__(self)
+        c = self.config = config
+        h, kvh = c.num_attention_heads, c.num_key_value_heads
+        signal = h - c.num_noise_heads
+        self.window, self.kv_heads = window, kvh
+        self.q_a_proj = _Weight(c.hidden_size, c.q_lora_rank)
+        self.q_a_layernorm = _Weight(c.q_lora_rank)
+        self.q_b_proj = _Weight(c.q_lora_rank, h * c.head_dim)
+        self.kv_a_proj_with_mqa = _Weight(c.hidden_size, c.cache_width)
+        self.kv_a_layernorm = _Weight(c.kv_lora_rank)
+        self.kv_b_proj = _Weight(
+            c.kv_lora_rank, kvh * (c.qk_nope_head_dim + c.v_head_dim))
+        self.o_proj = _Weight(signal * c.v_head_dim, c.hidden_size)
+        self.lambda_proj = _Weight(c.hidden_size, signal)
+        self.gate_proj = _Weight(c.hidden_size, signal * c.v_head_dim)
+
+    @property
+    def scope_name(self):
+        return "latent" if self.window is None else "window"
+
+    def _rotate(self, positions):
+        c = self.config
+        if self.window is None:
+            return lambda t: _rope(t, positions, c.rope_theta, c.yarn())
+        return lambda t: _rope(t, positions, c.swa_rope_theta)
+
+    def _finish(self, p, x, ctx):
+        """out = (diff(ctx) * sigmoid(x W_gate)) W_o, x the normed input."""
+        def sig(w):
+            return jax.nn.sigmoid(jnp.matmul(
+                x, w.astype(x.dtype), preferred_element_type=F32))
+
+        o = diff_combine(ctx, sig(p["lambda_proj"]), self.kv_heads,
+                         self.config.v_head_dim)
+        return _mm((o * sig(p["gate_proj"])).astype(x.dtype), p["o_proj"])
+
+    def forward_cached(self, x, cache, cur_len, live=None):
+        """x [b, s, hidden] at positions cur_len .. cur_len + s - 1; cache
+        `(latent rows [b, t, 1, width],)` of EVERY position. K and V are
+        decompressed a kv head at a time; a prefill from position 0 that
+        fills the cache takes the grouped-query kernel where the length
+        asks (a window layer's key blocks outside the band are neither
+        read nor multiplied). Returns (out, new cache)."""
+        cfg = self.config
+        (rows,) = cache
+        rows = as_array(rows)
+        traced = hasattr(cur_len, "_data")
+        start = as_array(cur_len) if traced else cur_len
+        whole = not traced and isinstance(start, int) and start == 0 \
+            and int(x.shape[1]) == rows.shape[1]
+        nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+
+        def f(p, x, rows):
+            b, s, _ = x.shape
+            positions = jnp.broadcast_to(start + jnp.arange(s), (b, s))
+            q_nope, q_rope, latent = latent_projections(
+                x, p, cfg, positions, self._rotate(positions))
+            with scope("kv_write"):
+                zero = jnp.zeros((), jnp.int32)
+                rows = jax.lax.dynamic_update_slice(
+                    rows, latent[:, :, None].astype(rows.dtype),
+                    (zero, jnp.asarray(start, jnp.int32), zero, zero))
+            lat = latent if whole else rows[:, :, 0].astype(x.dtype)
+            t = lat.shape[1]
+            kv = _mm(lat[..., :rank], p["kv_b_proj"]).reshape(
+                b, t, self.kv_heads, -1)
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                lat[:, :, None, rank:], (b, t, self.kv_heads,
+                                         cfg.qk_rope_head_dim))], -1)
+            v = kv[..., nope:]
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            with scope(self.scope_name):
+                if whole and _fa.use_gqa_flash(s, cfg.head_dim,
+                                               cfg.v_head_dim):
+                    ctx = _fa.flash_attention_gqa_bshd(
+                        q, k, v, window=self.window).reshape(b, s, -1)
+                else:
+                    ctx = gqa_attention(q, k, v, self.window, offset=start)
+            return self._finish(p, x, ctx), rows
+
+        out, rows = self._run(f, x, rows, name="gdla_attention")
+        return out, (as_array(rows),)
+
+    def forward_paged(self, x, cache, block_tables, context_lens,
+                      active=None, mesh=None):
+        """One new token a row (x [b, 1, hidden]) over `(pool,)` of latent
+        rows: the allocator's pages through the block tables in a full
+        layer, the rows' rings in a window layer (`[1, b x ring, page,
+        width]`, row b's ring its own). The row is written at
+        context_lens[b], then absorbed-form attention over what the row
+        sees, query head h against kv head h // G."""
+        cfg = self.config
+        (pool,) = cache
+        pool = as_array(pool)
+        lens = as_array(context_lens)
+        b, page = lens.shape[0], pool.shape[2]
+        act = jnp.broadcast_to(jnp.asarray(
+            True if active is None else as_array(active), bool), (b,))
+        rank, kvh = cfg.kv_lora_rank, self.kv_heads
+        g = cfg.num_attention_heads // kvh
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+        if self.window is None:
+            tables = as_array(block_tables)
+            write_tables, write_at = tables, lens
+            tables, read, first = tables, lens + 1, None
+        else:
+            rows = jnp.arange(b, dtype=jnp.int32)
+            ring = pool.shape[1] // b
+            write_tables = _pa.ring_tables(rows, ring)
+            write_at = lens % (ring * page)
+            seen = jnp.where(act, lens + 1, 0).astype(jnp.int32)
+            tables, read, first = _pa.ring_view(rows, ring, page, seen,
+                                                self.window)
+            if _trace.counting():
+                # the dense gather reads every page of a live row's ring
+                _trace.count("attn_window_pages_live", _pa.ring_pages_live(
+                    seen, self.window, page))
+                _trace.count("attn_window_pages_read",
+                             jnp.sum(act, dtype=jnp.int32) * ring)
+                _trace.count("attn_window_pages_context", jnp.sum(
+                    (seen + page - 1) // page, dtype=jnp.int32))
+
+        def f(p, x, pool):
+            positions = lens[:, None]
+            q_nope, q_rope, latent = latent_projections(
+                x, p, cfg, positions, self._rotate(positions))
+            with scope("kv_write"):
+                pool = _pa.update_paged_pool(pool, latent, write_tables,
+                                             write_at, active=act)
+            w_k, w_v = _split_kv_b(p["kv_b_proj"].astype(x.dtype), cfg, kvh)
+            q_abs = jnp.einsum(
+                "bkgn,rkn->bkgr", q_nope[:, 0].reshape(b, kvh, g, -1), w_k,
+                preferred_element_type=F32).astype(x.dtype)
+            q = jnp.concatenate([q_abs.reshape(b, kvh * g, rank),
+                                 q_rope[:, 0]], axis=-1)
+            with scope(self.scope_name):
+                o_latent = _pa.paged_latent_attention_xla(
+                    q, pool, tables, read, rank, scale, first=first)
+            ctx = jnp.einsum(
+                "bkgr,rkv->bkgv", o_latent.reshape(b, kvh, g, rank), w_v,
+                preferred_element_type=F32).astype(x.dtype)
+            return self._finish(p, x, ctx.reshape(b, 1, -1)), pool
+
+        out, pool = self._run(f, x, pool, name="gdla_attention")
         return out, (as_array(pool),)
 
 
@@ -1146,18 +1546,48 @@ class MambaMixer(_Mixer):
         return out, (as_array(conv), as_array(ssm))
 
 
+class PolyNorm(nn.Layer):
+    """A PolyNorm activation's own leaves: `weight` [3] (the cube's, the
+    square's and the input's), `bias` [1]."""
+
+    def __init__(self):
+        super().__init__()
+        self.weight = self.create_parameter(
+            shape=[3], default_initializer=I.Constant(1.0 / 3.0))
+        self.bias = self.create_parameter(
+            shape=[1], default_initializer=I.Constant(0.0))
+
+
 class GatedFFN(nn.Layer):
-    def __init__(self, hidden, width):
+    """W_down(act(x W_gate) * x W_up): SiLU where `act` is None, else
+    PolyNorm with `act` (`scale`, `clamp`, `eps`) and leaves of its own,
+    `act_fn`."""
+
+    def __init__(self, hidden, width, act=None):
         super().__init__()
         self.gate_proj = _Weight(hidden, width)
         self.up_proj = _Weight(hidden, width)
         self.down_proj = _Weight(width, hidden)
+        self.act = act
+        if act is not None:
+            self.act_fn = PolyNorm()
 
     def forward(self, x):
         shape = [int(s) for s in x.shape]
-        y = _apply_op(gated_ffn, x.reshape([-1, shape[-1]]),
-                      self.gate_proj.weight, self.up_proj.weight,
-                      self.down_proj.weight, _name="gated_ffn")
+        flat = x.reshape([-1, shape[-1]])
+        if self.act is None:
+            y = _apply_op(gated_ffn, flat, self.gate_proj.weight,
+                          self.up_proj.weight, self.down_proj.weight,
+                          _name="gated_ffn")
+        else:
+            a = self.act
+            y = _apply_op(
+                lambda x, wg, wu, wd, w, b: gated_ffn(
+                    x, wg, wu, wd, act=(w, b, a["scale"], a["clamp"],
+                                        a["eps"])),
+                flat, self.gate_proj.weight, self.up_proj.weight,
+                self.down_proj.weight, self.act_fn.weight, self.act_fn.bias,
+                _name="gated_ffn")
         return y.reshape(shape)
 
 
@@ -1168,13 +1598,15 @@ class RoutedSharedFFN(nn.Layer):
     def __init__(self, config):
         super().__init__()
         c, m = config, config.moe_spec()
+        act = m.get("act")
         self.experts = ExpertShareLayer(
             c.hidden_size, m["width"], m["num_experts"], m["top_k"],
             ep_rank=c.ep_rank, ep_degree=c.ep_degree,
             routed_scaling_factor=m["scale"], norm_topk_prob=m["norm_topk"],
-            pick_bias=m["pick_bias"])
+            pick_bias=m["pick_bias"], act=act)
         self.shared_experts = GatedFFN(
-            c.hidden_size, m["shared"] * m["width"]) if m["shared"] else None
+            c.hidden_size, m["shared"] * m["width"], act=act) \
+            if m["shared"] else None
 
     def forward(self, x, live=None):
         routed = self.experts(x, live=live)
@@ -1182,6 +1614,41 @@ class RoutedSharedFFN(nn.Layer):
             return routed
         with scope("shared"):
             return routed + self.shared_experts(x)
+
+
+class HyperConnection(nn.Layer):
+    """One sublayer's mHC (the module docstring's `hyper` equations):
+    `phi` [n C, 2n + n^2], `alpha` [3] (pre, post, res), `bias` [2n +
+    n^2]. `forward(streams, sublayer)` reads the sublayer's input from the
+    streams [..., n, C], calls `sublayer(x) -> (y, extra)` and writes y
+    back while it re-mixes them; the mixes and the Sinkhorn run under the
+    scope `mhc`."""
+
+    def __init__(self, config):
+        super().__init__()
+        n, c = config.mhc_expansion_rate, config.hidden_size
+        self.iters, self.eps = config.mhc_sinkhorn_iters, config.rms_norm_eps
+        self.clamp = config.hidden_clamp
+        self.phi = self.create_parameter(
+            shape=[n * c, 2 * n + n * n],
+            default_initializer=I.Normal(0.0, 0.02))
+        self.alpha = self.create_parameter(
+            shape=[3], default_initializer=I.Constant(0.01))
+        self.bias = self.create_parameter(
+            shape=[2 * n + n * n], default_initializer=I.Constant(0.0))
+
+    def forward(self, streams, sublayer):
+        with scope("mhc"):
+            x, res, post = _apply_op(
+                lambda s, phi, a, b: hyper_in(s, phi, a, b, self.iters,
+                                              self.eps),
+                streams, self.phi, self.alpha, self.bias, _name="hyper_in")
+        y, extra = sublayer(x)
+        with scope("mhc"):
+            out = _apply_op(
+                lambda s, r, p, y: hyper_out(s, r, p, y, self.clamp),
+                streams, res, post, y, _name="hyper_out")
+        return out, extra
 
 
 # a mixer kind -> how it is built from a config
@@ -1192,29 +1659,39 @@ MIXERS = {
     "sink_window": lambda c: SinkGQAttention(c, True),
     "sink_full": lambda c: SinkGQAttention(c, False),
     "mamba": MambaMixer,
+    "gdla_window": lambda c: DiffLatentAttention(c, c.sliding_window),
+    "gdla_full": lambda c: DiffLatentAttention(c, None),
 }
 
 
 class LatentMoEDecoderLayer(nn.Layer):
     def __init__(self, config, kinds):
         super().__init__()
-        mixer, ffn, norms = kinds
-        shared = config.moe_spec()["shared"]
+        mixer, ffn, norms, *residual = kinds
+        residual = residual[0] if residual else "plain"
+        spec = config.moe_spec()
+        shared = spec["shared"]
         if mixer not in MIXERS or norms not in ("sandwich", "pre") \
                 or ffn not in ("dense", "routed+shared", "routed") \
-                or (ffn != "dense" and (ffn == "routed") == bool(shared)):
+                or (ffn != "dense" and (ffn == "routed") == bool(shared)) \
+                or residual not in ("plain", "hyper"):
             raise ValueError(f"unknown layer kinds {kinds}")
         self.eps = config.rms_norm_eps
         self.routed = ffn != "dense"
         self.sandwich = norms == "sandwich"
+        self.hyper = residual == "hyper"
         self.input_layernorm = _Weight(config.hidden_size)
         self.self_attn = MIXERS[mixer](config)
         self.pre_mlp_layernorm = _Weight(config.hidden_size)
         self.mlp = RoutedSharedFFN(config) if self.routed else GatedFFN(
-            config.hidden_size, config.intermediate_size)
+            config.hidden_size, config.intermediate_size,
+            act=spec.get("act"))
         if self.sandwich:
             self.post_attention_layernorm = _Weight(config.hidden_size)
             self.post_mlp_layernorm = _Weight(config.hidden_size)
+        if self.hyper:
+            self.attn_hc = HyperConnection(config)
+            self.mlp_hc = HyperConnection(config)
 
     def _norm(self, x, which):
         return _apply_op(rms_norm, x, getattr(self, which).weight,
@@ -1222,6 +1699,8 @@ class LatentMoEDecoderLayer(nn.Layer):
 
     def _block(self, x, attend, live=None):
         """The block around `attend(normed x) -> (out, cache)`."""
+        if self.hyper:
+            return self._hyper_block(x, attend, live)
         with scope("attn"):
             a, cache = attend(self._norm(x, "input_layernorm"))
             if self.sandwich:
@@ -1233,6 +1712,28 @@ class LatentMoEDecoderLayer(nn.Layer):
             if self.sandwich:
                 m = self._norm(m, "post_mlp_layernorm")
             return x + m, cache
+
+    def _hyper_block(self, streams, attend, live):
+        """The block over n residual streams [..., n, hidden]: each
+        sublayer, its norms included, between its mHC's read and write."""
+        def attn(x):
+            a, cache = attend(self._norm(x, "input_layernorm"))
+            if self.sandwich:
+                a = self._norm(a, "post_attention_layernorm")
+            return a, cache
+
+        def mlp(x):
+            m = self._norm(x, "pre_mlp_layernorm")
+            m = self.mlp(m, live=live) if self.routed else self.mlp(m)
+            if self.sandwich:
+                m = self._norm(m, "post_mlp_layernorm")
+            return m, None
+
+        with scope("attn"):
+            streams, cache = self.attn_hc(streams, attn)
+        with scope("mlp"):
+            streams, _ = self.mlp_hc(streams, mlp)
+        return streams, cache
 
     def forward(self, x):
         return self._block(x, lambda a: (self.self_attn(a), None))[0]
@@ -1260,23 +1761,36 @@ class LatentMoEModel(nn.Layer):
             [LatentMoEDecoderLayer(config, kinds)
              for kinds in config.layer_kinds()])
         self.norm = _Weight(config.hidden_size)
+        # n residual streams where the layers are hyper-connected
+        self.streams = config.mhc_expansion_rate if any(
+            k[3:] == ("hyper",) for k in config.layer_kinds()) else None
 
     def _embed(self, input_ids):
         scale = getattr(self.config, "embed_scale", None)
+        n = self.streams
 
         def take(ids, w):
             rows = jnp.take(w, ids, axis=0)
-            return rows if scale is None \
+            rows = rows if scale is None \
                 else (rows.astype(F32) * scale).astype(rows.dtype)
+            # where the layers are hyper-connected, copied into every stream
+            return rows if n is None else jnp.broadcast_to(
+                rows[..., None, :], rows.shape[:-1] + (n, rows.shape[-1]))
 
         with scope("embed"):
             return _apply_op(take, input_ids, self.embed_tokens.weight,
                              _name="embedding")
 
     def _final_norm(self, h):
+        eps = self.config.rms_norm_eps
         with scope("head"):
-            return _apply_op(rms_norm, h, self.norm.weight, _name="rms_norm",
-                             eps=self.config.rms_norm_eps)
+            if self.streams is None:
+                return _apply_op(rms_norm, h, self.norm.weight,
+                                 _name="rms_norm", eps=eps)
+            return _apply_op(   # the norm of the streams' sum
+                lambda h, g: rms_norm(jnp.sum(h.astype(F32), axis=-2)
+                                      .astype(h.dtype), g, eps),
+                h, self.norm.weight, _name="rms_norm")
 
     def forward(self, input_ids, attn_mask=None):
         if attn_mask is not None:
@@ -1449,3 +1963,13 @@ class JambaForCausalLM(AfmoeForCausalLM):
     every Mamba layer's state where the prompt's last token left it (the
     stack tells the mixer which positions are live). Serving only: the
     scan has no backward."""
+
+
+class MotifForCausalLM(AfmoeForCausalLM):
+    """Motif-3-Beta's shape over the same stack: GDLA layers over a window
+    (rings) and over every position (pages), a dense then routed + shared
+    FFNs with PolyNorm, norms before the sublayers, four residual streams
+    mixed by mHC around every sublayer, an untied float32 head taken at
+    each prompt's last position over the streams' sum. The
+    multi-token-prediction layer is not built. Serving only, like its
+    parents: nothing here has a tested backward."""

@@ -146,6 +146,50 @@ def test_sink_gqa_prefill_choice(monkeypatch, row):
     assert taken == [want]
 
 
+# (prompt bucket, window layer?) -> (what attends, its window):
+# motif-3-beta-ep8-l7.reason-closed's token buckets, 80 query heads of 192
+# over values of 128 on 16 kv heads decompressed from latent rows, a window
+# of 128 or none
+GDLA_PREFILL = {
+    "reason-closed-512-window": ((512, True), (GQA_XLA, 128)),
+    "reason-closed-512-full": ((512, False), (GQA_XLA, None)),
+    "reason-closed-1024-window": ((1024, True), (GQA_FLASH, 128)),
+    "reason-closed-2048-window": ((2048, True), (GQA_FLASH, 128)),
+    "reason-closed-2048-full": ((2048, False), (GQA_FLASH, None)),
+}
+
+
+@pytest.mark.parametrize("row", sorted(GDLA_PREFILL))
+def test_gdla_prefill_choice(monkeypatch, row):
+    from paddle_tpu.models import MotifConfig, latent_moe
+
+    (s, window), want = GDLA_PREFILL[row]
+    taken = []
+
+    def attend(name):
+        def f(q, k, v, window=None, offset=0, **kw):
+            assert (q.shape[2:], k.shape[2:], v.shape[2:]) == (
+                (80, 192), (16, 192), (16, 128))
+            taken.append((name, window))
+            return jnp.zeros(q.shape[:2] + (80 * 128,), q.dtype) \
+                if name == GQA_XLA else jnp.zeros(q.shape[:3] + (128,),
+                                                  q.dtype)
+        return f
+
+    monkeypatch.setattr(fa, GQA_FLASH, attend(GQA_FLASH))
+    monkeypatch.setattr(latent_moe, GQA_XLA, attend(GQA_XLA))
+    cfg = MotifConfig(num_hidden_layers=1)
+    zeros = lambda shape, _dtype: jnp.zeros(tuple(shape), BF16)  # noqa: E731
+    paddle.nn.initializer.set_global_initializer(zeros, zeros)
+    try:
+        mixer = latent_moe.DiffLatentAttention(cfg, 128 if window else None)
+    finally:
+        paddle.nn.initializer.set_global_initializer(None, None)
+    jax.eval_shape(lambda x: as_array(mixer(Tensor(x))),
+                   S((1, s, 4096), BF16))
+    assert taken == [want]
+
+
 # ---------------------------------------------------------------------------
 # decode attention over pages: paged_attention_dispatch
 # ---------------------------------------------------------------------------
@@ -305,6 +349,12 @@ EXPERTS = {
     "long-closed-step": ((8, 4096, 2048, 16, BF16), HIT),
     "long-closed-prefill-2048": ((2048, 4096, 2048, 16, BF16), GROUPED),
     "long-closed-prefill-16384": ((16384, 4096, 2048, 16, BF16), GROUPED),
+    # motif-3-beta-ep8-l7.reason-closed: 8 slots; prefills of one prompt,
+    # 512 to 2,048 tokens; 48 held experts of 4,096 x 1,280 (PolyNorm,
+    # which the choice does not read)
+    "reason-closed-step": ((8, 4096, 1280, 48, BF16), HIT),
+    "reason-closed-prefill-512": ((512, 4096, 1280, 48, BF16), GROUPED),
+    "reason-closed-prefill-2048": ((2048, 4096, 1280, 48, BF16), GROUPED),
     "one-token": ((1, 7680, 2048, 16, BF16), HIT),
     "tokens-64": ((64, 7680, 2048, 16, BF16), HIT),
     # between the two kernels the dense products: every held expert is

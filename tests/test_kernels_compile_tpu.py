@@ -493,6 +493,45 @@ def test_grouped_ffn_at_the_expert_cells_prefill_shapes(v5e, row):
                        S((n,), jnp.bool_)) == 1
 
 
+def _polynorm(held):
+    """A PolyNorm activation's operands for `held` experts, as an expert
+    layer hands them to the kernels: (w, b, scale, clamp, eps)."""
+    return (S((held, 3), F32), S((held, 1), F32), 0.5, 0.5, 1e-6)
+
+
+def test_hit_ffn_with_polynorm_at_the_reason_cell_shape(v5e):
+    """8 rows through 48 held experts of 4,096 x 1,280 in bf16 with
+    PolyNorm: blocks of 256 columns walked twice (gate and up of the whole
+    width into VMEM, then the down products)."""
+    assert eh.use_hit_path(8, 4096, 1280, BF16, BF16)
+    assert eh._block_width(4096, 1280, 2) == 256
+    w, act = S((48, 4096, 1280), BF16), _polynorm(48)
+
+    def f(x, dw, wg, wu, wd, aw, ab):
+        return eh.hit_ffn(x, dw, wg, wu, wd, act=(aw, ab) + act[2:])
+
+    assert compile_for(v5e[0], f, S((8, 4096), BF16), S((8, 48), F32), w, w,
+                       S((48, 1280, 4096), BF16), act[0], act[1]) == 1
+
+
+@pytest.mark.parametrize("n", [512, 2048])
+def test_grouped_ffn_with_polynorm_at_the_reason_cell_shapes(v5e, n):
+    """The reason cell's smallest and largest prefill bucket through 48
+    held experts of 4,096 x 1,280 with PolyNorm, two passes of blocks of
+    256 columns a tile."""
+    assert eg.use_grouped_path(n, 4096, 1280, BF16, BF16)
+    assert 6 * 4096 * 1280 * 2 > eg._WHOLE_EXPERT_VMEM_BYTES
+    w, act = S((48, 4096, 1280), BF16), _polynorm(48)
+
+    def f(x, dw, wg, wu, wd, live, aw, ab):
+        return eg.grouped_ffn(x, dw, wg, wu, wd, live,
+                              act=(aw, ab) + act[2:])
+
+    assert compile_for(v5e[0], f, S((n, 4096), BF16), S((n, 48), F32), w, w,
+                       S((48, 1280, 4096), BF16), S((n,), jnp.bool_),
+                       act[0], act[1]) == 1
+
+
 def test_latent_prefill_groups_its_pairs_and_copies_no_stack(v5e,
                                                              monkeypatch):
     """`openpangu-ultra-moe-ep16-l5.decode-closed`'s prefill of 2 x 1,024
