@@ -623,24 +623,27 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
 
     # -- a window layer whose keys are wider than its values, with a sink a
     # head in the softmax (mimo-v2.5-ep16-l11: 8 kv heads of 8 query heads,
-    # keys of 192 over values of 128, a window of 128): its prefill, and its
-    # decode step over rings of 2 pages of 256 that store a key 256 wide
-    # and score it by 192
+    # keys of 192 over values of 128, a window of 128): its prefill over a
+    # full row and one of half its length, and its decode step over rings
+    # of 2 pages of 256 that store a key 256 wide and score it by 192
     sink_heads, window = gqa_kv_heads * gqa_group, 128
 
     def sink_of(rng):
         return rng.standard_normal(sink_heads, dtype=np.float32)
 
     def gqa_args(rng):
-        return (_bf16(rng, (1, gqa_seq, sink_heads, 192)),
-                _bf16(rng, (1, gqa_seq, gqa_kv_heads, 192)),
-                _bf16(rng, (1, gqa_seq, gqa_kv_heads, 128)), sink_of(rng))
+        return (_bf16(rng, (2, gqa_seq, sink_heads, 192)),
+                _bf16(rng, (2, gqa_seq, gqa_kv_heads, 192)),
+                _bf16(rng, (2, gqa_seq, gqa_kv_heads, 128)), sink_of(rng),
+                np.array([gqa_seq, gqa_seq // 2], np.int32))
 
-    def gqa_window(q, k, v, sink):
-        return fa.flash_attention_gqa_bshd(q, k, v, window=window, sink=sink)
+    def gqa_window(q, k, v, sink, lens):
+        return fa.flash_attention_gqa_bshd(q, k, v, window=window, sink=sink,
+                                           lens=lens)
 
-    def dense_window(q, k, v, sink):
-        """The same in f32: a sink is one more column of the softmax."""
+    def dense_window(q, k, v, sink, lens):
+        """The same in f32: a sink is one more column of the softmax; a
+        row's queries from its length on are zeros."""
         n = q.shape[1]
         kk = jnp.repeat(k.astype(f32), gqa_group, axis=2)
         vv = jnp.repeat(v.astype(f32), gqa_group, axis=2)
@@ -649,7 +652,9 @@ def kernel_cases(heads=16, head_dim=128, hidden=2048, ffn=8192,
         s = jnp.where((back >= 0) & (back < window), s, -jnp.inf)
         column = jnp.broadcast_to(sink[None, :, None, None], s.shape[:3] + (1,))
         p = jax.nn.softmax(jnp.concatenate([s, column], -1), -1)[..., :n]
-        return jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+        return jnp.where((jnp.arange(n)[None, :] < lens[:, None])[
+            ..., None, None], out, 0.0)
 
     def ring_args(rng):
         rows = jnp.arange(decode_batch)

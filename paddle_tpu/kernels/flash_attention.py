@@ -1060,53 +1060,69 @@ def _gqa_key_blocks(i, block_q, block_k, window):
     last = ((i + 1) * block_q - 1) // block_k
     if window is None:
         return 0, last
-    return jnp.maximum(i * block_q - window + 1, 0) // block_k, last
+    return np.maximum(i * block_q - window + 1, 0) // block_k, last
 
 
-def _gqa_tiling(seq, group, window):
-    """(query heads a block, key block, key steps a query block) from the
-    shape alone. The query heads of a kv head share a block up to 1,024
-    rows of queries (8 heads: the float32 scores of 16 would not fit beside
-    their softmax), a larger group in several. A window no wider than
-    `GQA_BLOCK_K` has a key block of its own size (of 512 keys a query
-    block of 128 with a window of 128 sees 255) and walks ONLY the blocks a
-    query block can see, counted from its first (`narrow`: the grid's key
-    steps are those, not the sequence's); a wider window and none walk
-    every block of the sequence and skip the ones outside."""
+def _gqa_tiling(group, window):
+    """(query heads a block, key block) from the shape alone. The query
+    heads of a kv head share a block up to 1,024 rows of queries (8 heads:
+    the float32 scores of 16 would not fit beside their softmax), a larger
+    group in several. A window no wider than `GQA_BLOCK_K` has a key block
+    of its own size (of 512 keys a query block of 128 with a window of 128
+    sees 255); a wider window and none take `GQA_BLOCK_K`."""
     gb = max(g for g in range(1, group + 1)
              if group % g == 0 and g * GQA_BLOCK_Q <= 1024)
     if window is None or window >= GQA_BLOCK_K:
-        return gb, GQA_BLOCK_K, None
+        return gb, GQA_BLOCK_K
     bk = 128
     while bk < window:
         bk *= 2
-
-    def seen(i):
-        lo = max(i * GQA_BLOCK_Q - window + 1, 0) // bk
-        return ((i + 1) * GQA_BLOCK_Q - 1) // bk - lo + 1
-
-    return gb, bk, max(seen(i) for i in range(seq // GQA_BLOCK_Q))
+    return gb, bk
 
 
-def _gqa_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k,
-                    n_kv, window, narrow=False, sunk=False):
-    """One (kv head, query block, key block): the query heads of a block
-    are the rows of one [heads * block_q, d] operand, so a key block is
-    read once for all of them. Operands go to the MXU in their own type;
-    scores, softmax and the accumulator are float32. `narrow`: grid step j
-    is the j-th key block the query block SEES (`_gqa_tiling`). `sunk`: an
-    operand after V, a sink logit a query head across 128 lanes, which the
-    softmax's state starts from (it stands in the denominator and carries
-    no value)."""
+def _gqa_steps(seq, block_k, window):
+    """The grid's steps for one (row, kv head) from the shape alone: the
+    (query block, key block) pairs in which some query sees some key,
+    query block by query block, each one's key blocks in order; and the
+    index of each query block's first step (and the count of all, last).
+    A row walks the steps of the query blocks that hold a true token: a
+    prefix of these. A key block's end needs no bound by the length: a
+    query block's last key block is its own diagonal's."""
+    lo, hi = _gqa_key_blocks(np.arange(seq // GQA_BLOCK_Q), GQA_BLOCK_Q,
+                             block_k, window)
+    lo = np.broadcast_to(lo, hi.shape)
+    query = np.repeat(np.arange(hi.size), hi - lo + 1)
+    first = np.concatenate([[0], np.cumsum(hi - lo + 1)])
+    key = np.arange(query.size) - first[query] + lo[query]
+    return (query.astype(np.int32), key.astype(np.int32),
+            first.astype(np.int32))
+
+
+def _gqa_fwd_kernel(query_ref, key_ref, first_ref, walked_ref, q_ref, k_ref,
+                    v_ref, *rest, scale, block_q, block_k, window,
+                    sunk=False):
+    """One (kv head, step): step t is query block query_ref[t] against key
+    block key_ref[t] (`_gqa_steps`); grid row g walks its first
+    walked_ref[g] steps, those of its query blocks that hold a true token,
+    and does nothing in the rest. The query heads of a block are the rows
+    of one [heads * block_q, d] operand, so a key block is read once for
+    all of them. Operands go to the MXU in their own type; scores,
+    softmax and the accumulator are float32. A block that the causal
+    diagonal or the window's edge crosses is masked; one wholly inside what
+    each of its queries sees is not. `sunk`: an operand after V, a sink
+    logit a query head across 128 lanes, which the softmax's state starts
+    from (it stands in the denominator and carries no value)."""
     if sunk:
         sink_ref, o_ref, acc, m_scr, l_scr = rest
     else:
         o_ref, acc, m_scr, l_scr = rest
-    i, step = pl.program_id(1), pl.program_id(2)
+    g, t = pl.program_id(0), pl.program_id(1)
+    i, j = query_ref[t], key_ref[t]
+    walks = t < walked_ref[g]
     group = q_ref.shape[1]
     rows = group * block_q
 
-    @pl.when(step == 0)
+    @pl.when(walks & (t == first_ref[i]))
     def _():
         if sunk:
             m_scr[...] = jnp.broadcast_to(
@@ -1118,28 +1134,27 @@ def _gqa_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k,
             l_scr[...] = jnp.zeros_like(l_scr)
         acc[...] = jnp.zeros_like(acc)
 
-    lo, hi = _gqa_key_blocks(i, block_q, block_k, window)
-    j = lo + step if narrow else step
-
-    @pl.when((j >= lo) & (j <= hi))
-    def _():
+    def attend(masked):
         q = q_ref[0].reshape(rows, q_ref.shape[-1])
         k, v = k_ref[0], v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * np.float32(scale)
-        q_pos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (group, block_q, block_k), 1).reshape(rows, block_k)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_k), 1)
-        seen = q_pos >= k_pos
-        if window is not None:
-            seen = seen & (q_pos - k_pos < window)
-        s = jnp.where(seen, s, NEG_INF)
+        if masked:
+            q_pos = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (group, block_q, block_k), 1).reshape(rows, block_k)
+            k_pos = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block_k), 1)
+            seen = q_pos >= k_pos
+            if window is not None:
+                seen = seen & (q_pos - k_pos < window)
+            s = jnp.where(seen, s, NEG_INF)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(seen, jnp.exp(s - m_new), np.float32(0.0))
+        p = jnp.exp(s - m_new)
+        if masked:
+            p = jnp.where(seen, p, np.float32(0.0))
         l_scr[:, :1] = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1,
                                                       keepdims=True)
         acc[...] = acc[...] * alpha + jax.lax.dot_general(
@@ -1147,7 +1162,16 @@ def _gqa_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k,
             preferred_element_type=jnp.float32)
         m_scr[:, :1] = m_new
 
-    @pl.when(step == n_kv - 1)
+    # the diagonal crosses the block where its last key follows its first
+    # query; the window's edge, where its last query is `window` or more
+    # positions after its first key
+    edge = (j + 1) * block_k - 1 > i * block_q
+    if window is not None:
+        edge = edge | ((i + 1) * block_q - 1 - j * block_k >= window)
+    pl.when(walks & edge)(lambda: attend(True))
+    pl.when(walks & jnp.logical_not(edge))(lambda: attend(False))
+
+    @pl.when(walks & (t == first_ref[i + 1] - 1))
     def _():
         l = l_scr[:, :1]
         out = acc[...] / jnp.where(l == 0.0, np.float32(1.0), l)
@@ -1169,46 +1193,72 @@ def use_gqa_flash(seq, head_dim, value_dim=None):
     return gqa_supports(seq, head_dim, value_dim) and seq >= GQA_MIN_SEQ
 
 
-def flash_attention_gqa_bshd(q, k, v, window=None, scale=None, sink=None):
+def flash_attention_gqa_bshd(q, k, v, window=None, scale=None, sink=None,
+                             lens=None):
     """Causal self-attention of q [b, s, h, d] over k [b, s, h_kv, d] and
     v [b, s, h_kv, d_v] (query head n reads kv head n // (h / h_kv); a
     value may be narrower than a key, and the output is as wide as a
-    value); with `window`, position i sees j only if i - j < window, and
-    key blocks wholly outside a query block's window are neither fetched
-    nor multiplied; with `sink` [h], a logit a head stands in the softmax's
-    denominator and carries no value. Forward only."""
+    value); with `window`, position i sees j only if i - j < window; with
+    `sink` [h], a logit a head stands in the softmax's denominator and
+    carries no value. `lens` [b] int32 (None: every row is full) is each
+    row's true length, a traced operand: a row's queries from it on are
+    zeros, and the grid walks only the (query block, key block) pairs in
+    which a true query sees a key, so key blocks outside a query block's
+    window or past its diagonal, and query blocks past the length, are
+    neither fetched nor multiplied. Forward only."""
+    b, s, _, d = q.shape
+    if not gqa_supports(s, d, v.shape[3]):
+        raise ValueError(
+            f"flash_attention_gqa: unsupported shape seq={s} d={d} "
+            f"d_v={v.shape[3]} (need multiples of {GQA_BLOCK_K}/64/128)")
+    lens = jnp.full((b,), s, jnp.int32) if lens is None \
+        else jnp.asarray(lens).astype(jnp.int32)
+    return _gqa_attention(
+        q, k, v, sink, lens, window=None if window is None else int(window),
+        scale=float(1.0 / math.sqrt(d) if scale is None else scale),
+        interpret=_interpret())
+
+
+# one trace a shape: the layers of a program that share a shape share the
+# kernel's trace and its lowering, which set-up pays once, not a layer each
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("window", "scale", "interpret"))
+def _gqa_attention(q, k, v, sink, lens, *, window, scale, interpret):
     b, s, h, d = q.shape
     h_kv, d_v = k.shape[2], v.shape[3]
     group = h // h_kv
-    if not gqa_supports(s, d, d_v):
-        raise ValueError(
-            f"flash_attention_gqa: unsupported shape seq={s} d={d} "
-            f"d_v={d_v} (need multiples of {GQA_BLOCK_K}/64/128)")
-    if scale is None:
-        scale = 1.0 / math.sqrt(d)
     bq = GQA_BLOCK_Q
-    gb, bk, steps = _gqa_tiling(s, group, window)
-    narrow = steps is not None
-    n_q, n_kv = s // bq, steps if narrow else s // bk
+    gb, bk = _gqa_tiling(group, window)
     n_gb = group // gb
+    query, key, first = _gqa_steps(s, bk, window)
     # [b * h_kv * n_gb, gb, s, d] and [b * h_kv, s, d]
     qt = q.reshape(b, s, h_kv * n_gb, gb, d).transpose(0, 2, 3, 1, 4).reshape(
         b * h_kv * n_gb, gb, s, d)
     kt = jnp.swapaxes(k, 1, 2).reshape(b * h_kv, s, d)
     vt = jnp.swapaxes(v, 1, 2).reshape(b * h_kv, s, d_v)
+    # the steps each grid row walks: those of its row's query blocks that
+    # hold a true token (traced integers divide by `lax.div` here and in
+    # the index maps: `//` is a floor division, with a sign's correction
+    # in every grid step's scalar work)
+    walked = jnp.repeat(
+        jnp.asarray(first)[jax.lax.div(lens + bq - 1, np.int32(bq))],
+        h_kv * n_gb)
 
-    def kv_map(g, i, j):
-        # a block outside what the query block sees repeats the nearest one
-        # inside, so the pipeline copies nothing for it
-        lo, hi = _gqa_key_blocks(i, bq, bk, window)
-        return (g if n_gb == 1 else g // n_gb,
-                jnp.minimum(lo + j, hi) if narrow else jnp.clip(j, lo, hi), 0)
+    def step(g, t, walked_ref):
+        # a step past the row's last repeats that one's blocks, so the
+        # pipeline copies nothing for it
+        return jnp.minimum(t, jnp.maximum(walked_ref[g] - 1, 0))
 
-    q_map = lambda g, i, j: (g, 0, i, 0)  # noqa: E731
+    def q_map(g, t, query_ref, key_ref, first_ref, walked_ref):
+        return g, 0, query_ref[step(g, t, walked_ref)], 0
+
+    def kv_map(g, t, query_ref, key_ref, first_ref, walked_ref):
+        return (g if n_gb == 1 else jax.lax.div(g, np.int32(n_gb)),
+                key_ref[step(g, t, walked_ref)], 0)
+
     kernel = functools.partial(
-        _gqa_fwd_kernel, scale=float(scale), block_q=bq, block_k=bk,
-        n_kv=n_kv, window=None if window is None else int(window),
-        narrow=narrow, sunk=sink is not None)
+        _gqa_fwd_kernel, scale=scale, block_q=bq, block_k=bk, window=window,
+        sunk=sink is not None)
     in_specs = [pl.BlockSpec((1, gb, bq, d), q_map),
                 pl.BlockSpec((1, bk, d), kv_map),
                 pl.BlockSpec((1, bk, d_v), kv_map)]
@@ -1216,27 +1266,35 @@ def flash_attention_gqa_bshd(q, k, v, window=None, scale=None, sink=None):
     if sink is not None:
         # [b * h_kv * n_gb, gb, 128]: a query head's logit across the lanes
         # of the state it starts
-        in_specs.append(pl.BlockSpec((1, gb, 128),
-                                     lambda g, i, j: (g, 0, 0)))
+        in_specs.append(pl.BlockSpec((1, gb, 128), lambda g, t, *_: (g, 0, 0)))
         operands.append(jnp.broadcast_to(
             sink.astype(jnp.float32).reshape(1, h // gb, gb, 1),
             (b, h // gb, gb, 128)).reshape(b * h_kv * n_gb, gb, 128))
     with _x64_off():
-        out = _pc(
-            kernel,
-            grid=(b * h_kv * n_gb, n_q, n_kv),
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            # the longest row's steps: one program for every length
+            grid=(b * h_kv * n_gb, jnp.maximum(jnp.max(walked), 1)),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, gb, bq, d_v), q_map),
-            out_shape=jax.ShapeDtypeStruct(qt.shape[:3] + (d_v,), q.dtype),
             scratch_shapes=[
                 pltpu.VMEM((gb * bq, d_v), jnp.float32),
                 pltpu.VMEM((gb * bq, 128), jnp.float32),
                 pltpu.VMEM((gb * bq, 128), jnp.float32),
             ],
-            interpret=_interpret(),
-        )(*operands)
-    return out.reshape(b, h_kv, group, s, d_v).transpose(
-        0, 3, 1, 2, 4).reshape(b, s, h, d_v)
+        )
+        out = _pc(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(qt.shape[:3] + (d_v,), q.dtype),
+            interpret=interpret,
+        )(jnp.asarray(query), jnp.asarray(key), jnp.asarray(first), walked,
+          *operands)
+    # the blocks of queries past a row's length are never written
+    out = jnp.where(jnp.arange(s)[None, None, None, :, None]
+                    < lens[:, None, None, None, None],
+                    out.reshape(b, h_kv, group, s, d_v), 0)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d_v)
 
 
 def flash_attn_unpadded(q, k, v, cu_seqlens_q, cu_seqlens_k, max_seqlen_q,

@@ -953,11 +953,19 @@ class _Weight(nn.Layer):
             else I.Normal(0.0, 0.02))
 
 
+def _true_lens(live):
+    """[b] int32 real tokens a row from `live` ([b, s] bool, a prefill's
+    real tokens first), or None where every position is real."""
+    return None if live is None else jnp.sum(as_array(live), axis=-1,
+                                             dtype=jnp.int32)
+
+
 class _Mixer(nn.Layer):
     """A mixer's leaves are `_Weight`s (or bare parameters) named by
     LEAVES. Each `forward_cached` is handed `live` ([b, s] bool, a
     prefill's real tokens first, or None): attention, causal, need not read
-    it; a mixer that carries a state from token to token must."""
+    it, though the prefill kernel skips the padding by it; a mixer that
+    carries a state from token to token must."""
     LEAVES = ()
 
     def _run(self, fn, *inputs, name):
@@ -1122,6 +1130,7 @@ class DiffLatentAttention(LatentAttention):
         whole = not traced and isinstance(start, int) and start == 0 \
             and int(x.shape[1]) == rows.shape[1]
         nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        lens = _true_lens(live)
 
         def f(p, x, rows):
             b, s, _ = x.shape
@@ -1146,7 +1155,8 @@ class DiffLatentAttention(LatentAttention):
                 if whole and _fa.use_gqa_flash(s, cfg.head_dim,
                                                cfg.v_head_dim):
                     ctx = _fa.flash_attention_gqa_bshd(
-                        q, k, v, window=self.window).reshape(b, s, -1)
+                        q, k, v, window=self.window, lens=lens
+                    ).reshape(b, s, -1)
                 else:
                     ctx = gqa_attention(q, k, v, self.window, offset=start)
             return self._finish(p, x, ctx), rows
@@ -1252,6 +1262,7 @@ class _GQAttention(_Mixer):
         # it has just computed, through the kernel where the length asks
         whole = not traced and isinstance(start, int) and start == 0 \
             and int(x.shape[1]) == k_rows.shape[1]
+        lens = _true_lens(live)
 
         def f(p, x, k_rows, v_rows):
             b, s, _ = x.shape
@@ -1269,7 +1280,8 @@ class _GQAttention(_Mixer):
             with scope(self.scope_name):
                 if whole and _fa.use_gqa_flash(s, d_k, d_v):
                     ctx = _fa.flash_attention_gqa_bshd(
-                        q, k, v, window=window, **sink).reshape(b, s, -1)
+                        q, k, v, window=window, lens=lens, **sink
+                    ).reshape(b, s, -1)
                 elif whole:
                     ctx = gqa_attention(q, k, v, window, **sink)
                 else:
@@ -1480,8 +1492,7 @@ class MambaMixer(_Mixer):
         row's last real token, and the conv tail its last d_conv - 1 real
         inputs. Returns (out, new cache)."""
         conv, ssm = (as_array(a) for a in cache)
-        lens = None if live is None else jnp.sum(
-            as_array(live), axis=-1, dtype=jnp.int32)
+        lens = _true_lens(live)
 
         def f(p, x, conv, ssm):
             b, s, _ = x.shape

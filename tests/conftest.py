@@ -61,6 +61,44 @@ def load_repo_script(relpath):
     return sys.modules[name]
 
 
+def check_gqa_lengths(attend, q, k, v, lens, atol, window=None, sink=None):
+    """`flash_attention_gqa_bshd` over rows of true lengths `lens`, with
+    the key and value blocks wholly past a row's length and the row's
+    padded queries NaN: each row's true queries agree with `attend(q, k,
+    v)` of that row alone (the reference's attention), its padded queries
+    are zeros, and a full row's `lens` is `lens=None` exactly."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.kernels import flash_attention as fa
+
+    b, s, h, _ = q.shape
+    _, block_k = fa._gqa_tiling(h // k.shape[2], window)
+    n = np.asarray(lens)
+    pos = np.arange(s)[None, :, None, None]
+    past = pos >= (-(-n // block_k) * block_k)[:, None, None, None]
+    nan = np.float32(np.nan)
+    got = np.asarray(fa.flash_attention_gqa_bshd(
+        jnp.where(pos >= n[:, None, None, None], nan, q),
+        jnp.where(past, nan, k), jnp.where(past, nan, v), window=window,
+        sink=sink, lens=jnp.asarray(n, jnp.int32)))
+    for row, length in enumerate(n):
+        assert not got[row, length:].any()
+        if length:
+            want = attend(q[row, :length], k[row, :length],
+                          v[row, :length])
+            np.testing.assert_allclose(got[row, :length].reshape(length, -1),
+                                       np.asarray(want), atol=atol)
+    row = int(np.argmax(n))
+    assert n[row] == s
+    one = (q[row:row + 1], k[row:row + 1], v[row:row + 1])
+    np.testing.assert_array_equal(
+        np.asarray(fa.flash_attention_gqa_bshd(
+            *one, window=window, sink=sink, lens=jnp.asarray([s]))),
+        np.asarray(fa.flash_attention_gqa_bshd(*one, window=window,
+                                               sink=sink)))
+
+
 def check_padded_prefill(model, prompts, nb, bucket):
     """`model.forward_prefill` of `prompts` padded to `[nb, bucket]` (rows
     beyond them of length 0) against each prompt alone and unpadded: the

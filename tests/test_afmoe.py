@@ -402,23 +402,39 @@ def test_the_pages_read_are_counted_from_the_table_the_kernel_is_handed():
     assert int(pa.decode_pages_fetched(tables, jnp.array([0, 0]), page)) == 1
 
 
-@pytest.mark.parametrize("window", [None, 200, 512, 1500])
-def test_the_prefill_kernel_in_interpret_mode_against_the_dense_mask(window):
+@pytest.mark.parametrize("window, s, h, kv, lens", [
+    (None, 1024, 4, 2, None), (200, 1024, 4, 2, None),
+    (512, 1024, 4, 2, None), (1500, 1024, 4, 2, None),
+    # Trinity's 32 query heads on 4, rows of 0, 1, a key block's edge - 1
+    # and + 1 and every token; Jamba's 20 on one (4 blocks of 5)
+    (None, 1024, 32, 4, (0, 1, 511, 513, 1024)),
+    (2048, 2560, 32, 4, (2560, 1, 0, 2047, 513)),
+    (None, 1024, 20, 1, (1, 1024, 513, 0, 511))],
+    ids=["None", "200", "512", "1500", "lens", "2048-lens", "20-on-1-lens"])
+def test_the_prefill_kernel_in_interpret_mode_against_the_dense_mask(
+        window, s, h, kv, lens):
     """Grouped heads, causal, with and without a window that straddles key
-    blocks: flash_attention_gqa_bshd against the reference's attention."""
+    blocks: flash_attention_gqa_bshd against the reference's attention;
+    with `lens`, rows of those true lengths in one batch, the padding NaN
+    (`check_gqa_lengths`)."""
     rng = np.random.default_rng(6)
-    s, h, kv, d = 1024, 4, 2, 128
-    q = jnp.asarray(rng.normal(size=(1, s, h, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(1, s, kv, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(1, s, kv, d)), jnp.float32)
-    got = fa.flash_attention_gqa_bshd(q, k, v, window=window)
-    want = reference.attend(q[0], k[0], v[0], window)
-    np.testing.assert_allclose(np.asarray(got)[0].reshape(s, -1),
-                               np.asarray(want), atol=2e-5)
+    d = 128
+    b = 1 if lens is None else len(lens)
+    q = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(b, s, kv, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(b, s, kv, d)), jnp.float32)
+    if lens is None:
+        got = fa.flash_attention_gqa_bshd(q, k, v, window=window)
+        want = reference.attend(q[0], k[0], v[0], window)
+        np.testing.assert_allclose(np.asarray(got)[0].reshape(s, -1),
+                                   np.asarray(want), atol=2e-5)
+    else:
+        from conftest import check_gqa_lengths
+        check_gqa_lengths(lambda *a: reference.attend(*a, window), q, k, v,
+                          lens, 2e-5, window=window)
     # which key blocks a query block fetches: none wholly outside its window
     lo, hi = fa._gqa_key_blocks(7, 128, 512, window)
-    assert int(hi) == 1 and int(lo) == (0 if window in (None, 1500, 512)
-                                        else 1)
+    assert int(hi) == 1 and int(lo) == (1 if window == 200 else 0)
 
 
 def test_a_prefill_from_the_kernels_lengths_on_takes_the_kernel(monkeypatch):

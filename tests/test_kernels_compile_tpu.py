@@ -414,8 +414,8 @@ def test_gqa_prefill_kernel_at_the_long_cell_shapes(v5e, seq, kv_heads,
     (16 query heads a kv head, two blocks of 8) or on 8 with a window of 128
     (key blocks of 128, two a query block) and a sink a head."""
     assert fa.use_gqa_flash(seq, 192, 128)
-    assert fa._gqa_tiling(seq, 64 // kv_heads, window) \
-        == ((8, 128, 2) if window else (8, 512, None))
+    assert fa._gqa_tiling(64 // kv_heads, window) \
+        == ((8, 128) if window else (8, 512))
 
     def f(q, k, v, *rest):
         return fa.flash_attention_gqa_bshd(q, k, v, window=window,
@@ -425,6 +425,34 @@ def test_gqa_prefill_kernel_at_the_long_cell_shapes(v5e, seq, kv_heads,
         v5e[0], f, S((1, seq, 64, 192), BF16), S((1, seq, kv_heads, 192), BF16),
         S((1, seq, kv_heads, 128), BF16),
         *((S((64,), F32),) if sink else ())) == 1
+
+
+@pytest.mark.parametrize("rows,seq,heads,kv_heads,key,window,sink", [
+    (1, 8192, 32, 4, 128, None, False), (1, 8192, 32, 4, 128, 2048, False),
+    (4, 2048, 32, 4, 128, 2048, False),
+    (1, 16384, 64, 4, 192, None, True), (1, 16384, 64, 8, 192, 128, True),
+    (1, 16384, 20, 1, 128, None, False), (2, 4096, 20, 1, 128, None, False)],
+    ids=["mixed-full", "mixed-window", "mixed-4-rows", "long-full-sink",
+         "long-window-sink", "docs", "docs-2-rows"])
+def test_gqa_prefill_kernel_with_true_lengths(v5e, rows, seq, heads,
+                                              kv_heads, key, window, sink):
+    """The grouped-query causal forward with each row's true length a
+    traced operand, at the mixed, long and docs cells' shapes (Trinity's
+    32 query heads on 4; MiMo's 64 on 4 and, with a window of 128, on 8,
+    keys of 192 over values of 128 and a sink; Jamba's 20 on 1, 4 blocks of
+    5): the step tables and the lengths ride in as scalar prefetch, and
+    the grid's length is the longest row's, one program for every
+    length."""
+    def f(q, k, v, lens, *rest):
+        return fa.flash_attention_gqa_bshd(q, k, v, window=window,
+                                           sink=rest[0] if rest else None,
+                                           lens=lens)
+
+    assert compile_for(
+        v5e[0], f, S((rows, seq, heads, key), BF16),
+        S((rows, seq, kv_heads, key), BF16),
+        S((rows, seq, kv_heads, 128), BF16), S((rows,), I32),
+        *((S((heads,), F32),) if sink else ())) == 1
 
 
 @pytest.mark.parametrize("rows,seq", [(1, 4096), (1, 16384), (2, 1000)])

@@ -531,17 +531,34 @@ def test_the_cache_writes_take_each_pools_own_width():
                                   np.asarray(vs)[1, 2, 0])
 
 
-@pytest.mark.parametrize("window, with_sink, kv", [
-    (None, False, 1), (128, True, 2), (128, False, 2), (200, True, 2),
-    (None, True, 4)])
+@pytest.mark.parametrize("window, with_sink, kv, lens", [
+    (None, False, 1, None), (128, True, 2, None), (128, False, 2, None),
+    (200, True, 2, None), (None, True, 4, None),
+    (None, True, 4, (1024, 0, 511, 1, 513)),
+    (128, True, 8, (1, 127, 0, 1024, 129))],
+    ids=["None-False-1", "128-True-2", "128-False-2", "200-True-2",
+         "None-True-4", "full-lens", "window-lens"])
 def test_the_prefill_kernel_with_a_wide_key_a_window_and_a_sink(
-        window, with_sink, kv):
+        window, with_sink, kv, lens):
     """`flash_attention_gqa_bshd` in interpret mode at keys of 192 over
     values of 128 against the reference's attention: causal over 16 query
     heads a kv head (two blocks of 8), a window of 128 walked two key
     blocks of 128 a query block, its edge at i - j = 127 / 128, a sink a
-    head."""
+    head. With `lens`, MiMo's 64 query heads on 4 (full) and on 8 (a window
+    of 128) over rows of 0, 1, a key block's edge - 1 and + 1 and every
+    token in one batch, the padding NaN (`check_gqa_lengths`)."""
     rng = np.random.default_rng(6)
+    if lens is not None:
+        from conftest import check_gqa_lengths
+
+        s, h, b = 1024, 64, len(lens)
+        q = jnp.asarray(rng.normal(size=(b, s, h, 192)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(b, s, kv, 192)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(b, s, kv, 128)), jnp.float32)
+        sink = jnp.asarray(rng.normal(size=(h,)), jnp.float32)
+        check_gqa_lengths(lambda *a: reference.attend(*a, window, sink), q,
+                          k, v, lens, 3e-5, window=window, sink=sink)
+        return
     s, h = 1024, 16 if kv == 1 else 8
     q = jnp.asarray(rng.normal(size=(1, s, h, 192)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(1, s, kv, 192)), jnp.float32)
@@ -571,18 +588,34 @@ def test_the_prefill_kernel_with_a_wide_key_a_window_and_a_sink(
 
 
 def test_the_tiling_of_the_prefill_kernel_is_made_from_the_shape():
-    # (query heads a block, key block, key steps a query block or None)
-    assert fa._gqa_tiling(8192, 8, None) == (8, 512, None)
-    assert fa._gqa_tiling(8192, 8, 2048) == (8, 512, None)   # Trinity's
-    assert fa._gqa_tiling(8192, 8, 512) == (8, 512, None)
-    assert fa._gqa_tiling(16384, 8, 128) == (8, 128, 2)      # MiMo's window
-    assert fa._gqa_tiling(16384, 16, None) == (8, 512, None)  # MiMo's full
-    assert fa._gqa_tiling(2048, 8, 200) == (8, 256, 2)
-    assert fa._gqa_tiling(2048, 4, 500) == (4, 512, 2)
-    assert fa._gqa_tiling(2048, 3, None) == (3, 512, None)
+    # (query heads a block, key block)
+    assert fa._gqa_tiling(8, None) == (8, 512)
+    assert fa._gqa_tiling(8, 2048) == (8, 512)   # Trinity's
+    assert fa._gqa_tiling(8, 512) == (8, 512)
+    assert fa._gqa_tiling(8, 128) == (8, 128)    # MiMo's window
+    assert fa._gqa_tiling(16, None) == (8, 512)  # MiMo's full
+    assert fa._gqa_tiling(8, 200) == (8, 256)
+    assert fa._gqa_tiling(4, 500) == (4, 512)
+    assert fa._gqa_tiling(3, None) == (3, 512)
+    assert fa._gqa_tiling(20, None) == (5, 512)  # Jamba's: 4 blocks of 5
     # a window of 128: query block i sees key blocks i - 1 and i alone
     lo, hi = fa._gqa_key_blocks(7, 128, 128, 128)
     assert (int(lo), int(hi)) == (6, 7)
+    # the grid's steps: the causal triangle of 512-key blocks, 4 query
+    # blocks a key block (1 + 2 + 3 + 4 steps for each 4), and a window of
+    # 128's band of two key blocks a query block (one for the first)
+    query, key, first = fa._gqa_steps(2048, 512, None)
+    assert query.size == 4 * (1 + 2 + 3 + 4) and first[-1] == query.size
+    assert list(zip(query[:7], key[:7])) == [
+        (0, 0), (1, 0), (2, 0), (3, 0), (4, 0), (4, 1), (5, 0)]
+    assert list(first[:6]) == [0, 1, 2, 3, 4, 6]
+    query, key, first = fa._gqa_steps(16384, 128, 128)
+    assert query.size == 2 * 128 - 1
+    assert list(key[first[7]:first[8]]) == [6, 7]
+    # a window of 2,048: query block 40 (positions 5,120 ..) sees keys
+    # from 3,073 on, key blocks 6 to 10
+    query, key, first = fa._gqa_steps(8192, 512, 2048)
+    assert list(key[first[40]:first[41]]) == [6, 7, 8, 9, 10]
 
 
 def test_a_prefill_from_the_kernels_lengths_on_takes_the_kernel(monkeypatch):
@@ -614,6 +647,36 @@ def test_a_prefill_from_the_kernels_lengths_on_takes_the_kernel(monkeypatch):
         flash = np.asarray(m(ids)._data)
     assert calls == [(None, False), (300, True)]
     np.testing.assert_allclose(flash, dense, atol=2e-4)
+
+
+def test_a_padded_prefill_through_the_kernel_is_the_unpadded_prompt(
+        monkeypatch):
+    """A bucket of 512 that the kernel takes (its length lowered to 512):
+    prompts of 300 and 129 tokens and two rows of none prefill through the
+    grouped-query kernel with each row's true length, in the full layer
+    and in the window layer with its sink, and the first token's logits
+    and the K/V at live positions are each prompt's alone, whose length
+    takes XLA's attention (`check_padded_prefill`)."""
+    from conftest import check_padded_prefill
+
+    c = MiMoV2Config.tiny(layers=2)
+    c.head_dim = c.swa_head_dim = 192
+    c.v_head_dim = c.swa_v_head_dim = 128
+    c.hidden_size, c.num_attention_heads, c.swa_num_attention_heads = 64, 2, 2
+    c.sliding_window, c.max_position_embeddings = 300, 2048
+    paddle.seed(3)
+    m = MiMoV2ForCausalLM(c)
+    m.eval()
+    monkeypatch.setattr(fa, "GQA_MIN_SEQ", 512)
+    calls = []
+    real = fa.flash_attention_gqa_bshd
+    monkeypatch.setattr(fa, "flash_attention_gqa_bshd", lambda *a, **kw:
+                        calls.append((kw["window"], kw["lens"] is not None))
+                        or real(*a, **kw))
+    rng = np.random.default_rng(9)
+    check_padded_prefill(m, [rng.integers(0, 96, 300),
+                             rng.integers(0, 96, 129)], 4, 512)
+    assert calls == [(None, True), (300, True)]
 
 
 # -- the expert layer ---------------------------------------------------------

@@ -203,6 +203,35 @@ def test_yarn_takes_the_published_ramp():
         64, 10000.0, (64.0, 4096, 32.0, 1.0))), rtol=1e-6)
 
 
+def test_a_padded_prefill_through_the_kernel_is_the_unpadded_prompt(
+        monkeypatch):
+    """Latent attention at the kernel's widths (keys of 120 + 8 rope over
+    values of 128) in a bucket of 512 that the kernel takes (its length
+    lowered to 512): prompts of 300 and 129 tokens and two rows of none
+    prefill through the grouped-query kernel with each row's true length,
+    in the window layers and the full one, and the first token's logits
+    and the latent rows at live positions are each prompt's alone, whose
+    length takes XLA's attention (`check_padded_prefill`)."""
+    from conftest import check_padded_prefill
+    from paddle_tpu.kernels import flash_attention as fa
+
+    c = MotifConfig.tiny(layers=3, window=300)
+    c.head_dim, c.v_head_dim, c.max_position_embeddings = 128, 128, 2048
+    paddle.seed(3)
+    m = latent_moe.MotifForCausalLM(c)
+    m.eval()
+    monkeypatch.setattr(fa, "GQA_MIN_SEQ", 512)
+    calls = []
+    real = fa.flash_attention_gqa_bshd
+    monkeypatch.setattr(fa, "flash_attention_gqa_bshd", lambda *a, **kw:
+                        calls.append((kw["window"], kw["lens"] is not None))
+                        or real(*a, **kw))
+    rng = np.random.default_rng(9)
+    check_padded_prefill(m, [rng.integers(0, 96, 300),
+                             rng.integers(0, 96, 129)], 4, 512)
+    assert calls == [(300, True), (300, True), (None, True)]
+
+
 # -- the engine ---------------------------------------------------------------
 
 
